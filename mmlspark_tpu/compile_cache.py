@@ -4,13 +4,16 @@ BENCH_r05's low-MFU lanes are dispatch-bound (killed by the sync-free
 stepping in :mod:`~mmlspark_tpu.parallel.trainer`), but every process
 RESTART and every :meth:`Fleet.rollout` replica warm pays a second tax —
 recompiling programs whose HLO has not changed. This module removes it in
-two layers, both keyed off the ``runtime.compile_cache_dir`` config key
-("" = off, nothing touches disk):
+two layers that share ONE directory, decided by :func:`cache_dir` alone:
+``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (jax reads that
+variable itself, so nothing in code overrides it), else the
+``runtime.compile_cache_dir`` config key ("" = off, nothing touches disk):
 
-1. :func:`enable_from_config` wires jax's own persistent compilation cache
-   (``jax_compilation_cache_dir``) so EVERY jit path — trainer steps, eval
-   programs, transform closures — reuses XLA output across processes.
-   Idempotent; call it once at process entry (the CLI does).
+1. :func:`enable` turns on jax's own persistent compilation cache at that
+   directory so EVERY jit path — trainer steps, eval programs, transform
+   closures — reuses XLA output across processes. Idempotent; call it
+   once at process entry (the CLI, ``bench.py`` and ``chip_smoke.py`` do;
+   the last two pass ``<checkout>/.jax_cache`` as the default).
 
 2. :func:`load_or_compile` — an on-disk AOT *program* cache for the serve
    bucket executables behind :meth:`ModelEntry._compile`. jax's cache only
@@ -36,12 +39,14 @@ not route here (``# lint: allow-compile`` opts out deliberately).
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
 import pickle
+import shutil
 import threading
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Tuple
 
 from mmlspark_tpu.observability import events, metrics
 from mmlspark_tpu.utils import config as mmlconfig
@@ -49,11 +54,15 @@ from mmlspark_tpu.utils.logging import get_logger
 
 logger = get_logger("compile_cache")
 
-_FORMAT_VERSION = 1
+_FORMAT_VERSION = 2   # v2: header carries the program's device ids
 _SUFFIX = ".xprog"
 
-_lock = threading.Lock()
-_enabled_dir: Optional[str] = None  # enable_from_config idempotence
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+# AOT namespace under the cache root, set by :func:`lane` ("" = the root's
+# own ``aot/``). Process-wide on purpose: in-process fleet replicas compile
+# on their own threads and must land in the lane the harness opened.
+_lane = ""
 
 
 class CacheResult(NamedTuple):
@@ -69,48 +78,85 @@ class CacheResult(NamedTuple):
 
 
 def cache_dir() -> str:
-    """The configured cache root ("" = caching off)."""
-    return str(mmlconfig.get("runtime.compile_cache_dir") or "")
+    """THE decision of where compiled programs persist in this process
+    ("" = nowhere). ``JAX_COMPILATION_CACHE_DIR`` wins when set: jax reads
+    it at import for its own cache, the AOT entries go under the same
+    directory, and no code sets another. Otherwise the
+    ``runtime.compile_cache_dir`` config key."""
+    return os.environ.get(ENV_VAR) \
+        or str(mmlconfig.get("runtime.compile_cache_dir") or "")
 
 
 def worker_env(root: Optional[str] = None) -> Dict[str, str]:
-    """Environment exports that point a CHILD process at the same
-    persistent cache. The process-fleet supervisor spawns each replica
-    with this merged into its environment, so replica N+1 (and every warm
-    restart) cold-starts by LOADING the programs replica N stored —
-    multi-reader is safe by construction here: entries publish via
-    tmp-file + ``os.replace`` and are sha256-verified on load, so a
-    concurrent writer loses the race harmlessly and a reader never
-    observes a torn file. Returns ``{}`` when caching is off."""
+    """Environment exports that point a CHILD process at a persistent
+    cache (``root``, default this process's own). The process-fleet
+    supervisor spawns each replica with this merged into its environment,
+    so replica N+1 (and every warm restart) cold-starts by LOADING the
+    programs replica N stored — multi-reader is safe by construction
+    here: entries publish via tmp-file + ``os.replace`` and are
+    sha256-verified on load, so a concurrent writer loses the race
+    harmlessly and a reader never observes a torn file. Returns ``{}``
+    when caching is off."""
     root = cache_dir() if root is None else str(root or "")
     if not root:
         return {}
-    return {"MMLSPARK_TPU_RUNTIME_COMPILE_CACHE_DIR": os.path.abspath(root)}
+    return {ENV_VAR: os.path.abspath(root)}
 
 
-def enable_from_config() -> Optional[str]:
-    """Wire ``jax_compilation_cache_dir`` from ``runtime.compile_cache_dir``
-    for all jit paths. Returns the directory when enabled, None when the
-    key is unset. Idempotent per directory; safe to call before or after
-    jax initializes its backends."""
-    global _enabled_dir
+def enable(default_dir: str = "") -> Optional[str]:
+    """Turn on jax's persistent compilation cache at :func:`cache_dir` for
+    all jit paths; returns the directory, or None when caching is off.
+    ``default_dir`` is what an entry point falls back to when neither the
+    environment nor the config names a directory (it becomes
+    ``runtime.compile_cache_dir``, so the AOT layer follows). Idempotent;
+    call before the first compile — jax binds its cache to one directory
+    for the life of the process."""
     root = cache_dir()
+    if not root and default_dir:
+        root = os.path.abspath(default_dir)
+        mmlconfig.set("runtime.compile_cache_dir", root)
     if not root:
         return None
-    with _lock:
-        if _enabled_dir == root:
-            return root
+    import jax
+    if not os.environ.get(ENV_VAR) \
+            and jax.config.jax_compilation_cache_dir != root:
         os.makedirs(root, exist_ok=True)
-        import jax
         jax.config.update("jax_compilation_cache_dir", root)
-        # cache tiny programs too: the serve buckets and bench lanes this
-        # exists for compile in well under the 1s default threshold
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _enabled_dir = root
-    if events.recording_enabled():
-        events.emit("compile_cache", "enabled", dir=root)
-    logger.info("persistent compilation cache at %s", root)
+        if events.recording_enabled():
+            events.emit("compile_cache", "enabled", dir=root)
+        logger.info("persistent compilation cache at %s", root)
+    # cache tiny programs too: the serve buckets and bench lanes this
+    # exists for compile in well under jax's 1 s default threshold
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return root
+
+
+@contextlib.contextmanager
+def lane(name: str, fallback_root: str) -> Iterator[str]:
+    """Give a bench lane or chaos scenario its own, initially EMPTY, AOT
+    namespace ``<cache_dir()>/lanes/<name>`` for the duration; yields that
+    directory (what a harness hands its spawned workers as their cache
+    root). AOT entries are keyed by model NAME + version, so harnesses
+    that reuse a serving name for different architectures must not share
+    entries; and their counts (compiles, hits, cold -> warm) are only a
+    function of the seed if every run starts cold. A fixed name under the
+    resolved root — not a temporary directory — keeps everything a run
+    writes where the cache was placed. ``fallback_root`` is used (as
+    ``runtime.compile_cache_dir``) only when no cache is configured."""
+    global _lane
+    fell_back = not cache_dir()
+    if fell_back:
+        mmlconfig.set("runtime.compile_cache_dir",
+                      os.path.abspath(fallback_root))
+    prior_lane, _lane = _lane, os.path.join("lanes", name)
+    path = os.path.join(cache_dir(), _lane)
+    shutil.rmtree(path, ignore_errors=True)
+    try:
+        yield path
+    finally:
+        _lane = prior_lane
+        if fell_back:
+            mmlconfig.unset("runtime.compile_cache_dir")
 
 
 def device_fingerprint() -> str:
@@ -118,17 +164,13 @@ def device_fingerprint() -> str:
     executable is only loadable onto the platform/topology it was built
     for, and a jax/jaxlib bump invalidates the wire format."""
     import jax
-    try:
-        import jaxlib.version
-        jaxlib_v = jaxlib.version.__version__
-    except ImportError:
-        jaxlib_v = "?"
+    import jaxlib
     devs = jax.devices()
     return "|".join([
         f"jax={jax.__version__}",
-        f"jaxlib={jaxlib_v}",
-        f"platform={devs[0].platform if devs else '?'}",
-        f"kind={getattr(devs[0], 'device_kind', '?') if devs else '?'}",
+        f"jaxlib={jaxlib.__version__}",
+        f"platform={devs[0].platform}",
+        f"kind={devs[0].device_kind}",
         f"n={len(devs)}",
     ])
 
@@ -154,7 +196,7 @@ def entry_key(model: str, version: str, bucket: int,
 
 def _aot_dir(root: str) -> str:
     # separate the AOT program entries from jax's own cache files
-    return os.path.join(root, "aot")
+    return os.path.join(root, _lane, "aot")
 
 
 def _counter(name: str):
@@ -209,10 +251,16 @@ def _load_entry(path: str, fingerprint: str) -> CacheResult | None:
         _quarantine(path)
         return None
     try:
+        import jax
         from jax.experimental import serialize_executable
         payload, in_tree, out_tree = pickle.loads(body)
+        # pin the program to the devices it was compiled for: left to its
+        # default, the loader spreads it over EVERY device of the backend
+        # and a one-device program comes back expecting N input shards
+        by_id = {d.id: d for d in jax.devices()}
         program = serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree)
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in header["devices"]])
     except Exception as e:  # deserialization is version-fragile by nature
         logger.warning("compile cache entry %s failed to deserialize "
                        "(%s: %s); quarantined", path, type(e).__name__, e)
@@ -246,12 +294,15 @@ def _store_entry(path: str, program, meta: Dict[str, Any],
     try:
         from jax.experimental import serialize_executable
         body = pickle.dumps(serialize_executable.serialize(program))
+        devices = [d.id for d in
+                   program.runtime_executable().local_devices()]
     except Exception as e:
         _counter("bypasses").inc()
         _event("bypass", reason=f"serialize: {type(e).__name__}: {e}",
                **meta)
         return False
     header = dict(meta, v=_FORMAT_VERSION, env=fingerprint,
+                  devices=devices,
                   sha256=hashlib.sha256(body).hexdigest(), size=len(body))
     os.makedirs(os.path.dirname(path), exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
@@ -282,7 +333,7 @@ def _cached_compile(stem: str, meta: Dict[str, Any],
     root = cache_dir()
     if not root:
         _counter("bypasses").inc()
-        _event("bypass", reason="runtime.compile_cache_dir unset", **meta)
+        _event("bypass", reason="no compile cache directory", **meta)
         return CacheResult(fresh(), "bypass")
     path = os.path.join(_aot_dir(root), stem + _SUFFIX)
     fingerprint = device_fingerprint()

@@ -97,12 +97,9 @@ def _mesh_to_dict(obj: Any):
     no meaning in another process) and ``resolve_mesh`` accepts the dict
     back, so save/load round-trips the SHAPE — the portable part.
     Returns None for non-mesh objects."""
-    try:
-        from dataclasses import asdict
-        from jax.sharding import Mesh
-        from mmlspark_tpu.parallel.mesh import MeshSpec
-    except ImportError:  # pragma: no cover - jax always present here
-        return None
+    from dataclasses import asdict
+    from jax.sharding import Mesh
+    from mmlspark_tpu.parallel.mesh import MeshSpec
     if isinstance(obj, MeshSpec):
         return asdict(obj)
     if isinstance(obj, Mesh):

@@ -57,7 +57,6 @@ import numpy as np
 
 from mmlspark_tpu.parallel.sharding import (embedding_lookup_specs,
                                             embedding_table_sharding,
-                                            shard_map_compat,
                                             tensor_axis_size)
 from mmlspark_tpu.utils import config as mmlconfig
 
@@ -148,9 +147,9 @@ def make_fused_lookup(mesh):
         seg = jnp.repeat(jnp.arange(b, dtype=jnp.int32), slots)
         return jax.ops.segment_sum(vals, seg, num_segments=b)
 
-    fused = shard_map_compat(body, mesh, in_specs=(table_spec, ids_spec,
-                                                   ids_spec),
-                             out_specs=out_spec, check_vma=False)
+    fused = jax.shard_map(body, mesh=mesh,
+                          in_specs=(table_spec, ids_spec, ids_spec),
+                          out_specs=out_spec, check_vma=False)
 
     def lookup(table, ids, weights):
         return fused(table, ids.astype(jnp.int32),
@@ -207,8 +206,9 @@ def make_sparse_grad(mesh):
         # so the grad comes out replicated over data with no psum
         return jnp.zeros_like(tab).at[rows].add(vals)   # lax.scatter-add
 
-    sharded = shard_map_compat(
-        body, mesh, in_specs=(table_spec, ids_spec, ids_spec, ids_spec),
+    sharded = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(table_spec, ids_spec, ids_spec, ids_spec),
         out_specs=table_spec, check_vma=False)
 
     def grad_fn(table_like, ids, weights, grad_bags):

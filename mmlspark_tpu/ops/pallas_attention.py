@@ -127,16 +127,23 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.transpose(0, 2, 1, 3)
 
 
-# per-(batch, head) K/V stay fully VMEM-resident in the kernel; cap their
-# footprint well under the ~16 MB of VMEM (f32 worst case, x2 for K and V,
-# headroom for q/acc blocks and pipelining buffers)
+# per-(batch, head) K and V stay fully VMEM-resident in the kernel, and
+# Mosaic must fit them in its scoped VMEM: 16 MiB by default on a v5e (of
+# 128 MiB physical). L * d <= 2^20 elements keeps K + V at 8 MiB in fp32
+# (4 MiB in bf16) — half the limit, the rest left to the q/out blocks, the
+# fp32 upcasts and the score tile. Measured on the chip (PR 21, d = 64):
+# fp32 still compiles at K + V = 16 MiB (L = 32768) and bf16 at L = 65536;
+# twice that is refused ("Scoped allocation with size 32.00M and limit
+# 16.00M"), as is fp32 d = 128 at L = 16384 (16.50M).
 _VMEM_KV_LIMIT = 1 << 20   # L * d elements
 
 
 def supports(q_shape, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> bool:
-    """Whether the fused kernel applies: block-divisible length, a
-    lane-friendly head dim, and K/V small enough to stage per (batch,
-    head) in VMEM (others fall back to the jnp reference)."""
+    """Whether the fused kernel applies: block-divisible length of at
+    least two query blocks, a sublane-friendly head dim, and K + V of at
+    most 2 * 2^20 elements (8 MiB in fp32) to hold per (batch, head) in
+    Mosaic's 16 MiB scoped VMEM. ``full_attention`` counts, or under
+    ``use_flash="require"`` refuses, every other shape."""
     _, L, _, d = q_shape
     return L % block_q == 0 and L % block_k == 0 and L >= 2 * block_q \
         and d % 8 == 0 and L * d <= _VMEM_KV_LIMIT

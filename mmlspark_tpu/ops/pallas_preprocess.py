@@ -25,6 +25,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh
+
+from mmlspark_tpu.parallel.sharding import map_batch_shards
 
 
 def _interpret() -> bool:
@@ -40,12 +43,10 @@ def _normalize_kernel(u8_ref, mean_ref, inv_std_ref, out_ref):
 _BLOCK_B = 8  # sublane tiling requires batch blocks divisible by 8
 
 
-@functools.partial(jax.jit, static_argnames=("image_shape", "out_dtype"))
-def fused_normalize(u8_flat: jax.Array, mean_vec: jax.Array,
-                    inv_std_vec: jax.Array,
-                    image_shape: Tuple[int, int, int],
-                    out_dtype=jnp.bfloat16) -> jax.Array:
-    """(B, N) uint8 -> (B, H, W, C) normalized out_dtype; N = H*W*C."""
+def _normalize_call(u8_flat: jax.Array, mean_vec: jax.Array,
+                    inv_std_vec: jax.Array, out_dtype) -> jax.Array:
+    """(B, N) uint8 -> (B, N) normalized: the pallas_call over ONE
+    device's rows."""
     b, n = u8_flat.shape
     bp = ((b + _BLOCK_B - 1) // _BLOCK_B) * _BLOCK_B
     if bp != b:
@@ -66,13 +67,34 @@ def fused_normalize(u8_flat: jax.Array, mean_vec: jax.Array,
     )(u8_flat,
       jnp.broadcast_to(mean_vec[None, :], (_BLOCK_B, n)),
       jnp.broadcast_to(inv_std_vec[None, :], (_BLOCK_B, n)))
-    return out[:b].reshape((b,) + tuple(image_shape))
+    return out[:b]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("image_shape", "out_dtype", "mesh"))
+def fused_normalize(u8_flat: jax.Array, mean_vec: jax.Array,
+                    inv_std_vec: jax.Array,
+                    image_shape: Tuple[int, int, int],
+                    out_dtype=jnp.bfloat16,
+                    mesh: Optional[Mesh] = None) -> jax.Array:
+    """(B, N) uint8 -> (B, H, W, C) normalized out_dtype; N = H*W*C.
+
+    A Mosaic kernel is an opaque custom call to the SPMD partitioner:
+    inside a multi-device jit it would all-gather the batch and run the
+    whole thing on every chip. With ``mesh``, the call is shard_mapped
+    over the mesh's batch axes so each device normalizes its own rows."""
+    call = map_batch_shards(
+        functools.partial(_normalize_call, out_dtype=out_dtype), mesh,
+        batched=(True, False, False))
+    out = call(u8_flat, mean_vec, inv_std_vec)
+    return out.reshape((u8_flat.shape[0],) + tuple(image_shape))
 
 
 def make_preprocess_fn(image_shape: Tuple[int, int, int],
                        mean: Sequence[float] = (127.5, 127.5, 127.5),
                        std: Sequence[float] = (127.5, 127.5, 127.5),
-                       out_dtype=jnp.bfloat16):
+                       out_dtype=jnp.bfloat16,
+                       mesh: Optional[Mesh] = None):
     """Returns fn(u8_flat (B, N)) -> (B, H, W, C) normalized activations.
 
     Compose inside the SAME jit as the model forward so the normalized
@@ -82,6 +104,10 @@ def make_preprocess_fn(image_shape: Tuple[int, int, int],
         @jax.jit
         def forward(params, u8):
             return module.apply(params, pre(u8))
+
+    Inside a multi-device jit (a ``DistributedTrainer`` loss) pass the
+    trainer's ``mesh`` so every chip normalizes its own batch rows (see
+    :func:`fused_normalize`).
     """
     h, w, c = image_shape
     n = h * w * c
@@ -95,7 +121,7 @@ def make_preprocess_fn(image_shape: Tuple[int, int, int],
         if u8_flat.dtype != jnp.uint8:
             u8_flat = u8_flat.astype(jnp.uint8)
         return fused_normalize(u8_flat, mean_vec, inv_std_vec,
-                               (h, w, c), out_dtype)
+                               (h, w, c), out_dtype, mesh)
     return preprocess
 
 

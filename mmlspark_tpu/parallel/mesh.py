@@ -188,14 +188,6 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
             "num_processes/process_id were given without a "
             "coordinator_address; pass all three (or none, for "
             "single-process / auto-detected cluster runs)")
-    else:
-        # Convenience call with nothing to join: if a backend is already
-        # live in this process (interactive session, test runner), starting
-        # a coordination service now can abort later XLA work — skip.
-        # Reading the backend cache does NOT initialize it.
-        from jax._src import xla_bridge
-        if getattr(xla_bridge, "_backends", None):
-            return
     try:
         jax.distributed.initialize(**kwargs)
     except RuntimeError as e:

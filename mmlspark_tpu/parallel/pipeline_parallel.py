@@ -27,7 +27,6 @@ from typing import Any, Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from mmlspark_tpu.parallel.sharding import shard_map_compat as shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mmlspark_tpu.parallel.sharding import active_batch_axes
@@ -127,7 +126,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
             jnp.where(idx == S - 1, acc, jnp.zeros_like(acc)), pipe_axis)
         return acc.reshape(x.shape)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pipeline_spec(mesh, pipe_axis), x_spec),
         out_specs=x_spec, check_vma=False)

@@ -33,9 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from mmlspark_tpu.parallel.sharding import (
-    active_batch_axes, shard_map_compat as shard_map,
-)
+from mmlspark_tpu.observability import metrics as obsmetrics
+from mmlspark_tpu.parallel.sharding import active_batch_axes
 
 
 def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -43,19 +42,35 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    use_flash: str = "auto") -> jnp.ndarray:
     """Single-device attention (B, L, H, D).
 
-    On an accelerator backend with block-divisible shapes this runs the
-    fused Pallas flash kernel (``ops/pallas_attention.py``) — the L x L
-    score matrix never touches HBM. Everything else (CPU lanes, ragged
-    lengths like ViT's 197 tokens) takes the jnp reference below: matmuls
-    in the input dtype (bf16 tiles the MXU); scores, softmax and the
-    output accumulation in fp32, cast back once at the end.
+    On an accelerator backend with shapes the kernel ``supports`` this
+    runs the fused Pallas flash kernel (``ops/pallas_attention.py``) — the
+    L x L score matrix never touches HBM. Everything else (CPU lanes,
+    ragged lengths like ViT's 197 tokens) takes the jnp reference below:
+    matmuls in the input dtype (bf16 tiles the MXU); scores, softmax and
+    the output accumulation in fp32, cast back once at the end.
+
     ``use_flash``: "auto" | "never" (reference path, used by the parity
-    tests themselves).
+    tests themselves) | "require" (the flash kernel or a ValueError —
+    for callers whose result is only meaningful on the kernel: the
+    ``longctx`` bench lane, ``chip_smoke.py``). Under "auto" on an
+    accelerator, each trace that takes the reference because
+    ``supports`` said no increments the ``attention.flash_fallbacks``
+    counter, so the downgrade is visible in metrics and reports.
     """
-    if use_flash == "auto" and jax.default_backend() != "cpu":
+    if use_flash not in ("auto", "never", "require"):
+        raise ValueError(f"unknown use_flash {use_flash!r}")
+    if use_flash != "never":
         from mmlspark_tpu.ops import pallas_attention
-        if pallas_attention.supports(q.shape):
+        on_chip = jax.default_backend() != "cpu"
+        fits = pallas_attention.supports(q.shape)
+        if fits and (on_chip or use_flash == "require"):
             return pallas_attention.flash_attention(q, k, v, causal=causal)
+        if use_flash == "require":
+            raise ValueError(
+                f"use_flash='require': the flash kernel does not support "
+                f"q shape {tuple(q.shape)} (pallas_attention.supports)")
+        if on_chip:
+            obsmetrics.counter("attention.flash_fallbacks").inc()
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("blhd,bkhd->bhlk", q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -116,7 +131,7 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
     return out.astype(q.dtype).transpose(0, 2, 1, 3)       # (B,Lq,H,D)
 
 
-def _qkv_spec(mesh: Mesh, seq_axis: str, n_heads: int) -> P:
+def _qkv_spec(mesh: Mesh, seq_axis: Optional[str], n_heads: int) -> P:
     """(B, L, H, D) spec: batch over data axes, L over seq, and — when the
     head count divides it — H over ``tensor``, so a tp x sp mesh keeps the
     tensor-sharded qkv projections sharded through attention instead of
@@ -134,7 +149,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if mesh.shape.get(seq_axis, 1) == 1:
         return full_attention(q, k, v, causal)
     spec = _qkv_spec(mesh, seq_axis, q.shape[2])
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_local, axis_name=seq_axis, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)
@@ -170,8 +185,25 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError(
             f"ulysses needs per-shard heads ({local_heads}) divisible by "
             f"|{seq_axis}|={s}")
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_local, axis_name=seq_axis, causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
+    return fn(q, k, v)
+
+
+def sharded_full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                           mesh: Mesh, causal: bool = True,
+                           use_flash: str = "auto") -> jnp.ndarray:
+    """``full_attention`` per device on its own batch rows (and its own
+    heads on a tensor axis). Attention is independent across batch and
+    heads, so this needs no collective — but the flash kernel is an
+    opaque custom call the SPMD partitioner cannot split: bare inside a
+    multi-device jit it would all-gather q/k/v and run every (batch,
+    head) on every chip."""
+    spec = _qkv_spec(mesh, None, q.shape[2])
+    fn = jax.shard_map(
+        partial(full_attention, causal=causal, use_flash=use_flash),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)
     return fn(q, k, v)
@@ -185,7 +217,9 @@ def make_attention_fn(mesh: Optional[Mesh], impl: str = "auto",
         impl = ("ring" if mesh is not None
                 and mesh.shape.get(seq_axis, 1) > 1 else "full")
     if impl == "full":
-        return full_attention
+        if mesh is None or mesh.size == 1:
+            return full_attention
+        return partial(sharded_full_attention, mesh=mesh)
     if impl == "ring":
         return partial(ring_attention, mesh=mesh, seq_axis=seq_axis)
     if impl == "ulysses":

@@ -53,27 +53,6 @@ DEFAULT_RULES: List[Tuple[str, P]] = [
 # authoritative as written.
 _GENERIC_PATTERNS = {r".*kernel", r".*"}
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.5
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=None):
-    """``shard_map`` across jax versions: the function moved from
-    ``jax.experimental.shard_map`` to top-level, and the replication-check
-    kwarg renamed ``check_rep`` -> ``check_vma`` along the way. The one
-    call shape sequence/pipeline parallel need, spelled once."""
-    import inspect
-    kwargs = {}
-    if check_vma is not None:
-        params = inspect.signature(_shard_map).parameters
-        key = "check_vma" if "check_vma" in params else "check_rep"
-        kwargs[key] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
 def _path_str(path) -> str:
     parts = []
     for k in path:
@@ -232,6 +211,23 @@ def active_batch_axes(mesh: Mesh,
     this too, so the policy can't drift between modules.
     """
     return tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1) or None
+
+
+def map_batch_shards(fn, mesh: Optional[Mesh], batched: Sequence[bool]):
+    """``fn`` run per device on that device's own batch rows.
+
+    The wrapper an opaque custom call (a Pallas kernel) needs inside a
+    multi-device jit: the SPMD partitioner cannot split a Mosaic call, so
+    it would all-gather the operands and run the whole batch on every
+    chip. ``batched[i]`` says whether argument ``i`` carries the batch on
+    dim 0 (split over the mesh's batch axes) or is replicated; the output
+    is batched. No mesh, or one device, returns ``fn`` unchanged."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    rows = P(active_batch_axes(mesh))
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(rows if b else P() for b in batched),
+        out_specs=rows, check_vma=False)
 
 
 def batch_sharding(mesh: Mesh, batch_axes: Sequence[str] = BATCH_AXES,

@@ -34,12 +34,14 @@ what makes a red chaos run *debuggable* instead of an anecdote.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
 import random
 from typing import Any, Callable, Dict, List, Optional
 
+from mmlspark_tpu import compile_cache
 from mmlspark_tpu.reliability.faults import (FaultPlan, FaultSpec,
                                              InjectedFault)
 from mmlspark_tpu.testing import loadgen
@@ -48,6 +50,12 @@ from mmlspark_tpu.utils.logging import get_logger
 _LOG = get_logger("reliability.chaos")
 
 VERDICT_FILE = "chaos_verdict.json"
+
+# The process scenarios (host, elastic) exercise the control plane —
+# spawn, announce, kill, restart, drain — on a toy MLP. Their workers run
+# on the CPU ON PURPOSE: the scenario process may already hold the chip
+# (one process per chip), and nothing they verify is a device number.
+_CONTROL_PLANE_ENV = {"JAX_PLATFORMS": "cpu"}
 
 # Registered scenarios (name -> one-line description). The CLI dispatches
 # through this registry; an unknown --scenario prints it and exits 2
@@ -1437,16 +1445,16 @@ def run_fleetprefix_scenario(seed: int, outdir: str, replicas: int = 3,
     prior = {k: mmlconfig.get(k) for k in
              ("generate.max_seq_len", "generate.max_sequences",
               "generate.kv_block_tokens", "generate.advertise_top_k",
-              "fleet.affinity_enabled", "fleet.affinity_min_depth",
-              "runtime.compile_cache_dir")}
+              "fleet.affinity_enabled", "fleet.affinity_min_depth")}
     mmlconfig.set("generate.max_seq_len", 64)
     mmlconfig.set("generate.max_sequences", 4)
     mmlconfig.set("generate.kv_block_tokens", 8)
     mmlconfig.set("generate.advertise_top_k", 8)
     mmlconfig.set("fleet.affinity_enabled", True)
     mmlconfig.set("fleet.affinity_min_depth", 1)
-    mmlconfig.set("runtime.compile_cache_dir",
-                  os.path.join(outdir, "compile_cache"))
+    cache_lane = contextlib.ExitStack()
+    cache_lane.enter_context(compile_cache.lane(
+        "chaos_fleetprefix", os.path.join(outdir, "compile_cache")))
 
     bt = 8
     pop = loadgen.PromptPopulation(rng, prefixes=3, prefix_tokens=2 * bt,
@@ -1597,6 +1605,7 @@ def run_fleetprefix_scenario(seed: int, outdir: str, replicas: int = 3,
     except Exception as e:
         errors.append(f"fleetprefix scenario: {type(e).__name__}: {e}")
     finally:
+        cache_lane.close()
         for k, v in prior.items():
             mmlconfig.set(k, v)
 
@@ -1751,9 +1760,7 @@ def run_host_scenario(seed: int, outdir: str, replicas: int = 2,
 
     os.makedirs(outdir, exist_ok=True)
     events_dir = os.path.join(outdir, "events")
-    cache_dir = os.path.join(outdir, "compile-cache")
     os.makedirs(events_dir, exist_ok=True)
-    os.makedirs(cache_dir, exist_ok=True)
     errors: List[str] = []
     verdict: Dict[str, Any] = {"seed": seed, "scenario": "host",
                                "replicas": replicas, "requests": requests}
@@ -1775,10 +1782,13 @@ def run_host_scenario(seed: int, outdir: str, replicas: int = 2,
                   os.path.join(events_dir, f"events-{os.getpid()}.jsonl"))
 
     names = [f"w{i}" for i in range(replicas)]
-    spawner = ProcessSpawner([model_flag], events_dir=events_dir,
-                             compile_cache_dir=cache_dir,
-                             extra_args=["--max-batch", "4",
-                                         "--queue-depth", "32"])
+    with compile_cache.lane(
+            "chaos_host", os.path.join(outdir, "compile-cache")) as cache_dir:
+        spawner = ProcessSpawner([model_flag], events_dir=events_dir,
+                                 compile_cache_dir=cache_dir,
+                                 extra_args=["--max-batch", "4",
+                                             "--queue-depth", "32"],
+                                 env=_CONTROL_PLANE_ENV)
     # tight supervision: a SIGKILLed worker respawns within ~50 ms of the
     # reap, and half a second of uptime confirms the incarnation healthy
     sup = Supervisor(spawner, names, min_uptime_s=0.5, base_delay_s=0.05,
@@ -1992,8 +2002,14 @@ def _autopilot_drive(model, stream, arrivals, *, kill_round: int,
     from mmlspark_tpu.serve.fleet import Fleet
     from mmlspark_tpu.serve.server import ServerClosed, ServerOverloaded
 
+    # ONE bucket: the drive coalesces a round's requests into 4-row
+    # batches while the reference scores them one by one, and a row's
+    # float bits are only guaranteed equal under the SAME program (XLA's
+    # CPU matmul differs in the last bit between a 2-row and a 4-row
+    # batch) — so every batch, here and in the reference, pads to 4 rows
     fleet = Fleet({"chaos": model}, replicas=replicas, start=False,
-                  server_kwargs={"max_batch": 4, "queue_depth": 8})
+                  server_kwargs={"max_batch": 4, "queue_depth": 8,
+                                 "buckets": (4,)})
     vclock = {"t": 1000.0}
     scraper = FleetScraper(fleet, clock=lambda: vclock["t"])
     engine = SloEngine(clock=lambda: vclock["t"],
@@ -2265,13 +2281,12 @@ def run_autopilot_scenario(seed: int, outdir: str, replicas: int = 3,
     # bucket programs from the shared on-disk cache the reference server
     # populates — that is what makes steady_compiles_zero assertable
     # through scale_up events
-    from mmlspark_tpu.utils import config as mmlconfig
-    prior_cache = mmlconfig.get("runtime.compile_cache_dir")
-    mmlconfig.set("runtime.compile_cache_dir",
-                  os.path.join(outdir, "compile_cache"))
-    try:
-        # ground truth: the full stream on one server, same model object
-        ref_server = Server({"chaos": model}, max_batch=4, queue_depth=32)
+    with compile_cache.lane("chaos_autopilot",
+                            os.path.join(outdir, "compile_cache")):
+        # ground truth: the full stream on one server, same model object,
+        # same single bucket as the fleet (see _autopilot_drive)
+        ref_server = Server({"chaos": model}, max_batch=4, queue_depth=32,
+                            buckets=(4,))
         try:
             reference = [np.asarray(
                 ref_server.submit("chaos", x, timeout=30))
@@ -2300,8 +2315,6 @@ def run_autopilot_scenario(seed: int, outdir: str, replicas: int = 3,
                                 kill_round=kill_round, kill_idx=kill_idx,
                                 replicas=replicas, policy=policy,
                                 events_path=events_path)
-    finally:
-        mmlconfig.set("runtime.compile_cache_dir", prior_cache)
 
     identical = all(
         np.array_equal(auto["scores"][i], reference[i])
@@ -2458,9 +2471,7 @@ def run_elastic_scenario(seed: int, outdir: str, replicas: int = 2,
 
     os.makedirs(outdir, exist_ok=True)
     events_dir = os.path.join(outdir, "events")
-    cache_dir = os.path.join(outdir, "compile-cache")
     os.makedirs(events_dir, exist_ok=True)
-    os.makedirs(cache_dir, exist_ok=True)
     errors: List[str] = []
     verdict: Dict[str, Any] = {"seed": seed, "scenario": "elastic",
                                "replicas": replicas, "requests": requests}
@@ -2486,10 +2497,14 @@ def run_elastic_scenario(seed: int, outdir: str, replicas: int = 2,
     mmlconfig.set("observability.events_path", up_log)
 
     names = [f"w{i}" for i in range(replicas)]
-    spawner = ProcessSpawner([model_flag], events_dir=events_dir,
-                             compile_cache_dir=cache_dir,
-                             extra_args=["--max-batch", "4",
-                                         "--queue-depth", "32"])
+    with compile_cache.lane(
+            "chaos_elastic",
+            os.path.join(outdir, "compile-cache")) as cache_dir:
+        spawner = ProcessSpawner([model_flag], events_dir=events_dir,
+                                 compile_cache_dir=cache_dir,
+                                 extra_args=["--max-batch", "4",
+                                             "--queue-depth", "32"],
+                                 env=_CONTROL_PLANE_ENV)
     sup = Supervisor(spawner, names, min_uptime_s=0.5, base_delay_s=0.05,
                      max_delay_s=0.5, breaker_failures=3,
                      breaker_reset_s=30.0)

@@ -9,10 +9,13 @@ per-pid event-log sidecar, the SHARED persistent compile cache), and the
 
 - **spawn**: :class:`ProcessSpawner` launches ``python -m mmlspark_tpu.cli
   serve --port 0`` and reads the one-line JSON announce from the child's
-  stdout to learn the ephemeral port. The child inherits
-  ``runtime.compile_cache_dir`` through its environment
+  stdout to learn the ephemeral port. The child is pointed at the shared
+  compile cache through its environment
   (:func:`mmlspark_tpu.compile_cache.worker_env`), so replica N+1
-  cold-starts by LOADING compiled programs, not compiling them.
+  cold-starts by LOADING compiled programs, not compiling them — and it
+  starts with an explicit ``JAX_PLATFORMS``
+  (:meth:`ProcessSpawner.platform`): one process per chip, never a silent
+  CPU fallback.
 - **supervise**: one :meth:`Supervisor.poll_once` step reaps exits,
   schedules restarts through the existing :class:`RetryPolicy`
   exponential backoff (deterministic, non-blocking — a crash-looping
@@ -164,6 +167,14 @@ class ProcessWorker:
             self._log_fh = None
 
 
+# chips per worker -> TPU_CHIPS_PER_PROCESS_BOUNDS for K consecutive chip
+# ids, each combination tried on a v5e 2x2 host with libtpu 0.0.34
+# (PR 21): four 1-chip, two 2-chip ("0,1" + "2,3") and one 4-chip worker
+# come up side by side; "2,1,1" for a pair, or the pairs "0,2" + "1,3",
+# die at backend init.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
+
+
 class ProcessSpawner:
     """Factory for :class:`ProcessWorker` children.
 
@@ -193,6 +204,12 @@ class ProcessSpawner:
         self.extra_args = list(extra_args)
         self.env = dict(env or {})
         self.devices_per_worker = int(devices_per_worker)
+        if self.devices_per_worker > 0 \
+                and self.devices_per_worker not in _CHIP_BOUNDS:
+            raise ValueError(
+                f"devices_per_worker={devices_per_worker}: a worker owns "
+                f"{sorted(_CHIP_BOUNDS)} chips (a rectangular block of the "
+                "host's chip grid), or 0 for none")
         # stable name -> slot assignment: a restarted replica keeps ITS
         # chips (first spawn claims the next slot, every respawn reuses
         # it), so two workers never share a chip across restarts
@@ -217,19 +234,47 @@ class ProcessSpawner:
         return slot
 
     def device_env(self, name: str) -> Dict[str, str]:
-        """Per-worker accelerator pinning: with ``devices_per_worker=K``,
-        slot ``i`` sees chips ``[i*K, (i+1)*K)`` — disjoint visible-device
-        sets, so N single-host workers split the host's chips instead of
-        all fighting over chip 0 (the JAX default when every process sees
-        every device). Exported in every runtime's spelling; platforms
-        ignore the vars they don't read. 0 = no pinning (workers share)."""
+        """Per-worker chip ownership: with ``devices_per_worker=K``, slot
+        ``i`` sees chips ``[i*K, (i+1)*K)`` — disjoint sets, because a
+        chip belongs to ONE process: a second process that reaches for it
+        fails at backend init. 0 = the worker owns no chip of its own.
+
+        ``TPU_VISIBLE_CHIPS`` alone is not enough for several processes on
+        one host (libtpu 0.0.34, v5e 2x2 host, PR 21): every process but
+        the first dies on libtpu's host-wide lockfile, or on "devices
+        found ... does not match the topology". Each worker must also be
+        told it is a one-process slice of K chips
+        (``TPU_PROCESS_BOUNDS`` / ``TPU_CHIPS_PER_PROCESS_BOUNDS``)."""
         k = self.devices_per_worker
         if k <= 0:
             return {}
         chips = ",".join(str(self.slot_of(name) * k + j) for j in range(k))
         return {"TPU_VISIBLE_CHIPS": chips,
-                "CUDA_VISIBLE_DEVICES": chips,
-                "HIP_VISIBLE_DEVICES": chips}
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[k]}
+
+    def platform(self) -> str:
+        """The ``JAX_PLATFORMS`` every worker starts with — always an
+        explicit one: the operator's ``env``, else ``tpu`` for workers
+        that own chips, else the variable this process inherited. Left
+        unset, jax takes whatever initializes and falls back to the CPU
+        silently — on a one-chip host, the fate of every worker that
+        loses the race for the chip — so having none of the three is an
+        error. With ``tpu`` pinned, a worker whose chips are missing
+        (slot beyond the host, chip held by another process) exits
+        non-zero at backend init. Control-plane harnesses whose parent
+        holds the chip pass ``env={"JAX_PLATFORMS": "cpu"}`` on
+        purpose."""
+        platform = self.env.get("JAX_PLATFORMS") or (
+            "tpu" if self.devices_per_worker > 0
+            else os.environ.get("JAX_PLATFORMS", ""))
+        if not platform:
+            raise ValueError(
+                "worker platform is not pinned: give each worker its own "
+                "chips (devices_per_worker / --devices-per-worker K), or "
+                "set JAX_PLATFORMS for this process or in the spawner's "
+                "env")
+        return platform
 
     def build_env(self, name: Optional[str] = None) -> Dict[str, str]:
         from mmlspark_tpu import compile_cache
@@ -245,6 +290,7 @@ class ProcessSpawner:
         if name is not None:
             env.update(self.device_env(name))
         env.update(self.env)
+        env["JAX_PLATFORMS"] = self.platform()
         return env
 
     def spawn(self, name: str) -> ProcessWorker:

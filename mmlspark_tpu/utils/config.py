@@ -43,10 +43,9 @@ _DEFAULTS: Dict[str, Any] = {
     "data.prefetch_depth": 0,      # to_device_iterator queue depth
                                    # (0 = inherit runtime.prefetch_depth)
     # evaluation: rows above which evaluators run as jitted XLA programs
-    # instead of driver numpy. The device path wins when chips are
-    # locally attached (the scored column crosses PCIe once instead of
-    # funneling through single-threaded numpy sorts); on remote/tunneled
-    # devices the transfer dominates — raise (or set huge) there.
+    # instead of driver numpy. The device path wins once the scored column
+    # is large enough that crossing PCIe once beats funneling through
+    # single-threaded numpy sorts; below it the transfer dominates.
     "evaluate.device_rows": 1_000_000,
     # reliability (retry/backoff + network timeouts; reliability/ package)
     "reliability.http_timeout": 30.0,  # seconds per urlopen (downloader)
@@ -174,10 +173,11 @@ _DEFAULTS: Dict[str, Any] = {
                                              # replica out of rotation
     "fleet.supervisor_breaker_reset_s": 60.0,  # open -> one probe respawn
     "fleet.supervisor_poll_s": 0.2,          # monitor thread cadence
-    "fleet.devices_per_worker": 0,    # >0: each spawned worker process is
-                                      # pinned to its own disjoint block of
-                                      # K local chips via a per-slot
-                                      # visible-devices env (CLI:
+    "fleet.devices_per_worker": 0,    # >0: each spawned worker process
+                                      # owns a disjoint block of K local
+                                      # chips (TPU_VISIBLE_CHIPS) and
+                                      # starts with JAX_PLATFORMS=tpu;
+                                      # 0 = no chip of its own (CLI:
                                       # `fleet --devices-per-worker K`)
     "fleet.hosts": "",                # comma list of hosts for the multi-
                                       # host launcher (serve/launcher.py;
@@ -195,7 +195,6 @@ _DEFAULTS: Dict[str, Any] = {
     "observability.events_path": "",  # non-empty = append JSONL events here
     "observability.metrics": False,   # hot-path (per-step) metric collection
     "observability.annotate": False,  # span() also opens a TraceAnnotation
-    "observability.peak_tflops": 197.0,  # MFU denominator (v5e bf16 peak)
     "observability.trace_slow_ms": 0.0,  # >0 = serve requests slower than
                                          # this emit full span detail +
                                          # histogram exemplars (tail

@@ -301,7 +301,11 @@ def test_advance_state_trims_window_and_rebases_counters():
 def test_autopilot_scales_fleet_out_and_back_bit_identically(tmp_path):
     model = _model()
     xs = [np.arange(_DIM, dtype=np.float32) + i for i in range(12)]
-    ref_server = Server({"m": model}, max_batch=4, queue_depth=32)
+    # one bucket on both sides: float bits are only guaranteed equal
+    # under the same program, and the fleet batches what the reference
+    # scores one by one
+    ref_server = Server({"m": model}, max_batch=4, queue_depth=32,
+                        buckets=(4,))
     try:
         reference = [np.asarray(ref_server.submit("m", x, timeout=30))
                      for x in xs]
@@ -313,7 +317,8 @@ def test_autopilot_scales_fleet_out_and_back_bit_identically(tmp_path):
     try:
         vclock = {"t": 1000.0}
         fleet = Fleet({"m": model}, replicas=1, start=False,
-                      server_kwargs={"max_batch": 4, "queue_depth": 32})
+                      server_kwargs={"max_batch": 4, "queue_depth": 32,
+                                     "buckets": (4,)})
         policy = AutopilotPolicy(
             min_replicas=1, max_replicas=2, scale_up_queue=2.0,
             scale_down_queue=0.0, scale_cooldown_s=10.0,

@@ -20,18 +20,12 @@ import bench  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
-def _no_global_cache_enable(monkeypatch):
-    """bench.main()'s first act is wiring jax_compilation_cache_dir to the
-    repo-local .jax_cache — correct for the CLI process, but a PROCESS-WIDE
-    jax.config mutation that would leak into every later test file. On the
-    emulated multi-device CPU mesh, a persistent-cache *hit* on the sharded
-    donated train-step executable crashes the runtime (deserialize +
-    execute segfaults; reproducible at the seed with
-    JAX_COMPILATION_CACHE_DIR + min_compile_time 0), so the leak turns a
-    slow full-suite run — where step compiles cross the 1s write threshold
-    — into a crash two files later. Tests exercise main()'s contract, not
-    its cache side effect: drop the side effect."""
-    monkeypatch.setattr(bench, "_enable_compile_cache", lambda: None)
+def _cache_placed_from_outside(monkeypatch, tmp_path):
+    """bench.main() turns the compile cache on; left alone it would bind
+    this test process to <checkout>/.jax_cache. Place it from outside the
+    way the driver can — main() then updates no jax cache setting and the
+    checkout stays clean (asserted below)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
 
 
 def test_med_ratio_is_within_round_median():
@@ -92,14 +86,40 @@ def test_robin_rounds_respects_deadline_with_min_two_rounds():
 
 
 def test_mfu_is_null_on_cpu_but_tflops_reported():
-    # the CPU test backend has no meaningful peak: utilization must be
-    # None rather than a fabricated number, while achieved TFLOP/s (a
+    # a CPU run is never a device measurement: utilization must be None
+    # rather than a fabricated number, while achieved TFLOP/s (a
     # backend-independent arithmetic fact) is still reported
     tflops, mfu = bench._mfu(1000.0, 1e9, 32)
     assert tflops == pytest.approx(1000.0 / 32 * 1e9 / 1e12, abs=1e-4)
     assert mfu is None
-    # zero/unknown FLOPs -> both readouts null (no cost analysis)
-    assert bench._mfu(1000.0, 0.0, 32) == (None, None)
+
+
+def test_mfu_divides_by_the_device_kinds_table_row(monkeypatch):
+    import jax
+    from mmlspark_tpu.observability import peaks
+
+    class _Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    tflops, mfu = bench._mfu(1000.0, 1e12, 10)     # 100 TFLOP/s achieved
+    assert tflops == pytest.approx(100.0)
+    assert mfu == pytest.approx(
+        100.0 / peaks.DEVICE_PEAKS["TPU v5 lite"].bf16_tflops, abs=1e-6)
+    # an accelerator nobody sourced a peak for is an error, not a v5e
+    _Dev.device_kind = "TPU v99"
+    with pytest.raises(KeyError, match="TPU v99"):
+        bench._mfu(1000.0, 1e12, 10)
+
+
+def test_step_flops_raises_instead_of_returning_zero():
+    class _NoCost:
+        def lower(self, *a):
+            raise RuntimeError("backend offers no cost model")
+
+    with pytest.raises(RuntimeError):
+        bench._step_flops(_NoCost())
 
 
 def _fake_config(value=123.0):
@@ -111,12 +131,21 @@ def _fake_config(value=123.0):
 
 
 def test_main_prints_exactly_one_json_line(monkeypatch, capsys):
+    import jax
     monkeypatch.setattr(bench, "CONFIGS", {"train": _fake_config()})
     monkeypatch.setattr(sys, "argv", ["bench.py"])
+    cache_before = jax.config.jax_compilation_cache_dir
     assert bench.main() == 0          # 2 = regression-gate red, 3 = killed
     out = capsys.readouterr().out.strip().splitlines()
     assert len(out) == 1, out
     line = json.loads(out[0])
+    # the line says which device it was taken on — here, not a chip
+    assert (line["platform"], line["device_kind"],
+            line["device_count"]) == ("cpu", "cpu", 8)
+    # the cache was placed from outside: nothing set another in code and
+    # nothing appeared in the checkout
+    assert jax.config.jax_compilation_cache_dir == cache_before
+    assert not (Path(bench.__file__).parent / ".jax_cache").exists()
     assert line["metric"] == \
         "cifar10_resnet20_train_images_per_sec_per_chip"
     assert line["value"] == 123.0 and line["vs_baseline"] == 1.5

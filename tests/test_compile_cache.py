@@ -360,24 +360,77 @@ def test_fleet_rollout_warm_uses_the_cache(cache_dir, events_file):
     np.testing.assert_array_equal(after, again)
 
 
-# -- enable_from_config ------------------------------------------------------
+# -- cache_dir / enable: one function decides the directory ------------------
 
-def test_enable_from_config_wires_jax_and_is_idempotent(cache_dir):
+def test_enable_wires_jax_from_config_and_is_idempotent(cache_dir):
     import jax
 
     prior = jax.config.jax_compilation_cache_dir
     try:
-        assert compile_cache.enable_from_config() == cache_dir
+        assert compile_cache.enable() == cache_dir
         assert jax.config.jax_compilation_cache_dir == cache_dir
         assert os.path.isdir(cache_dir)
-        assert compile_cache.enable_from_config() == cache_dir  # idempotent
+        assert compile_cache.enable() == cache_dir  # idempotent
     finally:
         jax.config.update("jax_compilation_cache_dir", prior)
-        compile_cache._enabled_dir = None
 
 
-def test_enable_from_config_noop_when_unset():
-    assert compile_cache.enable_from_config() is None
+def test_enable_noop_when_unset_and_default_dir_when_given(tmp_path):
+    import jax
+
+    assert compile_cache.enable() is None
+    prior = jax.config.jax_compilation_cache_dir
+    default = str(tmp_path / "default_cache")
+    try:
+        assert compile_cache.enable(default) == default
+        # the AOT layer follows the default: one directory for both
+        assert compile_cache.cache_dir() == default
+        assert jax.config.jax_compilation_cache_dir == default
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prior)
+
+
+def test_env_var_is_the_cache_for_both_layers(
+        cache_dir, tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is the cache for
+    both layers: it outranks the config key and any entry-point default
+    (that no jax.config.update of the directory runs is asserted in
+    tests/test_chip_smoke.py)."""
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    assert compile_cache.cache_dir() == env_dir
+    assert compile_cache.enable(str(tmp_path / "default")) == env_dir
+    assert compile_cache.worker_env() == {compile_cache.ENV_VAR: env_dir}
+    # AOT entries land under it
+    jitted, params = _jitted_and_params()
+    res = compile_cache.load_or_compile("m", "v1", 4, (8,), np.float32,
+                                        jitted, params)
+    assert res.source == "miss"
+    assert os.path.exists(_entry_path(env_dir))
+    assert not os.path.exists(cache_dir)
+
+
+def test_lane_scopes_aot_entries_under_the_root(cache_dir, tmp_path):
+    jitted, params = _jitted_and_params()
+    with compile_cache.lane("harness", str(tmp_path / "unused")) as path:
+        assert path == os.path.join(cache_dir, "lanes", "harness")
+        compile_cache.load_or_compile("m", "v1", 4, (8,), np.float32,
+                                      jitted, params)
+        assert os.path.exists(_entry_path(path))
+    assert not os.path.exists(_entry_path(cache_dir))
+    # every entry starts the lane empty; the root outside it is untouched
+    compile_cache.load_or_compile("m", "v1", 4, (8,), np.float32,
+                                  jitted, params)
+    with compile_cache.lane("harness", str(tmp_path / "unused")) as path:
+        assert not os.path.exists(_entry_path(path))
+    assert os.path.exists(_entry_path(cache_dir))
+    # nothing configured: the fallback root carries the lane
+    config.unset("runtime.compile_cache_dir")
+    fallback = str(tmp_path / "fallback")
+    with compile_cache.lane("harness", fallback) as path:
+        assert path == os.path.join(fallback, "lanes", "harness")
+        assert compile_cache.cache_dir() == fallback
+    assert compile_cache.cache_dir() == ""
 
 
 # -- device-fused eval: the one-sync contract --------------------------------

@@ -758,6 +758,9 @@ def test_bench_baseline_gate_exit_codes(tmp_path, monkeypatch, capsys):
     prev = signal.getsignal(signal.SIGTERM)
     monkeypatch.setattr(sys, "argv", ["bench.py", "--configs", "train",
                                       "--baseline", str(bp)])
+    # main() turns the compile cache on: place it from outside, or this
+    # test process stays bound to <checkout>/.jax_cache for every later test
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     try:
         monkeypatch.setattr(bench, "CONFIGS", {"train": lambda: dict(lane)})
         assert bench.main() == 0

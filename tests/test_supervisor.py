@@ -360,8 +360,9 @@ def test_process_spawner_argv_and_env(tmp_path):
     env = sp.build_env()
     # announce line must cross the pipe unbuffered
     assert env["PYTHONUNBUFFERED"] == "1"
-    # the shared compile cache rides the env into the child
-    assert env["MMLSPARK_TPU_RUNTIME_COMPILE_CACHE_DIR"] == \
+    # the shared compile cache rides the env into the child, in the
+    # spelling jax itself reads
+    assert env["JAX_COMPILATION_CACHE_DIR"] == \
         os.path.abspath(str(tmp_path / "cache"))
     # children import the tree the supervisor runs from
     import mmlspark_tpu
@@ -387,19 +388,87 @@ def test_process_spawner_device_pinning_disjoint_per_slot(tmp_path):
     e0, e1 = sp.device_env("w0"), sp.device_env("w1")
     assert e0["TPU_VISIBLE_CHIPS"] == "0,1"
     assert e1["TPU_VISIBLE_CHIPS"] == "2,3"
-    # exported in every runtime's spelling
+    # TPU_VISIBLE_CHIPS alone leaves every process but the first dead on
+    # libtpu's lockfile: each worker is declared a one-process 2-chip slice
     for e in (e0, e1):
-        assert e["CUDA_VISIBLE_DEVICES"] == e["TPU_VISIBLE_CHIPS"]
-        assert e["HIP_VISIBLE_DEVICES"] == e["TPU_VISIBLE_CHIPS"]
-    # the pinning rides build_env into the child process
-    assert sp.build_env("w1")["TPU_VISIBLE_CHIPS"] == "2,3"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+    with pytest.raises(ValueError, match="devices_per_worker=3"):
+        ProcessSpawner(["m=mlp_tabular:{}"], devices_per_worker=3)
+    # the pinning rides build_env into the child process, and a worker
+    # that owns chips is told to use them: with the platform pinned, jax
+    # fails at backend init instead of falling back to the CPU
+    env = sp.build_env("w1")
+    assert env["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert env["JAX_PLATFORMS"] == "tpu"
 
 
 def test_process_spawner_device_pinning_off_by_default(tmp_path):
     sp = ProcessSpawner(["m=mlp_tabular:{}"],
                         events_dir=str(tmp_path / "ev"))
-    assert sp.device_env("w0") == {}     # 0 = workers share the host
+    assert sp.device_env("w0") == {}     # 0 = the worker owns no chip
     assert "TPU_VISIBLE_CHIPS" not in sp.build_env("w0")
+
+
+def test_process_spawner_never_leaves_the_platform_unpinned(
+        tmp_path, monkeypatch):
+    """Every worker starts with an explicit JAX_PLATFORMS: the spawner's
+    env, else its own chips, else what this process inherited — and
+    having none of them is an error, not a child that takes whatever
+    backend initializes."""
+    mk = lambda **kw: ProcessSpawner(["m=mlp_tabular:{}"],
+                                     events_dir=str(tmp_path / "ev"), **kw)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert mk().build_env("w0")["JAX_PLATFORMS"] == "cpu"     # inherited
+    assert mk(devices_per_worker=1).platform() == "tpu"       # own chips
+    assert mk(devices_per_worker=1,
+              env={"JAX_PLATFORMS": "cpu"}).platform() == "cpu"  # operator
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert mk(env={"JAX_PLATFORMS": "cpu"}).build_env(
+        "w0")["JAX_PLATFORMS"] == "cpu"
+    with pytest.raises(ValueError, match="not pinned"):
+        mk().build_env("w0")
+
+
+def test_fleet_parent_never_loads_jax():
+    """The `mmlspark-tpu fleet` process only spawns, routes and scrapes. A
+    chip belongs to the one process that initializes it, so the parent
+    must leave jax alone — cheapest proof: everything `cmd_fleet` imports
+    (autopilot included) comes up without jax in `sys.modules`."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "from mmlspark_tpu import cli\n"
+        "from mmlspark_tpu.observability.aggregate import FleetScraper\n"
+        "from mmlspark_tpu.reliability import preemption\n"
+        "from mmlspark_tpu.serve.http import serve_http\n"
+        "from mmlspark_tpu.serve.router import Router\n"
+        "from mmlspark_tpu.serve.supervisor import ProcessSpawner, "
+        "Supervisor\n"
+        "from mmlspark_tpu.control.autopilot import Autopilot\n"
+        "from mmlspark_tpu.serve.fleet import ProcessFleet\n"
+        "sys.exit(1 if 'jax' in sys.modules else 0)\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_worker_told_to_use_a_chip_that_finds_none_exits_nonzero(tmp_path):
+    """``serve`` under JAX_PLATFORMS=tpu on a host with no TPU must die at
+    backend init (rc != 0, no announce) — not announce itself from the
+    CPU."""
+    import subprocess
+    import sys
+    sp = ProcessSpawner(['m=mlp_tabular:{"input_dim": 4}'],
+                        events_dir=str(tmp_path / "ev"),
+                        devices_per_worker=1,
+                        env={"TPU_VISIBLE_CHIPS": "63"})  # no such chip
+    proc = subprocess.run(sp.build_argv("w0"), env=sp.build_env("w0"),
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert '"serving"' not in proc.stdout
 
 
 def test_process_spawner_explicit_env_outranks_pinning(tmp_path):

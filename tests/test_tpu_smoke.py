@@ -10,10 +10,14 @@ by Mosaic rather than the CPU interpreter — catching backend-specific
 regressions the virtual CPU mesh cannot.
 """
 import os
+import sys
 
 import jax
 import numpy as np
 import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402
 
 pytestmark = [
     pytest.mark.tpu,
@@ -160,6 +164,21 @@ def test_pallas_flash_attention_compiles_under_mosaic():
         got = np.asarray(jax.device_get(
             flash_attention(q, k, v, causal=causal)))
         np.testing.assert_allclose(got, ref, atol=8e-3, rtol=1e-2)
+
+
+@pytest.mark.parametrize("kernel_check", chip_smoke.KERNEL_CHECKS,
+                         ids=lambda f: f.__name__)
+def test_pallas_kernels_at_bench_width(kernel_check):
+    """The shapes ``bench.py`` runs — fused_normalize at (128, 224*224*3)
+    and (256, 32*32*3), the 256->224 fused crop at batch 32, causal flash
+    at B1 L8192 H8 D64 bf16 forward and backward plus the fp32 case on the
+    edge of ``supports`` — through ``chip_smoke``'s own checks: numerics
+    against the reference AND a Mosaic call in the lowered program."""
+    recorded = {}
+    kernel_check(chip_smoke.FULL, False,
+                 lambda name, *facts: recorded.setdefault(name, facts))
+    # the sharded check has nothing to do on a one-chip host
+    assert recorded or kernel_check is chip_smoke.kernel_flash_sharded
 
 
 def test_device_resize_matches_host_within_one_gray_level():
