@@ -25,12 +25,14 @@ at the published width of every model it touches and at the kernel shapes
 It is not a benchmark: it proves the program runs on the device and says
 how long compiling took. It exits non-zero — and prints no result —
 unless ``jax.default_backend() == "tpu"``, and whenever any check fails;
-nothing is caught and downgraded. On success the LAST line of stdout is
-one JSON object: ``{"ok": true, "device": {"platform", "kind", "count"},
-"versions", "cache", "legs", ...}`` with per-leg compile and steady
-seconds and the compile-cache directory with its hit/miss counts. Run it
-twice against one cache directory: the second run must show cache hits,
-far fewer compile seconds and the same output digests.
+nothing is caught and downgraded. On success stdout holds two lines, each
+one JSON object. First the report: ``{"ok", "device", "versions", "cache",
+"legs", ...}`` with per-leg compile and steady seconds and the
+compile-cache directory with its hit/miss counts. Then, LAST, the verdict
+the driver reads, with exactly these keys and the device as jax reports it:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+Run it twice against one cache directory: the second run must show cache
+hits, far fewer compile seconds and the same output digests.
 
 The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else
 ``<checkout>/.jax_cache`` (``mmlspark_tpu.compile_cache.enable``). One
@@ -662,7 +664,7 @@ def leg_kernels(sz: Sizes, meter: CompileMeter,
 def run(sizes: Sizes = FULL, *, rehearsal: bool = False,
         default_cache_dir: str = os.path.join(HERE, ".jax_cache")
         ) -> Dict[str, Any]:
-    """All three legs; returns the result object ``main`` prints.
+    """All three legs; returns the report ``emit`` prints.
     ``rehearsal=True`` is the tier-1 CPU drive: any backend is accepted
     and the Mosaic-lowering proofs are skipped (the kernels run in the
     Pallas interpreter there). Everything else is checked the same."""
@@ -715,6 +717,14 @@ def run(sizes: Sizes = FULL, *, rehearsal: bool = False,
     }
 
 
+def emit(report: Dict[str, Any]) -> None:
+    """The report, then as the LAST line of stdout the verdict: ``ok`` and
+    ``device`` and no other key — the driver parses that line strictly."""
+    print(json.dumps(report), flush=True)
+    print(json.dumps({"ok": report["ok"], "device": report["device"]}),
+          flush=True)
+
+
 def main() -> int:
     sys.path.insert(0, HERE)
     import mmlspark_tpu
@@ -723,7 +733,7 @@ def main() -> int:
         raise SystemExit(
             "chip_smoke: run it from a checkout — mmlspark_tpu resolved to "
             f"{mmlspark_tpu.__file__}, not the package beside this file")
-    print(json.dumps(run()), flush=True)
+    emit(run())
     return 0
 
 

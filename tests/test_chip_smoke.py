@@ -50,7 +50,7 @@ import json, sys
 sys.path[:0] = [{repo!r}, {tests!r}]
 import chip_smoke
 from test_chip_smoke import TOY
-print(json.dumps(chip_smoke.run(TOY, rehearsal=True)))
+chip_smoke.emit(chip_smoke.run(TOY, rehearsal=True))
 """
 
 
@@ -69,7 +69,11 @@ def rehearsal(tmp_path_factory):
                           capture_output=True, text=True, timeout=900,
                           cwd=str(REPO))
     assert proc.returncode == 0, proc.stderr[-4000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1]), cache
+    report, verdict = map(json.loads, proc.stdout.strip().splitlines()[-2:])
+    # the LAST line is what the driver parses, strictly: these keys only
+    assert verdict == {"ok": True, "device": report["device"]}
+    assert list(verdict["device"]) == ["platform", "kind", "count"]
+    return report, cache
 
 
 def test_every_leg_runs_at_toy_size_on_the_cpu(rehearsal):
