@@ -7,16 +7,35 @@ back-compat re-export). This module deliberately imports no jax and no
 trainer code: the device commit is the injected ``put`` callable, so the
 prefetcher composes with any dispatch layer (``trainer.put_batch``, a
 plain ``jax.device_put``, or an identity function in host-only tests).
+
+Telemetry at this boundary (``docs/OBSERVABILITY.md``): the counters
+``input.batches_put`` / ``input.bytes_put`` always count; with the hot-span
+gate on (``spans.hot_spans``, resolved once per prefetcher) every batch
+leaves ``input:produce`` (producer thread: the pull from the host
+iterator), ``input:wait`` (consumer: the queue get) and ``input:put``
+(consumer: the device commit), all carrying the batch's ordinal.
 """
 from __future__ import annotations
 
+import contextvars
 import queue
 import threading
 from typing import Any, Callable, Dict, Iterable, Iterator, Optional
 
 from mmlspark_tpu.observability import metrics as obsmetrics
+from mmlspark_tpu.observability import spans as obsspans
 from mmlspark_tpu.reliability import watchdog as _watchdog
 from mmlspark_tpu.utils import config as mmlconfig
+
+
+def _nbytes(tree: Any) -> int:
+    """Bytes of a host batch: the sum of its leaves' ``nbytes`` (dicts,
+    lists and tuples are walked; this module imports no jax)."""
+    if isinstance(tree, dict):
+        return sum(_nbytes(v) for v in tree.values())
+    if isinstance(tree, (list, tuple)):
+        return sum(_nbytes(v) for v in tree)
+    return int(getattr(tree, "nbytes", 0))
 
 
 class DevicePrefetcher:
@@ -50,6 +69,28 @@ class DevicePrefetcher:
         self._done = False
         self._closed = False
         self._telemetry = obsmetrics.metrics_enabled()
+        # per-batch spans and counts: the gate (None, or the span
+        # constructor) and the counters are resolved once, here
+        self._hot = hot = obsspans.hot_spans()
+        self._batches_put = obsmetrics.counter("input.batches_put")
+        self._bytes_put = obsmetrics.counter("input.bytes_put")
+        self._batch = 0  # ordinal of the next batch handed to ``put``
+
+        def pulled():
+            """``host_batches``, each pull inside ``input:produce``: the
+            host work of one batch (shuffle, decode, assembly), apart
+            from the back-pressure wait on the queue below."""
+            it = iter(host_batches)
+            n = 0
+            while True:
+                with hot("input", "produce", batch=n) as sp:
+                    try:
+                        hb = next(it)
+                    except StopIteration:
+                        sp.drop()
+                        return
+                yield hb
+                n += 1
 
         def run():
             # liveness: beats on every produced batch AND on every bounded
@@ -58,7 +99,7 @@ class DevicePrefetcher:
             # stall the watchdog should catch
             beat = _watchdog.register("data.prefetch")
             try:
-                for hb in host_batches:
+                for hb in (pulled() if hot else host_batches):
                     beat.beat()
                     if self._stop.is_set():
                         return
@@ -83,8 +124,11 @@ class DevicePrefetcher:
                     except queue.Full:
                         continue
 
-        self._thread = threading.Thread(target=run, daemon=True,
-                                        name="mmlspark-tpu-prefetch")
+        # the producer inherits the caller's context, so its spans nest
+        # under the span this prefetcher was built in (``trainer:fit``)
+        self._thread = threading.Thread(
+            target=contextvars.copy_context().run, args=(run,), daemon=True,
+            name="mmlspark-tpu-prefetch")
         self._thread.start()
 
     def close(self) -> None:
@@ -113,7 +157,15 @@ class DevicePrefetcher:
     def __next__(self) -> Any:
         if self._done:
             raise StopIteration
-        item = self._q.get()
+        n = self._batch
+        hot = self._hot
+        if hot:
+            with hot("input", "wait", batch=n) as sp:
+                item = self._q.get()
+                if item is self._SENTINEL:
+                    sp.drop()  # the end of the stream is no batch
+        else:
+            item = self._q.get()
         if self._telemetry:
             obsmetrics.gauge("data.prefetch_queue_depth").set(
                 self._q.qsize())
@@ -123,4 +175,11 @@ class DevicePrefetcher:
             if self._err is not None:
                 raise self._err
             raise StopIteration
+        nbytes = _nbytes(item)
+        self._batch = n + 1
+        self._batches_put.inc()
+        self._bytes_put.inc(nbytes)
+        if hot:
+            with hot("input", "put", batch=n, bytes=nbytes):
+                return self._put(item)
         return self._put(item)

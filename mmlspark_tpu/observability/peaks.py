@@ -1,10 +1,9 @@
 """Published peak rates per accelerator: the ONE denominator table.
 
-Every utilization the repo reports (``bench.py``'s ``mfu``, the trainer's
-``trainer.mfu`` gauge) divides by a row of this table, looked up by the
-``device_kind`` string jax reports for the attached device. A device that
-is not listed has no peak: ``bench.py`` lets :func:`peaks_for` raise, the
-trainer writes no MFU gauge — neither assumes a v5e.
+Every utilization the repo reports (``bench.py``'s ``mfu``) divides by a
+row of this table, looked up by the ``device_kind`` string jax reports for
+the attached device. A device that is not listed has no peak:
+:func:`peaks_for` raises — nothing assumes a v5e.
 """
 from __future__ import annotations
 

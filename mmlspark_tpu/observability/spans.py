@@ -16,6 +16,14 @@ the two pieces instead of a preformatted f-string. With
 ``jax.profiler.TraceAnnotation`` so the same names line up in
 TensorBoard/Perfetto timelines (via the failure-safe
 ``utils.profiling.annotate``).
+
+Spans that fire once per step or per batch go through :func:`hot_spans`,
+not :func:`span`: live only with ``observability.annotate`` or the event
+log on, never for the flight recorder alone (a span per step would evict
+the incident timeline the ring exists for within seconds). A call site
+calls :func:`hot_spans` once, outside its loop, and holds what it returns:
+``None`` (it then enters :data:`NOOP`: one boolean test, nothing
+allocated) or the span constructor, which tests no gate again.
 """
 from __future__ import annotations
 
@@ -23,7 +31,7 @@ import contextvars
 import itertools
 import os
 import threading
-from typing import Any, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 from mmlspark_tpu.observability import events
 from mmlspark_tpu.utils import config
@@ -57,19 +65,25 @@ class _NoopSpan:
     def __exit__(self, exc_type, exc, tb) -> bool:
         return False
 
+    def drop(self) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
+NOOP = _NOOP  # what a hot call site enters when its resolved gate is off
 
 
 class _Span:
     __slots__ = ("name", "attrs", "span_id", "_token", "_start_wall",
-                 "_start_perf", "_parent", "_depth", "_annotation")
+                 "_start_perf", "_parent", "_depth", "_annotation",
+                 "_dropped")
 
     def __init__(self, name: str, attrs: dict, annotate: bool):
         self.name = name
         self.attrs = attrs
         self.span_id = next_span_id()
         self._annotation = None
+        self._dropped = False
         if annotate:
             from mmlspark_tpu.utils.profiling import annotate as _annotate
             self._annotation = _annotate(name)
@@ -90,6 +104,8 @@ class _Span:
             self._annotation.__exit__(exc_type, exc, tb)
         dur = events.perf() - self._start_perf
         _STACK.reset(self._token)
+        if self._dropped:
+            return False
         fields = {
             "span_id": self.span_id,
             "pid": os.getpid(),
@@ -105,6 +121,11 @@ class _Span:
             fields["attrs"] = self.attrs
         events.emit("span", self.name, **fields)
         return False
+
+    def drop(self) -> None:
+        """Emit no event for this span: the region turned out not to be
+        one (a pull that met the end of its stream)."""
+        self._dropped = True
 
 
 def span(kind: str, detail: str = "", **attrs: Any):
@@ -122,6 +143,29 @@ def span(kind: str, detail: str = "", **attrs: Any):
     if not (annotate or events.recording_enabled()):
         return _NOOP
     return _Span(f"{kind}:{detail}" if detail else kind, attrs, annotate)
+
+
+def hot_spans() -> Optional[Callable[..., _Span]]:
+    """The entry point for regions that fire once per step or per batch.
+    Resolves their gate, ``observability.annotate`` or the event log and
+    NOT the flight recorder alone, and returns ``None`` when it is off,
+    else ``hot_span(kind, detail="", **attrs)``: the constructor of the
+    same span :func:`span` gives (name, parent stack, event,
+    ``TraceAnnotation``), with the gate's answer bound, so that a span
+    costs no second look at the config. Call it once, outside the loop:
+
+        hot = spans.hot_spans()
+        for ...:
+            with hot("trainer", "dispatch", step=n) if hot else spans.NOOP:
+    """
+    annotate = bool(config.get("observability.annotate"))
+    if not (annotate or events.events_enabled()):
+        return None
+
+    def hot_span(kind: str, detail: str = "", **attrs: Any) -> _Span:
+        return _Span(f"{kind}:{detail}" if detail else kind, attrs, annotate)
+
+    return hot_span
 
 
 def current_span() -> Optional[Tuple[str, int]]:

@@ -33,7 +33,7 @@ from mmlspark_tpu.data.prefetch import DevicePrefetcher  # noqa: F401
 from mmlspark_tpu.parallel.mesh import mesh_from_config
 from mmlspark_tpu.observability import events as obsevents
 from mmlspark_tpu.observability import metrics as obsmetrics
-from mmlspark_tpu.observability.peaks import DEVICE_PEAKS
+from mmlspark_tpu.observability import spans as obsspans
 from mmlspark_tpu.observability import syncs as obssyncs
 from mmlspark_tpu.reliability import watchdog as _watchdog
 from mmlspark_tpu.reliability.faults import fault_site
@@ -252,7 +252,13 @@ class DistributedTrainer:
         self._flush_steps: Optional[int] = None  # resolved at first step
         self._steps_since_flush = 0
         self._throttled = is_cpu_mesh(self.mesh)
-        self._flops_per_step: Optional[float] = None  # lazy cost analysis
+        # per-step spans (``trainer:dispatch``, ``trainer:flush``) and the
+        # ``trainer.steps_dispatched`` counter: ``_resolve_hot`` runs at the
+        # first step and again when a ``fit`` begins, never per step;
+        # ``_dispatched`` is the ``step`` those spans carry
+        self._hot = None  # the span constructor while the gate is on
+        self._steps_dispatched: Optional[obsmetrics.Counter] = None
+        self._dispatched = 0
 
     # -- state -------------------------------------------------------------
     def _full_init_fn(self, init_params_fn: Callable[[], Any]):
@@ -317,8 +323,12 @@ class DistributedTrainer:
         accum = self.accum_steps
         flush = self.flush_steps()
 
+        # The named scopes are metadata only (no operation changes): stable
+        # names for the step's phases in xprof/TensorBoard, and the seam for
+        # a split of device time by phase (PERF.md, Open questions).
         def single_grad(params, batch, rng):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
+            with jax.named_scope("loss_and_grad"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
             return loss, grads
 
         def step(state, ring, batch, rng):
@@ -345,17 +355,19 @@ class DistributedTrainer:
                 grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
             else:
                 loss, grads = single_grad(params, batch, rng)
-            updates, opt_state = self.optimizer.update(
-                grads, state["opt_state"], params)
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state = self.optimizer.update(
+                    grads, state["opt_state"], params)
+                new_params = optax.apply_updates(params, updates)
             new_state = {"params": new_params, "opt_state": opt_state,
                          "step": state["step"] + 1}
             # metrics ring: the loss lands in slot (step mod flush) ON
             # device — no per-step host traffic; the host reads the whole
             # ring once per flush interval
-            new_ring = {"loss": ring["loss"].at[
-                jnp.mod(state["step"], flush)].set(loss),
-                "step": new_state["step"]}
+            with jax.named_scope("metrics_ring"):
+                new_ring = {"loss": ring["loss"].at[
+                    jnp.mod(state["step"], flush)].set(loss),
+                    "step": new_state["step"]}
             return new_state, new_ring, {"loss": loss}
 
         # Batch shardings are NOT pinned here: put_batch commits per-leaf
@@ -398,6 +410,12 @@ class DistributedTrainer:
         fn = self._get_train_step(donate_batch)
         if self._ring is None:
             self._ring = self._init_ring()
+        if self._steps_dispatched is None:
+            self._resolve_hot()
+        # host time to enqueue one step; off, one boolean test
+        dispatch = self._hot(
+            "trainer", "dispatch", step=self._dispatched,
+            donate=donate_batch) if self._hot else obsspans.NOOP
         with self.mesh:
             if donate_batch:
                 # batch donation is best-effort: leaves whose buffers cannot
@@ -409,11 +427,15 @@ class DistributedTrainer:
                     warnings.filterwarnings(
                         "ignore",
                         message="Some donated buffers were not usable")
+                    with dispatch:
+                        new_state, self._ring, metrics = fn(
+                            state, self._ring, batch, rng)
+            else:
+                with dispatch:
                     new_state, self._ring, metrics = fn(
                         state, self._ring, batch, rng)
-            else:
-                new_state, self._ring, metrics = fn(
-                    state, self._ring, batch, rng)
+        self._dispatched += 1
+        self._steps_dispatched.inc()
         # Steady state performs ZERO host syncs: the only wait is the ring
         # flush every flush_steps, which on the multi-device CPU runtime
         # also bounds async dispatch depth (hundreds of un-retired step
@@ -433,7 +455,9 @@ class DistributedTrainer:
         periodic loss telemetry WITHOUT per-step syncs read it here."""
         if self._ring is None:
             return None
-        vals = obssyncs.device_get(self._ring, "trainer.flush")
+        with (self._hot("trainer", "flush", steps=self._steps_since_flush)
+              if self._hot else obsspans.NOOP):
+            vals = obssyncs.device_get(self._ring, "trainer.flush")
         self._steps_since_flush = 0
         return {k: np.asarray(v) for k, v in vals.items()}
 
@@ -447,12 +471,19 @@ class DistributedTrainer:
             return self._eval_step(state["params"], batch, rng)
 
     # -- telemetry ---------------------------------------------------------
+    def _resolve_hot(self) -> None:
+        """Resolve the per-step telemetry once: the hot-span gate and the
+        counter object, so that a step pays neither a config lookup nor
+        the registry's lock."""
+        self._hot = obsspans.hot_spans()
+        self._steps_dispatched = obsmetrics.counter(
+            "trainer.steps_dispatched")
+
     def _estimate_flops(self, state, batch, rng) -> float:
         """FLOPs of one compiled train step via XLA cost analysis (a
         Mosaic custom call inside the step counts as zero). Lowers and
-        compiles the already-jitted step once per trainer — the result is
-        memoized in ``_flops_per_step``. A backend that cannot answer
-        raises: an MFU that quietly vanishes hides a broken device."""
+        compiles the already-jitted step again, so no step loop calls it
+        (``bench.py`` does, once). A backend that cannot answer raises."""
         fn = next(iter(self._train_steps.values()))
         ring = self._ring if self._ring is not None else self._init_ring()
         with self.mesh:
@@ -461,28 +492,13 @@ class DistributedTrainer:
 
     def _finish_epoch_telemetry(self, steps: int, rows: int,
                                 wall_s: float) -> None:
-        """End-of-epoch gauges + ``train.fit`` event (throughput, MFU)."""
+        """End-of-epoch throughput gauge + ``train.fit`` event."""
         eps = rows / max(wall_s, 1e-9)
         obsmetrics.gauge("trainer.examples_per_sec").set(eps)
-        mfu = None
-        if self._flops_per_step:
-            achieved = (self._flops_per_step * steps
-                        / max(wall_s, 1e-9) / 1e12)
-            obsmetrics.gauge("trainer.achieved_tflops").set(achieved)
-            # MFU only means something against the attached device's
-            # published peak: no table row (the CPU mesh, an unlisted
-            # accelerator), no gauge
-            kind = self.mesh.devices.flat[0].device_kind
-            peaks = DEVICE_PEAKS.get(kind)
-            if peaks is not None:
-                mfu = achieved / peaks.bf16_tflops
-                obsmetrics.gauge("trainer.mfu").set(mfu)
         if obsevents.events_enabled():
-            fields = dict(steps=steps, rows=rows, wall_s=round(wall_s, 6),
-                          examples_per_sec=round(eps, 3))
-            if mfu is not None:
-                fields["mfu"] = round(mfu, 4)
-            obsevents.emit("event", "train.fit", **fields)
+            obsevents.emit("event", "train.fit", steps=steps, rows=rows,
+                           wall_s=round(wall_s, 6),
+                           examples_per_sec=round(eps, 3))
 
     # -- data --------------------------------------------------------------
     def put_batch(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
@@ -511,7 +527,22 @@ class DistributedTrainer:
         ``log_fn(step, loss)``). ``collect_losses=False`` skips
         materializing the per-step loss history (it costs a device stack +
         transfer at the end) and returns an empty list.
+
+        The whole call is one ``trainer:fit`` span, the parent of the
+        per-step ``trainer:dispatch`` / ``input:*`` spans; the ``step`` a
+        dispatch carries restarts here, so that it equals the ``batch``
+        ordinal of the ``input:*`` spans that fed it.
         """
+        depth = prefetch if prefetch is not None else int(
+            mmlconfig.get("runtime.prefetch_depth"))
+        self._resolve_hot()
+        self._dispatched = 0
+        with obsspans.span("trainer", "fit", prefetch=depth):
+            return self._fit(state, batches, rng, log_every, log_fn, depth,
+                             collect_losses)
+
+    def _fit(self, state, batches, rng, log_every, log_fn, prefetch,
+             collect_losses) -> Tuple[Any, list]:
         rng = rng if rng is not None else jax.random.PRNGKey(0)
         if isinstance(batches, Dataset):
             batches = batches.iter()
@@ -555,9 +586,6 @@ class DistributedTrainer:
                     t_prev = now
                     steps += 1
                     rows_total += rows
-                    if self._flops_per_step is None:
-                        self._flops_per_step = self._estimate_flops(
-                            state, batch, rng)
                 if log_fn is not None and log_every and i % log_every == 0:
                     log_fn(i, float(losses[-1]))
                 elif metric_log is not None:  # cadence handled inside (no
