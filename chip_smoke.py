@@ -17,8 +17,9 @@ at the published width of every model it touches and at the kernel shapes
   dim 512, depth 6, heads 8) at ``generate.max_seq_len`` 512: four
   concurrent prompts of different lengths through the continuous batcher
   and the paged KV arena, checked against a full-recompute reference;
-- **kernels** — ``fused_normalize``, the fused crop+normalize and
-  ``flash_attention`` (forward and backward) at bench width, each checked
+- **kernels** — ``fused_normalize``, the fused crop+normalize,
+  ``flash_attention`` (forward and backward) at bench width and
+  ``short_attention`` (forward and backward) at ViT-B/16's shape, each checked
   against its jnp/numpy reference and each proven to have lowered to a
   Mosaic call, so an interpreted or reference path cannot pass.
 
@@ -90,12 +91,13 @@ class Sizes:
     lm_prompts: Tuple[int, ...] = (9, 40, 130, 300)
     lm_new_tokens: int = 32
     # kernels: (batch, image shape) for fused_normalize; (batch, src, dst)
-    # for the fused crop; (B, L, H, D) for flash attention
+    # for the fused crop; (B, L, H, D) for flash and short attention
     normalize: Tuple[Tuple[int, Tuple[int, int, int]], ...] = (
         (128, (224, 224, 3)), (256, (32, 32, 3)))
     crop: Tuple[int, int, int] = (32, 256, 224)
     flash_bf16: Tuple[int, int, int, int] = (1, 8192, 8, 64)
     flash_fp32: Tuple[int, int, int, int] = (1, 16384, 2, 64)
+    short_bf16: Tuple[int, int, int, int] = (128, 197, 12, 64)
 
 
 FULL = Sizes()
@@ -602,6 +604,39 @@ def kernel_flash_backward(sz: Sizes, rehearsal: bool, record) -> None:
     record("flash_bwd_bf16", sz.flash_bf16, c, s, err)
 
 
+def kernel_short_attention(sz: Sizes, rehearsal: bool, record) -> None:
+    """The short-sequence kernel, forward and backward, at the shape every
+    ViT-B/16 block calls it with (non-causal, 197 ragged tokens): a jax
+    upgrade that breaks its Mosaic lowering is caught here, before a
+    benchmark run."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops import pallas_attention
+    from mmlspark_tpu.parallel.sequence import full_attention
+
+    check(pallas_attention.supports_short(sz.short_bf16)
+          and not pallas_attention.supports(sz.short_bf16),
+          "short_bf16 is not a shape the short kernel takes")
+    q, k, v, w = _qkvw(sz.short_bf16, jnp.bfloat16)
+    w = w.astype(jnp.float32)
+
+    def both(use_flash):
+        def loss(q, k, v):
+            out = full_attention(q, k, v, causal=False, use_flash=use_flash)
+            return (out.astype(jnp.float32) * w).sum(), out
+        return jax.jit(lambda q, k, v: jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v))
+
+    short, reference = both("require"), both("never")
+    _require_mosaic(short, (q, k, v), "short attention", rehearsal)
+    ((_, out), grads), c, s = _kernel_run(short, (q, k, v))
+    (_, ref_out), ref_grads = reference(q, k, v)
+    err = max(_rel_err(g, r) for g, r in zip((out,) + grads,
+                                             (ref_out,) + ref_grads))
+    check(err <= BF16_REL_TOL, f"short attention: rel err {err:.3g}")
+    record("short_fwd_bwd_bf16", sz.short_bf16, c, s, err)
+
+
 def kernel_flash_sharded(sz: Sizes, rehearsal: bool, record) -> None:
     """Data-parallel flash on a multi-device host: each device runs the
     kernel on its own batch row (nothing to do on one device)."""
@@ -638,7 +673,8 @@ def kernel_flash_sharded(sz: Sizes, rehearsal: bool, record) -> None:
 
 
 KERNEL_CHECKS = (kernel_normalize, kernel_crop, kernel_flash_forward,
-                 kernel_flash_backward, kernel_flash_sharded)
+                 kernel_flash_backward, kernel_short_attention,
+                 kernel_flash_sharded)
 
 
 def leg_kernels(sz: Sizes, meter: CompileMeter,

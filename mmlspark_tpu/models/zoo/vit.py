@@ -1,8 +1,10 @@
 """ViT-B/16 — target of the fused-Pallas-preprocessing config
 (BASELINE.json config 5) and the long-context flagship: every encoder block
-takes a pluggable ``attention_fn``, the hook through which the sequence-
-parallel/ring attention implementations in ``mmlspark_tpu.parallel`` are
-swapped in for long inputs.
+takes a pluggable ``attention_fn(q, k, v, causal=...)`` over (B, L, H, D),
+the hook through which the sequence-parallel/ring attention implementations
+in ``mmlspark_tpu.parallel`` are swapped in for long inputs. The default is
+``parallel.sequence.full_attention``, which picks a fused kernel from the
+shape (197 tokens: one short-sequence call per block).
 
 Standard pre-norm ViT: patchify conv -> [CLS] -> encoder blocks
 (MHA + MLP, GELU) -> head. bfloat16 compute, fp32 norms/logits.
@@ -15,6 +17,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from mmlspark_tpu.models.zoo import register_model
+from mmlspark_tpu.parallel.sequence import full_attention
 
 
 class MlpBlock(nn.Module):
@@ -29,6 +32,33 @@ class MlpBlock(nn.Module):
         return nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")(h)
 
 
+class SelfAttention(nn.Module):
+    """Non-causal multi-head self-attention that owns its projections,
+    under the parameter names and shapes of flax's
+    ``nn.MultiHeadDotProductAttention`` (``query|key|value`` kernels
+    ``[dim, heads, head_dim]``, ``out`` ``[heads, head_dim, dim]``), so
+    checkpoints of either load into the other. q, k, v leave the
+    projections as (B, L, H, D) and the output projection contracts (H, D)
+    again: row-major that is (B, L, dim) on both sides, the layout the
+    fused kernels work in, so no per-head layout copy is needed around
+    them (XLA still transposes each operand once out of its own
+    batch-minor layout: ``PERF.md`` section 7)."""
+    dim: int
+    heads: int
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        attn_fn = self.attention_fn or full_attention
+        q, k, v = (nn.DenseGeneral((self.heads, self.dim // self.heads),
+                                   dtype=self.dtype, name=name)(x)
+                   for name in ("query", "key", "value"))
+        o = attn_fn(q, k, v, causal=False)
+        return nn.DenseGeneral(self.dim, axis=(-2, -1), dtype=self.dtype,
+                               name="out")(o)
+
+
 class EncoderBlock(nn.Module):
     dim: int
     heads: int
@@ -39,10 +69,8 @@ class EncoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         y = nn.LayerNorm(dtype=jnp.float32, name="norm1")(x)
-        attn = nn.MultiHeadDotProductAttention(
-            num_heads=self.heads, dtype=self.dtype, name="attn",
-            attention_fn=self.attention_fn or nn.dot_product_attention)
-        x = x + attn(y, y)
+        x = x + SelfAttention(self.dim, self.heads, self.dtype,
+                              self.attention_fn, name="attn")(y)
         y = nn.LayerNorm(dtype=jnp.float32, name="norm2")(x)
         x = x + MlpBlock(self.dim, self.dim * self.mlp_ratio, self.dtype,
                          name="mlp")(y)

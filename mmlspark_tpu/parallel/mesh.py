@@ -140,6 +140,16 @@ def mesh_from_config(devices: Optional[Sequence] = None) -> Mesh:
     return make_mesh(MeshSpec(**parse_mesh_axes(text)), devices)
 
 
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing ``with mesh:`` block (how the trainer,
+    ``JaxModel`` and the runners scope their jitted calls), None outside
+    one. For code that is traced deep inside a model and has to wrap an
+    opaque call in ``shard_map`` without a mesh argument to be handed."""
+    from jax._src.mesh import thread_resources
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
+
+
 def resolve_mesh(mesh_spec) -> Mesh:
     """MeshSpec | axis-size dict | "data=2,tensor=4" string | Mesh | None
     -> Mesh. None consults the launcher's ``runtime.mesh`` config (falling

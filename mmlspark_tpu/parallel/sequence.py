@@ -25,6 +25,7 @@ Shapes follow the framework convention (B, L, H, D) with L sharded over the
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -34,43 +35,18 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from mmlspark_tpu.observability import metrics as obsmetrics
+from mmlspark_tpu.parallel.mesh import ambient_mesh
 from mmlspark_tpu.parallel.sharding import active_batch_axes
 
 
-def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                   causal: bool = True,
-                   use_flash: str = "auto") -> jnp.ndarray:
-    """Single-device attention (B, L, H, D).
+def _on_chip() -> bool:
+    return jax.default_backend() != "cpu"
 
-    On an accelerator backend with shapes the kernel ``supports`` this
-    runs the fused Pallas flash kernel (``ops/pallas_attention.py``) — the
-    L x L score matrix never touches HBM. Everything else (CPU lanes,
-    ragged lengths like ViT's 197 tokens) takes the jnp reference below:
-    matmuls in the input dtype (bf16 tiles the MXU); scores, softmax and
-    the output accumulation in fp32, cast back once at the end.
 
-    ``use_flash``: "auto" | "never" (reference path, used by the parity
-    tests themselves) | "require" (the flash kernel or a ValueError —
-    for callers whose result is only meaningful on the kernel: the
-    ``longctx`` bench lane, ``chip_smoke.py``). Under "auto" on an
-    accelerator, each trace that takes the reference because
-    ``supports`` said no increments the ``attention.flash_fallbacks``
-    counter, so the downgrade is visible in metrics and reports.
-    """
-    if use_flash not in ("auto", "never", "require"):
-        raise ValueError(f"unknown use_flash {use_flash!r}")
-    if use_flash != "never":
-        from mmlspark_tpu.ops import pallas_attention
-        on_chip = jax.default_backend() != "cpu"
-        fits = pallas_attention.supports(q.shape)
-        if fits and (on_chip or use_flash == "require"):
-            return pallas_attention.flash_attention(q, k, v, causal=causal)
-        if use_flash == "require":
-            raise ValueError(
-                f"use_flash='require': the flash kernel does not support "
-                f"q shape {tuple(q.shape)} (pallas_attention.supports)")
-        if on_chip:
-            obsmetrics.counter("attention.flash_fallbacks").inc()
+def _reference_attention(q, k, v, causal: bool):
+    """Plain attention: matmuls in the input dtype (bf16 tiles the MXU);
+    scores, softmax and the output accumulation in fp32, cast back once
+    at the end. The L x L scores go through HBM."""
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("blhd,bkhd->bhlk", q, k,
                    preferred_element_type=jnp.float32) * scale
@@ -82,6 +58,82 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out = jnp.einsum("bhlk,bkhd->blhd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
+
+
+def _per_device(fn, mesh: Mesh, n_heads: int):
+    """``fn(q, k, v)`` shard_mapped so that each device runs it on its
+    own batch rows (and its own heads on a tensor axis). Attention is
+    independent across batch and heads, so this needs no collective — but
+    a Pallas kernel is an opaque custom call the SPMD partitioner cannot
+    split: bare inside a multi-device jit it is refused, or all-gathered
+    to run every (batch, head) on every chip."""
+    spec = _qkv_spec(mesh, None, n_heads)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
+
+
+def _on_own_rows(kernel, q, k, v):
+    """``kernel(q, k, v)`` per device (``_per_device``) over the mesh of
+    the enclosing ``with mesh:`` block, or None when the batch does not
+    split over that mesh. Outside a mesh, on one device, and inside a
+    ``shard_map`` body (the operands are one device's already) it is the
+    bare call."""
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return kernel(q, k, v)
+    if q.shape[0] % math.prod(
+            mesh.shape[a] for a in active_batch_axes(mesh) or ()):
+        return None
+    return _per_device(kernel, mesh, q.shape[2])(q, k, v)
+
+
+def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                   causal: bool = True,
+                   use_flash: str = "auto") -> jnp.ndarray:
+    """Attention (B, L, H, D) with the whole sequence on each device.
+
+    The one place that picks the implementation, from the shape alone
+    (``ops/pallas_attention.py``): a length the flash kernel ``supports``
+    (multiples of 256 from 512 up) streams K/V blocks through it; a
+    sequence whose one block fits VMEM (``supports_short``: ViT's 197
+    tokens, short prompts) takes the short kernel, one fused call forward
+    and one backward; in both the L x L scores never touch HBM. Whatever
+    neither takes, and every shape on the CPU, runs the jnp reference.
+    Under a multi-device ``with mesh:`` the kernel runs shard_mapped on
+    each device's own batch rows (``_on_own_rows``).
+
+    ``use_flash``: "auto" | "never" (reference path, used by the parity
+    tests themselves) | "require" (a fused kernel or a ValueError, on the
+    CPU in interpret mode — for callers whose result is only meaningful
+    on a kernel: the ``longctx`` bench lane, ``chip_smoke.py``). Every
+    trace increments ``attention.fused_calls.<short|flash|reference>``;
+    under "auto" on an accelerator a trace that takes the reference also
+    increments ``attention.flash_fallbacks``, so the downgrade is visible
+    in metrics and reports.
+    """
+    if use_flash not in ("auto", "never", "require"):
+        raise ValueError(f"unknown use_flash {use_flash!r}")
+    if use_flash == "require" or (use_flash == "auto" and _on_chip()):
+        from mmlspark_tpu.ops import pallas_attention
+        name = kernel = None
+        if pallas_attention.supports(q.shape):
+            name, kernel = "flash", pallas_attention.flash_attention
+        elif pallas_attention.supports_short(q.shape, q.dtype.itemsize):
+            name, kernel = "short", pallas_attention.short_attention
+        out = None if kernel is None else _on_own_rows(
+            lambda q, k, v: kernel(q, k, v, causal), q, k, v)
+        if out is not None:
+            obsmetrics.counter(f"attention.fused_calls.{name}").inc()
+            return out
+        if use_flash == "require":
+            raise ValueError(
+                f"use_flash='require': no fused kernel takes q shape "
+                f"{tuple(q.shape)} here (pallas_attention.supports, "
+                f"supports_short; the batch must split over the mesh)")
+        obsmetrics.counter("attention.flash_fallbacks").inc()
+    obsmetrics.counter("attention.fused_calls.reference").inc()
+    return _reference_attention(q, k, v, causal)
 
 
 # ---------------------------------------------------------------------------
@@ -195,18 +247,12 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def sharded_full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            mesh: Mesh, causal: bool = True,
                            use_flash: str = "auto") -> jnp.ndarray:
-    """``full_attention`` per device on its own batch rows (and its own
-    heads on a tensor axis). Attention is independent across batch and
-    heads, so this needs no collective — but the flash kernel is an
-    opaque custom call the SPMD partitioner cannot split: bare inside a
-    multi-device jit it would all-gather q/k/v and run every (batch,
-    head) on every chip."""
-    spec = _qkv_spec(mesh, None, q.shape[2])
-    fn = jax.shard_map(
+    """``full_attention`` per device (``_per_device``) over an explicit
+    ``mesh``: what ``full_attention`` does by itself under a ``with
+    mesh:`` block, for callers that hold the mesh and no such block."""
+    return _per_device(
         partial(full_attention, causal=causal, use_flash=use_flash),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False)
-    return fn(q, k, v)
+        mesh, q.shape[2])(q, k, v)
 
 
 def make_attention_fn(mesh: Optional[Mesh], impl: str = "auto",

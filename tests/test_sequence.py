@@ -218,8 +218,9 @@ def test_flash_attention_matches_reference():
 
 
 def test_flash_attention_support_gate():
-    """Ragged lengths (ViT's 197 tokens) and short sequences fall back to
-    the reference path instead of failing block divisibility."""
+    """Ragged lengths (ViT's 197 tokens) and short sequences are not the
+    flash kernel's: ``full_attention`` asks ``supports_short`` next
+    (tests/test_short_attention.py) instead of failing block divisibility."""
     from mmlspark_tpu.ops.pallas_attention import supports
     assert supports((2, 512, 4, 64))
     assert supports((1, 1024, 8, 128))

@@ -1,0 +1,289 @@
+"""The short-sequence attention kernel (``ops/pallas_attention.py``) and
+the one place that picks it (``parallel/sequence.full_attention``).
+
+On the CPU the kernel runs in Pallas' interpret mode, which fills what a
+block reads outside its array with NaN: a padded row or key column that
+leaked into any result would show as a NaN. The last tests compile the
+real Mosaic kernel for a described (not attached) v5e, which is what
+catches a lowering the interpreter accepts and the chip refuses.
+"""
+import functools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from mmlspark_tpu.models.zoo import build_model
+from mmlspark_tpu.observability import metrics as obsmetrics
+from mmlspark_tpu.ops import pallas_attention
+from mmlspark_tpu.parallel import sequence
+from mmlspark_tpu.parallel.sequence import full_attention
+
+VIT_B = (2, 197, 12, 64)
+VIT_TINY = (2, 65, 3, 64)
+ALIGNED = (1, 128, 4, 64)
+reference = functools.partial(full_attention, use_flash="never")
+
+
+def _qkvw(shape, dtype, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return [jax.random.normal(k, shape, jnp.float32).astype(dtype)
+            for k in keys]
+
+
+def _rel(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _out_and_grads(fn, q, k, v, w):
+    def loss(q, k, v):
+        return (fn(q, k, v).astype(jnp.float32)
+                * w.astype(jnp.float32)).sum()
+    return fn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("shape", [VIT_B, VIT_TINY, ALIGNED],
+                         ids=["vit_b16", "vit_tiny", "aligned128"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["f32", "bf16"])
+def test_forward_and_grad_match_the_reference(dtype, tol, shape, causal):
+    q, k, v, w = _qkvw(shape, dtype)
+    out, grads = _out_and_grads(
+        lambda q, k, v: pallas_attention.short_attention(q, k, v, causal),
+        q, k, v, w)
+    ref_out, ref_grads = _out_and_grads(
+        functools.partial(reference, causal=causal), q, k, v, w)
+    assert out.dtype == dtype and out.shape == shape
+    assert _rel(out, ref_out) < tol
+    for got, want in zip(grads, ref_grads):
+        assert got.dtype == dtype
+        assert _rel(got, want) < tol
+
+
+@pytest.mark.parametrize("shape", [VIT_B, VIT_TINY, (1, 130, 8, 16)],
+                         ids=["197", "65", "130x16"])
+def test_padded_rows_and_key_columns_give_nothing(shape):
+    """L is padded to 256 (128, 256) inside; the interpreter reads NaN
+    there. A padded key column with any probability, or a padded query
+    row with any weight in dk / dv, would make the result NaN; a finite
+    result that equals the unpadded reference to float32 rounding has
+    given them exactly none."""
+    assert pallas_attention._padded_len(shape[1]) > shape[1]
+    q, k, v, w = _qkvw(shape, jnp.float32, seed=3)
+    out, grads = _out_and_grads(
+        lambda q, k, v: pallas_attention.short_attention(q, k, v, False),
+        q, k, v, w)
+    ref_out, ref_grads = _out_and_grads(
+        functools.partial(reference, causal=False), q, k, v, w)
+    for got, want in zip((out,) + grads, (ref_out,) + ref_grads):
+        assert np.isfinite(np.asarray(got)).all()
+        assert _rel(got, want) < 2e-6
+
+
+def test_which_shapes_fit_one_block():
+    assert pallas_attention.supports_short((128, 197, 12, 64))
+    assert pallas_attention.supports_short((8, 65, 3, 64))
+    assert pallas_attention.supports_short((8, 40, 4, 16), itemsize=4)
+    # too long for one block, a head dim that does not divide the lanes
+    assert not pallas_attention.supports_short((1, 512, 12, 64))
+    assert not pallas_attention.supports_short((1, 197, 12, 80))
+    # the flash kernel's lengths stay the flash kernel's
+    assert pallas_attention.supports((1, 512, 8, 64))
+    assert not pallas_attention.supports((1, 197, 12, 64))
+
+
+def _counts():
+    return {name: obsmetrics.counter(name).value for name in (
+        "attention.fused_calls.short", "attention.fused_calls.flash",
+        "attention.fused_calls.reference", "attention.flash_fallbacks")}
+
+
+def _delta(before):
+    return {k.rsplit(".", 1)[-1]: int(v - before[k])
+            for k, v in _counts().items()}
+
+
+def test_counters_say_which_implementation_each_trace_took(monkeypatch):
+    q, k, v, _ = _qkvw(VIT_TINY, jnp.bfloat16)
+    before = _counts()
+    full_attention(q, k, v, causal=False)            # the CPU: reference
+    assert _delta(before) == {"short": 0, "flash": 0, "reference": 1,
+                              "flash_fallbacks": 0}
+    before = _counts()
+    full_attention(q, k, v, causal=False, use_flash="require")
+    assert _delta(before) == {"short": 1, "flash": 0, "reference": 0,
+                              "flash_fallbacks": 0}
+    # as on a chip: the shape decides, and only what neither kernel takes
+    # counts as a fallback
+    monkeypatch.setattr(sequence, "_on_chip", lambda: True)
+    before = _counts()
+    full_attention(q, k, v, causal=True)
+    assert _delta(before) == {"short": 1, "flash": 0, "reference": 0,
+                              "flash_fallbacks": 0}
+    odd = _qkvw((1, 197, 2, 80), jnp.bfloat16)[:3]
+    before = _counts()
+    full_attention(*odd, causal=False)
+    assert _delta(before) == {"short": 0, "flash": 0, "reference": 1,
+                              "flash_fallbacks": 1}
+    with pytest.raises(ValueError, match="no fused kernel"):
+        full_attention(*odd, causal=False, use_flash="require")
+    long = _qkvw((1, 512, 2, 64), jnp.bfloat16)[:3]
+    before = _counts()
+    full_attention(*long, causal=True)
+    assert _delta(before) == {"short": 0, "flash": 1, "reference": 0,
+                              "flash_fallbacks": 0}
+
+
+def _tiny_vit(**kw):
+    return build_model("vit_tiny", num_classes=10, **kw)["module"]
+
+
+def test_vit_parameter_tree_is_flax_multi_head_attention(rng):
+    module = _tiny_vit()
+    x = jnp.asarray(rng.standard_normal((2, 32, 32, 3)), jnp.float32)
+    params = module.init(jax.random.PRNGKey(0), x)["params"]
+    attn = jax.tree_util.tree_map(lambda a: a.shape, params["block0"]["attn"])
+    head = {"kernel": (192, 3, 64), "bias": (3, 64)}
+    assert attn == {"query": head, "key": head, "value": head,
+                    "out": {"kernel": (3, 64, 192), "bias": (192,)}}
+
+
+def test_vit_default_path_equals_the_reference_hook(monkeypatch, rng):
+    """The same parameter tree through the new default (as on a chip: the
+    short kernel, 4 blocks) and through ``attention_fn=`` the reference:
+    logits and every parameter's gradient."""
+    x = jnp.asarray(rng.standard_normal((2, 32, 32, 3)), jnp.float32)
+    labels = jnp.asarray([1, 7])
+    default = _tiny_vit(dtype=jnp.float32)
+    hooked = _tiny_vit(dtype=jnp.float32, attention_fn=reference)
+    params = default.init(jax.random.PRNGKey(0), x)
+
+    def run(module):
+        def loss(p):
+            logits = module.apply(p, x)
+            return -jax.nn.log_softmax(logits)[jnp.arange(2), labels].mean()
+        return module.apply(params, x), jax.grad(loss)(params)
+
+    monkeypatch.setattr(sequence, "_on_chip", lambda: True)
+    before = _counts()
+    logits, grads = run(default)
+    # two traces (the logits, the gradient) of four blocks
+    assert _delta(before)["short"] == 8 and _delta(before)["reference"] == 0
+    before = _counts()
+    ref_logits, ref_grads = run(hooked)
+    assert _delta(before)["short"] == 0
+    assert _rel(logits, ref_logits) < 1e-5
+    flat, ref_flat = (jax.tree_util.tree_leaves_with_path(g)
+                      for g in (grads, ref_grads))
+    assert [p for p, _ in flat] == [p for p, _ in ref_flat]
+    # the key bias moves every score of a row alike, so its gradient is
+    # rounding noise on both sides: a leaf is held to the typical leaf's
+    # norm where its own is smaller
+    floor = float(np.median([np.linalg.norm(w) for _, w in ref_flat]))
+    for (path, got), (_, want) in zip(flat, ref_flat):
+        gap = np.linalg.norm(np.asarray(got) - np.asarray(want))
+        assert gap < 1e-4 * max(float(np.linalg.norm(want)), floor), \
+            jax.tree_util.keystr(path)
+
+
+def _data_mesh(devices):
+    return Mesh(np.array(devices), ("data",))
+
+
+def test_default_path_on_a_mesh_runs_on_each_devices_own_rows(monkeypatch):
+    """Under ``with mesh:`` the kernel call is shard_mapped over the batch
+    axis: the lowered program holds a manual computation over ``data``
+    and the compiled one no all-gather; a batch the mesh does not divide
+    takes the reference and is counted."""
+    monkeypatch.setattr(sequence, "_on_chip", lambda: True)
+    mesh = _data_mesh(jax.devices()[:4])
+    rows = NamedSharding(mesh, P("data"))
+    q, k, v, _ = (jax.device_put(x, rows) for x in _qkvw(
+        (8,) + VIT_TINY[1:], jnp.float32))
+    fn = jax.jit(functools.partial(full_attention, causal=False))
+    with mesh:
+        lowered = fn.lower(q, k, v)
+        out = fn(q, k, v)
+    assert re.search(r"manual_computation|shard_map", lowered.as_text())
+    assert "all-gather" not in lowered.compile().as_text()
+    assert out.sharding.is_equivalent_to(rows, out.ndim)
+    assert _rel(out, reference(q, k, v, causal=False)) < 2e-6
+    before = _counts()
+    with mesh:
+        jax.jit(functools.partial(full_attention, causal=False)).lower(
+            *(x[:6] for x in (q, k, v)))
+    assert _delta(before) == {"short": 0, "flash": 0, "reference": 1,
+                              "flash_fallbacks": 1}
+
+
+# -- the real lowering, compiled for a described v5e (no chip needed) -------
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    monkeypatch.setattr(sequence, "_on_chip", lambda: True)
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_mosaic_compiles_the_kernel_at_vit_b16_shape(topo, as_on_chip,
+                                                    causal):
+    from jax.sharding import SingleDeviceSharding
+    x = jax.ShapeDtypeStruct((128, 197, 12, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: (
+            full_attention(q, k, v, causal=causal).astype(jnp.float32)
+            * w).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(x, x, x, x).compile().as_text()
+    assert len(re.findall(r"%short_attention_fwd\S* = ", text)) == 1
+    assert len(re.findall(r"%short_attention_bwd\S* = ", text)) == 1
+    assert text.count("tpu_custom_call") == 2
+    assert "[128,12,197,197]" not in text
+
+
+def test_vit_step_on_four_chips_keeps_the_kernel_on_each_chips_rows(
+        topo, as_on_chip):
+    """``vit-b16-train-dp4``'s construction at ``vit_tiny`` size: the
+    module built with no ``attention_fn``, the step jitted under ``with
+    mesh:``. A bare Mosaic call there is refused or all-gathered."""
+    mesh = _data_mesh(topo.devices[:4])
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    module = _tiny_vit()
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3), jnp.bfloat16)))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        shapes)
+    images = jax.ShapeDtypeStruct((64, 32, 32, 3), jnp.bfloat16,
+                                  sharding=rows)
+
+    def step(p, x):
+        return jax.grad(lambda p: module.apply(p, x).astype(
+            jnp.float32).sum())(p)
+
+    with mesh:
+        text = jax.jit(step).lower(params, images).compile().as_text()
+    assert text.count("tpu_custom_call") == 8        # 4 blocks, fwd + bwd
+    assert "all-gather" not in text
+    assert re.search(r"bf16\[16,65,192\]\S* custom-call\(|"
+                     r"\(bf16\[16,65,192\]", text)    # 64 rows / 4 chips
