@@ -91,11 +91,17 @@ def generate_api_reference() -> str:
                           for n, t, d, doc in rows]
                 lines += [""]
 
-    from mmlspark_tpu.models.zoo import available_models
+    import inspect
+    from mmlspark_tpu.models.zoo import _ZOO, available_models
     lines += ["## Model zoo", ""]
     for name in available_models():
-        lines.append(f"- `{name}`")
+        doc = inspect.getdoc(_ZOO[name])
+        lines.append(f"- `{name}`" + (
+            ": " + " ".join(doc.split("\n\n")[0].split()) if doc else ""))
     lines.append("")
+    from mmlspark_tpu.parallel.trainer import DistributedTrainer
+    lines += ["## DistributedTrainer", "",
+              inspect.getdoc(DistributedTrainer), ""]
     return "\n".join(lines)
 
 

@@ -37,4 +37,5 @@ from mmlspark_tpu.models.zoo import cnn1d as _cnn1d  # noqa: E402,F401
 from mmlspark_tpu.models.zoo import vit as _vit  # noqa: E402,F401
 from mmlspark_tpu.models.zoo import transformer as _transformer  # noqa: E402,F401
 from mmlspark_tpu.models.zoo import moe as _moe  # noqa: E402,F401
+from mmlspark_tpu.models.zoo import decoder as _decoder  # noqa: E402,F401
 from mmlspark_tpu.embed import model as _recommender  # noqa: E402,F401

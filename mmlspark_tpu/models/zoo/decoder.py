@@ -1,0 +1,307 @@
+"""Decoder LMs built from parts, and the first family made of them:
+``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's).
+
+``PartsBlock`` is the pre-norm residual block with nothing fixed: its norm,
+its attention (which owns its projections and its positions) and its
+feed-forward layer are factories ``name -> nn.Module``. A new decoder family
+is a set of parts, not a third trunk beside ``TransformerLM`` (whose
+parameter names and tied head stay as they are for the generate lane).
+
+Parts here:
+
+- ``RMSNorm``: float32 in and out, epsilon from the configuration;
+- ``rotary``: rotary positions on the last axis, half-split pairing
+  (dimension ``i`` turns with ``i + R/2``), float32 angles;
+- ``MlaAttention``: multi-head latent attention in its expanded (training)
+  form: a low-rank query, one compressed key/value row per token, a rotary
+  slice on every query head and ONE rotary key shared by all heads;
+- ``SwiGluMlp``: ``down(silu(gate x) * up x)``, no biases;
+- ``zoo/moe.DroplessMoe``: the routed layer, told which experts it holds.
+
+Parameter names hit the rules of ``parallel/sharding.DEFAULT_RULES``
+(``attn_query_*`` / ``attn_key_value_*`` / ``attn_out``, ``mlp_gate`` /
+``mlp_up`` / ``mlp_down``, ``experts_*``, ``router``, ``lm_head``).
+
+Blocks are recomputed in the backward pass one by one (``nn.remat``): a
+block saves its input and nothing else, which is what lets 4,096-token rows
+train beside the optimizer's state on one chip.
+"""
+from __future__ import annotations
+
+from typing import Any, Callable, Dict, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from mmlspark_tpu.models.zoo import register_model
+from mmlspark_tpu.models.zoo.moe import DroplessMoe
+from mmlspark_tpu.parallel.sequence import full_attention
+
+_INIT = nn.initializers.normal(0.02)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions over the last axis of ``(B, L, H, R)``: position
+    ``l`` turns the pair ``(i, i + R/2)`` by ``l * theta**(-2i/R)``."""
+    L, R = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
+    ang = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def _dense(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    kernel_init=_INIT, name=name)
+
+
+class MlaAttention(nn.Module):
+    """Multi-head latent attention, expanded form. Query/key heads are
+    ``nope + rope`` wide and value heads ``v_dim``; the attention call is
+    the framework's ``(q, k, v, causal)`` on ``(B, L, H, D)``, so the two
+    have to be equally wide (they are, 256, in the published model)."""
+    dim: int
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v_dim: int
+    theta: float = 1e6
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        if self.nope + self.rope != self.v_dim:
+            raise ValueError(
+                f"query/key heads are {self.nope + self.rope} wide and "
+                f"value heads {self.v_dim}: attention_fn(q, k, v) takes "
+                "one head width")
+        B, L, _ = x.shape
+        H, dt = self.heads, self.dtype
+        attn_fn = self.attention_fn or full_attention
+        with jax.named_scope("mla_attention"):
+            x = x.astype(dt)
+            cq = RMSNorm(self.eps, name="query_norm")(
+                _dense(self.q_rank, dt, "attn_query_a")(x)).astype(dt)
+            q = _dense(H * (self.nope + self.rope), dt, "attn_query_b")(
+                cq).reshape(B, L, H, self.nope + self.rope)
+            kva = _dense(self.kv_rank + self.rope, dt, "attn_key_value_a")(x)
+            ckv = RMSNorm(self.eps, name="key_value_norm")(
+                kva[..., :self.kv_rank]).astype(dt)
+            kv = _dense(H * (self.nope + self.v_dim), dt,
+                        "attn_key_value_b")(ckv).reshape(
+                            B, L, H, self.nope + self.v_dim)
+            q_r = rotary(q[..., self.nope:], self.theta)
+            # the one rotary key, shared by every head
+            k_r = rotary(kva[..., None, self.kv_rank:], self.theta)
+            q = jnp.concatenate([q[..., :self.nope], q_r], -1)
+            k = jnp.concatenate(
+                [kv[..., :self.nope],
+                 jnp.broadcast_to(k_r, (B, L, H, self.rope))], -1)
+            o = attn_fn(q, k, kv[..., self.nope:], causal=True)
+            return _dense(self.dim, dt, "attn_out")(
+                o.reshape(B, L, H * self.v_dim))
+
+
+class SwiGluMlp(nn.Module):
+    dim: int
+    hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        x = x.astype(self.dtype)
+        h = nn.silu(_dense(self.hidden, self.dtype, "mlp_gate")(x)) \
+            * _dense(self.hidden, self.dtype, "mlp_up")(x)
+        return _dense(self.dim, self.dtype, "mlp_down")(h)
+
+
+class PartsBlock(nn.Module):
+    """``h = x + attention(norm(x))``, ``y = h + ffn(norm(h))``. A
+    feed-forward part may return ``(y, stats)``, ``stats`` a dict of
+    scalars (a routed layer's load); the block returns ``(y, stats)``
+    always."""
+    norm: Callable[[str], nn.Module]
+    attention: Callable[[str], nn.Module]
+    ffn: Callable[[str], nn.Module]
+
+    @nn.compact
+    def __call__(self, x) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        h = x + self.attention("attn")(self.norm("norm1")(x)).astype(x.dtype)
+        out = self.ffn("ffn")(self.norm("norm2")(h))
+        y, stats = out if isinstance(out, tuple) else (out, {})
+        return h + y.astype(x.dtype), stats
+
+
+class Head(nn.Module):
+    """The untied output head; the chunked loss reads ``kernel`` itself
+    (``train/lm_loss.py``) and never calls this on a whole batch."""
+    vocab: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", _INIT, (x.shape[-1], self.vocab),
+                            jnp.float32)
+        return jnp.dot(x.astype(jnp.float32), kernel)
+
+
+class Glm4MoeLite(nn.Module):
+    """``glm4_moe_lite``: ``dense_layers`` SwiGLU blocks, then routed
+    blocks up to ``depth``, all with latent attention; one multi-token-
+    prediction module (DeepSeek-V3 section 2.2) when ``mtp`` is set.
+
+    ``__call__(tokens)`` gives ``(B, L, vocab)`` float32 logits of the
+    main head. ``__call__(tokens, hidden=True)`` gives what the chunked
+    loss wants instead: ``{"hidden", "mtp_hidden", "stats"}``, the normed
+    rows each head reads and the routed layers' load
+    (``moe.slots_here`` summed over them, ``moe.load_max_over_mean`` of
+    the worst).
+    """
+    vocab: int
+    dim: int
+    depth: int
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v_dim: int
+    mlp_hidden: int
+    expert_hidden: int
+    num_experts: int
+    top_k: int
+    experts_held: Optional[Tuple[int, int]] = None   # (count, first index)
+    shared_experts: int = 1
+    scaling: float = 1.0
+    dense_layers: int = 1
+    mtp: bool = True
+    theta: float = 1e6
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    def _block(self, routed: bool, name: str) -> nn.Module:
+        dt = self.dtype
+
+        def attention(n):
+            return MlaAttention(
+                self.dim, self.heads, self.q_rank, self.kv_rank, self.nope,
+                self.rope, self.v_dim, self.theta, self.eps, dt,
+                self.attention_fn, name=n)
+
+        def ffn(n):
+            if not routed:
+                return SwiGluMlp(self.dim, self.mlp_hidden, dt, name=n)
+            return DroplessMoe(
+                self.dim, self.num_experts, self.expert_hidden, self.top_k,
+                experts_held=self.experts_held, scaling=self.scaling,
+                shared=(lambda m: SwiGluMlp(
+                    self.dim, self.shared_experts * self.expert_hidden, dt,
+                    name=m)) if self.shared_experts else None,
+                dtype=dt, name=n)
+
+        # recomputed in the backward pass: a block saves its input alone
+        return nn.remat(PartsBlock)(
+            lambda n: RMSNorm(self.eps, name=n), attention, ffn, name=name)
+
+    @nn.compact
+    def __call__(self, tokens, hidden: bool = False):
+        embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype,
+                         embedding_init=_INIT, name="token_embedding")
+        final_norm = RMSNorm(self.eps, name="final_norm")
+        head = Head(self.vocab, name="lm_head")
+        x = embed(tokens)
+        loads = []
+        for i in range(self.depth):
+            x, stats = self._block(i >= self.dense_layers, f"block{i}")(x)
+            loads.append(stats)
+        out = {"hidden": final_norm(x)}
+        self.sow("intermediates", "hidden", out["hidden"])
+        if self.mtp:
+            with jax.named_scope("mtp"):
+                # row i joins the trunk's state with token i + 1 and
+                # predicts token i + 2; the last row joins a wrapped token
+                # that no earlier row can see (causal) and no loss counts
+                nxt = embed(jnp.roll(tokens, -1, axis=1))
+                z = jnp.concatenate(
+                    [RMSNorm(self.eps, name="mtp_hnorm")(x),
+                     RMSNorm(self.eps, name="mtp_enorm")(nxt)], -1)
+                z = _dense(self.dim, self.dtype, "mtp_eh_proj")(
+                    z.astype(self.dtype))
+                z, stats = self._block(True, "mtp_block")(z)
+                loads.append(stats)
+                out["mtp_hidden"] = final_norm(z)
+        if not hidden:
+            return head(out["hidden"])
+        if self.is_initializing():
+            head(out["hidden"][:, :1])
+        loads = [s for s in loads if s]
+        out["stats"] = {
+            "moe.slots_here": sum(
+                s["slots_here"] for s in loads).astype(jnp.float32),
+            "moe.load_max_over_mean": jnp.max(jnp.stack(
+                [s["load_max_over_mean"] for s in loads])),
+        } if loads else {}
+        return out
+
+
+def _spec(module: Glm4MoeLite, max_len: int):
+    return dict(
+        module=module, input_shape=(max_len,), input_dtype="int32",
+        feature_layer="hidden", feature_dim=module.dim,
+        layer_names=["hidden", "logits"],
+        # blocks use the (q, k, v, causal) attention contract
+        seq_attention=True)
+
+
+@register_model("glm4_moe_lite")
+def glm4_moe_lite(vocab: int = 154880, dim: int = 2048, depth: int = 47,
+                  heads: int = 20, q_rank: int = 768, kv_rank: int = 512,
+                  nope: int = 192, rope: int = 64, v_dim: int = 256,
+                  mlp_hidden: int = 10240, expert_hidden: int = 1536,
+                  num_experts: int = 64, top_k: int = 4,
+                  experts_held=None, shared_experts: int = 1,
+                  scaling: float = 1.8, dense_layers: int = 1,
+                  mtp: bool = True, theta: float = 1e6, eps: float = 1e-5,
+                  max_len: int = 4096, dtype=jnp.bfloat16,
+                  attention_fn=None):
+    """GLM-4.7-Flash as published (huggingface.co/zai-org/GLM-4.7-Flash
+    ``config.json``, ``model_type: glm4_moe_lite``). ``experts_held`` =
+    ``(count, first)`` is this chip's share of each routed layer: the
+    router still scores all ``num_experts``."""
+    held = None if experts_held is None else tuple(experts_held)
+    return _spec(Glm4MoeLite(
+        vocab, dim, depth, heads, q_rank, kv_rank, nope, rope, v_dim,
+        mlp_hidden, expert_hidden, num_experts, top_k, held,
+        shared_experts, scaling, dense_layers, mtp, theta, eps, dtype,
+        attention_fn), max_len)
+
+
+_TINY = dict(vocab=96, dim=32, depth=3, heads=2, q_rank=24, kv_rank=16,
+             nope=12, rope=4, v_dim=16, mlp_hidden=64, expert_hidden=16,
+             num_experts=8, top_k=2, max_len=64, dtype=jnp.float32)
+
+
+@register_model("glm4_moe_lite_tiny")
+def glm4_moe_lite_tiny(**overrides):
+    """Test-scale ``glm4_moe_lite`` (float32, so CPU parity is tight)."""
+    return glm4_moe_lite(**{**_TINY, **overrides})

@@ -22,13 +22,14 @@ tensor-parallel projections, expert-parallel FFNs, all in one jitted step.
 from __future__ import annotations
 
 import math
-from typing import Any, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.models.zoo import register_model
+from mmlspark_tpu.observability import metrics as obsmetrics
 
 
 class MoeMlp(nn.Module):
@@ -98,6 +99,150 @@ class MoeMlp(nn.Module):
             aux = E * jnp.sum(frac_routed * mean_prob)
             self.sow("losses", "moe_aux", aux)
         return y.reshape(B, L, D)
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing over the experts held here.
+#
+# Token-slots (token, choice) are sorted by expert, the held experts' rows
+# lying first and group by group, and gate/up/down run as grouped matrix
+# products over those groups (``jax.lax.ragged_dot``; on a TPU XLA lowers it
+# to a grouped Mosaic matmul that walks only the tiles the groups cover, so
+# the cost follows the slots routed here, not the S*K rows the buffers are
+# sized for). Both moves between token order and expert order are gathers,
+# forward and backward: a sort is a permutation, so the transpose of a
+# gather by ``order`` is a gather by its inverse, and no scatter runs.
+
+@jax.custom_vjp
+def _to_expert_order(x, order, inverse):
+    """``(S, D)`` rows -> ``(S*K, D)``: row ``r`` is the token of slot
+    ``order[r]`` (slot ``s*K + k`` is token ``s``'s choice ``k``)."""
+    return x[order // (order.shape[0] // x.shape[0])]
+
+
+def _to_expert_order_fwd(x, order, inverse):
+    return _to_expert_order(x, order, inverse), (x.shape[0], inverse)
+
+
+def _to_expert_order_bwd(res, g):
+    tokens, inverse = res
+    return (g[inverse].reshape(tokens, -1, g.shape[-1]).sum(1), None, None)
+
+
+_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+
+
+@jax.custom_vjp
+def _to_slot_order(y, order, inverse):
+    """``(S*K, D)`` rows in expert order -> slot order."""
+    return y[inverse]
+
+
+def _to_slot_order_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _to_slot_order_bwd(order, g):
+    return g[order], None, None
+
+
+_to_slot_order.defvjp(_to_slot_order_fwd, _to_slot_order_bwd)
+
+
+def _grouped(x, w, sizes):
+    obsmetrics.counter("moe.grouped_calls.ragged_dot").inc()
+    return jax.lax.ragged_dot(x, w, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+class DroplessMoe(nn.Module):
+    """Sigmoid-routed experts with a shared expert, no capacity and no
+    dropped token (DeepSeek-V3's layer; ``topk_method: noaux_tc`` with one
+    group).
+
+    ``s = sigmoid(x W_r)`` in float32 over ALL ``num_experts``; the choice
+    is the top ``top_k`` of ``s + b`` (``router_bias``: no gradient reaches
+    it, it only moves the choice); the weights are ``s`` at the chosen,
+    normalised to 1, times ``scaling``. ``experts_held = (count, first)``
+    says which experts' weights live here: the layer computes their part of
+    the sum, and a slot whose expert is held elsewhere adds nothing (its
+    part is that chip's to add). ``shared(name)`` makes the shared expert, a
+    dense feed-forward part every chip computes alike.
+
+    Returns ``(y, stats)``: ``slots_here`` (slots routed to held experts)
+    and ``load_max_over_mean`` (the fullest held expert over their mean).
+    Sows the choice under ``("intermediates", "router_choice")``.
+    """
+    dim: int
+    num_experts: int
+    expert_hidden: int
+    top_k: int
+    experts_held: Optional[Tuple[int, int]] = None
+    scaling: float = 1.0
+    shared: Optional[Callable[[str], nn.Module]] = None
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, D = x.shape
+        E, K, H = self.num_experts, self.top_k, self.expert_hidden
+        held, first = self.experts_held or (E, 0)
+        if not (0 <= first and first + held <= E):
+            raise ValueError(f"experts_held {(held, first)} of {E} experts")
+        S = B * L
+        xf = x.reshape(S, D)
+        init = nn.initializers.normal(0.02)
+
+        with jax.named_scope("moe_router"):
+            s = jax.nn.sigmoid(nn.Dense(
+                E, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32, kernel_init=init, name="router")(
+                    xf.astype(jnp.float32)))
+            bias = self.param("router_bias", nn.initializers.zeros, (E,),
+                              jnp.float32)
+            _, choice = jax.lax.top_k(s + bias, K)   # indices: no gradient
+            gate = jnp.take_along_axis(s, choice, axis=-1)
+            gate = gate / gate.sum(-1, keepdims=True) * self.scaling
+            self.sow("intermediates", "router_choice", choice)
+
+        with jax.named_scope("moe_dispatch"):
+            local = choice - first
+            here = (local >= 0) & (local < held)
+            # expert order: held experts by index, then everything else
+            key = jnp.where(here, local, held).reshape(S * K)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            inverse = jnp.zeros_like(order).at[order].set(
+                jnp.arange(S * K, dtype=jnp.int32))
+            sizes = jnp.bincount(key, length=held + 1)[:held].astype(
+                jnp.int32)
+            slots_here = sizes.sum()
+            computed = (jnp.arange(S * K) < slots_here)[:, None]
+            xs = jnp.where(computed, _to_expert_order(
+                xf.astype(self.dtype), order, inverse), 0)
+
+        with jax.named_scope("moe_experts"):
+            w_gate, w_up, w_down = (
+                self.param(name, init, shape, jnp.float32).astype(self.dtype)
+                for name, shape in (("experts_gate", (held, D, H)),
+                                    ("experts_up", (held, D, H)),
+                                    ("experts_down", (held, H, D))))
+            h = nn.silu(_grouped(xs, w_gate, sizes)) \
+                * _grouped(xs, w_up, sizes)
+            ys = _grouped(h.astype(self.dtype), w_down, sizes)
+
+        with jax.named_scope("moe_combine"):
+            # rows past the groups hold whatever the product left there
+            ys = _to_slot_order(jnp.where(computed, ys, 0), order, inverse)
+            y = jnp.einsum("skd,sk->sd", ys.reshape(S, K, D),
+                           jnp.where(here, gate, 0.0))
+            if self.shared is not None:
+                y = y + self.shared("shared")(xf).astype(jnp.float32)
+
+        load = sizes.astype(jnp.float32)
+        stats = {"slots_here": slots_here,
+                 "load_max_over_mean": load.max() / jnp.maximum(
+                     load.mean(), 1e-9)}
+        return y.reshape(B, L, D).astype(self.dtype), stats
 
 
 def _moe_lm(vocab, dim, depth, heads, max_len, num_experts, top_k,

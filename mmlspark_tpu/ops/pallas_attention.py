@@ -242,7 +242,10 @@ def _flash_bwd_impl(q, k, v, out, do, causal, block_k):
 
 def _flash_bwd_rule(causal, block_q, block_k, res, do):
     q, k, v, out = res
-    return _flash_bwd_impl(q, k, v, out, do, causal, block_k)
+    # metadata only: XLA names the scans `%while.N`, so the scope is what a
+    # reader joins instruction names against to find this backward's time
+    with jax.named_scope("flash_attention_bwd"):
+        return _flash_bwd_impl(q, k, v, out, do, causal, block_k)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -213,11 +213,38 @@ class DeviceEpochCache:
         yield from self._batches
 
 
+class _AuxKeys(Exception):
+    """Raised inside the first trace of a step whose loss reports scalars
+    beside itself: their keys, which the metrics ring has no slots for
+    yet. ``train_step`` catches it, makes the ring and traces again."""
+
+    def __init__(self, keys: Tuple[str, ...]):
+        super().__init__(keys)
+        self.keys = keys
+
+
 class DistributedTrainer:
     """Builds sharded init/train/eval steps for a pure loss function.
 
     loss_fn(params, batch, rng) -> scalar loss (fp32). The whole step —
     forward, backward, allreduce, optimizer — compiles to one XLA program.
+
+    ``loss_fn`` may also return ``(loss, aux)``, ``aux`` a flat dict of
+    float32 scalars (the parts of a sum, a routed layer's load). Each
+    rides the device-resident ring beside ``loss`` (no host sync), comes
+    back from ``train_step`` / ``flush_metrics`` under its key, and
+    ``flush_metrics`` publishes its newest value as a gauge of that name.
+    The keys are found in the first trace of the step: it stops after the
+    forward pass, the ring gains a slot per key, and the step is traced
+    again (one more trace of the forward pass, nothing compiled or run).
+    A loss that returns a bare scalar is traced once, into the step
+    program it always ran.
+
+    ``remat=True`` wraps the WHOLE loss in ``jax.checkpoint``: the
+    backward pass then recomputes the forward once and still holds every
+    activation while it runs, so no memory is saved. Recompute per block
+    inside the model instead (``nn.remat`` on the block, as
+    ``models/zoo/decoder.py`` does).
     """
 
     def __init__(self, loss_fn: LossFn, optimizer: optax.GradientTransformation,
@@ -249,6 +276,9 @@ class DistributedTrainer:
         # collective rendezvous can starve under hundreds of queued async
         # steps — real TPU runtimes bound their own launch queue).
         self._ring: Optional[Dict[str, jax.Array]] = None
+        # keys of the scalars the loss reports beside itself, found in the
+        # first trace of the step (``_AuxKeys``)
+        self._aux_names: Tuple[str, ...] = ()
         self._flush_steps: Optional[int] = None  # resolved at first step
         self._steps_since_flush = 0
         self._throttled = is_cpu_mesh(self.mesh)
@@ -305,16 +335,16 @@ class DistributedTrainer:
 
     def _init_ring(self) -> Dict[str, jax.Array]:
         """Fresh device-resident metrics ring: a ``flush_steps``-long loss
-        ring plus the step counter of the latest step written. Replicated
-        on purpose — every process flushes identical values under SPMD."""
+        ring (and one per aux scalar of the loss) plus the step counter of
+        the latest step written. Replicated on purpose — every process
+        flushes identical values under SPMD."""
         flush = self.flush_steps()
         repl = replicated(self.mesh)
         with self.mesh:
-            return {
-                "loss": jax.device_put(
-                    np.zeros((flush,), np.float32), repl),
-                "step": jax.device_put(np.zeros((), np.int32), repl),
-            }
+            ring = {name: jax.device_put(np.zeros((flush,), np.float32), repl)
+                    for name in ("loss",) + self._aux_names}
+            ring["step"] = jax.device_put(np.zeros((), np.int32), repl)
+            return ring
 
     def _build_train_step(self, donate_batch: bool):
         loss_fn = self.loss_fn
@@ -323,13 +353,29 @@ class DistributedTrainer:
         accum = self.accum_steps
         flush = self.flush_steps()
 
+        def loss_and_aux(params, batch, rng):
+            out = loss_fn(params, batch, rng)
+            loss, aux = out if isinstance(out, tuple) else (out, {})
+            if not isinstance(aux, dict):
+                raise TypeError("loss_fn has to return a loss or (loss, "
+                                "aux), aux a flat dict of scalars")
+            aux = {k: jnp.asarray(v, jnp.float32) for k, v in aux.items()}
+            if tuple(aux) != self._aux_names:
+                if any(v.shape != () for v in aux.values()):
+                    raise TypeError("aux has to be a flat dict of scalars")
+                if {"loss", "step"} & set(aux):
+                    raise ValueError("aux may not hold 'loss' or 'step': "
+                                     "the ring holds those itself")
+                raise _AuxKeys(tuple(aux))  # the ring has no room for them
+            return loss, aux
+
         # The named scopes are metadata only (no operation changes): stable
         # names for the step's phases in xprof/TensorBoard, and the seam for
         # a split of device time by phase (PERF.md, Open questions).
         def single_grad(params, batch, rng):
             with jax.named_scope("loss_and_grad"):
-                loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
-            return loss, grads
+                return jax.value_and_grad(loss_and_aux, has_aux=True)(
+                    params, batch, rng)
 
         def step(state, ring, batch, rng):
             params = state["params"]
@@ -339,22 +385,24 @@ class DistributedTrainer:
                 # one weight update per `accum` forward/backward passes
                 def micro(carry, mb_and_idx):
                     mb, idx = mb_and_idx
-                    loss_acc, grad_acc = carry
+                    acc, grad_acc = carry
                     # distinct rng per microbatch (dropout must differ)
-                    loss, grads = single_grad(params, mb,
-                                              jax.random.fold_in(rng, idx))
-                    return (loss_acc + loss,
+                    scalars, grads = single_grad(
+                        params, mb, jax.random.fold_in(rng, idx))
+                    return (jax.tree_util.tree_map(jnp.add, acc, scalars),
                             jax.tree_util.tree_map(jnp.add, grad_acc, grads)), None
                 microbatches = jax.tree_util.tree_map(
                     lambda x: x.reshape((accum, x.shape[0] // accum) + x.shape[1:]),
                     batch)
                 zero = jax.tree_util.tree_map(jnp.zeros_like, params)
-                (loss, grads), _ = jax.lax.scan(
-                    micro, (0.0, zero), (microbatches, jnp.arange(accum)))
+                ((loss, aux), grads), _ = jax.lax.scan(
+                    micro, ((0.0, {k: 0.0 for k in self._aux_names}), zero),
+                    (microbatches, jnp.arange(accum)))
                 loss = loss / accum
+                aux = {k: v / accum for k, v in aux.items()}
                 grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
             else:
-                loss, grads = single_grad(params, batch, rng)
+                (loss, aux), grads = single_grad(params, batch, rng)
             with jax.named_scope("optimizer_update"):
                 updates, opt_state = self.optimizer.update(
                     grads, state["opt_state"], params)
@@ -365,10 +413,12 @@ class DistributedTrainer:
             # device — no per-step host traffic; the host reads the whole
             # ring once per flush interval
             with jax.named_scope("metrics_ring"):
-                new_ring = {"loss": ring["loss"].at[
-                    jnp.mod(state["step"], flush)].set(loss),
-                    "step": new_state["step"]}
-            return new_state, new_ring, {"loss": loss}
+                scalars = {"loss": loss, **aux}
+                slot = jnp.mod(state["step"], flush)
+                new_ring = {k: ring[k].at[slot].set(v)
+                            for k, v in scalars.items()}
+                new_ring["step"] = new_state["step"]
+            return new_state, new_ring, scalars
 
         # Batch shardings are NOT pinned here: put_batch commits per-leaf
         # shardings (rank-aware — labels are rank-1, activations rank-N) and
@@ -380,11 +430,10 @@ class DistributedTrainer:
         # shardings), where each put_batch transfer is single-use — donating
         # it stops the step from double-buffering its inputs. Reused device
         # batches (DeviceEpochCache epochs) take the non-donating variant.
-        ring_shardings = {"loss": replicated(self.mesh),
-                          "step": replicated(self.mesh)}
         return jax.jit(
             step,
-            out_shardings=(self._state_shardings, ring_shardings, None),
+            out_shardings=(self._state_shardings, replicated(self.mesh),
+                           None),
             donate_argnums=(0, 1, 2) if donate_batch else (0, 1))
 
     def _get_train_step(self, donate_batch: bool):
@@ -416,6 +465,17 @@ class DistributedTrainer:
         dispatch = self._hot(
             "trainer", "dispatch", step=self._dispatched,
             donate=donate_batch) if self._hot else obsspans.NOOP
+
+        def call():
+            try:
+                return fn(state, self._ring, batch, rng)
+            except _AuxKeys as found:
+                # only a trace has run (nothing was donated): give the ring
+                # a slot per scalar the loss reports, and trace again
+                self._aux_names = found.keys
+                self._ring = self._init_ring()
+                return fn(state, self._ring, batch, rng)
+
         with self.mesh:
             if donate_batch:
                 # batch donation is best-effort: leaves whose buffers cannot
@@ -428,12 +488,10 @@ class DistributedTrainer:
                         "ignore",
                         message="Some donated buffers were not usable")
                     with dispatch:
-                        new_state, self._ring, metrics = fn(
-                            state, self._ring, batch, rng)
+                        new_state, self._ring, metrics = call()
             else:
                 with dispatch:
-                    new_state, self._ring, metrics = fn(
-                        state, self._ring, batch, rng)
+                    new_state, self._ring, metrics = call()
         self._dispatched += 1
         self._steps_dispatched.inc()
         # Steady state performs ZERO host syncs: the only wait is the ring
@@ -451,22 +509,31 @@ class DistributedTrainer:
         """Fetch the device metrics ring: ONE counted host sync
         (``trainer.flush``) retiring every step dispatched since the last
         flush. Returns ``{"loss": (flush_steps,) float32, "step": int32}``
-        host values, or None when no step has run. Callers that want
-        periodic loss telemetry WITHOUT per-step syncs read it here."""
+        host values (and a ring per aux scalar), or None when no step
+        has run. Callers that want periodic loss telemetry WITHOUT
+        per-step syncs read it here. The newest value of each aux scalar
+        is published as a gauge of its name."""
         if self._ring is None:
             return None
         with (self._hot("trainer", "flush", steps=self._steps_since_flush)
               if self._hot else obsspans.NOOP):
             vals = obssyncs.device_get(self._ring, "trainer.flush")
         self._steps_since_flush = 0
-        return {k: np.asarray(v) for k, v in vals.items()}
+        out = {k: np.asarray(v) for k, v in vals.items()}
+        if self._aux_names and int(out["step"]) > 0:
+            newest = (int(out["step"]) - 1) % self.flush_steps()
+            for name in self._aux_names:
+                obsmetrics.gauge(name).set(float(out[name][newest]))
+        return out
 
     def eval_step(self, state, batch, rng) -> jax.Array:
         if self._state_shardings is None:
             raise RuntimeError("call init() before eval_step()")
         if self._eval_step is None:
-            self._eval_step = jax.jit(
-                lambda params, batch, rng: self.loss_fn(params, batch, rng))
+            def loss_alone(params, batch, rng):
+                out = self.loss_fn(params, batch, rng)
+                return out[0] if isinstance(out, tuple) else out
+            self._eval_step = jax.jit(loss_alone)
         with self.mesh:
             return self._eval_step(state["params"], batch, rng)
 

@@ -1,0 +1,87 @@
+"""Next-token loss of a decoder LM without the whole logits in memory.
+
+``(tokens, vocab)`` float32 logits and their gradient are the largest
+arrays of an LM step (8,192 x 19,360 x 4 B = 634 MB each, per head) and
+nothing needs them at once: ``chunked_cross_entropy`` walks the rows in
+chunks, and each chunk's logits are recomputed in the backward pass
+(``jax.checkpoint``), so one chunk's worth lives at a time.
+
+``next_token_loss`` puts the targets in place: the main head's row ``i``
+predicts token ``i + 1``; a multi-token-prediction head's row ``i``
+(DeepSeek-V3 section 2.2, depth 1) predicts token ``i + 2`` through the
+same output matrix. Rows that have no target count nothing. No document
+mask: a packed row is one sequence.
+"""
+from __future__ import annotations
+
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+def chunked_cross_entropy(hidden: jax.Array, kernel: jax.Array,
+                          targets: jax.Array, weights: jax.Array,
+                          chunk: int = 2048,
+                          dtype=jnp.bfloat16) -> jax.Array:
+    """Sum over rows of ``weights * -log softmax(hidden @ kernel)[target]``.
+
+    ``hidden`` (N, D), ``kernel`` (D, V), ``targets`` and ``weights`` (N,).
+    The product runs in ``dtype`` with float32 accumulation; the softmax
+    and the sum are float32.
+    """
+    n = hidden.shape[0]
+    chunk = min(chunk, n)
+    pad = -n % chunk
+    if pad:
+        hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
+        targets = jnp.pad(targets, (0, pad))
+        weights = jnp.pad(weights, (0, pad))
+    w = kernel.astype(dtype)
+
+    @jax.checkpoint
+    def one(h, t, m):
+        logits = jnp.dot(h.astype(dtype), w,
+                         preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * m)
+
+    def body(total, xs):
+        return total + one(*xs), None
+
+    rows = (hidden.reshape(-1, chunk, hidden.shape[-1]),
+            targets.reshape(-1, chunk), weights.reshape(-1, chunk))
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), rows)
+    return total
+
+
+def next_token_loss(out: Dict[str, jax.Array], kernel: jax.Array,
+                    tokens: jax.Array, *, mtp_weight: float = 0.3,
+                    chunk: int = 2048, dtype=jnp.bfloat16
+                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``(loss, {"loss.main", "loss.mtp"})`` from a model's
+    ``{"hidden", "mtp_hidden"?}`` rows (``(B, L, D)``, already normed),
+    the output matrix and the ``(B, L)`` tokens they came from; ``loss =
+    main + mtp_weight * mtp``, each a mean over the rows that have a
+    target."""
+    B, L = tokens.shape
+    pos = jnp.arange(L)
+
+    def head(hidden, ahead):
+        with jax.named_scope("lm_loss"):
+            valid = jnp.broadcast_to(pos < L - ahead, (B, L))
+            total = chunked_cross_entropy(
+                hidden.reshape(B * L, -1), kernel,
+                jnp.roll(tokens, -ahead, axis=1).reshape(B * L),
+                valid.reshape(B * L).astype(jnp.float32), chunk, dtype)
+            return total / (B * (L - ahead))
+
+    main = head(out["hidden"], 1)
+    mtp: Optional[jax.Array] = None
+    if "mtp_hidden" in out:
+        mtp = head(out["mtp_hidden"], 2)
+    loss = main if mtp is None else main + mtp_weight * mtp
+    aux = {"loss.main": main}
+    if mtp is not None:
+        aux["loss.mtp"] = mtp
+    return loss, aux

@@ -1,0 +1,427 @@
+"""``glm4_moe_lite`` at its tiny preset against the plain reference
+(``benchmark/references/glm47_flash.py``), float32 on the CPU.
+
+Tolerances: both sides compute in float32 on one backend, so they differ
+only by the order of additions (the program's grouped products and chunked
+loss against the reference's dense loops): 1e-5 relative on logits and
+losses, 1e-4 on gradients (sums over 32 tokens and up to 96 features of
+products of four such numbers), 2e-3 of the largest element on Adam steps
+(``g / (sqrt(v) + eps)`` amplifies a relative gradient error where ``g`` is
+near zero).
+"""
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+from benchmark.references import glm47_flash as ref  # noqa: E402
+from mmlspark_tpu.models.zoo import build_model  # noqa: E402
+from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
+    MlaAttention, SwiGluMlp, rotary)
+from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
+from mmlspark_tpu.train.lm_loss import (  # noqa: E402
+    chunked_cross_entropy, next_token_loss)
+
+CFG = dict(hidden_size=32, num_attention_heads=2, q_lora_rank=24,
+           kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4,
+           v_head_dim=16, intermediate_size=64, moe_intermediate_size=16,
+           n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2,
+           routed_scaling_factor=1.8, num_hidden_layers=3,
+           first_k_dense_replace=1, num_nextn_predict_layers=1,
+           vocab_size=96, rms_norm_eps=1e-5, rope_theta=1e6,
+           deployment={"n_routed_experts_published": 8, "experts_first": 0})
+OPT = dict(learning_rate=1e-2, beta1=0.9, beta2=0.95, eps=1e-8,
+           weight_decay=0.1)
+ROWS, LEN = 2, 16
+
+
+def _held(count, first=0):
+    return dict(CFG, n_routed_experts=count, deployment={
+        "n_routed_experts_published": 8, "experts_first": first})
+
+
+def _tokens(seed, steps=1):
+    return np.random.default_rng(seed).integers(
+        0, CFG["vocab_size"], size=(steps, ROWS, LEN)).astype(np.int32)
+
+
+def _module(cfg=CFG, **kw):
+    d = cfg["deployment"]
+    return build_model(
+        "glm4_moe_lite_tiny",
+        experts_held=(cfg["n_routed_experts"], d["experts_first"]),
+        **kw)["module"]
+
+
+def _loss_fn(module, chunk=8):
+    def loss_fn(params, batch, rng):
+        out = module.apply(params, batch["tokens"], hidden=True)
+        loss, aux = next_token_loss(
+            out, params["params"]["lm_head"]["kernel"], batch["tokens"],
+            mtp_weight=ref.MTP_WEIGHT, chunk=chunk, dtype=jnp.float32)
+        return loss, {**aux, **out["stats"]}
+    return loss_fn
+
+
+@pytest.fixture(scope="module")
+def params():
+    return ref.init_params(CFG, jax.random.PRNGKey(7))
+
+
+def test_reference_tree_is_the_programs_tree(params):
+    module = _module()
+    own = module.init(jax.random.PRNGKey(0), jnp.zeros((1, LEN), jnp.int32))
+    shapes = lambda t: jax.tree_util.tree_map(lambda x: x.shape, t)
+    assert shapes(own) == shapes(params)
+
+
+def test_logits_match_the_reference(params):
+    tokens = _tokens(1)[0]
+    got = _module().apply(params, jnp.asarray(tokens))
+    assert got.shape == (ROWS, LEN, CFG["vocab_size"])
+    assert got.dtype == jnp.float32
+    for b in range(ROWS):
+        want = ref.logits(CFG, params, jnp.asarray(tokens[b]))
+        np.testing.assert_allclose(got[b], want, rtol=1e-5, atol=1e-6)
+
+
+def test_losses_and_gradients_match_the_reference(params):
+    tokens = _tokens(2)[0]
+    (loss, aux), grads = jax.value_and_grad(
+        _loss_fn(_module()), has_aux=True)(
+            params, {"tokens": jnp.asarray(tokens)}, None)
+    want_loss = want_main = want_mtp = 0.0
+    want = None
+    for b in range(ROWS):
+        (part, (main, mtp, _)), g = jax.value_and_grad(
+            lambda p: ref.sequence_loss(CFG, None, ROWS, p,
+                                        jnp.asarray(tokens[b])),
+            has_aux=True)(params)
+        want_loss, want_main, want_mtp = (
+            want_loss + part, want_main + main, want_mtp + mtp)
+        want = g if want is None else jax.tree_util.tree_map(
+            jnp.add, want, g)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    np.testing.assert_allclose(aux["loss.main"], want_main, rtol=1e-5)
+    np.testing.assert_allclose(aux["loss.mtp"], want_mtp, rtol=1e-5)
+    np.testing.assert_allclose(loss, want_main + 0.3 * want_mtp, rtol=1e-5)
+    flat = jax.tree_util.tree_leaves_with_path(want)
+    got = dict(jax.tree_util.tree_leaves_with_path(grads))
+    for path, w in flat:
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(
+            got[path], w, rtol=1e-4, atol=1e-4 * scale + 1e-9,
+            err_msg=jax.tree_util.keystr(path))
+    # no gradient reaches the router's bias, in either
+    bias = [g for p, g in got.items() if "router_bias" in
+            jax.tree_util.keystr(p)]
+    assert len(bias) == 3 and all(not np.any(np.asarray(b)) for b in bias)
+
+
+def test_three_adamw_steps_match_the_reference():
+    from mmlspark_tpu.parallel.mesh import mesh_from_config
+    from mmlspark_tpu.parallel.trainer import DistributedTrainer
+    seed, tokens = 11, _tokens(3, steps=3)
+    want = ref.train_reference(CFG, seed, tokens, steps=3, optimizer=OPT)
+    trainer = DistributedTrainer(
+        _loss_fn(_module()),
+        optax.adamw(OPT["learning_rate"], b1=OPT["beta1"], b2=OPT["beta2"],
+                    eps=OPT["eps"], weight_decay=OPT["weight_decay"],
+                    mask=lambda p: jax.tree_util.tree_map(
+                        lambda x: x.ndim >= 2, p)),
+        mesh=mesh_from_config(jax.devices()[:1]))
+    key = jax.random.PRNGKey(seed)
+    state = trainer.init(lambda: ref.init_params(CFG, key))
+    start = jax.tree_util.tree_map(np.asarray, state["params"])
+    for s in range(3):
+        state, m = trainer.train_step(
+            state, trainer.put_batch({"tokens": tokens[s]}),
+            jax.random.PRNGKey(0))
+        np.testing.assert_allclose(m["loss"], want["losses"][s], rtol=1e-5)
+        np.testing.assert_allclose(m["loss.main"], want["main"][s],
+                                   rtol=1e-5)
+        np.testing.assert_allclose(m["loss.mtp"], want["mtp"][s], rtol=1e-5)
+        if s == 0:      # the first gradient, from AdamW's first moment
+            mu = state["opt_state"][0].mu
+            for g, w in zip(jax.tree_util.tree_leaves(mu),
+                            want["first_grad"]):
+                np.testing.assert_allclose(
+                    np.asarray(g) / (1 - OPT["beta1"]), w, rtol=1e-4,
+                    atol=1e-4 * float(np.abs(w).max()) + 1e-9)
+    moved = ref.leaf_norms(jax.tree_util.tree_map(
+        lambda a, b: a - b, state["params"], start))
+    for k, v in moved.items():
+        np.testing.assert_allclose(float(v), want["delta_norms"][k],
+                                   rtol=2e-3, err_msg=k)
+    # every routed slot of the uncut tiny model is held here
+    assert float(m["moe.slots_here"]) == 3 * ROWS * LEN * 2
+
+
+# ------------------------------------------------------ the expert layer
+def _layer(held, first, **kw):
+    return DroplessMoe(32, 8, 16, 2, experts_held=(held, first),
+                       scaling=1.8, dtype=jnp.float32, **kw)
+
+
+def _layer_params(key, shared=True):
+    whole = _layer(8, 0, shared=(lambda n: SwiGluMlp(
+        32, 16, jnp.float32, name=n)) if shared else None)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, 32))
+    return whole, whole.init(key, x), x
+
+
+def _share(p, first, count):
+    ffn = dict(p["params"])
+    for name in ("experts_gate", "experts_up", "experts_down"):
+        ffn[name] = ffn[name][first:first + count]
+    return {"params": ffn}
+
+
+def test_the_shares_of_one_layer_add_up_to_the_uncut_reference():
+    """Four chips hold two experts each; the shared expert, which every
+    chip computes alike, is counted once. The sum is the uncut layer as
+    the REFERENCE computes it (dense loop over all eight)."""
+    whole, p, x = _layer_params(jax.random.PRNGKey(3))
+    mm = lambda eq, a, b: jnp.einsum(eq, a, b)
+    d = ref.dims(CFG)
+    want = jnp.stack([ref._experts(d, mm, p["params"], x[b])[0]
+                      for b in range(2)])
+    shared_only = SwiGluMlp(32, 16, jnp.float32).apply(
+        {"params": p["params"]["shared"]}, x)
+    total = 0.0
+    slots = 0
+    for first in range(0, 8, 2):
+        part = _layer(2, first, shared=lambda n: SwiGluMlp(
+            32, 16, jnp.float32, name=n))
+        y, stats = part.apply(_share(p, first, 2), x)
+        total = total + (y - shared_only)
+        slots += int(stats["slots_here"])
+    np.testing.assert_allclose(total + shared_only, want, rtol=1e-5,
+                               atol=1e-6)
+    assert slots == 2 * 16 * 2            # every slot computed exactly once
+    np.testing.assert_allclose(whole.apply(p, x)[0], want, rtol=1e-5,
+                               atol=1e-6)
+
+
+def test_dropless_under_a_router_biased_onto_two_experts():
+    """Every token is sent to experts 5 and 6: the share that holds them
+    computes every slot (far past any capacity), the others none."""
+    whole, p, x = _layer_params(jax.random.PRNGKey(4), shared=False)
+    bias = jnp.zeros((8,)).at[jnp.array([5, 6])].set(10.0)
+    p = {"params": dict(p["params"], router_bias=bias)}
+    S = 2 * 16
+    y, stats, = whole.apply(p, x)
+    assert int(stats["slots_here"]) == S * 2
+    np.testing.assert_allclose(stats["load_max_over_mean"], 4.0)  # 2 of 8
+    choice = whole.apply(p, x, mutable=["intermediates"])[1][
+        "intermediates"]["router_choice"][0]
+    assert set(np.unique(choice)) == {5, 6}
+    # dense by hand: both experts on every token, gates from the scores
+    xf = x.reshape(S, 32)
+    s = jax.nn.sigmoid(xf @ p["params"]["router"]["kernel"])
+    g = s[:, 5:7] / s[:, 5:7].sum(-1, keepdims=True) * 1.8
+    want = 0.0
+    for j, e in enumerate((5, 6)):
+        h = jax.nn.silu(xf @ p["params"]["experts_gate"][e]) \
+            * (xf @ p["params"]["experts_up"][e])
+        want = want + g[:, j:j + 1] * (h @ p["params"]["experts_down"][e])
+    np.testing.assert_allclose(y.reshape(S, 32), want, rtol=1e-5, atol=1e-6)
+    y56, st56 = _layer(2, 5).apply(_share(p, 5, 2), x)
+    np.testing.assert_allclose(y56, y, rtol=1e-5, atol=1e-6)
+    assert int(st56["slots_here"]) == S * 2
+    y01, st01 = _layer(2, 0).apply(_share(p, 0, 2), x)
+    assert int(st01["slots_here"]) == 0 and not np.any(np.asarray(y01))
+
+
+def test_a_share_that_holds_none_of_the_chosen_returns_the_shared_expert():
+    whole, p, x = _layer_params(jax.random.PRNGKey(5))
+    bias = jnp.zeros((8,)).at[jnp.array([5, 6])].set(10.0)
+    p = {"params": dict(p["params"], router_bias=bias)}
+    part = _layer(2, 0, shared=lambda n: SwiGluMlp(32, 16, jnp.float32,
+                                                   name=n))
+    y, stats = part.apply(_share(p, 0, 2), x)
+    want = SwiGluMlp(32, 16, jnp.float32).apply(
+        {"params": p["params"]["shared"]}, x)
+    np.testing.assert_allclose(y, want, rtol=1e-6, atol=1e-7)
+    assert int(stats["slots_here"]) == 0
+    # and its gradient is finite: nothing divides by the empty load
+    g = jax.grad(lambda q: part.apply(q, x)[0].sum())(_share(p, 0, 2))
+    assert all(np.all(np.isfinite(np.asarray(v)))
+               for v in jax.tree_util.tree_leaves(g))
+
+
+def test_expert_layer_gradients_match_a_dense_loop():
+    whole, p, x = _layer_params(jax.random.PRNGKey(6))
+    mm = lambda eq, a, b: jnp.einsum(eq, a, b)
+    d = ref.dims(_held(4, 2))
+    part = _layer(4, 2, shared=lambda n: SwiGluMlp(32, 16, jnp.float32,
+                                                   name=n))
+    ps = _share(p, 2, 4)
+
+    def prog(q, x):
+        return jnp.sum(jnp.sin(part.apply(q, x)[0]))
+
+    def dense(q, x):
+        return sum(jnp.sum(jnp.sin(ref._experts(
+            d, mm, q["params"], x[b])[0])) for b in range(2))
+    got = jax.grad(prog, argnums=(0, 1))(ps, x)
+    want = jax.grad(dense, argnums=(0, 1))(ps, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-6)
+
+
+# --------------------------------------------------- attention's parts
+def test_rotary_turns_pairs_by_position_and_keeps_norms():
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 5, 3, 8))
+    y = rotary(x, 1e4)
+    np.testing.assert_allclose(y[:, 0], x[:, 0], atol=1e-6)  # position 0
+    np.testing.assert_allclose(jnp.linalg.norm(y, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    # pair (i, i + 4) at position l turns by l * theta**(-2i/8)
+    l, i = 3, 1
+    ang = l * 1e4 ** (-2 * i / 8)
+    np.testing.assert_allclose(
+        y[0, l, :, i], x[0, l, :, i] * np.cos(ang)
+        - x[0, l, :, i + 4] * np.sin(ang), rtol=1e-5)
+    # relative: <rot(q, l), rot(k, m)> depends on l - m only
+    q, k = x[:, :1, :1], x[:, 1:2, :1]
+    def dot(lq, lk):
+        pad = lambda v, l: jnp.pad(v, ((0, 0), (l, 0), (0, 0), (0, 0)))
+        return jnp.sum(rotary(pad(q, lq), 1e4)[:, lq]
+                       * rotary(pad(k, lk), 1e4)[:, lk])
+    np.testing.assert_allclose(dot(4, 1), dot(7, 4), rtol=1e-5)
+
+
+def test_mla_rotates_only_its_slice_and_shares_one_rotary_key():
+    """The call's q and k, caught at the attention function: the first
+    ``nope`` lanes carry no position, the rotary key is the same for every
+    head, and the whole agrees with the reference's MLA."""
+    seen = {}
+
+    def catch(q, k, v, causal=True):
+        seen.update(q=q, k=k, v=v)
+        from mmlspark_tpu.parallel.sequence import full_attention
+        return full_attention(q, k, v, causal=causal)
+    attn = MlaAttention(32, 2, 24, 16, 12, 4, 16, dtype=jnp.float32,
+                        attention_fn=catch)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 6, 32))
+    p = attn.init(jax.random.PRNGKey(2), x)
+    y = attn.apply(p, x)
+    assert seen["q"].shape == seen["k"].shape == seen["v"].shape \
+        == (1, 6, 2, 16)
+    np.testing.assert_array_equal(seen["k"][:, :, 0, 12:],
+                                  seen["k"][:, :, 1, 12:])
+    assert not np.allclose(seen["k"][:, :, 0, :12], seen["k"][:, :, 1, :12])
+    # the same tokens one position later: only the rotary lanes move
+    attn.apply(p, jnp.concatenate([x[:, :1], x], 1))
+    q1 = seen["q"][:, 1:]
+    attn.apply(p, x)
+    np.testing.assert_allclose(q1[..., :12], seen["q"][..., :12], atol=1e-6)
+    assert not np.allclose(q1[..., 12:], seen["q"][..., 12:])
+    mm = lambda eq, a, b: jnp.einsum(eq, a, b)
+    want = ref._mla(ref.dims(CFG), mm, p["params"], x[0])
+    np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)
+
+
+def test_unequal_query_and_value_heads_are_refused():
+    attn = MlaAttention(32, 2, 24, 16, 12, 4, 8, dtype=jnp.float32)
+    with pytest.raises(ValueError, match="one head width"):
+        attn.init(jax.random.PRNGKey(0), jnp.zeros((1, 4, 32)))
+
+
+# ------------------------------------------------------------ the loss
+@pytest.mark.parametrize("chunk", [5, 8, 64])
+def test_chunked_cross_entropy_is_the_plain_one(chunk):
+    key = jax.random.PRNGKey(0)
+    h = jax.random.normal(key, (24, 16))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (16, 40))
+    t = jax.random.randint(jax.random.fold_in(key, 2), (24,), 0, 40)
+    m = (jnp.arange(24) % 5 != 0).astype(jnp.float32)
+
+    def plain(h, w):
+        return jnp.sum(optax.softmax_cross_entropy_with_integer_labels(
+            h @ w, t) * m)
+
+    def chunked(h, w):
+        return chunked_cross_entropy(h, w, t, m, chunk, jnp.float32)
+    np.testing.assert_allclose(chunked(h, w), plain(h, w), rtol=1e-6)
+    for g, want in zip(jax.grad(chunked, (0, 1))(h, w),
+                       jax.grad(plain, (0, 1))(h, w)):
+        np.testing.assert_allclose(g, want, rtol=1e-5, atol=1e-6)
+
+
+def test_next_token_loss_targets_one_and_two_ahead():
+    B, L, D, V = 2, 6, 8, 12
+    key = jax.random.PRNGKey(0)
+    out = {"hidden": jax.random.normal(key, (B, L, D)),
+           "mtp_hidden": jax.random.normal(jax.random.fold_in(key, 1),
+                                           (B, L, D))}
+    w = jax.random.normal(jax.random.fold_in(key, 2), (D, V))
+    tokens = jax.random.randint(jax.random.fold_in(key, 3), (B, L), 0, V)
+    loss, aux = next_token_loss(out, w, tokens, mtp_weight=0.3, chunk=4,
+                                dtype=jnp.float32)
+    ce = optax.softmax_cross_entropy_with_integer_labels
+    main = ce(out["hidden"][:, :-1] @ w, tokens[:, 1:]).mean()
+    mtp = ce(out["mtp_hidden"][:, :-2] @ w, tokens[:, 2:]).mean()
+    np.testing.assert_allclose(aux["loss.main"], main, rtol=1e-6)
+    np.testing.assert_allclose(aux["loss.mtp"], mtp, rtol=1e-6)
+    np.testing.assert_allclose(loss, main + 0.3 * mtp, rtol=1e-6)
+    only, aux = next_token_loss({"hidden": out["hidden"]}, w, tokens,
+                                chunk=4, dtype=jnp.float32)
+    assert set(aux) == {"loss.main"}
+    np.testing.assert_allclose(only, main, rtol=1e-6)
+
+
+def test_required_flops_follow_the_issue_count():
+    import json
+    with open(Path(__file__).resolve().parent.parent / "benchmark"
+              / "configs" / "glm-4.7-flash.json") as f:
+        cfg = json.load(f)
+    per_token = ref.train_flops_per_item(cfg, 4096) / 4096
+    assert 2.85e9 < per_token < 2.89e9            # "about 2.87 GFLOP"
+    parts = ref._fwd_flops_per_token(cfg, 4096)
+    assert 0.25 < parts["attention"] / parts["total"] < 0.27
+    n = sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
+        ref.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+    assert 706.0e6 < n < 707.0e6                  # 706.5M parameters here
+
+
+def test_parameter_names_fall_under_the_sharding_rules_that_exist(params):
+    """No new rule: the names were chosen for ``DEFAULT_RULES``. Expert
+    banks lead with the ``expert`` axis, projections and feed-forward
+    matrices split over ``tensor`` on the side their rule names, the
+    router, its bias and every norm scale stay whole."""
+    from jax.sharding import PartitionSpec as P
+    from mmlspark_tpu.parallel.mesh import MeshSpec, make_mesh
+    from mmlspark_tpu.parallel.sharding import param_shardings
+    mesh = make_mesh(MeshSpec(data=1, expert=4, tensor=2), jax.devices())
+    spec = {jax.tree_util.keystr(k): v.spec for k, v in
+            jax.tree_util.tree_leaves_with_path(
+                param_shardings(params, mesh))}
+    ffn = "['params']['block1']['ffn']"
+    attn = "['params']['block1']['attn']"
+    assert spec[ffn + "['experts_gate']"] == P("expert", None, "tensor")
+    assert spec[ffn + "['experts_up']"] == P("expert", None, "tensor")
+    assert spec[ffn + "['experts_down']"] == P("expert", "tensor", None)
+    whole = lambda spec: all(axis is None for axis in spec)
+    assert whole(spec[ffn + "['router']['kernel']"])
+    assert whole(spec[ffn + "['router_bias']"])
+    assert spec[ffn + "['shared']['mlp_gate']['kernel']"] == P(None, "tensor")
+    assert spec[ffn + "['shared']['mlp_down']['kernel']"] == P("tensor", None)
+    for name in ("attn_query_a", "attn_query_b", "attn_key_value_a",
+                 "attn_key_value_b"):
+        assert spec[attn + f"['{name}']['kernel']"] == P(None, "tensor"), name
+    assert spec[attn + "['attn_out']['kernel']"] == P("tensor", None)
+    assert whole(spec[attn + "['query_norm']['scale']"])
+    assert spec["['params']['lm_head']['kernel']"] == P(None, "tensor")
+    assert spec["['params']['token_embedding']['embedding']"] \
+        == P("tensor", None)
+    assert spec["['params']['block0']['ffn']['mlp_up']['kernel']"] \
+        == P(None, "tensor")
