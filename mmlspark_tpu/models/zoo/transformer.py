@@ -41,7 +41,10 @@ class DecoderBlock(nn.Module):
     def __call__(self, x):
         B, L, _ = x.shape
         D = self.dim // self.heads
-        attn_fn = self.attention_fn or full_attention
+        # a block with no attention of its own takes its trunk's, so one
+        # made by a ``block_factory`` needs nothing baked into it
+        attn_fn = self.attention_fn \
+            or getattr(self.parent, "attention_fn", None) or full_attention
         y = nn.LayerNorm(dtype=jnp.float32, name="norm1")(x)
         q = nn.Dense(self.dim, dtype=self.dtype, name="attn_query")(y)
         k = nn.Dense(self.dim, dtype=self.dtype, name="attn_key")(y)
@@ -75,15 +78,20 @@ class TransformerLM(nn.Module):
     block_factory: Optional[Callable[[int, str], nn.Module]] = None
 
     @nn.compact
-    def __call__(self, tokens):
-        """tokens (B, L) int32 -> logits (B, L, vocab) fp32."""
+    def __call__(self, tokens, positions=None):
+        """tokens (B, L) int32 -> logits (B, L, vocab) fp32. ``positions``
+        (B, L) int32 says where in its sequence each token stands (the
+        generate lane's steps start mid-sequence); None is ``0..L-1``."""
         B, L = tokens.shape
         emb = nn.Embed(self.vocab, self.dim, dtype=self.dtype,
                        name="token_embedding")
         x = emb(tokens)
         pos = self.param("pos_embedding", nn.initializers.normal(0.02),
                          (1, self.max_len, self.dim), jnp.float32)
-        x = x + pos[:, :L].astype(x.dtype)
+        if positions is None:
+            x = x + pos[:, :L].astype(x.dtype)
+        else:
+            x = x + jnp.take(pos[0], positions, axis=0).astype(x.dtype)
         for i in range(self.depth):
             if self.block_factory is not None:
                 block = self.block_factory(i, f"block{i}")

@@ -7,12 +7,17 @@ either runs each sequence alone (device idle at batch 1) or locks a batch
 together until its LONGEST member finishes (finished sequences pad along,
 waiting prompts starve). This module is the decode-native lane:
 
-- :class:`GenerativeEntry` — the compiled half. One **prefill** program
-  per prompt-length bucket (the full flax module ``apply`` with KV rows
-  captured and scattered into the arena, so prefill numerics are the
-  served model's numerics by construction) and ONE single-token **decode**
-  program per batch-size bucket (hand-written forward over gathered KV
-  pages, numerically mirroring the module). All programs AOT-compile
+- :class:`GenerativeEntry` — the compiled half: program shapes,
+  buckets and the compile seam, and nothing of the model or of the
+  arena's format. Every program that computes is the served flax module
+  itself, applied with a :class:`~mmlspark_tpu.serve.kvcache.CacheView`
+  as its ``attention_fn`` (the seam ring and Ulysses attention use): the
+  view writes each layer's new K/V rows into the arena and reads the
+  paged context back, so the numerics are the served model's by
+  construction and a new block needs no edit here. One **prefill**
+  program per prompt-length bucket, and ONE **step** body at three
+  leading shapes: **decode** ``(B, 1)`` per batch-size bucket, **chunk**
+  ``(1, C)``, **verify** ``(B, spec_width)``. All programs AOT-compile
   through :meth:`GenerativeEntry._compile` — the generative twin of
   ``ModelEntry._compile`` — into the persistent program cache, so a warm
   replica restart pays ZERO compiles.
@@ -32,10 +37,12 @@ retries elsewhere. Sampling (greedy, temperature/top-k) is seeded per
 (seed, position), so a failover RESTART from the prompt on a surviving
 replica replays the exact token stream.
 
-Decode steps donate the arena buffers (in-place on TPU); the arena's
-attention runs the same fused Pallas flash path as scoring on real chips
-(prefill attention goes through ``full_attention`` inside the module) and
-the jnp reference on the CPU test mesh.
+Every program donates the arena buffers (in-place on TPU). Attention
+over the arena (decode, chunk, verify) is jnp in the cache view: a scatter
+of the new rows, a gather of every sequence's full block table, a masked
+softmax; no kernel. Only prefill, which attends over the prompt's own
+rows, goes through ``full_attention`` (a fused Pallas kernel on real
+chips for the lengths it takes, the jnp reference on the CPU test mesh).
 
 Four compounding raw-speed attacks ride the same seams (all
 config-gated, all compiled through :meth:`GenerativeEntry._compile` so a
@@ -55,14 +62,14 @@ warm restart still pays zero XLA compiles):
 - **Speculative decoding** (``generate.draft_model`` +
   ``generate.spec_tokens``): a small draft model (its own
   :class:`GenerativeEntry` + arena) proposes k tokens per step; the
-  target checks them in ONE **verify** program call (the decode spec
-  widened to k+1 positions). Accept/reject replays the exact
+  target checks them in ONE **verify** program call (the step body at
+  k+1 positions a lane). Accept/reject replays the exact
   per-(seed, position) sampler, so greedy AND seeded-sampling outputs
   are token-identical to the non-speculative lane by construction.
 - **int8 KV blocks** (``generate.kv_dtype=int8``): the arena stores
   quantized rows (~2x concurrent-sequence capacity at fixed bytes);
-  dequantization is fused into the decode/verify/chunk programs via the
-  helpers in ``kvcache.py`` (lint Rule 13 keeps scale math there).
+  the cache view quantizes what it writes and dequantizes what it reads
+  inside the programs, and nothing here knows the format.
 """
 from __future__ import annotations
 
@@ -82,8 +89,8 @@ from mmlspark_tpu.reliability import watchdog as _watchdog
 from mmlspark_tpu.reliability.faults import fault_site
 from mmlspark_tpu.serve.batcher import bucket_for, default_buckets
 from mmlspark_tpu.serve.kvcache import (
-    RESERVED_BLOCK, KVCacheManager, blocks_needed, dequantize_rows,
-    prefix_block_hashes, quantize_rows,
+    RESERVED_BLOCK, CacheView, KVCacheManager, blocks_needed,
+    prefix_block_hashes,
 )
 from mmlspark_tpu.utils import config as mmlconfig
 from mmlspark_tpu.utils.logging import get_logger
@@ -146,31 +153,6 @@ def sample_token(logits: np.ndarray, *, temperature: float, top_k: int,
     p /= p.sum()
     rng = np.random.default_rng((int(seed) & 0x7FFFFFFF, int(position)))
     return int(rng.choice(p.size, p=p))
-
-
-# ---------------------------------------------------------------------------
-# numerics mirrored from models/zoo/transformer.py — the decode program
-# recomputes the module's math one token at a time. Flax formulas are
-# reproduced exactly (LayerNorm's clamped variance, tanh-approximate gelu,
-# fp32 norms and logits) so greedy decode is token-identical to a full
-# forward pass of the same sequence.
-
-
-def _layer_norm(x, scale, bias, eps: float = 1e-6):
-    xf = x.astype(np.float32)
-    mean = xf.mean(axis=-1, keepdims=True)
-    mean2 = (xf * xf).mean(axis=-1, keepdims=True)
-    import jax
-    import jax.numpy as jnp
-    var = jnp.maximum(0.0, mean2 - mean * mean)
-    y = (xf - mean) * jax.lax.rsqrt(var + eps)
-    return y * scale.astype(np.float32) + bias.astype(np.float32)
-
-
-def _dense(x, p, dtype):
-    import jax.numpy as jnp
-    return jnp.dot(x.astype(dtype), p["kernel"].astype(dtype)) \
-        + p["bias"].astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -338,6 +320,12 @@ class ContinuousBatcher:
 # compiled programs
 
 
+def _int32(*shape: int):
+    """An int32 operand's placeholder to lower a program on."""
+    import jax
+    return jax.ShapeDtypeStruct(shape, np.int32)
+
+
 class GenerativeEntry:
     """Compiled generative artifacts for one registered model: the KV
     arena plus bucketed prefill / decode executables.
@@ -427,20 +415,28 @@ class GenerativeEntry:
     def _compile(self, kind: str, bucket: int) -> Callable:
         """Build (or cache-load) the executable for one (kind, bucket).
         Every generative compilation funnels through here exactly once
-        per key — the compile-discipline tests wrap this method."""
+        per key — the compile-discipline tests wrap this method. Every
+        program takes ``params``, then the arena set (donated), then its
+        kind's operands, and returns the arena set followed by its
+        payload, if it has one."""
+        import jax
         from mmlspark_tpu import compile_cache
         if kind == "prefill":
-            jitted, abstract = self._prefill_spec(bucket)
-        elif kind == "decode":
-            jitted, abstract = self._decode_spec(bucket)
-        elif kind == "chunk":
-            jitted, abstract = self._chunk_spec(bucket)
-        elif kind == "verify":
-            jitted, abstract = self._verify_spec(bucket)
+            body, operands = self._prefill_spec(bucket)
+        elif kind in ("decode", "chunk", "verify"):
+            body, operands = self._step_spec(kind, bucket)
         elif kind == "cow":
-            jitted, abstract = self._cow_spec()
+            body, operands = self._cow_spec()
         else:
             raise ValueError(f"unknown program kind {kind!r}")
+        arenas = self.kv.abstract_arenas()
+        n_arenas = len(arenas)
+
+        def program(params, *args):
+            return body(params, args[:n_arenas], *args[n_arenas:])
+
+        jitted = jax.jit(  # lint: allow-compile
+            program, donate_argnums=tuple(range(1, n_arenas + 1)))
         shape_key = (f"{kind}:{bucket}|arena={self.kv.num_blocks}x"
                      f"{self.block_tokens}x{self.heads}x{self.head_dim}"
                      f"|layers={self.depth}|W={self.table_width}"
@@ -457,452 +453,104 @@ class GenerativeEntry:
             shape_key += f"|mesh={axes}|kvspec={tuple(spec)!r}"
         result = compile_cache.load_or_compile_program(
             self.entry.name, self.entry.version, kind, shape_key,
-            jitted, self.params, *abstract)
+            jitted, self.params, *arenas, *operands)
         if result.hit:
             self.entry.cache_hits += 1
         else:
             self.entry.compile_count += 1
         return result.program
 
-    def _arena_abstract(self):
-        """The arena operand placeholders every program takes right after
-        ``params`` — (k, v) plus the two fp32 scale planes when int8 —
-        and the matching ``donate_argnums``. On a mesh the placeholders
-        carry the arena's NamedSharding: an AOT-compiled executable
-        rejects committed inputs whose sharding differs from what it was
-        lowered with, so the placement must be part of the lowering."""
-        import jax
-        kv = self.kv
-        if kv.mesh is not None:
-            arena = jax.ShapeDtypeStruct(kv.arena_k.shape, kv.dtype,
-                                         sharding=kv.arena_sharding)
-            if kv.quantized:
-                sc = jax.ShapeDtypeStruct(kv.scale_k.shape, np.float32,
-                                          sharding=kv.scale_sharding)
-                return (arena, arena, sc, sc), (1, 2, 3, 4)
-            return (arena, arena), (1, 2)
-        arena = jax.ShapeDtypeStruct(kv.arena_k.shape, kv.dtype)
-        if kv.quantized:
-            sc = jax.ShapeDtypeStruct(kv.scale_k.shape, np.float32)
-            return (arena, arena, sc, sc), (1, 2, 3, 4)
-        return (arena, arena), (1, 2)
-
     # -- prefill -----------------------------------------------------------
     def _prefill_spec(self, bucket: int):
-        """Jitted prefill for one prompt-length bucket ``Lb``: run the
-        module's OWN apply (prefill numerics are the served model's by
-        construction), capture each block's K/V projections, scatter them
-        into the sequence's arena blocks, and return the last live
-        position's logits row."""
-        import jax
+        """Prefill for one prompt-length bucket ``Lb``: the module over
+        the whole padded prompt, its attention a :class:`CacheView` that
+        attends over the rows themselves (``full_attention``: the flash
+        kernel on the chip) and writes every layer's K/V into the
+        sequence's leading blocks; returns the last live position's
+        logits row."""
         import jax.numpy as jnp
-        module, depth = self.module, self.depth
+        module = self.module
         nb = bucket // self.block_tokens
-        bt, heads, hd = self.block_tokens, self.heads, self.head_dim
-        quant = self.kv.quantized
 
-        def kv_filter(mdl, _method):
-            return getattr(mdl, "name", None) in ("attn_key", "attn_value")
+        def prefill(params, arenas, tokens, last_pos, block_ids):
+            view = CacheView(arenas, block_ids[None])
+            logits = module.clone(attention_fn=view).apply(params, tokens)
+            return (*view.arenas(), jnp.take(logits[0], last_pos, axis=0))
 
-        def body(params, arena_k, arena_v, scale_k, scale_v, tokens,
-                 last_pos, block_ids):
-            logits, state = module.apply(
-                params, tokens, capture_intermediates=kv_filter,
-                mutable=["intermediates"])
-            inter = state["intermediates"]
-            ks = jnp.stack([inter[f"block{i}"]["attn_key"]["__call__"][0][0]
-                            for i in range(depth)])
-            vs = jnp.stack([inter[f"block{i}"]["attn_value"]["__call__"][0]
-                            [0] for i in range(depth)])
-            ks = ks.reshape(depth, nb, bt, heads, hd)
-            vs = vs.reshape(depth, nb, bt, heads, hd)
-            if quant:
-                ks, sk = quantize_rows(ks)
-                vs, sv = quantize_rows(vs)
-                scale_k = scale_k.at[:, block_ids].set(sk)
-                scale_v = scale_v.at[:, block_ids].set(sv)
-            arena_k = arena_k.at[:, block_ids].set(ks)
-            arena_v = arena_v.at[:, block_ids].set(vs)
-            row = jnp.take(logits[0], last_pos, axis=0)
-            return arena_k, arena_v, scale_k, scale_v, row
+        return prefill, (_int32(1, bucket), _int32(), _int32(nb))
 
-        if quant:
-            def prefill(params, ak, av, sk, sv, tokens, last_pos, blocks):
-                return body(params, ak, av, sk, sv, tokens, last_pos,
-                            blocks)
-        else:
-            def prefill(params, ak, av, tokens, last_pos, blocks):
-                ak, av, _sk, _sv, row = body(params, ak, av, None, None,
-                                             tokens, last_pos, blocks)
-                return ak, av, row
+    # -- decode, chunk, verify ---------------------------------------------
+    def _step_spec(self, kind: str, bucket: int):
+        """The step program: ``C`` new tokens of each of ``B`` sequences
+        at their ``positions``, through the module with a
+        :class:`CacheView` over ``block_tables`` as its attention, which
+        writes each layer's new K/V rows first and then reads the paged
+        history, so a row attends itself and the rows before it in its
+        window. Row ``j`` of a sequence's logits is the model's
+        next-token distribution after its fed token ``j``. Rows from
+        ``n_valid`` on write to the reserved scratch block and their
+        logits are ignored host-side — the program never branches on
+        occupancy.
 
-        arenas, donate = self._arena_abstract()
-        jitted = jax.jit(prefill, donate_argnums=donate)  # lint: allow-compile
-        abstract = arenas + (
-            jax.ShapeDtypeStruct((1, bucket), np.int32),
-            jax.ShapeDtypeStruct((), np.int32),
-            jax.ShapeDtypeStruct((nb,), np.int32),
-        )
-        return jitted, abstract
+        The three kinds are this body at three leading shapes, each with
+        the operands and the payload its callers know:
 
-    # -- decode ------------------------------------------------------------
-    def _decode_spec(self, batch: int):
-        """Jitted single-token decode for one batch bucket ``B``: scatter
-        each lane's new K/V into its pages, gather the paged history, and
-        run one manually-unrolled forward step mirroring the module's
-        math. Lanes without a live sequence (``seq_lens == 0``) write to
-        the reserved scratch block and their logits are ignored host-side
-        — the compiled program never branches on occupancy."""
-        import jax
+        - ``decode``, ``(B, 1)``: one token per lane of a batch bucket;
+          a lane without a live sequence has ``seq_lens == 0``.
+        - ``chunk``, ``(1, C)``: ``C`` consecutive prompt positions of
+          ONE sequence — chunked prefill, and the uncached suffix after a
+          prefix hit (``positions`` start at the first uncached token;
+          earlier shared blocks are only READ). Returns row
+          ``n_valid - 1``.
+        - ``verify``, ``(B, spec_width)``: a lane feeds ``n_valid`` in
+          ``[1, C]`` tokens, its last sampled one and the draft's
+          proposals (1 = plain decode riding the same program); the host
+          accepts proposals left to right while they match the target's
+          own sampler.
+        """
         import jax.numpy as jnp
-        depth, heads, hd, dim = self.depth, self.heads, self.head_dim, \
-            self.dim
-        bt, W, dtype = self.block_tokens, self.table_width, self.dtype
-        scale = 1.0 / np.sqrt(hd)
-        quant = self.kv.quantized
+        module, W = self.module, self.table_width
 
-        def body(params, arena_k, arena_v, scale_k, scale_v, tokens,
-                 positions, block_tables, seq_lens):
-            p = params.get("params", params)
-            table = p["token_embedding"]["embedding"]
-            x = jnp.take(table.astype(dtype), tokens, axis=0)
-            x = x + jnp.take(p["pos_embedding"][0], positions,
-                             axis=0).astype(x.dtype)
-            active = seq_lens > 0
-            blk_col = positions // bt
-            blk_idx = jnp.take_along_axis(
-                block_tables, blk_col[:, None], axis=1)[:, 0]
-            blk_idx = jnp.where(active, blk_idx, RESERVED_BLOCK)
-            offs = positions % bt
-            idx = jnp.arange(W * bt)
-            masked = idx[None, :] > positions[:, None]     # (B, K)
-            for i in range(depth):
-                blk = p[f"block{i}"]
-                y = _layer_norm(x, blk["norm1"]["scale"],
-                                blk["norm1"]["bias"])
-                q = _dense(y, blk["attn_query"], dtype)
-                k = _dense(y, blk["attn_key"], dtype)
-                v = _dense(y, blk["attn_value"], dtype)
-                qh = q.reshape(-1, heads, hd)
-                kr = k.reshape(-1, heads, hd)
-                vr = v.reshape(-1, heads, hd)
-                # scatter FIRST so the current token attends itself
-                if quant:
-                    qk, ssk = quantize_rows(kr)
-                    qv, ssv = quantize_rows(vr)
-                    arena_k = arena_k.at[i, blk_idx, offs].set(qk)
-                    arena_v = arena_v.at[i, blk_idx, offs].set(qv)
-                    scale_k = scale_k.at[i, blk_idx, offs].set(ssk)
-                    scale_v = scale_v.at[i, blk_idx, offs].set(ssv)
-                    k_all = dequantize_rows(
-                        arena_k[i][block_tables].reshape(
-                            -1, W * bt, heads, hd),
-                        scale_k[i][block_tables].reshape(
-                            -1, W * bt)).astype(dtype)
-                    v_all = dequantize_rows(
-                        arena_v[i][block_tables].reshape(
-                            -1, W * bt, heads, hd),
-                        scale_v[i][block_tables].reshape(
-                            -1, W * bt)).astype(dtype)
-                else:
-                    arena_k = arena_k.at[i, blk_idx, offs].set(kr)
-                    arena_v = arena_v.at[i, blk_idx, offs].set(vr)
-                    k_all = arena_k[i][block_tables].reshape(
-                        -1, W * bt, heads, hd)
-                    v_all = arena_v[i][block_tables].reshape(
-                        -1, W * bt, heads, hd)
-                s = jnp.einsum("bhd,bkhd->bhk", qh, k_all,
-                               preferred_element_type=jnp.float32) * scale
-                s = jnp.where(masked[:, None, :], -jnp.inf, s)
-                pr = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhk,bkhd->bhd", pr.astype(v_all.dtype),
-                               v_all,
-                               preferred_element_type=jnp.float32)
-                o = o.astype(qh.dtype)
-                x = x + _dense(o.reshape(-1, dim), blk["attn_out"], dtype)
-                y = _layer_norm(x, blk["norm2"]["scale"],
-                                blk["norm2"]["bias"])
-                h = _dense(y, blk["mlp_up"], dtype)
-                h = jax.nn.gelu(h)
-                x = x + _dense(h, blk["mlp_down"], dtype)
-            xf = _layer_norm(x, p["final_norm"]["scale"],
-                             p["final_norm"]["bias"])
-            logits = jnp.einsum("bd,vd->bv", xf.astype(jnp.float32),
-                                table.astype(jnp.float32))
-            return arena_k, arena_v, scale_k, scale_v, logits
+        def step(params, arenas, tokens, positions, block_tables, n_valid):
+            valid = jnp.arange(tokens.shape[1])[None, :] < n_valid[:, None]
+            view = CacheView(arenas, block_tables, positions, valid)
+            logits = module.clone(attention_fn=view).apply(
+                params, tokens, positions=positions)
+            return (*view.arenas(), logits)
 
-        if quant:
-            def decode(params, ak, av, sk, sv, tokens, positions, tables,
+        if kind == "verify":
+            C = self.spec_width
+            return step, (_int32(bucket, C), _int32(bucket, C),
+                          _int32(bucket, W), _int32(bucket))
+        if kind == "decode":
+            def decode(params, arenas, tokens, positions, block_tables,
                        seq_lens):
-                return body(params, ak, av, sk, sv, tokens, positions,
-                            tables, seq_lens)
-        else:
-            def decode(params, ak, av, tokens, positions, tables,
-                       seq_lens):
-                ak, av, _sk, _sv, out = body(params, ak, av, None, None,
-                                             tokens, positions, tables,
-                                             seq_lens)
-                return ak, av, out
+                *arenas, logits = step(
+                    params, arenas, tokens[:, None], positions[:, None],
+                    block_tables, (seq_lens > 0).astype(jnp.int32))
+                return (*arenas, logits[:, 0])
 
-        arenas, donate = self._arena_abstract()
-        jitted = jax.jit(decode, donate_argnums=donate)  # lint: allow-compile
-        abstract = arenas + (
-            jax.ShapeDtypeStruct((batch,), np.int32),
-            jax.ShapeDtypeStruct((batch,), np.int32),
-            jax.ShapeDtypeStruct((batch, W), np.int32),
-            jax.ShapeDtypeStruct((batch,), np.int32),
-        )
-        return jitted, abstract
+            return decode, (_int32(bucket), _int32(bucket),
+                            _int32(bucket, W), _int32(bucket))
 
-    # -- chunked / suffix prefill -----------------------------------------
-    def _chunk_spec(self, C: int):
-        """Jitted prefill CHUNK: ``C`` consecutive prompt positions of ONE
-        sequence, scatter-first then gather like decode so positions
-        within the chunk attend each other. Serves both chunked prefill
-        (long prompts interleaved with decode) and the uncached-suffix
-        prefill after a prefix-cache hit (``positions`` start at the
-        first uncached token; earlier shared blocks are only READ).
-        Invalid rows (``>= n_valid``) write to reserved scratch and their
-        logits are ignored host-side."""
-        import jax
-        import jax.numpy as jnp
-        depth, heads, hd, dim = self.depth, self.heads, self.head_dim, \
-            self.dim
-        bt, W, dtype = self.block_tokens, self.table_width, self.dtype
-        scale = 1.0 / np.sqrt(hd)
-        quant = self.kv.quantized
+        def chunk(params, arenas, tokens, positions, table_row, n_valid):
+            *arenas, logits = step(params, arenas, tokens[None],
+                                   positions[None], table_row[None],
+                                   n_valid[None])
+            return (*arenas, jnp.take(logits[0], jnp.maximum(n_valid - 1, 0),
+                                      axis=0))
 
-        def body(params, arena_k, arena_v, scale_k, scale_v, tokens,
-                 positions, table_row, n_valid):
-            p = params.get("params", params)
-            table = p["token_embedding"]["embedding"]
-            x = jnp.take(table.astype(dtype), tokens, axis=0)      # (C, d)
-            x = x + jnp.take(p["pos_embedding"][0], positions,
-                             axis=0).astype(x.dtype)
-            valid = jnp.arange(C) < n_valid
-            blk_idx = jnp.where(valid, jnp.take(table_row, positions // bt),
-                                RESERVED_BLOCK)
-            offs = positions % bt
-            idx = jnp.arange(W * bt)
-            masked = idx[None, :] > positions[:, None]     # (C, K) causal
-            for i in range(depth):
-                blk = p[f"block{i}"]
-                y = _layer_norm(x, blk["norm1"]["scale"],
-                                blk["norm1"]["bias"])
-                q = _dense(y, blk["attn_query"], dtype)
-                k = _dense(y, blk["attn_key"], dtype)
-                v = _dense(y, blk["attn_value"], dtype)
-                qh = q.reshape(C, heads, hd)
-                kr = k.reshape(C, heads, hd)
-                vr = v.reshape(C, heads, hd)
-                if quant:
-                    qk, ssk = quantize_rows(kr)
-                    qv, ssv = quantize_rows(vr)
-                    arena_k = arena_k.at[i, blk_idx, offs].set(qk)
-                    arena_v = arena_v.at[i, blk_idx, offs].set(qv)
-                    scale_k = scale_k.at[i, blk_idx, offs].set(ssk)
-                    scale_v = scale_v.at[i, blk_idx, offs].set(ssv)
-                    k_all = dequantize_rows(
-                        arena_k[i][table_row].reshape(W * bt, heads, hd),
-                        scale_k[i][table_row].reshape(W * bt)
-                    ).astype(dtype)
-                    v_all = dequantize_rows(
-                        arena_v[i][table_row].reshape(W * bt, heads, hd),
-                        scale_v[i][table_row].reshape(W * bt)
-                    ).astype(dtype)
-                else:
-                    arena_k = arena_k.at[i, blk_idx, offs].set(kr)
-                    arena_v = arena_v.at[i, blk_idx, offs].set(vr)
-                    k_all = arena_k[i][table_row].reshape(
-                        W * bt, heads, hd)
-                    v_all = arena_v[i][table_row].reshape(
-                        W * bt, heads, hd)
-                s = jnp.einsum("chd,khd->chk", qh, k_all,
-                               preferred_element_type=jnp.float32) * scale
-                s = jnp.where(masked[:, None, :], -jnp.inf, s)
-                pr = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("chk,khd->chd", pr.astype(v_all.dtype),
-                               v_all,
-                               preferred_element_type=jnp.float32)
-                o = o.astype(qh.dtype)
-                x = x + _dense(o.reshape(C, dim), blk["attn_out"], dtype)
-                y = _layer_norm(x, blk["norm2"]["scale"],
-                                blk["norm2"]["bias"])
-                h = _dense(y, blk["mlp_up"], dtype)
-                h = jax.nn.gelu(h)
-                x = x + _dense(h, blk["mlp_down"], dtype)
-            xf = _layer_norm(x, p["final_norm"]["scale"],
-                             p["final_norm"]["bias"])
-            logits = jnp.einsum("cd,vd->cv", xf.astype(jnp.float32),
-                                table.astype(jnp.float32))
-            row = jnp.take(logits, jnp.maximum(n_valid - 1, 0), axis=0)
-            return arena_k, arena_v, scale_k, scale_v, row
-
-        if quant:
-            def chunk(params, ak, av, sk, sv, tokens, positions, table_row,
-                      n_valid):
-                return body(params, ak, av, sk, sv, tokens, positions,
-                            table_row, n_valid)
-        else:
-            def chunk(params, ak, av, tokens, positions, table_row,
-                      n_valid):
-                ak, av, _sk, _sv, row = body(params, ak, av, None, None,
-                                             tokens, positions, table_row,
-                                             n_valid)
-                return ak, av, row
-
-        arenas, donate = self._arena_abstract()
-        jitted = jax.jit(chunk, donate_argnums=donate)  # lint: allow-compile
-        abstract = arenas + (
-            jax.ShapeDtypeStruct((C,), np.int32),
-            jax.ShapeDtypeStruct((C,), np.int32),
-            jax.ShapeDtypeStruct((W,), np.int32),
-            jax.ShapeDtypeStruct((), np.int32),
-        )
-        return jitted, abstract
-
-    # -- speculative verify ------------------------------------------------
-    def _verify_spec(self, batch: int):
-        """Jitted speculative VERIFY for one batch bucket: the decode
-        program widened to ``spec_width = spec_tokens + 1`` positions per
-        lane. Row ``j`` of a lane's logits is the target model's
-        next-token distribution after consuming fed token ``j`` — the
-        host accepts draft proposals left to right while they match the
-        target's own sampler, so the emitted stream is token-identical
-        to non-speculative decode by construction. Lanes feed
-        ``n_valid in [1, C]`` tokens (1 = plain decode riding the same
-        program); rows past ``n_valid`` scatter to reserved scratch."""
-        import jax
-        import jax.numpy as jnp
-        depth, heads, hd, dim = self.depth, self.heads, self.head_dim, \
-            self.dim
-        bt, W, dtype = self.block_tokens, self.table_width, self.dtype
-        C = self.spec_width
-        scale = 1.0 / np.sqrt(hd)
-        quant = self.kv.quantized
-
-        def body(params, arena_k, arena_v, scale_k, scale_v, tokens,
-                 positions, block_tables, n_valid):
-            p = params.get("params", params)
-            table = p["token_embedding"]["embedding"]
-            x = jnp.take(table.astype(dtype), tokens, axis=0)   # (B, C, d)
-            x = x + jnp.take(p["pos_embedding"][0], positions,
-                             axis=0).astype(x.dtype)
-            valid = jnp.arange(C)[None, :] < n_valid[:, None]   # (B, C)
-            blk_idx = jnp.take_along_axis(block_tables, positions // bt,
-                                          axis=1)
-            blk_idx = jnp.where(valid, blk_idx, RESERVED_BLOCK)
-            offs = positions % bt
-            idx = jnp.arange(W * bt)
-            masked = idx[None, None, :] > positions[:, :, None]  # (B,C,K)
-            for i in range(depth):
-                blk = p[f"block{i}"]
-                y = _layer_norm(x, blk["norm1"]["scale"],
-                                blk["norm1"]["bias"])
-                q = _dense(y, blk["attn_query"], dtype)
-                k = _dense(y, blk["attn_key"], dtype)
-                v = _dense(y, blk["attn_value"], dtype)
-                qh = q.reshape(-1, C, heads, hd)
-                kr = k.reshape(-1, C, heads, hd)
-                vr = v.reshape(-1, C, heads, hd)
-                # scatter the whole window FIRST: row j attends rows < j
-                # of its own window through the arena, like decode
-                if quant:
-                    qk, ssk = quantize_rows(kr)
-                    qv, ssv = quantize_rows(vr)
-                    arena_k = arena_k.at[i, blk_idx, offs].set(qk)
-                    arena_v = arena_v.at[i, blk_idx, offs].set(qv)
-                    scale_k = scale_k.at[i, blk_idx, offs].set(ssk)
-                    scale_v = scale_v.at[i, blk_idx, offs].set(ssv)
-                    k_all = dequantize_rows(
-                        arena_k[i][block_tables].reshape(
-                            -1, W * bt, heads, hd),
-                        scale_k[i][block_tables].reshape(
-                            -1, W * bt)).astype(dtype)
-                    v_all = dequantize_rows(
-                        arena_v[i][block_tables].reshape(
-                            -1, W * bt, heads, hd),
-                        scale_v[i][block_tables].reshape(
-                            -1, W * bt)).astype(dtype)
-                else:
-                    arena_k = arena_k.at[i, blk_idx, offs].set(kr)
-                    arena_v = arena_v.at[i, blk_idx, offs].set(vr)
-                    k_all = arena_k[i][block_tables].reshape(
-                        -1, W * bt, heads, hd)
-                    v_all = arena_v[i][block_tables].reshape(
-                        -1, W * bt, heads, hd)
-                s = jnp.einsum("bchd,bkhd->bchk", qh, k_all,
-                               preferred_element_type=jnp.float32) * scale
-                s = jnp.where(masked[:, :, None, :], -jnp.inf, s)
-                pr = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bchk,bkhd->bchd", pr.astype(v_all.dtype),
-                               v_all,
-                               preferred_element_type=jnp.float32)
-                o = o.astype(qh.dtype)
-                x = x + _dense(o.reshape(-1, C, dim), blk["attn_out"],
-                               dtype)
-                y = _layer_norm(x, blk["norm2"]["scale"],
-                                blk["norm2"]["bias"])
-                h = _dense(y, blk["mlp_up"], dtype)
-                h = jax.nn.gelu(h)
-                x = x + _dense(h, blk["mlp_down"], dtype)
-            xf = _layer_norm(x, p["final_norm"]["scale"],
-                             p["final_norm"]["bias"])
-            logits = jnp.einsum("bcd,vd->bcv", xf.astype(jnp.float32),
-                                table.astype(jnp.float32))
-            return arena_k, arena_v, scale_k, scale_v, logits
-
-        if quant:
-            def verify(params, ak, av, sk, sv, tokens, positions, tables,
-                       n_valid):
-                return body(params, ak, av, sk, sv, tokens, positions,
-                            tables, n_valid)
-        else:
-            def verify(params, ak, av, tokens, positions, tables,
-                       n_valid):
-                ak, av, _sk, _sv, out = body(params, ak, av, None, None,
-                                             tokens, positions, tables,
-                                             n_valid)
-                return ak, av, out
-
-        arenas, donate = self._arena_abstract()
-        jitted = jax.jit(verify, donate_argnums=donate)  # lint: allow-compile
-        abstract = arenas + (
-            jax.ShapeDtypeStruct((batch, C), np.int32),
-            jax.ShapeDtypeStruct((batch, C), np.int32),
-            jax.ShapeDtypeStruct((batch, W), np.int32),
-            jax.ShapeDtypeStruct((batch,), np.int32),
-        )
-        return jitted, abstract
+        return chunk, (_int32(bucket), _int32(bucket), _int32(W), _int32())
 
     # -- copy-on-write block copy -----------------------------------------
     def _cow_spec(self):
-        """Device block copy ``src -> dst`` across every layer (and the
-        scale planes when int8) — the copy-on-write a full-prefix-hit
+        """Device block copy ``src -> dst`` across every layer of every
+        array of the arena set — the copy-on-write a full-prefix-hit
         joiner owes before it may write its final prompt block."""
-        import jax
-        quant = self.kv.quantized
+        def cow(params, arenas, src, dst):
+            return tuple(a.at[:, dst].set(a[:, src]) for a in arenas)
 
-        if quant:
-            def cow(params, ak, av, sk, sv, src, dst):
-                ak = ak.at[:, dst].set(ak[:, src])
-                av = av.at[:, dst].set(av[:, src])
-                sk = sk.at[:, dst].set(sk[:, src])
-                sv = sv.at[:, dst].set(sv[:, src])
-                return ak, av, sk, sv
-        else:
-            def cow(params, ak, av, src, dst):
-                ak = ak.at[:, dst].set(ak[:, src])
-                av = av.at[:, dst].set(av[:, src])
-                return ak, av
-
-        arenas, donate = self._arena_abstract()
-        jitted = jax.jit(cow, donate_argnums=donate)  # lint: allow-compile
-        abstract = arenas + (
-            jax.ShapeDtypeStruct((), np.int32),
-            jax.ShapeDtypeStruct((), np.int32),
-        )
-        return jitted, abstract
+        return cow, (_int32(), _int32())
 
     def release(self) -> None:
         """Drop programs + arena accounting (lane shutdown)."""
@@ -1286,15 +934,10 @@ class GenerateLane:
         hand the caller whatever payload follows it (logits/row), if
         any. Works for the target and the draft entry alike."""
         kv = entry.kv
-        if kv.quantized:
-            out = program(entry.params, kv.arena_k, kv.arena_v,
-                          kv.scale_k, kv.scale_v, *operands)
-            kv.swap(*out[:4])
-            tail = out[4:]
-        else:
-            out = program(entry.params, kv.arena_k, kv.arena_v, *operands)
-            kv.swap(*out[:2])
-            tail = out[2:]
+        arenas = kv.arenas()
+        out = program(entry.params, *arenas, *operands)
+        kv.swap(*out[:len(arenas)])
+        tail = out[len(arenas):]
         return tail[0] if tail else None
 
     def _cow_copy(self, entry: GenerativeEntry,

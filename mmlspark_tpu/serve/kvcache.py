@@ -31,16 +31,22 @@ Contracts the rest of the lane builds on:
   holds indexed prefix content parks in an LRU cached pool rather than
   the free list, reclaimed (refcount 0 only) when admission needs room.
 - **Donation round-trip.** The decode/prefill executables donate the
-  arena buffers (in-place update on TPU); callers pass
-  ``arena_k``/``arena_v`` (and the quantization scales, when int8) in
-  and MUST store the returned set back via :meth:`swap` before the next
-  step.
+  arena buffers (in-place update on TPU); callers pass the arena set
+  (:meth:`KVCacheManager.arenas`: keys, values, and the quantization
+  scales when int8) in and MUST store the returned set back via
+  :meth:`swap` before the next step.
+- **The format lives here.** Inside a program the arena is reached
+  through a :class:`CacheView`, the model's ``attention_fn``: it writes
+  new K/V rows into their blocks, reads a sequence's context through its
+  block table, and attends. Program builders (``serve/generate.py``) hand
+  it the arena set and get the updated set back; how many arrays that is
+  and what is in them is this module's business alone.
 - **int8 storage (optional).** ``generate.kv_dtype=int8`` stores the
   arena quantized with one fp32 scale per (layer, block, row): roughly
   2x the concurrent-sequence capacity at the same byte budget.
   :func:`quantize_rows` / :func:`dequantize_rows` are the ONLY
-  quantization arithmetic in ``serve/`` (lint Rule 13) — program
-  builders call them, they never open-code scale math.
+  quantization arithmetic in ``serve/`` (lint Rule 13); the view calls
+  them on every write and read.
 - **Budget accounting.** ``arena_bytes()`` (arena + scales, real width)
   is charged to the owning :class:`~mmlspark_tpu.serve.registry.ModelEntry`
   so the registry's ``runtime.device_cache_mb`` LRU sees scoring params
@@ -101,8 +107,8 @@ def prefix_block_hashes(model: str, kv_dtype: str, prompt: Sequence[int],
 
 # ---------------------------------------------------------------------------
 # int8 block quantization — the ONE quant-arithmetic site in serve/
-# (lint Rule 13). Traced inside the compiled prefill/decode/verify
-# programs; per-row scales keep incremental single-position writes exact
+# (lint Rule 13). Traced inside the compiled programs, through the
+# cache view; per-row scales keep incremental single-position writes exact
 # (a whole-block scale would invalidate already-written rows).
 
 
@@ -121,6 +127,110 @@ def dequantize_rows(q, scale):
     """Invert :func:`quantize_rows`: int8 rows + per-row scales -> fp32."""
     import jax.numpy as jnp
     return q.astype(jnp.float32) * scale[..., None, None].astype(jnp.float32)
+
+
+class CacheView:
+    """The arena as an attention: ``view(q, k, v, causal=True)`` on
+    ``(B, C, H, D)``, the contract of ``parallel/sequence.full_attention``,
+    meaning *write these rows, read this context*. Built inside a traced
+    program from the arena set (``KVCacheManager.arenas()``) and handed to
+    the model as its ``attention_fn``; the model's blocks call it once
+    each, in order, which is how a call knows its layer. After the model
+    has run, :meth:`arenas` hands back the updated set.
+
+    With ``positions`` and ``valid`` (both ``(B, C)``) a call scatters its
+    layer's new K/V rows into ``block_tables`` ``(B, W)`` at their
+    positions (a row that is not valid goes to ``RESERVED_BLOCK``), FIRST,
+    so that a row attends itself and the rows before it in its own window;
+    then it gathers the ``W`` blocks of each sequence and attends over
+    them, masking every index past the row's position: matmuls in the
+    input dtype, scores and softmax in float32.
+
+    Without them the rows are whole prompts from position 0 and
+    ``block_tables`` ``(B, nb)`` names exactly the blocks they fill: a
+    call attends over the rows themselves through ``full_attention`` (the
+    flash kernel on the chip) and :meth:`arenas` writes all layers' blocks
+    in one scatter.
+    """
+
+    def __init__(self, arenas, block_tables, positions=None, valid=None):
+        import jax.numpy as jnp
+        self._kv = list(arenas[:2])          # [keys, values]
+        self._scales = list(arenas[2:])      # theirs, when the arena is int8
+        self.block_tables = block_tables
+        self.positions = positions
+        self.layer = 0
+        self._prompt_rows: List[Tuple[Any, Any]] = []
+        if positions is None:
+            return
+        bt = self._kv[0].shape[2]
+        self._blocks = jnp.where(
+            valid, jnp.take_along_axis(block_tables, positions // bt,
+                                       axis=1), RESERVED_BLOCK)
+        self._offsets = positions % bt
+        context = jnp.arange(block_tables.shape[1] * bt)
+        self._masked = context[None, None, :] > positions[:, :, None]
+
+    def __call__(self, q, k, v, causal: bool = True):
+        if not causal:
+            raise ValueError("the KV arena serves causal attention only")
+        layer, self.layer = self.layer, self.layer + 1
+        if self.positions is None:
+            from mmlspark_tpu.parallel.sequence import full_attention
+            self._prompt_rows.append((k, v))
+            return full_attention(q, k, v, causal=True)
+        import jax
+        import jax.numpy as jnp
+        self._write((layer, self._blocks, self._offsets), (k, v))
+        k_all, v_all = self._read(layer, q.dtype)
+        s = jnp.einsum("bchd,bkhd->bchk", q, k_all,
+                       preferred_element_type=jnp.float32) \
+            * (1.0 / np.sqrt(q.shape[-1]))
+        s = jnp.where(self._masked[:, :, None, :], -jnp.inf, s)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bchk,bkhd->bchd", p.astype(v_all.dtype), v_all,
+                       preferred_element_type=jnp.float32)
+        return o.astype(q.dtype)
+
+    def _write(self, at, rows) -> None:
+        """Keys and values ``rows`` into the arena at index ``at``, in
+        the arena's format."""
+        for which, r in enumerate(rows):
+            if self._scales:
+                r, scale = quantize_rows(r)
+                self._scales[which] = self._scales[which].at[at].set(scale)
+            self._kv[which] = self._kv[which].at[at].set(r)
+
+    def _read(self, layer: int, dtype):
+        """Every sequence's ``W`` blocks of one layer as ``(B, W * bt, H,
+        D)`` keys and values in ``dtype``."""
+        B = self.block_tables.shape[0]
+        out = []
+        for which, arena in enumerate(self._kv):
+            rows = arena[layer][self.block_tables].reshape(
+                B, -1, *arena.shape[3:])
+            if self._scales:
+                scale = self._scales[which][layer][self.block_tables]
+                rows = dequantize_rows(rows, scale.reshape(B, -1)
+                                       ).astype(dtype)
+            out.append(rows)
+        return out
+
+    def arenas(self):
+        """The updated arena set, in the order it came in."""
+        layers, _, bt, *row = self._kv[0].shape
+        if self.layer != layers:
+            raise ValueError(
+                f"the model called its attention {self.layer} times over "
+                f"an arena of {layers} layers")
+        if self._prompt_rows:
+            import jax.numpy as jnp
+            self._write(
+                (slice(None), self.block_tables.reshape(-1)),
+                [jnp.stack(r).reshape(layers, -1, bt, *row)
+                 for r in zip(*self._prompt_rows)])
+            self._prompt_rows = []
+        return (*self._kv, *self._scales)
 
 
 class KVCacheManager:
@@ -638,6 +748,28 @@ class KVCacheManager:
                             for b, h in list(self._cached.items())))
 
     # -- donation round-trip ----------------------------------------------
+    def arenas(self) -> Tuple[Any, ...]:
+        """The arena set a program takes and hands back, in :meth:`swap`'s
+        order: keys and values, then their scale planes when int8."""
+        if self.quantized:
+            return (self.arena_k, self.arena_v, self.scale_k, self.scale_v)
+        return (self.arena_k, self.arena_v)
+
+    def abstract_arenas(self) -> Tuple[Any, ...]:
+        """Placeholders of :meth:`arenas` to lower a program on. On a
+        mesh they carry the arena's NamedSharding: an AOT-compiled
+        executable rejects committed inputs whose sharding differs from
+        what it was lowered with, so the placement is part of the
+        lowering."""
+        import jax
+        arena = jax.ShapeDtypeStruct(self.arena_k.shape, self.dtype,
+                                     sharding=self.arena_sharding)
+        if not self.quantized:
+            return (arena, arena)
+        scale = jax.ShapeDtypeStruct(self.scale_k.shape, np.float32,
+                                     sharding=self.scale_sharding)
+        return (arena, arena, scale, scale)
+
     def swap(self, arena_k, arena_v, scale_k=None, scale_v=None) -> None:
         """Store the (donated-and-returned) arena set back after a
         prefill/decode program call; the old references are dead buffers

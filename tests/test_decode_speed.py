@@ -206,6 +206,59 @@ def test_chunked_prefill_with_prefix_cache_combined():
         srv.close()
 
 
+# -- a block the lane has never seen -----------------------------------------
+
+def test_lane_serves_an_unseen_block_without_an_edit():
+    """The lane holds no model mathematics: a trunk whose blocks come
+    from a ``block_factory`` and carry a gated feed-forward layer
+    (``ffn/mlp_gate``, ``ffn/mlp_up``, ``ffn/mlp_down``) decodes through
+    prefill, decode and chunk to the tokens of a full recompute. The
+    model is this test's own, not the zoo's."""
+    import jax.numpy as jnp
+    from mmlspark_tpu.models import zoo
+    from mmlspark_tpu.models.zoo.decoder import SwiGluMlp
+    from mmlspark_tpu.models.zoo.transformer import (
+        DecoderBlock, TransformerLM,
+    )
+    dim, heads, max_len = 64, 4, 128
+
+    def block_factory(i, name):
+        return DecoderBlock(
+            dim, heads, dtype=jnp.float32, name=name,
+            ffn_factory=lambda n: SwiGluMlp(dim, 96, jnp.float32, name=n))
+
+    @zoo.register_model("_test_lm_swiglu")
+    def _lm():
+        return dict(
+            module=TransformerLM(vocab=256, dim=dim, depth=2, heads=heads,
+                                 max_len=max_len, dtype=jnp.float32,
+                                 block_factory=block_factory),
+            input_shape=(max_len,), input_dtype="int32",
+            feature_layer="hidden", feature_dim=dim,
+            layer_names=["hidden", "logits"], seq_attention=True)
+
+    config.set("generate.prefill_chunk", 8)
+    config.set("generate.prefix_cache", False)
+    srv = Server({"lm": JaxModel().set_model("_test_lm_swiglu", seed=3)},
+                 start=False)
+    try:
+        lane = srv.enable_generate("lm", start=False)
+        params = lane.gen.params["params"]
+        assert set(params["block0"]["ffn"]) == {"mlp_gate", "mlp_up",
+                                                "mlp_down"}
+        short, long_p = [5, 9, 17], list(range(2, 29))   # whole, 4 chunks
+        futs = [srv.submit_generate("lm", p, max_new_tokens=6)
+                for p in (short, long_p)]
+        outs = _run_lane(srv, lane, futs)
+        assert {k for k, _ in lane.gen._programs} == {"prefill", "chunk",
+                                                      "decode"}
+        assert outs[0]["tokens"] == _reference_greedy(srv, "lm", short, 6)
+        assert outs[1]["tokens"] == _reference_greedy(srv, "lm", long_p, 6)
+    finally:
+        srv.close()
+        zoo._ZOO.pop("_test_lm_swiglu", None)
+
+
 # -- speculative decoding ----------------------------------------------------
 
 def _spec_server(draft_seed, spec_tokens=3):

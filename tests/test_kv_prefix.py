@@ -10,12 +10,16 @@ properties the fuzz at the bottom guards (the ISSUE's acceptance bar):
   the block is shared it must come back as a copy-on-write pair.
 - **Free-list conservation.** At every step each leasable block is in
   exactly one of {free, cached, refcounted} (``check_conservation``).
+
+One device test beside them: the arena as an attention
+(:class:`CacheView`) against plain attention over the same rows.
 """
 import numpy as np
 import pytest
 
 from mmlspark_tpu.serve.kvcache import (
-    RESERVED_BLOCK, KVCacheManager, blocks_needed, prefix_block_hashes,
+    RESERVED_BLOCK, CacheView, KVCacheManager, blocks_needed,
+    prefix_block_hashes,
 )
 
 
@@ -284,3 +288,51 @@ def test_refcount_cow_conservation_fuzz(seed):
         kv.free(sid)
     assert kv.used_blocks == 0
     assert kv.check_conservation()
+
+
+# -- the arena as an attention -----------------------------------------------
+
+@pytest.mark.parametrize("kv_dtype,tol", [(None, 1e-5), ("int8", 5e-2)])
+def test_cache_view_matches_plain_attention_over_the_same_rows(kv_dtype,
+                                                               tol):
+    """Rows written through a block table, a window at a time, and read
+    back as context give what plain causal attention gives over the same
+    rows; a row that is not valid lands in the scratch block only."""
+    import jax.numpy as jnp
+    from mmlspark_tpu.parallel.sequence import _reference_attention
+    layers, heads, hd, bt, B, W = 2, 2, 4, 8, 2, 3
+    kv = KVCacheManager(layers=layers, heads=heads, head_dim=hd,
+                        num_blocks=16, block_tokens=bt, kv_dtype=kv_dtype)
+    tables = np.stack([kv.block_table(f"s{b}", W) for b in range(B)
+                       if kv.try_reserve(f"s{b}", 11)])
+    assert RESERVED_BLOCK in tables[:, 2] and tables[:, :2].all()
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.normal(size=(layers, B, 12, heads, hd)),
+                           jnp.float32) for _ in range(3))
+    arenas, outs = kv.arenas(), []
+    # positions 0..7, then 8..10 in a window of four whose last row is pad
+    for lo, hi, n_valid in ((0, 8, 8), (8, 12, 3)):
+        positions = jnp.broadcast_to(jnp.arange(lo, hi), (B, hi - lo))
+        valid = jnp.broadcast_to(jnp.arange(hi - lo) < n_valid,
+                                 positions.shape)
+        view = CacheView(arenas, jnp.asarray(tables), positions, valid)
+        outs.append(jnp.stack([
+            view(q[i, :, lo:hi], k[i, :, lo:hi], v[i, :, lo:hi])
+            for i in range(layers)])[:, :, :n_valid])
+        arenas = view.arenas()
+    assert len(arenas) == (4 if kv_dtype else 2)
+    want = jnp.stack([
+        _reference_attention(q[i, :, :11], k[i, :, :11], v[i, :, :11], True)
+        for i in range(layers)])
+    np.testing.assert_allclose(np.concatenate(outs, axis=2), want,
+                               atol=tol, rtol=tol)
+    # the pad row (position 11) went to the scratch block, not to the
+    # sequences' own: their second block holds rows 8..10 and nothing else
+    keys = np.asarray(arenas[0])
+    second = keys[:, tables[:, 1]]
+    assert second[:, :, :3].any(axis=(-2, -1)).all()
+    assert not second[:, :, 3:].any()
+    assert keys[:, RESERVED_BLOCK, 3].any()
+    view(q[0, :, 8:], k[0, :, 8:], v[0, :, 8:])     # one call too many
+    with pytest.raises(ValueError, match="3 times"):
+        view.arenas()
