@@ -22,9 +22,12 @@ Parameter names hit the rules of ``parallel/sharding.DEFAULT_RULES``
 (``attn_query_*`` / ``attn_key_value_*`` / ``attn_out``, ``mlp_gate`` /
 ``mlp_up`` / ``mlp_down``, ``experts_*``, ``router``, ``lm_head``).
 
-Blocks are recomputed in the backward pass one by one (``nn.remat``): a
-block saves its input and nothing else, which is what lets 4,096-token rows
-train beside the optimizer's state on one chip.
+Blocks are recomputed in the backward pass one by one (``nn.remat``), which
+is what lets 4,096-token rows train beside the optimizer's state on one
+chip. A block keeps its input and a short list of named values whose
+recomputation costs more than their bytes (``Glm4MoeLite._block``): the
+flash kernel's output and log-sum-exps, so that its forward runs once a
+block and not twice, and the SwiGLU gate and up products.
 """
 from __future__ import annotations
 
@@ -33,12 +36,15 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from mmlspark_tpu.models.zoo import register_model
 from mmlspark_tpu.models.zoo.moe import DroplessMoe
 from mmlspark_tpu.parallel.sequence import full_attention
 
 _INIT = nn.initializers.normal(0.02)
+# the checkpoint name of ``SwiGluMlp``'s gate and up products
+MLP_GATE_UP = "mlp_gate_up"
 
 
 class RMSNorm(nn.Module):
@@ -130,9 +136,11 @@ class SwiGluMlp(nn.Module):
     @nn.compact
     def __call__(self, x):
         x = x.astype(self.dtype)
-        h = nn.silu(_dense(self.hidden, self.dtype, "mlp_gate")(x)) \
-            * _dense(self.hidden, self.dtype, "mlp_up")(x)
-        return _dense(self.dim, self.dtype, "mlp_down")(h)
+        gate = checkpoint_name(
+            _dense(self.hidden, self.dtype, "mlp_gate")(x), MLP_GATE_UP)
+        up = checkpoint_name(
+            _dense(self.hidden, self.dtype, "mlp_up")(x), MLP_GATE_UP)
+        return _dense(self.dim, self.dtype, "mlp_down")(nn.silu(gate) * up)
 
 
 class PartsBlock(nn.Module):
@@ -219,8 +227,26 @@ class Glm4MoeLite(nn.Module):
                     name=m)) if self.shared_experts else None,
                 dtype=dt, name=n)
 
-        # recomputed in the backward pass: a block saves its input alone
-        return nn.remat(PartsBlock)(
+        # Recomputed in the backward pass, but for what is named here (the
+        # names sit where the values are made; in units of the block's
+        # input, bf16 (B, L, dim)): the flash kernel's output and log-sum-
+        # exps, 2.5, without which its forward call runs twice a block; the
+        # SwiGLU gate and up products, 10 in the dense block and 1.5 in a
+        # routed block's shared expert. Each paid on the chip (PERF.md
+        # section 6, PR 29: +5.6% and +1.5% of a step). Left to the
+        # recomputation: the residual stream after attention (1 a block,
+        # +0.7%: under the 1% a name has to pay); q, k, v (7.5 a block,
+        # 1.5 GB a step, for under 10 ms); the routed experts' ragged_dot
+        # intermediates (1 GB a step for 5 ms, and the benchmark's
+        # moe.expert_matmul_roofline counts their recomputation as required
+        # work); dots_with_no_batch_dims_saveable (about 3 GB: no room
+        # beside AdamW's state). Attention that is not the flash kernel
+        # carries no such name and keeps what it kept before. (Imported
+        # here: Pallas costs every importer of the zoo over a second.)
+        from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
+        return nn.remat(
+            PartsBlock, policy=jax.checkpoint_policies.save_only_these_names(
+                FLASH_RESIDUALS, MLP_GATE_UP))(
             lambda n: RMSNorm(self.eps, name=n), attention, ffn, name=name)
 
     @nn.compact

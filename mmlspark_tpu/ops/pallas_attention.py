@@ -41,6 +41,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -180,7 +181,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     recomputes the probabilities from them block by block in VMEM, skips
     the causal blocks that are wholly masked, and multiplies in the input
     dtype with float32 accumulation. Training keeps the O(L * block)
-    memory profile; no score-sized block reaches HBM in either pass."""
+    memory profile; no score-sized block reaches HBM in either pass.
+
+    Under differentiation the output and the log-sum-exps carry the
+    checkpoint name ``FLASH_RESIDUALS``: a caller that recomputes its
+    forward pass (``jax.checkpoint`` / ``nn.remat``) names it in
+    ``save_only_these_names`` and the recomputation holds no second
+    forward call (``models/zoo/decoder.py`` does). With any other policy,
+    or none, the name changes nothing."""
     return _flash_forward(q, k, v, causal, block_q, block_k)[0]
 
 
@@ -251,9 +259,17 @@ def supports(q_shape, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> bool:
         and d % 8 == 0 and L * d <= _VMEM_KV_LIMIT
 
 
+# One name for both: the backward pass needs the output AND the log-sum-
+# exps, so a policy that saved one of them would still run the call again.
+# The output returned and the output saved are the same named value.
+FLASH_RESIDUALS = "flash_attention_residuals"
+
+
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
                               save_lse=True)
+    out = checkpoint_name(out, FLASH_RESIDUALS)
+    lse = checkpoint_name(lse, FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 

@@ -12,6 +12,7 @@ near zero).
 import sys
 from pathlib import Path
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -161,6 +162,89 @@ def test_three_adamw_steps_match_the_reference():
                                    rtol=2e-3, err_msg=k)
     # every routed slot of the uncut tiny model is held here
     assert float(m["moe.slots_here"]) == 3 * ROWS * LEN * 2
+
+
+# ------------------------------- what a block keeps for its backward
+def _flash(q, k, v, causal=True):
+    from mmlspark_tpu.parallel.sequence import full_attention
+    return full_attention(q, k, v, causal=causal, use_flash="require")
+
+
+_ATTENTION = {"flash": (_flash, 512), "reference": (None, 32)}
+_flax_remat = nn.remat
+# what ``decoder.py``'s ``nn.remat`` is, for the blocks compared with
+_REMAT = {"kept": _flax_remat,               # the module as it is
+          "input_only": lambda cls, **kw: _flax_remat(cls),
+          "nothing_recomputed": lambda cls, **kw: cls}
+
+
+def _block_grads(attention, jaxpr=False):
+    """Gradients of the tiny model (three blocks and the MTP module's)
+    under ``nn.remat`` as it stands when called, or their jaxpr."""
+    attention_fn, length = _ATTENTION[attention]
+    module = _module(max_len=length, attention_fn=attention_fn)
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, CFG["vocab_size"], size=(1, length)).astype(np.int32))
+    params = module.init(jax.random.PRNGKey(3), tokens)
+
+    def loss(p):
+        out = module.apply(p, tokens, hidden=True)
+        return jnp.sum(jnp.sin(out["hidden"])) \
+            + jnp.sum(jnp.sin(out["mtp_hidden"]))
+    if jaxpr:
+        return jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    return jax.jit(jax.grad(loss))(params)
+
+
+@pytest.fixture(scope="module")
+def kept_grads():
+    """The module's own gradients, once per attention."""
+    return {name: _block_grads(name) for name in _ATTENTION}
+
+
+def _pallas_calls(jaxpr):
+    """Names of the Pallas calls a jaxpr makes, one per call site, the
+    jitted and rematerialised sub-programs walked."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"] or "")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(_pallas_calls(sub))
+    return names
+
+
+@pytest.mark.parametrize("remat", ["input_only", "nothing_recomputed"])
+@pytest.mark.parametrize("attention", list(_ATTENTION))
+def test_what_a_block_keeps_changes_no_gradient(monkeypatch, kept_grads,
+                                                attention, remat):
+    """The values a block keeps across its recomputation are the ones the
+    recomputation would have made: gradients as with a block that keeps
+    its input alone, and as with nothing recomputed at all. Under the
+    flash kernel (interpret mode) the kernel's residuals and the SwiGLU
+    products are kept, under the reference attention the products alone."""
+    monkeypatch.setattr(nn, "remat", _REMAT[remat])
+    want = _block_grads(attention)
+    for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path(kept_grads[attention]),
+            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-6, atol=1e-6 * float(jnp.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("remat,forward_calls", [
+    ("kept", 4), ("input_only", 8), ("nothing_recomputed", 4)])
+def test_a_recomputed_block_holds_one_flash_forward(monkeypatch, remat,
+                                                    forward_calls):
+    """Four blocks in the gradient's jaxpr: the backward pass of a block
+    that keeps the kernel's output and log-sum-exps has no second forward
+    call."""
+    monkeypatch.setattr(nn, "remat", _REMAT[remat])
+    calls = _pallas_calls(_block_grads("flash", jaxpr=True))
+    backward = [c for c in calls if c == "long_attention_bwd"]
+    assert len(backward) == 4
+    assert len(calls) - len(backward) == forward_calls
 
 
 # ------------------------------------------------------ the expert layer

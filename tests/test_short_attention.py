@@ -337,3 +337,41 @@ def test_mosaic_compiles_the_flash_kernel_and_its_backward(
     b, L, h, _ = shape
     assert f"[{b},{h},{L},{L}]" not in text
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("width", [64, 256], ids=["d64", "d256"])
+def test_a_recomputed_decoder_block_runs_the_flash_forward_once(
+        topo, as_on_chip, width):
+    """The gradient of a small ``glm4_moe_lite`` (two blocks and the MTP
+    module's, each under ``nn.remat``) compiled for a v5e: ONE forward
+    flash call a block, where a block that kept only its input ran two
+    (the second to rebuild the backward's residuals), and one backward
+    call a block; both still found by the benchmark's accepted patterns."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topo.devices[0])
+    module = build_model(
+        "glm4_moe_lite_tiny", depth=2, max_len=512, nope=width * 3 // 4,
+        rope=width // 4, v_dim=width, dtype=jnp.bfloat16)["module"]
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 512), jnp.int32)))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        shapes)
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one)
+
+    def grads(p, x):
+        def loss(p):
+            out = module.apply(p, x, hidden=True)
+            return out["hidden"].sum() + out["mtp_hidden"].sum()
+        return jax.grad(loss)(p)
+
+    text = jax.jit(grads).lower(params, tokens).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    blocks = 3
+    for metric in ("kernel.flash_attention_ms", "kernel.flash_fwd_roofline",
+                   "kernel.flash_bwd_ms"):
+        rx = _benchmark_pattern(metric)
+        assert sum(bool(rx.search(c)) for c in calls) == blocks, metric
+    # and no further forward call that the patterns would not find
+    assert sum("flash" in c.split(" = ")[0] for c in calls) == blocks
