@@ -100,6 +100,10 @@ class Sizes:
     flash_bf16_d256: Tuple[int, int, int, int] = (2, 4096, 20, 256)
     flash_fp32: Tuple[int, int, int, int] = (1, 16384, 2, 64)
     short_bf16: Tuple[int, int, int, int] = (128, 197, 12, 64)
+    # the gated delta rule as benchmark cell qwen3-next-train-ep16share
+    # calls it: (B, L, value heads, head width), chunks of 64
+    gated_delta: Tuple[int, int, int, int] = (2, 4096, 32, 128)
+    gated_delta_chunk: int = 64
 
 
 FULL = Sizes()
@@ -649,6 +653,41 @@ def kernel_short_attention(sz: Sizes, rehearsal: bool, record) -> None:
     record("short_fwd_bwd_bf16", sz.short_bf16, c, s, err)
 
 
+def kernel_gated_delta(sz: Sizes, rehearsal: bool, record) -> None:
+    """The chunked gated delta rule (``ops/linear_attention.py``; XLA's
+    batched products and one scan, no Mosaic call), forward and backward
+    with bfloat16 operands, against its token-by-token float32 form: the
+    worst relative error over the output and the five gradients. Decays as
+    the model's init makes them (``-A softplus(1)``, ``A`` up to 16)."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.ops import linear_attention as la
+
+    B, L, H, d = shape = sz.gated_delta
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    q, k = (la.l2_normalize(jax.random.normal(kk, shape))
+            for kk in ks[:2])
+    v, w = (jax.random.normal(kk, shape) for kk in ks[2:4])
+    g = -jnp.linspace(1e-3, 16.0, H) * jax.nn.softplus(
+        1.0 + jax.random.normal(ks[4], (B, L, H)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, L, H)))
+
+    def both(impl, dtype):
+        def out(*a):
+            return la.gated_delta_rule(
+                *a, chunk=sz.gated_delta_chunk, impl=impl, dtype=dtype)
+        return jax.jit(lambda *a: (out(*a), jax.grad(
+            lambda *b: (out(*b) * w).sum(), argnums=(0, 1, 2, 3, 4))(*a)))
+    args = (q, k, v, g, beta)
+    (got, got_g), c, s = _kernel_run(both("chunked", jnp.bfloat16), args)
+    want, want_g = both("recurrent", jnp.float32)(*args)
+    err = max(_rel_err(got, want),
+              *(_rel_err(a, b) for a, b in zip(got_g, want_g)))
+    check(got.shape == shape and err <= BF16_REL_TOL,
+          f"gated_delta_bf16: rel err {err:.3g}")
+    record("gated_delta_bf16", shape, c, s, err)
+
+
 def kernel_flash_sharded(sz: Sizes, rehearsal: bool, record) -> None:
     """Data-parallel flash on a multi-device host: each device runs the
     kernel on its own batch row (nothing to do on one device)."""
@@ -685,8 +724,8 @@ def kernel_flash_sharded(sz: Sizes, rehearsal: bool, record) -> None:
 
 
 KERNEL_CHECKS = (kernel_normalize, kernel_crop, kernel_flash_forward,
-                 kernel_flash_backward, kernel_short_attention,
-                 kernel_flash_sharded)
+                 kernel_flash_backward, kernel_gated_delta,
+                 kernel_short_attention, kernel_flash_sharded)
 
 
 def leg_kernels(sz: Sizes, meter: CompileMeter,

@@ -1,5 +1,7 @@
-"""Decoder LMs built from parts, and the first family made of them:
-``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's).
+"""Decoder LMs built from parts, and the families made of them:
+``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's) and
+``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
+softmax layer, every feed-forward part a routed layer).
 
 ``PartsBlock`` is the pre-norm residual block with nothing fixed: its norm,
 its attention (which owns its projections and its positions) and its
@@ -9,23 +11,33 @@ parameter names and tied head stay as they are for the generate lane).
 
 Parts here:
 
-- ``RMSNorm``: float32 in and out, epsilon from the configuration;
+- ``RMSNorm``: float32 in and out, epsilon from the configuration; with
+  ``offset`` the scale is ``1 + w`` and ``w`` starts at zero;
 - ``rotary``: rotary positions on the last axis, half-split pairing
-  (dimension ``i`` turns with ``i + R/2``), float32 angles;
+  (dimension ``i`` turns with ``i + R/2``), float32 angles; with ``width``
+  on the first ``width`` dimensions only, the rest passing through;
 - ``MlaAttention``: multi-head latent attention in its expanded (training)
   form: a low-rank query, one compressed key/value row per token, a rotary
   slice on every query head and ONE rotary key shared by all heads;
+- ``GatedAttention``: softmax attention over grouped key/value heads (each
+  repeated to the query heads it serves at the attention call, so the
+  flash kernel and its backward run as they are), norms on q and k, a
+  rotary slice, and a sigmoid gate on the output;
+- ``GatedDeltaNet``: linear attention with a recurrent state
+  (``ops/linear_attention.py``): a short causal convolution, the gated
+  delta rule, a gated norm on the output;
 - ``SwiGluMlp``: ``down(silu(gate x) * up x)``, no biases;
 - ``zoo/moe.DroplessMoe``: the routed layer, told which experts it holds.
 
 Parameter names hit the rules of ``parallel/sharding.DEFAULT_RULES``
-(``attn_query_*`` / ``attn_key_value_*`` / ``attn_out``, ``mlp_gate`` /
-``mlp_up`` / ``mlp_down``, ``experts_*``, ``router``, ``lm_head``).
+(``attn_query_*`` / ``attn_key*`` / ``attn_value`` / ``attn_qkvz`` /
+``attn_out``, ``mlp_gate`` / ``mlp_up`` / ``mlp_down``, ``experts_*``,
+``router``, ``lm_head``).
 
 Blocks are recomputed in the backward pass one by one (``nn.remat``), which
 is what lets 4,096-token rows train beside the optimizer's state on one
 chip. A block keeps its input and a short list of named values whose
-recomputation costs more than their bytes (``Glm4MoeLite._block``): the
+recomputation costs more than their bytes (``_remat_block``): the
 flash kernel's output and log-sum-exps, so that its forward runs once a
 block and not twice, and the SwiGLU gate and up products.
 """
@@ -49,19 +61,29 @@ MLP_GATE_UP = "mlp_gate_up"
 
 class RMSNorm(nn.Module):
     eps: float = 1e-5
+    offset: bool = False        # scale = 1 + w, w zero at init
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.offset
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.offset:
+            scale = 1.0 + scale
         x = x.astype(jnp.float32)
         return x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
+def rotary(x: jax.Array, theta: float,
+           width: Optional[int] = None) -> jax.Array:
     """Rotary positions over the last axis of ``(B, L, H, R)``: position
-    ``l`` turns the pair ``(i, i + R/2)`` by ``l * theta**(-2i/R)``."""
+    ``l`` turns the pair ``(i, i + R/2)`` by ``l * theta**(-2i/R)``. With
+    ``width`` only the first ``width`` dimensions turn (pairs ``(i, i +
+    width/2)``, angles over ``width``) and the rest pass through."""
+    if width is not None and width != x.shape[-1]:
+        return jnp.concatenate(
+            [rotary(x[..., :width], theta), x[..., width:]], -1)
     L, R = x.shape[1], x.shape[-1]
     inv = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
     ang = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None, :]
@@ -128,6 +150,111 @@ class MlaAttention(nn.Module):
                 o.reshape(B, L, H * self.v_dim))
 
 
+class GatedAttention(nn.Module):
+    """Softmax attention with grouped key/value heads, a norm on every q
+    and k head, rotary positions on the first ``rotary_width`` of each
+    head, and a sigmoid gate on the output: ``[q | gate] = x W_q`` (halves
+    per head), ``o <- o * sigmoid(gate)``, ``y = o W_o``; no biases.
+
+    Each key/value head is repeated to the ``heads / kv_heads`` query
+    heads it serves where ``attention_fn(q, k, v)`` is called, so the
+    fused kernels take it as any equal-headed call; the repeated K/V
+    traffic is the price (a kernel that reads ``kv_heads`` heads for
+    ``heads`` is not there yet)."""
+    dim: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rotary_width: int
+    theta: float = 1e7
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads over "
+                             f"{self.kv_heads} key/value heads")
+        B, L, _ = x.shape
+        H, G, d, dt = self.heads, self.kv_heads, self.head_dim, self.dtype
+        attn_fn = self.attention_fn or full_attention
+        with jax.named_scope("gated_attention"):
+            x = x.astype(dt)
+            qg = _dense(H * 2 * d, dt, "attn_query_gate")(x).reshape(
+                B, L, H, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:]
+            k = _dense(G * d, dt, "attn_key")(x).reshape(B, L, G, d)
+            v = _dense(G * d, dt, "attn_value")(x).reshape(B, L, G, d)
+            q = RMSNorm(self.eps, offset=True, name="query_norm")(q)
+            k = RMSNorm(self.eps, offset=True, name="key_norm")(k)
+            q = rotary(q, self.theta, self.rotary_width).astype(dt)
+            k = rotary(k, self.theta, self.rotary_width).astype(dt)
+            k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
+            o = attn_fn(q, k, v, causal=True)
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+            return _dense(self.dim, dt, "attn_out")(o.reshape(B, L, H * d))
+
+
+class GatedDeltaNet(nn.Module):
+    """Gated DeltaNet (arXiv:2412.06464) as Qwen3-Next lays it out:
+    ``[q | k | v | z] = x W_qkvz`` and ``[b | a] = x W_ba``; ``[q | k | v]``
+    pass a causal depthwise convolution and ``silu``; ``beta = sigmoid(b)``,
+    ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; q and k are
+    L2-normalised over the head, each key head serves ``value_heads /
+    key_heads`` value heads; the gated delta rule
+    (``ops/linear_attention.gated_delta_rule``: chunked on whole rows);
+    ``o <- rmsnorm(o) * w_n * silu(z)`` over each head; ``y = o W_o``.
+    Columns of ``W_qkvz`` are ``[q | k | v | z]``, head-major inside each
+    (a checkpoint's per-key-head interleaving is a permutation of them)."""
+    dim: int
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int = 4
+    eps: float = 1e-6
+    chunk: int = 64
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        from mmlspark_tpu.ops import linear_attention as la
+        if self.value_heads % self.key_heads:
+            raise ValueError(f"{self.value_heads} value heads over "
+                             f"{self.key_heads} key heads")
+        B, L, _ = x.shape
+        Hk, Hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
+                          self.value_dim)
+        dt, f32 = self.dtype, jnp.float32
+        with jax.named_scope("gated_delta_net"):
+            x = x.astype(dt)
+            qkvz = _dense(2 * Hk * dk + 2 * Hv * dv, dt, "attn_qkvz")(x)
+            ba = _dense(2 * Hv, dt, "attn_ba")(x).astype(f32)
+            conv = self.param("conv_kernel", _INIT,
+                              (self.conv_width, 2 * Hk * dk + Hv * dv), f32)
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, f32, 1e-3, 16.0)), (Hv,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,), f32)
+            mixed = nn.silu(la.causal_conv1d(
+                qkvz[..., :2 * Hk * dk + Hv * dv], conv))
+            z = qkvz[..., 2 * Hk * dk + Hv * dv:].reshape(B, L, Hv, dv)
+            q = mixed[..., :Hk * dk].reshape(B, L, Hk, dk)
+            k = mixed[..., Hk * dk:2 * Hk * dk].reshape(B, L, Hk, dk)
+            v = mixed[..., 2 * Hk * dk:].reshape(B, L, Hv, dv)
+            beta = jax.nn.sigmoid(ba[..., :Hv])
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)
+            q, k = (jnp.repeat(la.l2_normalize(t), Hv // Hk, axis=2)
+                    for t in (q, k))
+            o = la.gated_delta_rule(q, k, v, g, beta, chunk=self.chunk,
+                                    dtype=dt)
+            o = RMSNorm(self.eps, name="gate_norm")(o) \
+                * nn.silu(z.astype(f32))
+            return _dense(self.dim, dt, "attn_out")(
+                o.astype(dt).reshape(B, L, Hv * dv))
+
+
 class SwiGluMlp(nn.Module):
     dim: int
     hidden: int
@@ -158,6 +285,66 @@ class PartsBlock(nn.Module):
         out = self.ffn("ffn")(self.norm("norm2")(h))
         y, stats = out if isinstance(out, tuple) else (out, {})
         return h + y.astype(x.dtype), stats
+
+
+class SplitBlock(nn.Module):
+    """``PartsBlock`` with its two halves as methods (``mix``: ``x +
+    attention(norm(x))``; ``feed``: ``h + ffn(norm(h))``), so that each can
+    be a unit of recomputation of its own: what the backward pass of the
+    feed-forward half keeps never lies beside what the mixer's keeps. The
+    parts are made in ``setup`` under ``PartsBlock``'s names (``norm1``,
+    ``attn``, ``norm2``, ``ffn``); the factories are called with no name."""
+    make_norm: Callable[[Optional[str]], nn.Module]
+    make_attention: Callable[[Optional[str]], nn.Module]
+    make_ffn: Callable[[Optional[str]], nn.Module]
+
+    def setup(self):
+        self.norm1, self.attn = self.make_norm(None), self.make_attention(None)
+        self.norm2, self.ffn = self.make_norm(None), self.make_ffn(None)
+
+    def mix(self, x):
+        return x + self.attn(self.norm1(x)).astype(x.dtype)
+
+    def feed(self, h) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        out = self.ffn(self.norm2(h))
+        y, stats = out if isinstance(out, tuple) else (out, {})
+        return h + y.astype(h.dtype), stats
+
+    def __call__(self, x) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        return self.feed(self.mix(x))
+
+
+def _remat_block(norm, attention, ffn, name: str,
+                 split: bool = False) -> nn.Module:
+    """A ``PartsBlock`` recomputed in the backward pass, but for what is
+    named here (the names sit where the values are made; in units of the
+    block's input, bf16 (B, L, dim)): the flash kernel's output and log-sum-
+    exps, 2.5, without which its forward call runs twice a block; the
+    SwiGLU gate and up products, 10 in ``glm4_moe_lite``'s dense block and
+    1.5 in a routed block's shared expert. Each paid on the chip (PERF.md
+    section 6, PR 29: +5.6% and +1.5% of a step). Left to the
+    recomputation: the residual stream after attention (1 a block, +0.7%:
+    under the 1% a name has to pay); q, k, v (7.5 a block, 1.5 GB a step,
+    for under 10 ms); the routed experts' ragged_dot intermediates (1 GB a
+    step for 5 ms, and the benchmark's moe.expert_matmul_roofline counts
+    their recomputation as required work);
+    dots_with_no_batch_dims_saveable (about 3 GB: no room beside AdamW's
+    state). Attention that is not the flash kernel carries no such name
+    and keeps what it kept before; a Gated DeltaNet layer names nothing
+    (its scan keeps a state a chunk across ITS backward, inside the
+    recomputation). ``split`` recomputes the block's two halves apart
+    (``SplitBlock``) and keeps the residual stream between them: for a
+    block whose halves' backward passes do not fit side by side.
+    (Imported here: Pallas costs every importer of the zoo over a
+    second.)"""
+    from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
+    policy = jax.checkpoint_policies.save_only_these_names(
+        FLASH_RESIDUALS, MLP_GATE_UP)
+    if split:
+        return nn.remat(SplitBlock, policy=policy, methods=("mix", "feed"))(
+            norm, attention, ffn, name=name)
+    return nn.remat(PartsBlock, policy=policy)(norm, attention, ffn,
+                                               name=name)
 
 
 class Head(nn.Module):
@@ -227,27 +414,8 @@ class Glm4MoeLite(nn.Module):
                     name=m)) if self.shared_experts else None,
                 dtype=dt, name=n)
 
-        # Recomputed in the backward pass, but for what is named here (the
-        # names sit where the values are made; in units of the block's
-        # input, bf16 (B, L, dim)): the flash kernel's output and log-sum-
-        # exps, 2.5, without which its forward call runs twice a block; the
-        # SwiGLU gate and up products, 10 in the dense block and 1.5 in a
-        # routed block's shared expert. Each paid on the chip (PERF.md
-        # section 6, PR 29: +5.6% and +1.5% of a step). Left to the
-        # recomputation: the residual stream after attention (1 a block,
-        # +0.7%: under the 1% a name has to pay); q, k, v (7.5 a block,
-        # 1.5 GB a step, for under 10 ms); the routed experts' ragged_dot
-        # intermediates (1 GB a step for 5 ms, and the benchmark's
-        # moe.expert_matmul_roofline counts their recomputation as required
-        # work); dots_with_no_batch_dims_saveable (about 3 GB: no room
-        # beside AdamW's state). Attention that is not the flash kernel
-        # carries no such name and keeps what it kept before. (Imported
-        # here: Pallas costs every importer of the zoo over a second.)
-        from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
-        return nn.remat(
-            PartsBlock, policy=jax.checkpoint_policies.save_only_these_names(
-                FLASH_RESIDUALS, MLP_GATE_UP))(
-            lambda n: RMSNorm(self.eps, name=n), attention, ffn, name=name)
+        return _remat_block(lambda n: RMSNorm(self.eps, name=n), attention,
+                            ffn, name)
 
     @nn.compact
     def __call__(self, tokens, hidden: bool = False):
@@ -280,17 +448,105 @@ class Glm4MoeLite(nn.Module):
             return head(out["hidden"])
         if self.is_initializing():
             head(out["hidden"][:, :1])
-        loads = [s for s in loads if s]
-        out["stats"] = {
-            "moe.slots_here": sum(
-                s["slots_here"] for s in loads).astype(jnp.float32),
-            "moe.load_max_over_mean": jnp.max(jnp.stack(
-                [s["load_max_over_mean"] for s in loads])),
-        } if loads else {}
+        out["stats"] = _load_stats(loads)
         return out
 
 
-def _spec(module: Glm4MoeLite, max_len: int):
+def _load_stats(loads) -> Dict[str, jax.Array]:
+    """The routed layers' load as the trainer's ring carries it:
+    ``moe.slots_here`` summed over them, ``moe.load_max_over_mean`` of the
+    worst."""
+    loads = [s for s in loads if s]
+    if not loads:
+        return {}
+    return {"moe.slots_here": sum(
+                s["slots_here"] for s in loads).astype(jnp.float32),
+            "moe.load_max_over_mean": jnp.max(jnp.stack(
+                [s["load_max_over_mean"] for s in loads]))}
+
+
+class Qwen3Next(nn.Module):
+    """``qwen3_next``: layer ``l`` is ``GatedAttention`` when ``(l + 1) %
+    attention_interval == 0``, else ``GatedDeltaNet``; every feed-forward
+    part is a ``DroplessMoe`` routed by softmax with a gated shared expert;
+    norms are ``1 + w``; final norm, untied head, no multi-token-prediction
+    module. ``__call__`` as ``Glm4MoeLite``'s: logits, or with
+    ``hidden=True`` ``{"hidden", "stats"}`` for the chunked loss."""
+    vocab: int
+    dim: int
+    depth: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rotary_width: int
+    linear_key_heads: int
+    linear_value_heads: int
+    linear_key_dim: int
+    linear_value_dim: int
+    conv_width: int
+    expert_hidden: int
+    shared_hidden: int
+    num_experts: int
+    top_k: int
+    experts_held: Optional[Tuple[int, int]] = None   # (count, first index)
+    attention_interval: int = 4
+    theta: float = 1e7
+    eps: float = 1e-6
+    chunk: int = 64
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    def softmax_layer(self, index: int) -> bool:
+        return (index + 1) % self.attention_interval == 0
+
+    def _block(self, index: int, name: str) -> nn.Module:
+        dt = self.dtype
+
+        def attention(n):
+            if self.softmax_layer(index):
+                return GatedAttention(
+                    self.dim, self.heads, self.kv_heads, self.head_dim,
+                    self.rotary_width, self.theta, self.eps, dt,
+                    self.attention_fn, name=n)
+            return GatedDeltaNet(
+                self.dim, self.linear_key_heads, self.linear_value_heads,
+                self.linear_key_dim, self.linear_value_dim, self.conv_width,
+                self.eps, self.chunk, dt, name=n)
+
+        def ffn(n):
+            return DroplessMoe(
+                self.dim, self.num_experts, self.expert_hidden, self.top_k,
+                experts_held=self.experts_held,
+                shared=lambda m: SwiGluMlp(self.dim, self.shared_hidden, dt,
+                                           name=m),
+                dtype=dt, scores="softmax", shared_gate=True, name=n)
+
+        return _remat_block(
+            lambda n: RMSNorm(self.eps, offset=True, name=n), attention,
+            ffn, name, split=True)
+
+    @nn.compact
+    def __call__(self, tokens, hidden: bool = False):
+        embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype,
+                         embedding_init=_INIT, name="token_embedding")
+        head = Head(self.vocab, name="lm_head")
+        x = embed(tokens)
+        loads = []
+        for i in range(self.depth):
+            x, stats = self._block(i, f"block{i}")(x)
+            loads.append(stats)
+        out = {"hidden": RMSNorm(self.eps, offset=True,
+                                 name="final_norm")(x)}
+        self.sow("intermediates", "hidden", out["hidden"])
+        if not hidden:
+            return head(out["hidden"])
+        if self.is_initializing():
+            head(out["hidden"][:, :1])
+        out["stats"] = _load_stats(loads)
+        return out
+
+
+def _spec(module: nn.Module, max_len: int):
     return dict(
         module=module, input_shape=(max_len,), input_dtype="int32",
         feature_layer="hidden", feature_dim=module.dim,
@@ -331,3 +587,41 @@ _TINY = dict(vocab=96, dim=32, depth=3, heads=2, q_rank=24, kv_rank=16,
 def glm4_moe_lite_tiny(**overrides):
     """Test-scale ``glm4_moe_lite`` (float32, so CPU parity is tight)."""
     return glm4_moe_lite(**{**_TINY, **overrides})
+
+
+@register_model("qwen3_next")
+def qwen3_next(vocab: int = 151936, dim: int = 2048, depth: int = 48,
+               heads: int = 16, kv_heads: int = 2, head_dim: int = 256,
+               rotary_fraction: float = 0.25, linear_key_heads: int = 16,
+               linear_value_heads: int = 32, linear_key_dim: int = 128,
+               linear_value_dim: int = 128, conv_width: int = 4,
+               expert_hidden: int = 512, shared_hidden: int = 512,
+               num_experts: int = 512, top_k: int = 10, experts_held=None,
+               attention_interval: int = 4, theta: float = 1e7,
+               eps: float = 1e-6, chunk: int = 64, max_len: int = 4096,
+               dtype=jnp.bfloat16, attention_fn=None):
+    """Qwen3-Next-80B-A3B as published (huggingface.co/Qwen/
+    Qwen3-Next-80B-A3B-Instruct ``config.json``, ``model_type:
+    qwen3_next``), without its multi-token-prediction module.
+    ``experts_held`` = ``(count, first)`` as for ``glm4_moe_lite``."""
+    held = None if experts_held is None else tuple(experts_held)
+    return _spec(Qwen3Next(
+        vocab, dim, depth, heads, kv_heads, head_dim,
+        int(head_dim * rotary_fraction), linear_key_heads,
+        linear_value_heads, linear_key_dim, linear_value_dim, conv_width,
+        expert_hidden, shared_hidden, num_experts, top_k, held,
+        attention_interval, theta, eps, chunk, dtype, attention_fn), max_len)
+
+
+_QWEN_TINY = dict(vocab=96, dim=32, depth=4, heads=4, kv_heads=2, head_dim=16,
+                  linear_key_heads=2, linear_value_heads=4, linear_key_dim=8,
+                  linear_value_dim=8, expert_hidden=16, shared_hidden=16,
+                  num_experts=16, top_k=3, chunk=8, max_len=64,
+                  dtype=jnp.float32)
+
+
+@register_model("qwen3_next_tiny")
+def qwen3_next_tiny(**overrides):
+    """Test-scale ``qwen3_next`` (float32, so CPU parity is tight): one
+    period of the layer pattern, chunks of 8 tokens."""
+    return qwen3_next(**{**_QWEN_TINY, **overrides})

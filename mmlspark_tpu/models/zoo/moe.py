@@ -156,14 +156,20 @@ def _grouped(x, w, sizes):
 
 
 class DroplessMoe(nn.Module):
-    """Sigmoid-routed experts with a shared expert, no capacity and no
-    dropped token (DeepSeek-V3's layer; ``topk_method: noaux_tc`` with one
-    group).
+    """Routed experts with a shared expert, no capacity and no dropped
+    token. One layer, one dispatch, one set of grouped products; how the
+    scores are made and whether the shared expert is gated are arguments.
 
-    ``s = sigmoid(x W_r)`` in float32 over ALL ``num_experts``; the choice
-    is the top ``top_k`` of ``s + b`` (``router_bias``: no gradient reaches
-    it, it only moves the choice); the weights are ``s`` at the chosen,
-    normalised to 1, times ``scaling``. ``experts_held = (count, first)``
+    ``scores="sigmoid"`` (DeepSeek-V3's layer; ``topk_method: noaux_tc``
+    with one group): ``s = sigmoid(x W_r)`` in float32 over ALL
+    ``num_experts``; the choice is the top ``top_k`` of ``s + b``
+    (``router_bias``: no gradient reaches it, it only moves the choice).
+    ``scores="softmax"``: ``s = softmax(x W_r)`` in float32 over all of
+    them, the choice its top ``top_k``, no bias. Either way the weights are
+    ``s`` at the chosen, normalised to 1, times ``scaling``.
+    ``shared_gate`` puts the shared expert behind a sigmoid of one more
+    output of the token: ``sigmoid(x w_g) * shared(x)``.
+    ``experts_held = (count, first)``
     says which experts' weights live here: the layer computes their part of
     the sum, and a slot whose expert is held elsewhere adds nothing (its
     part is that chip's to add). ``shared(name)`` makes the shared expert, a
@@ -181,6 +187,8 @@ class DroplessMoe(nn.Module):
     scaling: float = 1.0
     shared: Optional[Callable[[str], nn.Module]] = None
     dtype: Any = jnp.bfloat16
+    scores: str = "sigmoid"
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -189,18 +197,24 @@ class DroplessMoe(nn.Module):
         held, first = self.experts_held or (E, 0)
         if not (0 <= first and first + held <= E):
             raise ValueError(f"experts_held {(held, first)} of {E} experts")
+        if self.scores not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown scores {self.scores!r}")
         S = B * L
         xf = x.reshape(S, D)
         init = nn.initializers.normal(0.02)
 
         with jax.named_scope("moe_router"):
-            s = jax.nn.sigmoid(nn.Dense(
+            logits = nn.Dense(
                 E, use_bias=False, dtype=jnp.float32,
                 param_dtype=jnp.float32, kernel_init=init, name="router")(
-                    xf.astype(jnp.float32)))
-            bias = self.param("router_bias", nn.initializers.zeros, (E,),
-                              jnp.float32)
-            _, choice = jax.lax.top_k(s + bias, K)   # indices: no gradient
+                    xf.astype(jnp.float32))
+            if self.scores == "sigmoid":
+                s = jax.nn.sigmoid(logits)
+                ranked = s + self.param(
+                    "router_bias", nn.initializers.zeros, (E,), jnp.float32)
+            else:
+                s = ranked = jax.nn.softmax(logits, axis=-1)
+            _, choice = jax.lax.top_k(ranked, K)     # indices: no gradient
             gate = jnp.take_along_axis(s, choice, axis=-1)
             gate = gate / gate.sum(-1, keepdims=True) * self.scaling
             self.sow("intermediates", "router_choice", choice)
@@ -236,7 +250,13 @@ class DroplessMoe(nn.Module):
             y = jnp.einsum("skd,sk->sd", ys.reshape(S, K, D),
                            jnp.where(here, gate, 0.0))
             if self.shared is not None:
-                y = y + self.shared("shared")(xf).astype(jnp.float32)
+                side = self.shared("shared")(xf).astype(jnp.float32)
+                if self.shared_gate:
+                    side = side * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=jnp.float32,
+                        param_dtype=jnp.float32, kernel_init=init,
+                        name="shared_gate")(xf.astype(jnp.float32)))
+                y = y + side
 
         load = sizes.astype(jnp.float32)
         stats = {"slots_here": slots_here,

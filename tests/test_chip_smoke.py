@@ -35,7 +35,8 @@ TOY = chip_smoke.Sizes(
     normalize=((12, (16, 16, 3)),), crop=(2, 32, 24),
     flash_bf16=(1, 512, 2, 16), flash_bf16_d256=(2, 512, 2, 32),
     flash_fp32=(1, 512, 1, 16),
-    short_bf16=(2, 37, 3, 16))
+    short_bf16=(2, 37, 3, 16), gated_delta=(2, 40, 3, 16),
+    gated_delta_chunk=16)
 
 
 @pytest.fixture(autouse=True)
@@ -105,7 +106,7 @@ def test_every_leg_runs_at_toy_size_on_the_cpu(rehearsal):
     k = legs["kernels"]
     assert k["mosaic_lowering_proven"] is False     # interpreted here
     assert {"flash_fwd_bf16", "flash_fwd_fp32", "flash_bwd_bf16",
-            "flash_bwd_bf16_d256",
+            "flash_bwd_bf16_d256", "gated_delta_bf16",
             "short_fwd_bwd_bf16",
             "flash_fwd_bf16_sharded_x8"} <= set(k["kernels"])
 
