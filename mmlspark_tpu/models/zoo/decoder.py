@@ -367,9 +367,7 @@ class Glm4MoeLite(nn.Module):
     ``__call__(tokens)`` gives ``(B, L, vocab)`` float32 logits of the
     main head. ``__call__(tokens, hidden=True)`` gives what the chunked
     loss wants instead: ``{"hidden", "mtp_hidden", "stats"}``, the normed
-    rows each head reads and the routed layers' load
-    (``moe.slots_here`` summed over them, ``moe.load_max_over_mean`` of
-    the worst).
+    rows each head reads and the routed layers' load (``_load_stats``).
     """
     vocab: int
     dim: int
@@ -454,13 +452,16 @@ class Glm4MoeLite(nn.Module):
 
 def _load_stats(loads) -> Dict[str, jax.Array]:
     """The routed layers' load as the trainer's ring carries it:
-    ``moe.slots_here`` summed over them, ``moe.load_max_over_mean`` of the
+    ``moe.slots_here`` summed over them, ``moe.overflow_layers`` (how many
+    of them ran at full size this step), ``moe.load_max_over_mean`` of the
     worst."""
     loads = [s for s in loads if s]
     if not loads:
         return {}
     return {"moe.slots_here": sum(
                 s["slots_here"] for s in loads).astype(jnp.float32),
+            "moe.overflow_layers": sum(
+                s["overflowed"] for s in loads).astype(jnp.float32),
             "moe.load_max_over_mean": jnp.max(jnp.stack(
                 [s["load_max_over_mean"] for s in loads]))}
 

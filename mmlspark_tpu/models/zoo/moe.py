@@ -21,6 +21,7 @@ tensor-parallel projections, expert-parallel FFNs, all in one jitted step.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Optional, Tuple
 
@@ -104,55 +105,131 @@ class MoeMlp(nn.Module):
 # ---------------------------------------------------------------------------
 # Dropless routing over the experts held here.
 #
-# Token-slots (token, choice) are sorted by expert, the held experts' rows
+# Token-slots (token, choice) are sorted by expert, the held experts' slots
 # lying first and group by group, and gate/up/down run as grouped matrix
 # products over those groups (``jax.lax.ragged_dot``; on a TPU XLA lowers it
-# to a grouped Mosaic matmul that walks only the tiles the groups cover, so
-# the cost follows the slots routed here, not the S*K rows the buffers are
-# sized for). Both moves between token order and expert order are gathers,
-# forward and backward: a sort is a permutation, so the transpose of a
-# gather by ``order`` is a gather by its inverse, and no scatter runs.
+# to a grouped Mosaic matmul that walks only the tiles the groups cover).
+# Every array between the sort and the token-order sum has ``R`` rows, a
+# bound on the slots routed here that the layer's shapes give
+# (``_bounded_rows``), not the ``S*K`` slots there are: the first ``R``
+# entries of the sort name the rows, one gather brings them from token order
+# (``_rows_of_tokens``), and each returns as an addend of its token's row
+# (``_sum_by_token``, a scatter-add); the two are each other's transpose, so
+# the backward pass moves ``R`` rows too and no ``(S*K, D)`` array is made.
+# A step that routes more than ``R`` slots here runs the same path at
+# ``S*K`` rows under a ``lax.cond``: no slot is dropped at any load.
 
-@jax.custom_vjp
-def _to_expert_order(x, order, inverse):
-    """``(S, D)`` rows -> ``(S*K, D)``: row ``r`` is the token of slot
-    ``order[r]`` (slot ``s*K + k`` is token ``s``'s choice ``k``)."""
-    return x[order // (order.shape[0] // x.shape[0])]
-
-
-def _to_expert_order_fwd(x, order, inverse):
-    return _to_expert_order(x, order, inverse), (x.shape[0], inverse)
-
-
-def _to_expert_order_bwd(res, g):
-    tokens, inverse = res
-    return (g[inverse].reshape(tokens, -1, g.shape[-1]).sum(1), None, None)
+# the expert-order buffers hold this many times the slots an even router
+# sends to the held experts, rounded up to whole tiles of rows
+_ROWS_OVER_EVEN_LOAD = 4
+_ROWS_TILE = 512
 
 
-_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+def _bounded_rows(slots: int, held: int, experts: int) -> int:
+    even = _ROWS_OVER_EVEN_LOAD * slots * held
+    tiles = -(-even // (experts * _ROWS_TILE))
+    return min(slots, tiles * _ROWS_TILE)
 
 
-@jax.custom_vjp
-def _to_slot_order(y, order, inverse):
-    """``(S*K, D)`` rows in expert order -> slot order."""
-    return y[inverse]
+def _sum_by_token(tokens, rows, token):
+    """``(R, D)`` rows -> float32 ``(tokens, D)``: row ``r`` is an addend
+    of ``out[token[r]]``. XLA's scatter-add, which sorts the ids itself (on
+    a v5e 1.9 ms for 20,480 float32 rows of 2,048; PERF.md section 6, PR
+    31, has the forms that lost to it); its transpose is the gather
+    ``g[token]``."""
+    # no embedding table's bag: an expert layer's rows, one order of
+    # addition on every path (the dense-loop tests hold it to 1e-5)
+    return jax.ops.segment_sum(  # lint: allow-embed
+        rows.astype(jnp.float32), token, num_segments=tokens)
 
 
-def _to_slot_order_fwd(y, order, inverse):
-    return y[inverse], order
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_of_tokens(tokens, x, token, live):
+    """``(tokens, D)`` -> ``(R, D)``: row ``r`` is ``x[token[r]]``; the
+    transpose of ``_sum_by_token``, and it of this. A row that is not
+    ``live`` lies past the groups of the products that read this, which
+    leave whatever they find in its cotangent: it adds nothing to the
+    token's."""
+    return x[token]
 
 
-def _to_slot_order_bwd(order, g):
-    return g[order], None, None
+def _rows_of_tokens_fwd(tokens, x, token, live):
+    return x[token], (token, live)
 
 
-_to_slot_order.defvjp(_to_slot_order_fwd, _to_slot_order_bwd)
+def _rows_of_tokens_bwd(tokens, res, g):
+    token, live = res
+    g = jnp.where(live[:, None], g, 0)
+    return _sum_by_token(tokens, g, token).astype(g.dtype), None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 def _grouped(x, w, sizes):
     obsmetrics.counter("moe.grouped_calls.ragged_dot").inc()
     return jax.lax.ragged_dot(x, w, sizes,
                               preferred_element_type=jnp.float32)
+
+
+def _routed_rows(rows, xf, gate, w_gate, w_up, w_down, order, sizes):
+    """The held experts' part of the layer's sum, float32 ``(S, D)``, over
+    expert-order buffers of ``rows`` rows: exact while no more than ``rows``
+    slots are routed here. ``xf (S, D)`` and the weights in the products'
+    dtype, ``gate (S, K)`` float32, ``order`` the slots sorted by expert
+    (slot ``s*K + k`` is token ``s``'s choice ``k``), ``sizes`` the held
+    experts' groups."""
+    tokens, top_k = gate.shape
+    with jax.named_scope("moe_dispatch"):
+        slot = order[:rows]               # the held slots, then filler
+        token = slot // top_k
+        live = jnp.arange(rows) < sizes.sum()
+        xs = _rows_of_tokens(tokens, xf, token, live)
+    with jax.named_scope("moe_experts"):
+        h = nn.silu(_grouped(xs, w_gate, sizes)) * _grouped(xs, w_up, sizes)
+        ys = _grouped(h.astype(xs.dtype), w_down, sizes)
+    with jax.named_scope("moe_combine"):
+        # rows past the groups hold whatever the product left there
+        ys = jnp.where(live[:, None], ys, 0)
+        weight = gate.reshape(tokens * top_k).at[slot].get(
+            unique_indices=True, mode="promise_in_bounds")
+        return _sum_by_token(tokens, ys * weight[:, None], token)
+
+
+def _at_either_size(bound, small, full, sizes, *operands):
+    return jax.lax.cond(sizes.sum() <= bound, small, full, *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(bound, xf, gate, w_gate, w_up, w_down, order, sizes):
+    """``_routed_rows`` at ``bound`` rows while the step's slots fit them,
+    at all ``S*K`` when they do not. Differentiated as a whole: the
+    backward pass makes the same choice and runs the chosen size's forward
+    again, so neither pass hands the other an array of the size it did not
+    run (``lax.cond``'s own derivative keeps both branches' residuals)."""
+    operands = (xf, gate, w_gate, w_up, w_down, order, sizes)
+    return _at_either_size(
+        bound, functools.partial(_routed_rows, bound),
+        functools.partial(_routed_rows, gate.size), sizes, *operands)
+
+
+def _routed_fwd(bound, *operands):
+    return _routed(bound, *operands), operands
+
+
+def _routed_bwd(bound, operands, g):
+    def pull(rows):
+        def back(g, *operands):
+            moved, ids = operands[:5], operands[5:]     # order, sizes
+            return jax.vjp(lambda *m: _routed_rows(rows, *m, *ids),
+                           *moved)[1](g)
+        return back
+    sizes, slots = operands[-1], operands[1].size
+    return (*_at_either_size(bound, pull(bound), pull(slots), sizes, g,
+                             *operands), None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class DroplessMoe(nn.Module):
@@ -175,8 +252,14 @@ class DroplessMoe(nn.Module):
     part is that chip's to add). ``shared(name)`` makes the shared expert, a
     dense feed-forward part every chip computes alike.
 
-    Returns ``(y, stats)``: ``slots_here`` (slots routed to held experts)
-    and ``load_max_over_mean`` (the fullest held expert over their mean).
+    A layer that holds a share of the experts sizes its expert-order
+    buffers by a bound on the slots routed here (``_bounded_rows``) and
+    runs the same path over all ``tokens x top_k`` rows in a step that
+    routes more to it: the choice is a device scalar's.
+
+    Returns ``(y, stats)``: ``slots_here`` (slots routed to held experts),
+    ``overflowed`` (1 if they passed the bound in this call, else 0) and
+    ``load_max_over_mean`` (the fullest held expert over their mean).
     Sows the choice under ``("intermediates", "router_choice")``.
     """
     dim: int
@@ -225,30 +308,24 @@ class DroplessMoe(nn.Module):
             # expert order: held experts by index, then everything else
             key = jnp.where(here, local, held).reshape(S * K)
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(S * K, dtype=jnp.int32))
             sizes = jnp.bincount(key, length=held + 1)[:held].astype(
                 jnp.int32)
             slots_here = sizes.sum()
-            computed = (jnp.arange(S * K) < slots_here)[:, None]
-            xs = jnp.where(computed, _to_expert_order(
-                xf.astype(self.dtype), order, inverse), 0)
+            bound = _bounded_rows(S * K, held, E)
 
-        with jax.named_scope("moe_experts"):
-            w_gate, w_up, w_down = (
-                self.param(name, init, shape, jnp.float32).astype(self.dtype)
-                for name, shape in (("experts_gate", (held, D, H)),
-                                    ("experts_up", (held, D, H)),
-                                    ("experts_down", (held, H, D))))
-            h = nn.silu(_grouped(xs, w_gate, sizes)) \
-                * _grouped(xs, w_up, sizes)
-            ys = _grouped(h.astype(self.dtype), w_down, sizes)
+        w_gate, w_up, w_down = (
+            self.param(name, init, shape, jnp.float32).astype(self.dtype)
+            for name, shape in (("experts_gate", (held, D, H)),
+                                ("experts_up", (held, D, H)),
+                                ("experts_down", (held, H, D))))
+        operands = (xf.astype(self.dtype), gate, w_gate, w_up, w_down,
+                    order, sizes)
+        if bound == S * K:      # buffers of every slot: one size, no cond
+            y = _routed_rows(bound, *operands)
+        else:
+            y = _routed(bound, *operands)
 
         with jax.named_scope("moe_combine"):
-            # rows past the groups hold whatever the product left there
-            ys = _to_slot_order(jnp.where(computed, ys, 0), order, inverse)
-            y = jnp.einsum("skd,sk->sd", ys.reshape(S, K, D),
-                           jnp.where(here, gate, 0.0))
             if self.shared is not None:
                 side = self.shared("shared")(xf).astype(jnp.float32)
                 if self.shared_gate:
@@ -260,6 +337,7 @@ class DroplessMoe(nn.Module):
 
         load = sizes.astype(jnp.float32)
         stats = {"slots_here": slots_here,
+                 "overflowed": (slots_here > bound).astype(jnp.int32),
                  "load_max_over_mean": load.max() / jnp.maximum(
                      load.mean(), 1e-9)}
         return y.reshape(B, L, D).astype(self.dtype), stats

@@ -361,6 +361,134 @@ def test_expert_layer_gradients_match_a_dense_loop():
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-6)
 
 
+# ------------------------------- expert-order buffers of a bounded size
+# 2 of 16 experts over 512 tokens x top-2: the buffers hold 512 of the
+# 1,024 slots, four times what an even router sends here
+WIDE = dict(slots=1024, bound=512)
+
+
+def _wide_params(scores, onto_held):
+    """A seeded layer and its input; ``onto_held`` moves the router so
+    that every token chooses the two held experts (feature 0 of the input
+    is 1, so row 0 of the router's matrix is a bias for either kind of
+    score)."""
+    layer = DroplessMoe(32, 16, 16, 2, experts_held=(2, 4), scaling=1.8,
+                        dtype=jnp.float32, scores=scores)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 256, 32))
+    x = x.at[..., 0].set(1.0)
+    p = dict(layer.init(jax.random.PRNGKey(8), x)["params"])
+    if onto_held:
+        kernel = p["router"]["kernel"]
+        p["router"] = {"kernel": kernel.at[0, 4:6].add(12.0)}
+    return layer, {"params": p}, x
+
+
+def _dense_loop(layer, p, x):
+    """The held experts' part of the layer, every held expert on every
+    token, weighted by the gates of the slots that chose it."""
+    q = p["params"]
+    xf = x.reshape(-1, x.shape[-1])
+    logits = xf @ q["router"]["kernel"]
+    if layer.scores == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        ranked = s + q["router_bias"]
+    else:
+        s = ranked = jax.nn.softmax(logits, -1)
+    _, choice = jax.lax.top_k(ranked, layer.top_k)
+    gate = jnp.take_along_axis(s, choice, -1)
+    gate = gate / gate.sum(-1, keepdims=True) * layer.scaling
+    held, first = layer.experts_held
+    y = 0.0
+    for e in range(held):
+        w = jnp.where(choice == first + e, gate, 0.0).sum(-1)
+        h = jax.nn.silu(xf @ q["experts_gate"][e]) \
+            * (xf @ q["experts_up"][e])
+        y = y + w[:, None] * (h @ q["experts_down"][e])
+    return y.reshape(x.shape)
+
+
+@pytest.mark.parametrize("scores", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("onto_held", [False, True],
+                         ids=["within_the_bound", "past_the_bound"])
+def test_bounded_buffers_give_the_dense_loop_at_either_size(scores,
+                                                            onto_held):
+    """Output and gradients of a layer whose buffers are smaller than its
+    slots, while the step's slots fit them and when every slot is routed
+    here: the second runs the same path at full size and says so."""
+    layer, p, x = _wide_params(scores, onto_held)
+    y, stats = jax.jit(layer.apply)(p, x)
+    slots_here = int(stats["slots_here"])
+    if onto_held:
+        assert slots_here == WIDE["slots"] > WIDE["bound"]
+    else:
+        assert 0 < slots_here <= WIDE["bound"]
+    assert int(stats["overflowed"]) == int(onto_held)
+    np.testing.assert_allclose(y, _dense_loop(layer, p, x), rtol=1e-5,
+                               atol=1e-6)
+
+    def loss(fn):
+        return lambda q, x: jnp.sum(jnp.sin(fn(q, x)))
+    got = jax.jit(jax.grad(loss(lambda q, x: layer.apply(q, x)[0]),
+                           argnums=(0, 1)))(p, x)
+    want = jax.grad(loss(lambda q, x: _dense_loop(layer, q, x)),
+                    argnums=(0, 1))(p, x)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-5, atol=1e-5 * float(jnp.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def _slot_sized_arrays(jaxpr, rows, found, inside=()):
+    """Every value of ``rows`` rows of the layer's or its experts' width
+    that an equation of ``jaxpr`` makes (the sort's keys have one column),
+    with the branches of each ``cond`` on its way (branch 0 is
+    ``lax.cond``'s false side: the overflow path)."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            if len(shape) >= 2 and shape[0] == rows and shape[-1] >= 16:
+                found.append((inside, eqn.primitive.name, shape))
+        for name, value in eqn.params.items():
+            subs = value if isinstance(value, (tuple, list)) else (value,)
+            for i, sub in enumerate(subs):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    step = (f"cond[{i}]" if eqn.primitive.name == "cond"
+                            else eqn.primitive.name)
+                    _slot_sized_arrays(sub, rows, found, inside + (step,))
+    return found
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_no_slot_sized_array_outside_the_overflow_branch(what):
+    """Between the sort and the sum by token every array has the bound's
+    rows: nothing of ``S*K`` rows is made but where a step overflows, in
+    the forward pass or the backward."""
+    layer, p, x = _wide_params("softmax", False)
+    fn = lambda q, x: jnp.sum(layer.apply(q, x)[0])
+    if what == "gradient":
+        fn = jax.grad(fn, argnums=(0, 1))
+    jaxpr = jax.make_jaxpr(fn)(p, x).jaxpr
+    found = _slot_sized_arrays(jaxpr, WIDE["slots"], [])
+    assert found, "the overflow branch itself was not seen"
+    assert all("cond[0]" in inside for inside, _, _ in found), [
+        f for f in found if "cond[0]" not in f[0]]
+    bounded = _slot_sized_arrays(jaxpr, WIDE["bound"], [])
+    assert any("cond[1]" in inside and shape == (WIDE["bound"], 32)
+               for inside, _, shape in bounded)
+
+
+def test_a_layer_that_holds_every_expert_lowers_no_cond():
+    layer = DroplessMoe(32, 16, 16, 2, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 256, 32))
+    p = layer.init(jax.random.PRNGKey(8), x)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q: jnp.sum(layer.apply(q, x)[0])))(p))
+    assert "cond" not in text and "ragged_dot" in text
+    assert int(layer.apply(p, x)[1]["overflowed"]) == 0
+
+
 # --------------------------------------------------- attention's parts
 def test_rotary_turns_pairs_by_position_and_keeps_norms():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 5, 3, 8))

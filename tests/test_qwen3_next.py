@@ -239,7 +239,7 @@ def test_losses_and_gradients_match_the_reference(params):
         want = g if want is None else jax.tree_util.tree_map(
             jnp.add, want, g)
     assert set(aux) == {"loss.main", "moe.slots_here",
-                        "moe.load_max_over_mean"}
+                        "moe.overflow_layers", "moe.load_max_over_mean"}
     np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
     np.testing.assert_allclose(aux["loss.main"], want_loss, rtol=1e-5)
     got = dict(jax.tree_util.tree_leaves_with_path(grads))
@@ -294,6 +294,7 @@ def test_three_adamw_steps_match_the_reference():
             err_msg=k)
     # every routed slot of the uncut tiny model is held here
     assert float(m["moe.slots_here"]) == 4 * ROWS * LEN * 3
+    assert float(m["moe.overflow_layers"]) == 0     # buffers of every slot
     assert len(want["routing"]) == 4
     assert want["routing"][0]["choice"].shape == (ROWS * LEN, 3)
     assert want["routing"][0]["ranked"].shape == (ROWS * LEN, 16)

@@ -130,24 +130,29 @@ def test_an_aux_the_ring_cannot_hold_is_refused_at_the_first_step(
 
 
 # ----------------------------------------------- the glm4_moe_lite step
-AUX = ("loss.main", "loss.mtp", "moe.slots_here", "moe.load_max_over_mean")
+AUX = ("loss.main", "loss.mtp", "moe.slots_here", "moe.overflow_layers",
+       "moe.load_max_over_mean")
 
 
-def _lm_trainer():
-    module = build_model("glm4_moe_lite_tiny", experts_held=(4, 2))["module"]
+def _lm_trainer(length=16, chunk=8, edit=lambda params: params, **model):
+    """The tiny model's trainer, its state and a batch of two rows;
+    ``edit`` changes the seeded parameters, ``model`` the zoo entry's
+    arguments."""
+    module = build_model("glm4_moe_lite_tiny",
+                         **{"experts_held": (4, 2), **model})["module"]
 
     def loss_fn(params, batch, rng):
         out = module.apply(params, batch["tokens"], hidden=True)
         loss, parts = next_token_loss(
             out, params["params"]["lm_head"]["kernel"], batch["tokens"],
-            chunk=8, dtype=jnp.float32)
+            chunk=chunk, dtype=jnp.float32)
         return loss, {**parts, **out["stats"]}
     trainer = DistributedTrainer(
         loss_fn, optax.adamw(1e-3), mesh=mesh_from_config(jax.devices()[:1]))
     tokens = np.random.default_rng(1).integers(
-        0, 96, size=(2, 16)).astype(np.int32)
-    state = trainer.init(lambda: module.init(
-        jax.random.PRNGKey(0), jnp.asarray(tokens)))
+        0, 96, size=(2, length)).astype(np.int32)
+    state = trainer.init(lambda: edit(module.init(
+        jax.random.PRNGKey(0), jnp.asarray(tokens))))
     return trainer, state, {"tokens": tokens}
 
 
@@ -175,8 +180,37 @@ def test_the_lm_step_counts_its_grouped_products_and_publishes_its_gauges():
     # 4 of the 8 experts are held: about half of 3 layers x 32 tokens x 2
     assert 0 < float(m["moe.slots_here"]) < 3 * 32 * 2
     assert float(m["moe.load_max_over_mean"]) >= 1.0
+    assert float(m["moe.overflow_layers"]) == 0     # buffers of every slot
     np.testing.assert_allclose(
         m["loss"], m["loss.main"] + 0.3 * m["loss.mtp"], rtol=1e-6)
+
+
+@pytest.mark.parametrize("onto_held, layers", [(False, 0), (True, 3)])
+def test_the_layers_that_ran_at_full_size_are_counted_in_the_ring(
+        onto_held, layers):
+    """2 of 16 experts over 512 tokens x top-2: the expert-order buffers
+    hold 512 of the 1,024 slots. A router biased onto the held experts
+    sends all of them here, in the two routed blocks and the MTP block."""
+    def bias_onto_held(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, v: v.at[4:6].set(10.0)
+            if "router_bias" in jax.tree_util.keystr(path) else v, params)
+    trainer, state, batch = _lm_trainer(
+        length=256, chunk=64, num_experts=16, experts_held=(2, 4),
+        max_len=256, **({"edit": bias_onto_held} if onto_held else {}))
+    before = obssyncs.total()
+    for _ in range(2):
+        state, m = trainer.train_step(state, trainer.put_batch(batch),
+                                      jax.random.PRNGKey(0))
+    assert obssyncs.total() == before       # the predicate stays on device
+    ring = trainer.flush_metrics()
+    np.testing.assert_array_equal(ring["moe.overflow_layers"][:2], layers)
+    assert obsmetrics.gauge("moe.overflow_layers").value == layers
+    if onto_held:
+        np.testing.assert_array_equal(ring["moe.slots_here"][:2], 3 * 1024)
+    else:
+        assert np.all(ring["moe.slots_here"][:2] <= 3 * 512)
+    assert np.all(np.isfinite(ring["loss"][:2]))
 
 
 def test_the_lm_step_program_carries_its_named_scopes():
