@@ -1,7 +1,11 @@
-"""Decoder LMs built from parts, and the families made of them:
-``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's) and
+"""Decoder LMs built from parts, and the three families made of them:
+``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's),
 ``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
-softmax layer, every feed-forward part a routed layer).
+softmax layer, every feed-forward part a routed layer) and
+``granite_hybrid`` (Granite 4.0-H: Mamba-2 mixers and grouped softmax
+attention without positions in the order of a published list, a dense
+SwiGLU part in every layer, scaled residual additions, one table for the
+embedding and the head).
 
 ``PartsBlock`` is the pre-norm residual block with nothing fixed: its norm,
 its attention (which owns its projections and its positions) and its
@@ -23,23 +27,31 @@ Parts here:
   repeated to the query heads it serves at the attention call, so the
   flash kernel and its backward run as they are), norms on q and k, a
   rotary slice, and a sigmoid gate on the output;
+- ``GroupedAttention``: the same grouped heads with nothing else: no
+  positions, no norms, no gate, and a softmax scale of its own;
 - ``GatedDeltaNet``: linear attention with a recurrent state
   (``ops/linear_attention.py``): a short causal convolution, the gated
   delta rule, a gated norm on the output;
+- ``Mamba2Mixer``: the state-space layer (the same module's ``ssd``): a
+  convolution with a bias over ``[x | B | C]``, a scalar decay a head,
+  ``B`` and ``C`` shared by groups of heads, a skip, the gate BEFORE the
+  norm;
 - ``SwiGluMlp``: ``down(silu(gate x) * up x)``, no biases;
 - ``zoo/moe.DroplessMoe``: the routed layer, told which experts it holds.
 
 Parameter names hit the rules of ``parallel/sharding.DEFAULT_RULES``
-(``attn_query_*`` / ``attn_key*`` / ``attn_value`` / ``attn_qkvz`` /
-``attn_out``, ``mlp_gate`` / ``mlp_up`` / ``mlp_down``, ``experts_*``,
-``router``, ``lm_head``).
+(``attn_query*`` / ``attn_key*`` / ``attn_value`` / ``attn_qkvz`` /
+``attn_gate_value_key_query_dt`` / ``attn_out``, ``mlp_gate`` / ``mlp_up``
+/ ``mlp_down``, ``experts_*``, ``router``, ``lm_head``,
+``token_embedding``).
 
 Blocks are recomputed in the backward pass one by one (``nn.remat``), which
 is what lets 4,096-token rows train beside the optimizer's state on one
 chip. A block keeps its input and a short list of named values whose
 recomputation costs more than their bytes (``_remat_block``): the
 flash kernel's output and log-sum-exps, so that its forward runs once a
-block and not twice, and the SwiGLU gate and up products.
+block and not twice, and the SwiGLU gate and up products (a family whose
+feed-forward part is too wide for that names the first alone).
 """
 from __future__ import annotations
 
@@ -237,8 +249,9 @@ class GatedDeltaNet(nn.Module):
                 "A_log", lambda key, shape: jnp.log(jax.random.uniform(
                     key, shape, f32, 1e-3, 16.0)), (Hv,))
             dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,), f32)
-            mixed = nn.silu(la.causal_conv1d(
-                qkvz[..., :2 * Hk * dk + Hv * dv], conv))
+            with jax.named_scope("gdn_conv"):
+                mixed = nn.silu(la.causal_conv1d(
+                    qkvz[..., :2 * Hk * dk + Hv * dv], conv))
             z = qkvz[..., 2 * Hk * dk + Hv * dv:].reshape(B, L, Hv, dv)
             q = mixed[..., :Hk * dk].reshape(B, L, Hk, dk)
             k = mixed[..., Hk * dk:2 * Hk * dk].reshape(B, L, Hk, dk)
@@ -253,6 +266,111 @@ class GatedDeltaNet(nn.Module):
                 * nn.silu(z.astype(f32))
             return _dense(self.dim, dt, "attn_out")(
                 o.astype(dt).reshape(B, L, Hv * dv))
+
+
+class GroupedAttention(nn.Module):
+    """Causal softmax attention with grouped key/value heads and nothing
+    else: no positions (in ``granite_hybrid`` the state-space layers carry
+    the order), no norm on q or k, no gate, no biases; ``softmax(scale x q
+    k^T) v`` with a published ``scale`` that need not be ``head_dim ** -0.5``.
+    ``attention_fn(q, k, v)`` keeps its own ``head_dim ** -0.5``, so ``q``
+    is multiplied by ``scale x head_dim ** 0.5`` before the call (0.125 in
+    the published model: a power of two, exact in bfloat16). Each
+    key/value head is repeated to the ``heads / kv_heads`` query heads it
+    serves at that call, as in ``GatedAttention``."""
+    dim: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    scale: Optional[float] = None       # None: head_dim ** -0.5
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads over "
+                             f"{self.kv_heads} key/value heads")
+        B, L, _ = x.shape
+        H, G, d, dt = self.heads, self.kv_heads, self.head_dim, self.dtype
+        attn_fn = self.attention_fn or full_attention
+        with jax.named_scope("grouped_attention"):
+            x = x.astype(dt)
+            q = _dense(H * d, dt, "attn_query")(x).reshape(B, L, H, d)
+            k = _dense(G * d, dt, "attn_key")(x).reshape(B, L, G, d)
+            v = _dense(G * d, dt, "attn_value")(x).reshape(B, L, G, d)
+            if self.scale is not None:
+                q = q * jnp.asarray(self.scale * d ** 0.5, dt)
+            k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
+            o = attn_fn(q, k, v, causal=True)
+            return _dense(self.dim, dt, "attn_out")(o.reshape(B, L, H * d))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias`` such that ``softplus(dt_bias) = exp(U(log 1e-3, log
+    1e-1))`` floored at 1e-4: Mamba-2's own initialiser."""
+    dt = jnp.maximum(1e-4, jnp.exp(jax.random.uniform(
+        key, shape, dtype, jnp.log(1e-3), jnp.log(1e-1))))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 (arXiv:2405.21060) as ``granite_hybrid`` lays it out: ``[z |
+    xBC | dt] = u W_in``; ``xBC <- silu(conv(xBC) + b)`` (causal,
+    depthwise); ``[x | B | C] = xBC`` with ``x`` on ``heads`` heads of
+    ``head_dim`` and ``B``, ``C`` on ``groups`` groups of ``state``, head
+    ``h`` reading group ``h // (heads / groups)``; ``dt = softplus(dt +
+    dt_bias)`` (no clamp) and ``A = -exp(A_log)`` a head, float32; the
+    state-space rule (``ops/linear_attention.ssd``: chunked on whole rows)
+    plus the skip ``D x``; ``y <- rmsnorm(y * silu(z)) * w_n``, the gate
+    first and the mean square over all ``heads x head_dim`` channels; ``out
+    = y W_out``. No biases but the convolution's. Columns of ``W_in`` are
+    ``[z | x | B | C | dt]``, head-major inside each."""
+    dim: int
+    heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    conv_width: int = 4
+    eps: float = 1e-5
+    chunk: int = 256
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from mmlspark_tpu.ops import linear_attention as la
+        if self.heads % self.groups:
+            raise ValueError(f"{self.heads} heads over {self.groups} groups")
+        B, L, _ = u.shape
+        H, P, N, G = self.heads, self.head_dim, self.state, self.groups
+        d_in, mixed, dt_, f32 = H * P, H * P + 2 * G * N, self.dtype, \
+            jnp.float32
+        with jax.named_scope("mamba2_mixer"):
+            zxbcdt = _dense(d_in + mixed + H, dt_,
+                            "attn_gate_value_key_query_dt")(u.astype(dt_))
+            conv = self.param("conv_kernel", _INIT,
+                              (self.conv_width, mixed), f32)
+            conv_bias = self.param("conv_bias", _INIT, (mixed,), f32)
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, f32, 1.0, 16.0)), (H,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H,), f32)
+            skip = self.param("D_skip", nn.initializers.ones, (H,), f32)
+            z = zxbcdt[..., :d_in]
+            with jax.named_scope("ssm_conv"):
+                xbc = nn.silu(la.causal_conv1d(
+                    zxbcdt[..., d_in:d_in + mixed], conv, conv_bias))
+            x = xbc[..., :d_in].reshape(B, L, H, P)
+            Bm = xbc[..., d_in:d_in + G * N].reshape(B, L, G, N)
+            Cm = xbc[..., d_in + G * N:].reshape(B, L, G, N)
+            dt = jax.nn.softplus(
+                zxbcdt[..., d_in + mixed:].astype(f32) + dt_bias)
+            y = la.ssd(x, dt, -jnp.exp(a_log), Bm, Cm, chunk=self.chunk,
+                       dtype=dt_)
+            y = y + skip[:, None] * x.astype(f32)
+            y = y.reshape(B, L, d_in) * nn.silu(z.astype(f32))
+            y = RMSNorm(self.eps, name="gate_norm")(y)
+            return _dense(self.dim, dt_, "attn_out")(y.astype(dt_))
 
 
 class SwiGluMlp(nn.Module):
@@ -271,51 +389,62 @@ class SwiGluMlp(nn.Module):
 
 
 class PartsBlock(nn.Module):
-    """``h = x + attention(norm(x))``, ``y = h + ffn(norm(h))``. A
-    feed-forward part may return ``(y, stats)``, ``stats`` a dict of
-    scalars (a routed layer's load); the block returns ``(y, stats)``
-    always."""
+    """``h = x + r attention(norm(x))``, ``y = h + r ffn(norm(h))``, ``r``
+    = ``residual_scale`` (1 in most families). A feed-forward part may
+    return ``(y, stats)``, ``stats`` a dict of scalars (a routed layer's
+    load); the block returns ``(y, stats)`` always."""
     norm: Callable[[str], nn.Module]
     attention: Callable[[str], nn.Module]
     ffn: Callable[[str], nn.Module]
+    residual_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        h = x + self.attention("attn")(self.norm("norm1")(x)).astype(x.dtype)
+        h = _add(x, self.attention("attn")(self.norm("norm1")(x)),
+                 self.residual_scale)
         out = self.ffn("ffn")(self.norm("norm2")(h))
         y, stats = out if isinstance(out, tuple) else (out, {})
-        return h + y.astype(x.dtype), stats
+        return _add(h, y, self.residual_scale), stats
+
+
+def _add(x, y, scale: float):
+    """The residual addition ``x + scale y`` in ``x``'s type."""
+    y = y.astype(x.dtype)
+    return x + y if scale == 1.0 else x + jnp.asarray(scale, x.dtype) * y
 
 
 class SplitBlock(nn.Module):
     """``PartsBlock`` with its two halves as methods (``mix``: ``x +
     attention(norm(x))``; ``feed``: ``h + ffn(norm(h))``), so that each can
-    be a unit of recomputation of its own: what the backward pass of the
+    be a unit of recomputation of its own (``x + r attention(norm(x))``
+    with ``residual_scale``, as there): what the backward pass of the
     feed-forward half keeps never lies beside what the mixer's keeps. The
     parts are made in ``setup`` under ``PartsBlock``'s names (``norm1``,
     ``attn``, ``norm2``, ``ffn``); the factories are called with no name."""
     make_norm: Callable[[Optional[str]], nn.Module]
     make_attention: Callable[[Optional[str]], nn.Module]
     make_ffn: Callable[[Optional[str]], nn.Module]
+    residual_scale: float = 1.0
 
     def setup(self):
         self.norm1, self.attn = self.make_norm(None), self.make_attention(None)
         self.norm2, self.ffn = self.make_norm(None), self.make_ffn(None)
 
     def mix(self, x):
-        return x + self.attn(self.norm1(x)).astype(x.dtype)
+        return _add(x, self.attn(self.norm1(x)), self.residual_scale)
 
     def feed(self, h) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         out = self.ffn(self.norm2(h))
         y, stats = out if isinstance(out, tuple) else (out, {})
-        return h + y.astype(h.dtype), stats
+        return _add(h, y, self.residual_scale), stats
 
     def __call__(self, x) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         return self.feed(self.mix(x))
 
 
-def _remat_block(norm, attention, ffn, name: str,
-                 split: bool = False) -> nn.Module:
+def _remat_block(norm, attention, ffn, name: str, split: bool = False,
+                 residual_scale: float = 1.0,
+                 keep: Optional[Tuple[str, ...]] = None) -> nn.Module:
     """A ``PartsBlock`` recomputed in the backward pass, but for what is
     named here (the names sit where the values are made; in units of the
     block's input, bf16 (B, L, dim)): the flash kernel's output and log-sum-
@@ -334,17 +463,19 @@ def _remat_block(norm, attention, ffn, name: str,
     (its scan keeps a state a chunk across ITS backward, inside the
     recomputation). ``split`` recomputes the block's two halves apart
     (``SplitBlock``) and keeps the residual stream between them: for a
-    block whose halves' backward passes do not fit side by side.
-    (Imported here: Pallas costs every importer of the zoo over a
-    second.)"""
+    block whose halves' backward passes do not fit side by side. ``keep``
+    is the list of names kept, by default the two above
+    (``FLASH_RESIDUALS``, ``MLP_GATE_UP``); ``residual_scale`` is the
+    block's. (Imported here: Pallas costs every importer of the zoo over
+    a second.)"""
     from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
     policy = jax.checkpoint_policies.save_only_these_names(
-        FLASH_RESIDUALS, MLP_GATE_UP)
+        *((FLASH_RESIDUALS, MLP_GATE_UP) if keep is None else keep))
     if split:
         return nn.remat(SplitBlock, policy=policy, methods=("mix", "feed"))(
-            norm, attention, ffn, name=name)
-    return nn.remat(PartsBlock, policy=policy)(norm, attention, ffn,
-                                               name=name)
+            norm, attention, ffn, residual_scale, name=name)
+    return nn.remat(PartsBlock, policy=policy)(
+        norm, attention, ffn, residual_scale, name=name)
 
 
 class Head(nn.Module):
@@ -547,6 +678,86 @@ class Qwen3Next(nn.Module):
         return out
 
 
+class GraniteHybrid(nn.Module):
+    """``granite_hybrid`` (``model_type: granitemoehybrid`` without routed
+    experts): ``h_0 = embedding_multiplier x E[token]``; layer ``l`` is ``h
+    <- h + r mixer_l(norm(h))``, ``h <- h + r mlp(norm(h))`` with ``r`` =
+    ``residual_multiplier``, ``mixer_l`` a ``Mamba2Mixer`` where
+    ``layer_types[l]`` is ``"mamba"`` and a ``GroupedAttention`` (no
+    positions, softmax scale ``attention_multiplier``) where it is
+    ``"attention"``, the feed-forward part a dense ``SwiGluMlp``; plain
+    RMS norms, a final norm, ``logits = (h E^T) / logits_scaling``: ONE
+    table, read by the embedding's gather and by the head.
+
+    ``__call__(tokens)`` gives ``(B, L, vocab)`` float32 logits.
+    ``__call__(tokens, hidden=True)`` gives ``{"hidden", "stats"}`` for
+    the chunked loss: the normed rows ALREADY divided by
+    ``logits_scaling``, so that ``next_token_loss(out, E^T, tokens)`` with
+    ``E = params["token_embedding"]["embedding"]`` is the model's loss;
+    ``stats`` is empty (no routed layer). Each block is recomputed in the
+    backward pass in halves and keeps the flash kernel's residuals alone:
+    the gate and up products are 8,192 wide here."""
+    vocab: int
+    dim: int
+    layer_types: Tuple[str, ...]
+    heads: int
+    kv_heads: int
+    head_dim: int
+    mamba_heads: int
+    mamba_head_dim: int
+    state: int
+    groups: int
+    conv_width: int
+    mlp_hidden: int
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    eps: float = 1e-5
+    chunk: int = 256
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    def _block(self, kind: str, name: str) -> nn.Module:
+        from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
+        dt = self.dtype
+
+        def attention(n):
+            if kind == "attention":
+                return GroupedAttention(
+                    self.dim, self.heads, self.kv_heads, self.head_dim,
+                    self.attention_multiplier, dt, self.attention_fn, name=n)
+            return Mamba2Mixer(
+                self.dim, self.mamba_heads, self.mamba_head_dim, self.state,
+                self.groups, self.conv_width, self.eps, self.chunk, dt,
+                name=n)
+
+        return _remat_block(
+            lambda n: RMSNorm(self.eps, name=n), attention,
+            lambda n: SwiGluMlp(self.dim, self.mlp_hidden, dt, name=n),
+            name, split=True, residual_scale=self.residual_multiplier,
+            keep=(FLASH_RESIDUALS,))
+
+    @nn.compact
+    def __call__(self, tokens, hidden: bool = False):
+        if not self.layer_types \
+                or set(self.layer_types) - {"mamba", "attention"}:
+            raise ValueError(f"layer_types {self.layer_types!r}: "
+                             "'mamba' or 'attention' a layer")
+        embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype,
+                         embedding_init=_INIT, name="token_embedding")
+        x = embed(tokens) * jnp.asarray(self.embedding_multiplier,
+                                        self.dtype)
+        for i, kind in enumerate(self.layer_types):
+            x, _ = self._block(kind, f"block{i}")(x)
+        normed = RMSNorm(self.eps, name="final_norm")(x)
+        self.sow("intermediates", "hidden", normed)
+        h = normed / self.logits_scaling
+        if not hidden:
+            return jnp.dot(h, embed.embedding.T)
+        return {"hidden": h, "stats": {}}
+
+
 def _spec(module: nn.Module, max_len: int):
     return dict(
         module=module, input_shape=(max_len,), input_dtype="int32",
@@ -626,3 +837,52 @@ def qwen3_next_tiny(**overrides):
     """Test-scale ``qwen3_next`` (float32, so CPU parity is tight): one
     period of the layer pattern, chunks of 8 tokens."""
     return qwen3_next(**{**_QWEN_TINY, **overrides})
+
+
+GRANITE_4_H_MICRO_LAYERS = (("mamba",) * 5 + ("attention",)
+                            + ("mamba",) * 9 + ("attention",)
+                            + ("mamba",) * 9 + ("attention",)
+                            + ("mamba",) * 9 + ("attention",)
+                            + ("mamba",) * 4)
+
+
+@register_model("granite_hybrid")
+def granite_hybrid(vocab: int = 100352, dim: int = 2048,
+                   layer_types=GRANITE_4_H_MICRO_LAYERS, heads: int = 32,
+                   kv_heads: int = 8, head_dim: int = 64,
+                   mamba_heads: int = 64, mamba_head_dim: int = 64,
+                   state: int = 128, groups: int = 1, conv_width: int = 4,
+                   mlp_hidden: int = 8192, embedding_multiplier: float = 12.0,
+                   attention_multiplier: float = 0.015625,
+                   residual_multiplier: float = 0.22,
+                   logits_scaling: float = 8.0, eps: float = 1e-5,
+                   chunk: int = 256, max_len: int = 8192,
+                   dtype=jnp.bfloat16, attention_fn=None):
+    """Granite 4.0-H Micro as published (huggingface.co/ibm-granite/
+    granite-4.0-h-micro ``config.json``, ``model_type: granitemoehybrid``
+    with ``num_local_experts`` 0): forty layers, a Mamba-2 mixer or
+    grouped attention without positions by ``layer_types``, a dense SwiGLU
+    part in each, four published multipliers, a tied head."""
+    return _spec(GraniteHybrid(
+        vocab, dim, tuple(layer_types), heads, kv_heads, head_dim,
+        mamba_heads, mamba_head_dim, state, groups, conv_width, mlp_hidden,
+        embedding_multiplier, attention_multiplier, residual_multiplier,
+        logits_scaling, eps, chunk, dtype, attention_fn), max_len)
+
+
+_GRANITE_TINY = dict(vocab=96, dim=32,
+                     layer_types=("mamba", "attention", "mamba") * 2, heads=4,
+                     kv_heads=2, head_dim=8, mamba_heads=4, mamba_head_dim=8,
+                     state=8, groups=1, mlp_hidden=48,
+                     embedding_multiplier=3.0, attention_multiplier=0.25,
+                     residual_multiplier=0.5, logits_scaling=2.0, chunk=8,
+                     max_len=64, dtype=jnp.float32)
+
+
+@register_model("granite_hybrid_tiny")
+def granite_hybrid_tiny(**overrides):
+    """Test-scale ``granite_hybrid`` (float32, so CPU parity is tight): two
+    periods of a short pattern with both kinds of layer, chunks of 8
+    tokens, multipliers that are not 1 and a softmax scale that is not
+    ``head_dim ** -0.5``."""
+    return granite_hybrid(**{**_GRANITE_TINY, **overrides})

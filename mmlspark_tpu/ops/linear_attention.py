@@ -1,15 +1,16 @@
-"""Linear attention with a recurrent state: the gated delta rule, and the
-short causal convolution in front of it.
+"""Linear attention with a recurrent state: two rules over one state a
+head, and the short causal convolution in front of them.
 
-Per head, with a state ``S`` of ``(dk, dv)`` that is zero where the row
-starts (Gated DeltaNet, Yang et al. 2024, arXiv:2412.06464)::
+**The gated delta rule** (Gated DeltaNet, Yang et al. 2024,
+arXiv:2412.06464). Per head, with a state ``S`` of ``(dk, dv)`` that is
+zero where the row starts::
 
     S   <- exp(g_t) S                   # per-head, per-token decay, g_t <= 0
     u_t  = beta_t (v_t - S^T k_t)       # the delta rule's correction
     S   <- S + k_t u_t^T
     o_t  = S^T q_t
 
-``gated_delta_rule`` is the one entry; it has two forms of the same
+``gated_delta_rule`` is its entry; it has two forms of the same
 mathematics:
 
 - **recurrent**: the four lines above under ``lax.scan``, one token a
@@ -30,25 +31,51 @@ mathematics:
   backward pass is that scan's transpose: the state at each chunk's start
   is what it keeps (``(N, B, H, dk, dv)`` float32), never a state a token.
 
+**The state-space rule of Mamba-2** (SSD; Dao and Gu 2024,
+arXiv:2405.21060). The same skeleton with the correction taken out (``T =
+I``, ``W = 0``, ``U = V`` above), a scalar decay a head, and keys and
+queries (``B`` and ``C``) shared by groups of heads. Per head, with a
+state ``S`` of ``(N, P)``::
+
+    S   <- exp(dt_t A) S + dt_t B_t x_t^T       # A < 0, dt_t > 0
+    y_t  = S^T C_t
+
+``ssd`` is its entry, with the same two forms. Chunked (256): with ``g_t
+= dt_t A`` and ``G``, ``D`` as above, ``O = exp(G) (C S_0) + ((C B^T) * D)
+(dt x)`` and ``S_C = exp(G_C) S_0 + B^T (exp(G_C - G) dt x)``. With no
+correction a chunk's addend to the state does not depend on the state, so
+it too is a batched product over all chunks (per GROUP: ``B^T`` against
+the group's heads side by side), as are ``C B^T`` (per group) and ``C
+S_0``; the decays go on the ``P``-wide side, so that neither ``B`` nor
+``C`` is ever repeated to the heads. The walk from chunk to chunk is ONE
+``lax.scan`` carrying the float32 state as ``(rows, G, (H / G) P, N)`` (a
+head's state transposed, a group's heads one under the other, ``N`` in
+the lanes), a multiply and an add a step (``S <- exp(G_C) S + Z_c``),
+under the scope ``ssd_scan``; it hands out the state each chunk starts
+from, which is also what its transpose needs.
+
 Every decay is the exponential of a difference that is not positive
 (``G_i - G_j`` under the causal mask, ``G_i``, ``G_C - G_j``); none is a
 quotient of two exponentials, which would be 0 / 0 once a head's decay
 over a chunk passes float32's range (at ``g`` = -20 a token, three tokens
-in). ``T`` is made in float32 at the highest matmul precision: blocks of at
-most 16 rows by the finite Neumann product ``(I - A)(I + A^2)(I + A^4)(I +
-A^8)``, joined two and two by ``[[P, 0], [R, Q]]^-1 = [[P^-1, 0], [-Q^-1 R
-P^-1, Q^-1]]``, so that no power of ``A`` past the fifteenth is formed.
+in; at Mamba-2's ``dt A`` = -1.6 a token, a chunk of 256 passes it several
+times over). ``T`` is made in float32 at the highest matmul precision:
+blocks of at most 16 rows by the finite Neumann product ``(I - A)(I +
+A^2)(I + A^4)(I + A^8)``, joined two and two by ``[[P, 0], [R, Q]]^-1 =
+[[P^-1, 0], [-Q^-1 R P^-1, Q^-1]]``, so that no power of ``A`` past the
+fifteenth is formed.
 The other products take ``dtype`` operands (bfloat16 on the chip) and
 accumulate in float32; state, decay and sums are float32.
 
-Counters, per TRACE: ``linear_attention.calls.<chunked|recurrent>``, and
+Counters, per TRACE: ``linear_attention.calls.<chunked|recurrent>``,
+``linear_attention.rule_calls.<delta|ssd>``, and
 ``linear_attention.fallbacks`` for a trace on an accelerator that took the
 token-by-token form under ``impl="auto"``.
 """
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,19 +83,20 @@ import jax.numpy as jnp
 from mmlspark_tpu.observability import metrics as obsmetrics
 
 CHUNK = 64
+SSD_CHUNK = 256
 _HIGHEST = jax.lax.Precision.HIGHEST
 _NEUMANN_ROWS = 16
 
 
-def causal_conv1d(x: jax.Array, kernel: jax.Array) -> jax.Array:
+def causal_conv1d(x: jax.Array, kernel: jax.Array,
+                  bias: Optional[jax.Array] = None) -> jax.Array:
     """Depthwise causal convolution over the sequence: ``x`` (B, L, C),
     ``kernel`` (W, C); ``y_t = sum_j kernel[j] * x_{t - (W-1) + j}`` with
-    ``W - 1`` zeros before the row's start, no bias."""
+    ``W - 1`` zeros before the row's start, plus ``bias`` (C,) if given."""
     width, L = kernel.shape[0], x.shape[1]
-    with jax.named_scope("gdn_conv"):
-        xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-        return sum(xp[:, j:j + L] * kernel[j].astype(x.dtype)
-                   for j in range(width))
+    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    y = sum(xp[:, j:j + L] * kernel[j].astype(x.dtype) for j in range(width))
+    return y if bias is None else y + bias.astype(x.dtype)
 
 
 def l2_normalize(x: jax.Array) -> jax.Array:
@@ -104,13 +132,27 @@ def inv_unit_lower(a: jax.Array) -> jax.Array:
     return jnp.concatenate([top, jnp.concatenate([low, q], -1)], -2)
 
 
+def _token_blocks(x, block: int):
+    """(B, L, ...) -> float32 (ceil(L / block), block, B, ...), zeros past
+    the row's end."""
+    pad = -x.shape[1] % block
+    x = jnp.pad(x.astype(jnp.float32),
+                ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    x = jnp.moveaxis(x, 1, 0)
+    return x.reshape((-1, block) + x.shape[1:])
+
+
+def _rows_of_blocks(o, length: int):
+    """What ``_token_blocks`` split, put together again: (B, L, ...)."""
+    return jnp.moveaxis(o.reshape((-1,) + o.shape[2:]), 0, 1)[:, :length]
+
+
 def _recurrent(q, k, v, g, beta, block: int):
     """Token by token, in blocks of ``block`` tokens whose inner loop is
     recomputed in the backward pass: a state a block is kept, and a state
     a token only while one block's backward runs."""
     f32 = jnp.float32
     B, L, H, dk = q.shape
-    pad = -L % block
 
     def step(S, x):
         q_t, k_t, v_t, g_t, b_t = x
@@ -120,34 +162,40 @@ def _recurrent(q, k, v, g, beta, block: int):
         S = S + k_t[..., :, None] * u[..., None, :]
         return S, jnp.einsum("bhkv,bhk->bhv", S, q_t, precision=_HIGHEST)
 
-    def blocks(x):      # (B, L, H, ...) -> (L / block, block, B, H, ...)
-        x = jnp.pad(x.astype(f32),
-                    ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = jnp.moveaxis(x, 1, 0)
-        return x.reshape((-1, block) + x.shape[1:])
     _, o = jax.lax.scan(
         jax.checkpoint(lambda S, x: jax.lax.scan(step, S, x)),
         jnp.zeros((B, H, dk, v.shape[-1]), f32),
-        tuple(blocks(x) for x in (q, k, v, g, beta)))
-    return jnp.moveaxis(o.reshape((-1,) + o.shape[2:]), 0, 1)[:, :L]
+        tuple(_token_blocks(x, block) for x in (q, k, v, g, beta)))
+    return _rows_of_blocks(o, L)
+
+
+def _whole_chunks(xs, chunk: int):
+    """Rows (B, L, ...) padded with zeros to whole chunks: the arrays and
+    the number of chunks. Both rules pass their state through a token of
+    zeros unchanged."""
+    L = xs[0].shape[1]
+    pad = -L % chunk
+    if pad:
+        xs = tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                   for x in xs)
+    return xs, (L + pad) // chunk
+
+
+def _chunks(x, N: int):
+    """(B, N * C, H, ...) -> (B, H, N, C, ...)."""
+    x = x.reshape((x.shape[0], N, -1) + x.shape[2:])
+    return jnp.moveaxis(x, 3, 1)
 
 
 def _chunked(q, k, v, g, beta, chunk: int, dtype):
     f32 = jnp.float32
     B, L, H, dk = q.shape
     dv = v.shape[-1]
-    pad = -L % chunk
-    if pad:     # g = 0, beta = 0, k = 0: the state passes through unchanged
-        q, k, v, g, beta = (jnp.pad(
-            x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
-    N, C = (L + pad) // chunk, chunk
-
-    def split(x):               # (B, L, H, ...) -> (B, H, N, C, ...)
-        x = x.reshape((B, N, C) + x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
-    q, k, v = (split(x.astype(f32)) for x in (q, k, v))
-    g, beta = split(g.astype(f32)), split(beta.astype(f32))
+    # padding: g = 0, beta = 0, k = 0
+    (q, k, v, g, beta), N = _whole_chunks((q, k, v, g, beta), chunk)
+    C = chunk
+    q, k, v = (_chunks(x.astype(f32), N) for x in (q, k, v))
+    g, beta = _chunks(g.astype(f32), N), _chunks(beta.astype(f32), N)
 
     G = jnp.cumsum(g, axis=-1)                          # (B, H, N, C)
     rows = jnp.arange(C)
@@ -178,7 +226,7 @@ def _chunked(q, k, v, g, beta, chunk: int, dtype):
     S0, U = jnp.moveaxis(S0, 0, 2), jnp.moveaxis(U, 0, 2)
     o = _mm("bhnid,bhnde->bhnie", q * eG, S0, dtype) \
         + _mm("bhnij,bhnje->bhnie", P, U, dtype)
-    o = jnp.moveaxis(o, 1, 3).reshape(B, L + pad, H, dv)
+    o = jnp.moveaxis(o, 1, 3).reshape(B, N * C, H, dv)
     return o[:, :L]
 
 
@@ -194,21 +242,121 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     ``dtype``: the matrix products' operand type in the chunked form
     (default: ``q``'s own); the recurrent form is float32 throughout.
     """
-    if impl not in ("auto", "chunked", "recurrent"):
-        raise ValueError(f"unknown impl {impl!r}")
     if g.shape != q.shape[:3] or beta.shape != q.shape[:3] \
             or k.shape != q.shape or v.shape[:3] != q.shape[:3]:
         raise ValueError(
             f"shapes q {q.shape} k {k.shape} v {v.shape} g {g.shape} "
             f"beta {beta.shape}")
+    taken = _form("delta", impl, q.shape[1], chunk)
     dtype = dtype or q.dtype
     q = q.astype(jnp.float32) * q.shape[-1] ** -0.5
-    taken = impl
-    if impl == "auto":
-        taken = "chunked" if q.shape[1] >= chunk else "recurrent"
-        if taken == "recurrent" and jax.default_backend() != "cpu":
-            obsmetrics.counter("linear_attention.fallbacks").inc()
-    obsmetrics.counter(f"linear_attention.calls.{taken}").inc()
     if taken == "recurrent":
         return _recurrent(q, k, v, g, beta, chunk)
     return _chunked(q, k, v, g, beta, chunk, dtype)
+
+
+def _form(rule: str, impl: str, length: int, chunk: int) -> str:
+    """The form a call takes, counted: ``impl`` itself, or under "auto"
+    the chunked one from one whole chunk up."""
+    if impl not in ("auto", "chunked", "recurrent"):
+        raise ValueError(f"unknown impl {impl!r}")
+    taken = impl
+    if impl == "auto":
+        taken = "chunked" if length >= chunk else "recurrent"
+        if taken == "recurrent" and jax.default_backend() != "cpu":
+            obsmetrics.counter("linear_attention.fallbacks").inc()
+    obsmetrics.counter(f"linear_attention.calls.{taken}").inc()
+    obsmetrics.counter(f"linear_attention.rule_calls.{rule}").inc()
+    return taken
+
+
+# ---------------------------------------------------------------- Mamba-2
+def _ssd_recurrent(x, dt, A, Bm, Cm, block: int):
+    """Token by token, float32, in blocks whose inner loop the backward
+    pass recomputes (as ``_recurrent``)."""
+    B, L, H, P = x.shape
+    rep = H // Bm.shape[2]
+
+    def step(S, t):
+        x_t, dt_t, B_t, C_t = t
+        B_t, C_t = (jnp.repeat(m, rep, axis=1) for m in (B_t, C_t))
+        S = jnp.exp(dt_t * A)[..., None, None] * S \
+            + B_t[..., :, None] * (dt_t[..., None] * x_t)[..., None, :]
+        return S, jnp.einsum("bhnp,bhn->bhp", S, C_t, precision=_HIGHEST)
+
+    _, y = jax.lax.scan(
+        jax.checkpoint(lambda S, t: jax.lax.scan(step, S, t)),
+        jnp.zeros((B, H, Bm.shape[-1], P), jnp.float32),
+        tuple(_token_blocks(a, block) for a in (x, dt, Bm, Cm)))
+    return _rows_of_blocks(y, L)
+
+
+def _ssd_chunked(x, dt, A, Bm, Cm, chunk: int, dtype):
+    f32 = jnp.float32
+    B, L, H, P = x.shape
+    Gr, N = Bm.shape[2:]
+    rep = H // Gr
+    # padding: dt = 0, no decay and no addend
+    (x, dt, Bm, Cm), Nc = _whole_chunks((x, dt, Bm, Cm), chunk)
+    C = chunk
+
+    def heads(a):               # (B, H, ...) -> (B, Gr, rep, ...)
+        return a.reshape((B, Gr, rep) + a.shape[2:])
+    dt = dt.astype(f32)
+    # v (B, Gr, rep, Nc, C, P); B, C (B, Gr, Nc, C, N); G (B, H, Nc, C)
+    v = heads(_chunks(x.astype(f32) * dt[..., None], Nc))
+    Bm, Cm = _chunks(Bm, Nc), _chunks(Cm, Nc)
+    G = jnp.cumsum(_chunks(dt * A, Nc), axis=-1)
+    rows = jnp.arange(C)
+    seen = rows[:, None] >= rows[None, :]
+    D = jnp.exp(jnp.where(seen, G[..., :, None] - G[..., None, :], -jnp.inf))
+    scores = _mm("bgnid,bgnjd->bgnij", Cm, Bm, dtype)[:, :, None] * heads(D)
+    inside = _mm("bgrnij,bgrnje->bgrnie", scores, v, dtype)
+    # a chunk's addend to the state, all chunks at once: (exp(G_C - G) dt
+    # x)^T B. A head's state lies transposed, (P, N), a group's heads one
+    # under the other: N fills the lanes, and B stays the product's second
+    # operand (the CPU's bfloat16 dot does not take it transposed as the
+    # first, and the rehearsals run there)
+    Z = _mm("bgrnje,bgnjd->bgnred",
+            v * heads(jnp.exp(G[..., -1:] - G))[..., None], Bm, dtype)
+    last = jnp.repeat(jnp.exp(G[..., -1]), P, axis=1)   # (B, H * P, Nc)
+
+    def walk(S, z):
+        Z_c, a_c = z
+        return a_c * S + Z_c, S
+
+    with jax.named_scope("ssd_scan"):
+        _, S0 = jax.lax.scan(
+            walk, jnp.zeros((B, Gr, rep * P, N), f32),
+            (jnp.moveaxis(Z.reshape(B, Gr, Nc, rep * P, N), 2, 0),
+             jnp.moveaxis(last.reshape(B, Gr, rep * P, 1, Nc), 4, 0)))
+    S0 = jnp.moveaxis(S0, 0, 2).reshape(B, Gr, Nc, rep, P, N)
+    y = inside + heads(jnp.exp(G))[..., None] * _mm(
+        "bgnid,bgnred->bgrnie", Cm, S0, dtype)
+    y = jnp.moveaxis(y.reshape(B, H, Nc, C, P), 1, 3).reshape(
+        B, Nc * C, H, P)
+    return y[:, :L]
+
+
+def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
+        C: jax.Array, *, chunk: int = SSD_CHUNK, impl: str = "auto",
+        dtype: Any = None) -> jax.Array:
+    """Mamba-2's state-space rule over whole rows, state zero at each
+    row's start: ``S_t = exp(dt_t A) S_{t-1} + dt_t B_t x_t^T``, ``y_t =
+    S_t^T C_t`` a head.
+
+    ``x`` (B, L, H, P), ``dt`` (B, L, H) positive (after its softplus),
+    ``A`` (H,) negative, ``B`` and ``C`` (B, L, G, N) with head ``h``
+    reading group ``h // (H / G)``; returns (B, L, H, P) float32, without
+    the skip term ``D x``. ``impl`` and ``dtype`` as ``gated_delta_rule``'s.
+    """
+    H = x.shape[2]
+    if dt.shape != x.shape[:3] or A.shape != (H,) or B.shape != C.shape \
+            or B.shape[:2] != x.shape[:2] or H % B.shape[2]:
+        raise ValueError(f"shapes x {x.shape} dt {dt.shape} A {A.shape} "
+                         f"B {B.shape} C {C.shape}")
+    taken = _form("ssd", impl, x.shape[1], chunk)
+    A = A.astype(jnp.float32)
+    if taken == "recurrent":
+        return _ssd_recurrent(x, dt, A, B, C, chunk)
+    return _ssd_chunked(x, dt, A, B, C, chunk, dtype or x.dtype)
