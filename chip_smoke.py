@@ -654,19 +654,23 @@ def kernel_short_attention(sz: Sizes, rehearsal: bool, record) -> None:
 
 
 def kernel_gated_delta(sz: Sizes, rehearsal: bool, record) -> None:
-    """The chunked gated delta rule (``ops/linear_attention.py``; XLA's
-    batched products and one scan, no Mosaic call), forward and backward
-    with bfloat16 operands, against its token-by-token float32 form: the
-    worst relative error over the output and the five gradients. Decays as
-    the model's init makes them (``-A softplus(1)``, ``A`` up to 16)."""
+    """The chunked gated delta rule (``ops/linear_attention.py``: the
+    chunk-local half as the Pallas calls of ``ops/pallas_delta_rule.py``
+    where they take the shape, else XLA's batched products; one scan
+    either way), forward and backward with bfloat16 operands, against its
+    token-by-token float32 form: the worst relative error over the output
+    and the five gradients, and the path the trace took (the counter
+    ``linear_attention.chunk_calls.*``). Twice: q and k at value-head
+    width, and at key-head width (half the heads), the shape
+    ``GatedDeltaNet`` sends. Decays as the model's init makes them (``-A
+    softplus(1)``, ``A`` up to 16)."""
     import jax
     import jax.numpy as jnp
+    from mmlspark_tpu.observability import metrics as obsmetrics
     from mmlspark_tpu.ops import linear_attention as la
 
     B, L, H, d = shape = sz.gated_delta
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
-    q, k = (la.l2_normalize(jax.random.normal(kk, shape))
-            for kk in ks[:2])
     v, w = (jax.random.normal(kk, shape) for kk in ks[2:4])
     g = -jnp.linspace(1e-3, 16.0, H) * jax.nn.softplus(
         1.0 + jax.random.normal(ks[4], (B, L, H)))
@@ -678,14 +682,29 @@ def kernel_gated_delta(sz: Sizes, rehearsal: bool, record) -> None:
                 *a, chunk=sz.gated_delta_chunk, impl=impl, dtype=dtype)
         return jax.jit(lambda *a: (out(*a), jax.grad(
             lambda *b: (out(*b) * w).sum(), argnums=(0, 1, 2, 3, 4))(*a)))
-    args = (q, k, v, g, beta)
-    (got, got_g), c, s = _kernel_run(both("chunked", jnp.bfloat16), args)
-    want, want_g = both("recurrent", jnp.float32)(*args)
-    err = max(_rel_err(got, want),
-              *(_rel_err(a, b) for a, b in zip(got_g, want_g)))
-    check(got.shape == shape and err <= BF16_REL_TOL,
-          f"gated_delta_bf16: rel err {err:.3g}")
-    record("gated_delta_bf16", shape, c, s, err)
+
+    def paths():
+        return {p: obsmetrics.counter(
+            f"linear_attention.chunk_calls.{p}").value
+            for p in ("pallas", "xla")}
+    for name, key_heads in (("gated_delta_bf16", H),
+                            ("gated_delta_bf16_key_heads", max(1, H // 2))):
+        q, k = (la.l2_normalize(jax.random.normal(kk, (B, L, key_heads, d)))
+                for kk in ks[:2])
+        args = (q, k, v, g, beta)
+        before = paths()
+        chunked = both("chunked", jnp.bfloat16)
+        if d % 128 == 0 and sz.gated_delta_chunk == 64:
+            _require_mosaic(chunked, args, name, rehearsal, calls=2)
+        (got, got_g), c, s = _kernel_run(chunked, args)
+        took = [p for p, n in paths().items() if n > before[p]]
+        want, want_g = both("recurrent", jnp.float32)(*args)
+        err = max(_rel_err(got, want),
+                  *(_rel_err(a, b) for a, b in zip(got_g, want_g)))
+        check(got.shape == shape and err <= BF16_REL_TOL,
+              f"{name}: rel err {err:.3g}")
+        check(len(took) == 1, f"{name}: the trace took {took}")
+        record(name, shape, c, s, err, path=took[0], key_heads=key_heads)
 
 
 def kernel_flash_sharded(sz: Sizes, rehearsal: bool, record) -> None:
@@ -733,12 +752,14 @@ def leg_kernels(sz: Sizes, meter: CompileMeter,
     out: Dict[str, Any] = {}
     compile_s = steady_s = 0.0
 
-    def record(name: str, shape, c: float, s: float, err: float) -> None:
+    def record(name: str, shape, c: float, s: float, err: float,
+               **more) -> None:
         nonlocal compile_s, steady_s
         compile_s += c
         steady_s += s
         out[name] = {"shape": list(shape), "compile_s": round(c, 3),
-                     "steady_ms": round(s * 1e3, 3), "rel_err": round(err, 6)}
+                     "steady_ms": round(s * 1e3, 3), "rel_err": round(err, 6),
+                     **more}
 
     for kernel_check in KERNEL_CHECKS:
         kernel_check(sz, rehearsal, record)

@@ -258,10 +258,9 @@ class GatedDeltaNet(nn.Module):
             v = mixed[..., 2 * Hk * dk:].reshape(B, L, Hv, dv)
             beta = jax.nn.sigmoid(ba[..., :Hv])
             g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)
-            q, k = (jnp.repeat(la.l2_normalize(t), Hv // Hk, axis=2)
-                    for t in (q, k))
-            o = la.gated_delta_rule(q, k, v, g, beta, chunk=self.chunk,
-                                    dtype=dt)
+            o = la.gated_delta_rule(
+                la.l2_normalize(q), la.l2_normalize(k), v, g, beta,
+                chunk=self.chunk, dtype=dt)
             o = RMSNorm(self.eps, name="gate_norm")(o) \
                 * nn.silu(z.astype(f32))
             return _dense(self.dim, dt, "attn_out")(

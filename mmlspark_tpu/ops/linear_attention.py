@@ -30,6 +30,18 @@ mathematics:
   step (``W S`` and ``K^T U``), under the scope ``gated_delta_rule``. Its
   backward pass is that scan's transpose: the state at each chunk's start
   is what it keeps (``(N, B, H, dk, dv)`` float32), never a state a token.
+  The batched half has two executors, picked from the call's shapes as
+  ``full_attention`` picks its kernel: where ``chunk`` is 64, both head
+  widths are multiples of 128 and the value heads are whole groups a key
+  head (``pallas_delta_rule.supports``), the Pallas calls of
+  ``ops/pallas_delta_rule.py`` make a chunk's tiles in VMEM from q, k, v
+  as ``(B, L, H * d)`` rows (q and k at KEY-head width: a value head
+  reads key head ``h // (Hv / Hk)`` through the block's index map) and
+  hand the walk ``W``, ``Kd`` in ``dtype`` and ``U_0`` in float32, chunk-
+  major; after the walk a third call writes ``O`` as rows. Otherwise
+  (``_chunked``) XLA's batched products over float32 head-major copies,
+  q and k repeated to the value heads first: the form every test holds
+  the calls to, and what other shapes run. The walk is the same scan.
 
 **The state-space rule of Mamba-2** (SSD; Dao and Gu 2024,
 arXiv:2405.21060). The same skeleton with the correction taken out (``T =
@@ -68,9 +80,10 @@ The other products take ``dtype`` operands (bfloat16 on the chip) and
 accumulate in float32; state, decay and sums are float32.
 
 Counters, per TRACE: ``linear_attention.calls.<chunked|recurrent>``,
-``linear_attention.rule_calls.<delta|ssd>``, and
-``linear_attention.fallbacks`` for a trace on an accelerator that took the
-token-by-token form under ``impl="auto"``.
+``linear_attention.rule_calls.<delta|ssd>``,
+``linear_attention.chunk_calls.<pallas|xla>`` (the executor of the delta
+rule's batched half) and ``linear_attention.fallbacks`` for a trace on an
+accelerator that took the token-by-token form under ``impl="auto"``.
 """
 from __future__ import annotations
 
@@ -81,6 +94,7 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.observability import metrics as obsmetrics
+from mmlspark_tpu.ops import pallas_delta_rule as pdr
 
 CHUNK = 64
 SSD_CHUNK = 256
@@ -187,6 +201,25 @@ def _chunks(x, N: int):
     return jnp.moveaxis(x, 3, 1)
 
 
+def _delta_walk(W, U0, Kd, last, dtype):
+    """The walk of the state from chunk to chunk, ONE ``lax.scan``: ``W``,
+    ``U0``, ``Kd`` (N, B, H, C, width) and ``last`` = exp(G_C) (N, B, H);
+    the state each chunk starts from, (N, B, H, dk, dv), and the chunks'
+    corrections ``U`` (N, B, H, C, dv), float32."""
+    def walk(S, x):
+        W_c, U0_c, Kd_c, a_c = x
+        U_c = U0_c - _mm("bhid,bhde->bhie", W_c, S, dtype)
+        nxt = a_c[..., None, None] * S \
+            + _mm("bhid,bhie->bhde", Kd_c, U_c, dtype)
+        return nxt, (S, U_c)
+
+    with jax.named_scope("gated_delta_rule"):
+        _, (S0, U) = jax.lax.scan(
+            walk, jnp.zeros(W.shape[1:3] + (W.shape[-1], U0.shape[-1]),
+                            jnp.float32), (W, U0, Kd, last))
+    return S0, U
+
+
 def _chunked(q, k, v, g, beta, chunk: int, dtype):
     f32 = jnp.float32
     B, L, H, dk = q.shape
@@ -212,22 +245,40 @@ def _chunked(q, k, v, g, beta, chunk: int, dtype):
     Kd = k * jnp.exp(G[..., -1:] - G)[..., None]
     last = jnp.exp(G[..., -1])                          # (B, H, N)
 
-    def walk(S, x):
-        W_c, U0_c, Kd_c, a_c = x
-        U_c = U0_c - _mm("bhid,bhde->bhie", W_c, S, dtype)
-        nxt = a_c[..., None, None] * S \
-            + _mm("bhid,bhie->bhde", Kd_c, U_c, dtype)
-        return nxt, (S, U_c)
-
-    with jax.named_scope("gated_delta_rule"):
-        _, (S0, U) = jax.lax.scan(
-            walk, jnp.zeros((B, H, dk, dv), f32),
-            tuple(jnp.moveaxis(x, 2, 0) for x in (W, U0, Kd, last)))
-    S0, U = jnp.moveaxis(S0, 0, 2), jnp.moveaxis(U, 0, 2)
+    S0, U = (jnp.moveaxis(x, 0, 2) for x in _delta_walk(
+        *(jnp.moveaxis(x, 2, 0) for x in (W, U0, Kd, last)), dtype))
     o = _mm("bhnid,bhnde->bhnie", q * eG, S0, dtype) \
         + _mm("bhnij,bhnje->bhnie", P, U, dtype)
     o = jnp.moveaxis(o, 1, 3).reshape(B, N * C, H, dv)
     return o[:, :L]
+
+
+def _chunked_kernel(q, k, v, g, beta, dtype):
+    """``_chunked`` with the chunk-local half in
+    ``ops/pallas_delta_rule.delta_chunk``: q and k keep their key heads, no
+    float32 head-major copy of anything a head wide is made, and the
+    output products write (B, L, Hv, dv) as they are."""
+    B, L, Hk, dk = q.shape
+    Hv, dv = v.shape[2:]
+    # padding: g = 0, beta = 0, k = 0; to whole programs of the calls
+    (q, k, v, g, beta), _ = _whole_chunks(
+        (q, k, v, g, beta), pdr.padded_length(L))
+    Lp = q.shape[1]
+
+    def rows(x):                # (B, L, H, d) -> (B, L, H * d), no copy
+        return x.reshape(B, Lp, -1)
+
+    def lanes(x):               # (B, L, Hv) -> float32 (B, Hv, pairs, 128)
+        return jnp.moveaxis(x.astype(jnp.float32), 2, 1).reshape(
+            B, Hv, -1, pdr.PAIR)
+    g = lanes(g)
+    W, U0, Kd, qe, P = pdr.delta_chunk(
+        rows(q), rows(k.astype(jnp.float32)), rows(v), g, lanes(beta),
+        (Hk, Hv, dk, dv), dtype)
+    last = jnp.exp(jnp.sum(g.reshape(B, Hv, -1, pdr.CHUNK), -1))
+    S0, U = _delta_walk(W, U0, Kd, jnp.moveaxis(last, 2, 0), dtype)
+    o = pdr.delta_chunk_out(qe, P, S0, U, dtype)
+    return o.reshape(B, Lp, Hv, dv)[:, :L]
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -235,23 +286,35 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                      impl: str = "auto", dtype: Any = None) -> jax.Array:
     """The gated delta rule over whole rows, state zero at each row's start.
 
-    ``q``, ``k`` (B, L, H, dk), ``v`` (B, L, H, dv), ``g`` (log decay, <= 0)
-    and ``beta`` (B, L, H); returns (B, L, H, dv) float32. ``q`` is scaled
-    by ``dk ** -0.5``. ``impl``: "auto" (chunked from
-    one whole chunk up, else token by token) | "chunked" | "recurrent".
-    ``dtype``: the matrix products' operand type in the chunked form
-    (default: ``q``'s own); the recurrent form is float32 throughout.
+    ``q``, ``k`` (B, L, Hk, dk), ``v`` (B, L, Hv, dv), ``g`` (log decay, <=
+    0) and ``beta`` (B, L, Hv); value head ``h`` reads key head ``h // (Hv /
+    Hk)``; returns (B, L, Hv, dv) float32. ``q`` is scaled by ``dk **
+    -0.5``. ``impl``: "auto" (chunked from one whole chunk up, else token
+    by token) | "chunked" | "recurrent". ``dtype``: the matrix products'
+    operand type in the chunked form (default: ``q``'s own); the recurrent
+    form is float32 throughout. The chunked form takes the Pallas calls
+    where ``pallas_delta_rule.supports`` the shapes, else XLA's batched
+    products: counted as ``linear_attention.chunk_calls.<pallas|xla>``.
     """
-    if g.shape != q.shape[:3] or beta.shape != q.shape[:3] \
-            or k.shape != q.shape or v.shape[:3] != q.shape[:3]:
+    Hk, Hv = q.shape[2], v.shape[2]
+    if g.shape != v.shape[:3] or beta.shape != v.shape[:3] \
+            or k.shape != q.shape or v.shape[:2] != q.shape[:2] \
+            or Hv % Hk:
         raise ValueError(
             f"shapes q {q.shape} k {k.shape} v {v.shape} g {g.shape} "
             f"beta {beta.shape}")
     taken = _form("delta", impl, q.shape[1], chunk)
     dtype = dtype or q.dtype
     q = q.astype(jnp.float32) * q.shape[-1] ** -0.5
+    if taken == "chunked" and pdr.supports(
+            chunk, Hk, Hv, q.shape[-1], v.shape[-1]):
+        obsmetrics.counter("linear_attention.chunk_calls.pallas").inc()
+        return _chunked_kernel(q, k, v, g, beta, dtype)
+    if Hk != Hv:
+        q, k = (jnp.repeat(x, Hv // Hk, axis=2) for x in (q, k))
     if taken == "recurrent":
         return _recurrent(q, k, v, g, beta, chunk)
+    obsmetrics.counter("linear_attention.chunk_calls.xla").inc()
     return _chunked(q, k, v, g, beta, chunk, dtype)
 
 

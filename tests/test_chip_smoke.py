@@ -107,8 +107,11 @@ def test_every_leg_runs_at_toy_size_on_the_cpu(rehearsal):
     assert k["mosaic_lowering_proven"] is False     # interpreted here
     assert {"flash_fwd_bf16", "flash_fwd_fp32", "flash_bwd_bf16",
             "flash_bwd_bf16_d256", "gated_delta_bf16",
-            "short_fwd_bwd_bf16",
+            "gated_delta_bf16_key_heads", "short_fwd_bwd_bf16",
             "flash_fwd_bf16_sharded_x8"} <= set(k["kernels"])
+    # head width 16, chunk 16: not the Pallas calls' shape
+    assert k["kernels"]["gated_delta_bf16"]["path"] == "xla"
+    assert k["kernels"]["gated_delta_bf16_key_heads"]["key_heads"] == 1
 
 
 def test_smoke_writes_only_where_the_environment_placed_the_cache(rehearsal):

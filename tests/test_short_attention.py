@@ -375,3 +375,48 @@ def test_a_recomputed_decoder_block_runs_the_flash_forward_once(
         assert sum(bool(rx.search(c)) for c in calls) == blocks, metric
     # and no further forward call that the patterns would not find
     assert sum("flash" in c.split(" = ")[0] for c in calls) == blocks
+
+
+@pytest.mark.parametrize("key_heads", [16, 32], ids=["grouped", "one_to_one"])
+def test_mosaic_compiles_the_delta_rules_chunk_calls(topo, as_on_chip,
+                                                     key_heads):
+    """The gated delta rule, forward and backward, at the shape of
+    benchmark cell ``qwen3-next-train-ep16share`` through Mosaic for a
+    v5e: the four Pallas calls of ``ops/pallas_delta_rule.py`` under the
+    names ``linattn.chunk_kernel_ms`` finds them by and no accepted
+    pattern does, and the walk still the two ``while``s (the scan and its
+    transpose) that ``linattn.delta_rule_ms`` finds by their state."""
+    from jax.sharding import SingleDeviceSharding
+    from mmlspark_tpu.ops import linear_attention as la
+    one = SingleDeviceSharding(topo.devices[0])
+    B, L, Hv, d = 2, 4096, 32, 128
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def grads(q, k, v, g, beta, w):
+        return jax.value_and_grad(lambda *a: (la.gated_delta_rule(
+            *a, dtype=jnp.bfloat16) * w).sum(), argnums=(0, 1, 2, 3, 4))(
+                q, k, v, g, beta)
+
+    text = jax.jit(grads).lower(
+        s((B, L, key_heads, d)), s((B, L, key_heads, d)),
+        s((B, L, Hv, d), jnp.bfloat16), s((B, L, Hv)), s((B, L, Hv)),
+        s((B, L, Hv, d))).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    names = sorted(re.match(r"(?:ROOT )?%([a-z_]+)", c).group(1)
+                   for c in calls)
+    assert names == ["delta_chunk_bwd", "delta_chunk_fwd",
+                     "delta_chunk_out", "delta_chunk_out_bwd"]
+    mine = _benchmark_pattern("linattn.chunk_kernel_ms")
+    assert all(mine.search(c) for c in calls)
+    for metric in ("kernel.flash_attention_ms", "kernel.flash_fwd_roofline",
+                   "kernel.flash_bwd_ms", "kernel.attention_ms",
+                   "linattn.delta_rule_ms", "ssm.state_walk_ms"):
+        assert not any(_benchmark_pattern(metric).search(c) for c in calls)
+    walk = _benchmark_pattern("linattn.delta_rule_ms")
+    assert sum(bool(walk.search(line.strip()))
+               for line in text.splitlines()) == 2
+    # no float32 head-major copy of q, k or v, and no repeated key heads
+    assert f"f32[{B},{Hv},{L // 64},64,{d}]" not in text
