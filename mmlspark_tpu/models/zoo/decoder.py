@@ -379,12 +379,17 @@ class SwiGluMlp(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        x = x.astype(self.dtype)
-        gate = checkpoint_name(
-            _dense(self.hidden, self.dtype, "mlp_gate")(x), MLP_GATE_UP)
-        up = checkpoint_name(
-            _dense(self.hidden, self.dtype, "mlp_up")(x), MLP_GATE_UP)
-        return _dense(self.dim, self.dtype, "mlp_down")(nn.silu(gate) * up)
+        # the scope names the dense feed-forward part wherever it runs (a
+        # block's own, a routed layer's shared expert) for the split of
+        # device time by part (``observability/scopes.py``)
+        with jax.named_scope("ffn"):
+            x = x.astype(self.dtype)
+            gate = checkpoint_name(
+                _dense(self.hidden, self.dtype, "mlp_gate")(x), MLP_GATE_UP)
+            up = checkpoint_name(
+                _dense(self.hidden, self.dtype, "mlp_up")(x), MLP_GATE_UP)
+            return _dense(self.dim, self.dtype, "mlp_down")(
+                nn.silu(gate) * up)
 
 
 class PartsBlock(nn.Module):

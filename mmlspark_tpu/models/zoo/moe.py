@@ -313,17 +313,20 @@ class DroplessMoe(nn.Module):
             slots_here = sizes.sum()
             bound = _bounded_rows(S * K, held, E)
 
-        w_gate, w_up, w_down = (
-            self.param(name, init, shape, jnp.float32).astype(self.dtype)
-            for name, shape in (("experts_gate", (held, D, H)),
-                                ("experts_up", (held, D, H)),
-                                ("experts_down", (held, H, D))))
-        operands = (xf.astype(self.dtype), gate, w_gate, w_up, w_down,
-                    order, sizes)
-        if bound == S * K:      # buffers of every slot: one size, no cond
-            y = _routed_rows(bound, *operands)
-        else:
-            y = _routed(bound, *operands)
+        # the outer scope names what ``_routed_rows``'s own three leave
+        # out: the weights' casts and the choice between the two sizes
+        with jax.named_scope("moe_experts"):
+            w_gate, w_up, w_down = (
+                self.param(name, init, shape, jnp.float32).astype(self.dtype)
+                for name, shape in (("experts_gate", (held, D, H)),
+                                    ("experts_up", (held, D, H)),
+                                    ("experts_down", (held, H, D))))
+            operands = (xf.astype(self.dtype), gate, w_gate, w_up, w_down,
+                        order, sizes)
+            if bound == S * K:  # buffers of every slot: one size, no cond
+                y = _routed_rows(bound, *operands)
+            else:
+                y = _routed(bound, *operands)
 
         with jax.named_scope("moe_combine"):
             if self.shared is not None:

@@ -14,6 +14,7 @@ from __future__ import annotations
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.models.zoo import register_model
@@ -27,9 +28,12 @@ class MlpBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        h = nn.Dense(self.hidden, dtype=self.dtype, name="mlp_up")(x)
-        h = nn.gelu(h)
-        return nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")(h)
+        # the decoder families' name for this part (``SwiGluMlp``): one
+        # key for the split of device time by part
+        with jax.named_scope("ffn"):
+            h = nn.Dense(self.hidden, dtype=self.dtype, name="mlp_up")(x)
+            h = nn.gelu(h)
+            return nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")(h)
 
 
 class SelfAttention(nn.Module):

@@ -420,6 +420,9 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
                          f"B {B.shape} C {C.shape}")
     taken = _form("ssd", impl, x.shape[1], chunk)
     A = A.astype(jnp.float32)
-    if taken == "recurrent":
-        return _ssd_recurrent(x, dt, A, B, C, chunk)
-    return _ssd_chunked(x, dt, A, B, C, chunk, dtype or x.dtype)
+    # the whole rule under one name (the walk, ``ssd_scan``, inside it): a
+    # row of its own in the split of device time by part
+    with jax.named_scope("ssd"):
+        if taken == "recurrent":
+            return _ssd_recurrent(x, dt, A, B, C, chunk)
+        return _ssd_chunked(x, dt, A, B, C, chunk, dtype or x.dtype)

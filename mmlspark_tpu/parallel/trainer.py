@@ -33,6 +33,7 @@ from mmlspark_tpu.data.prefetch import DevicePrefetcher  # noqa: F401
 from mmlspark_tpu.parallel.mesh import mesh_from_config
 from mmlspark_tpu.observability import events as obsevents
 from mmlspark_tpu.observability import metrics as obsmetrics
+from mmlspark_tpu.observability import scopes as obsscopes
 from mmlspark_tpu.observability import spans as obsspans
 from mmlspark_tpu.observability import syncs as obssyncs
 from mmlspark_tpu.reliability import watchdog as _watchdog
@@ -289,6 +290,10 @@ class DistributedTrainer:
         self._hot = None  # the span constructor while the gate is on
         self._steps_dispatched: Optional[obsmetrics.Counter] = None
         self._dispatched = 0
+        # the step variants whose program ``_publish_scopes`` has published
+        # (under the same gate, at their first dispatch)
+        self._scoped: set = set()
+        self._scope_program: Optional[str] = None
 
     # -- state -------------------------------------------------------------
     def _full_init_fn(self, init_params_fn: Callable[[], Any]):
@@ -338,13 +343,18 @@ class DistributedTrainer:
         ring (and one per aux scalar of the loss) plus the step counter of
         the latest step written. Replicated on purpose — every process
         flushes identical values under SPMD."""
-        flush = self.flush_steps()
         repl = replicated(self.mesh)
         with self.mesh:
-            ring = {name: jax.device_put(np.zeros((flush,), np.float32), repl)
-                    for name in ("loss",) + self._aux_names}
-            ring["step"] = jax.device_put(np.zeros((), np.int32), repl)
-            return ring
+            return {name: jax.device_put(np.zeros(shape, dtype), repl)
+                    for name, (shape, dtype) in self._ring_layout().items()}
+
+    def _ring_layout(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """Name -> (shape, dtype) of the ring's arrays, as they are now: an
+        aux scalar's slot is there once the first step has found it."""
+        layout = {name: ((self.flush_steps(),), np.float32)
+                  for name in ("loss",) + self._aux_names}
+        layout["step"] = ((), np.int32)
+        return layout
 
     def _build_train_step(self, donate_batch: bool):
         loss_fn = self.loss_fn
@@ -462,9 +472,13 @@ class DistributedTrainer:
         if self._steps_dispatched is None:
             self._resolve_hot()
         # host time to enqueue one step; off, one boolean test
-        dispatch = self._hot(
-            "trainer", "dispatch", step=self._dispatched,
-            donate=donate_batch) if self._hot else obsspans.NOOP
+        if self._hot:
+            dispatch = self._hot("trainer", "dispatch",
+                                 step=self._dispatched, donate=donate_batch)
+            if donate_batch not in self._scoped:
+                self._publish_scopes(fn, donate_batch, state, batch, rng)
+        else:
+            dispatch = obsspans.NOOP
 
         def call():
             try:
@@ -546,15 +560,53 @@ class DistributedTrainer:
         self._steps_dispatched = obsmetrics.counter(
             "trainer.steps_dispatched")
 
+    def _compile_step(self, fn, state, batch, rng):
+        """The already-jitted step ``fn`` lowered and compiled again (a
+        load where the persistent cache holds it), on arrays or on their
+        ``jax.ShapeDtypeStruct``s; the ring by its layout when this runs.
+        No step loop calls it."""
+        repl = replicated(self.mesh)
+        ring = {name: jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+                for name, (shape, dtype) in self._ring_layout().items()}
+        with self.mesh:
+            return fn.lower(state, ring, batch, rng).compile()
+
+    def _publish_scopes(self, fn, donate_batch: bool, state, batch,
+                        rng) -> None:
+        """Publish the step program's scope table
+        (``observability/scopes.py``) under the name a device trace gives
+        the program: a thunk over the jitted step and the shapes and
+        shardings of the arguments of this call. No buffer is kept and
+        nothing is lowered until somebody asks for the table."""
+        def spec(x):
+            committed = getattr(x, "committed", False)
+            return jax.ShapeDtypeStruct(
+                np.shape(x), x.dtype,
+                sharding=x.sharding if committed else None)
+        args = jax.tree_util.tree_map(spec, (state, batch, rng))
+        self._scoped.add(donate_batch)
+        self._scope_program = "jit_" + fn.__name__
+        obsscopes.publish(self._scope_program,
+                          lambda: self._compile_step(fn, *args))
+
+    def step_scopes(self) -> Optional[obsscopes.Table]:
+        """Instruction name -> ``Scope(path, tops)`` of the step program
+        last published: which ``jax.named_scope`` each operation of a
+        device profile belongs to (``docs/OBSERVABILITY.md``). ``None``
+        until a step has been dispatched with ``observability.annotate``
+        or the event log on. The first call lowers and compiles the step
+        again; no step loop calls it."""
+        if self._scope_program is None:
+            return None
+        return obsscopes.table(self._scope_program)
+
     def _estimate_flops(self, state, batch, rng) -> float:
         """FLOPs of one compiled train step via XLA cost analysis (a
         Mosaic custom call inside the step counts as zero). Lowers and
         compiles the already-jitted step again, so no step loop calls it
         (``bench.py`` does, once). A backend that cannot answer raises."""
         fn = next(iter(self._train_steps.values()))
-        ring = self._ring if self._ring is not None else self._init_ring()
-        with self.mesh:
-            cost = fn.lower(state, ring, batch, rng).compile().cost_analysis()
+        cost = self._compile_step(fn, state, batch, rng).cost_analysis()
         return float(cost["flops"])
 
     def _finish_epoch_telemetry(self, steps: int, rows: int,
