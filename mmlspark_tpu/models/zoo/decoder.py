@@ -49,9 +49,10 @@ Blocks are recomputed in the backward pass one by one (``nn.remat``), which
 is what lets 4,096-token rows train beside the optimizer's state on one
 chip. A block keeps its input and a short list of named values whose
 recomputation costs more than their bytes (``_remat_block``): the
-flash kernel's output and log-sum-exps, so that its forward runs once a
-block and not twice, and the SwiGLU gate and up products (a family whose
-feed-forward part is too wide for that names the first alone).
+flash kernel's output and log-sum-exps and the five tiles the gated delta
+rule's forward call writes, so that each kernel's forward runs once a
+block and not twice, and the SwiGLU gate and up products. The list is one
+for every family: a name that no value of a block carries costs nothing.
 """
 from __future__ import annotations
 
@@ -69,6 +70,8 @@ from mmlspark_tpu.parallel.sequence import full_attention
 _INIT = nn.initializers.normal(0.02)
 # the checkpoint name of ``SwiGluMlp``'s gate and up products
 MLP_GATE_UP = "mlp_gate_up"
+# and of ``GatedDeltaNet``'s input projection's output
+DELTA_NET_QKVZ = "delta_net_qkvz"
 
 
 class RMSNorm(nn.Module):
@@ -241,7 +244,9 @@ class GatedDeltaNet(nn.Module):
         dt, f32 = self.dtype, jnp.float32
         with jax.named_scope("gated_delta_net"):
             x = x.astype(dt)
-            qkvz = _dense(2 * Hk * dk + 2 * Hv * dv, dt, "attn_qkvz")(x)
+            qkvz = checkpoint_name(_dense(
+                2 * Hk * dk + 2 * Hv * dv, dt, "attn_qkvz")(x),
+                DELTA_NET_QKVZ)
             ba = _dense(2 * Hv, dt, "attn_ba")(x).astype(f32)
             conv = self.param("conv_kernel", _INIT,
                               (self.conv_width, 2 * Hk * dk + Hv * dv), f32)
@@ -447,34 +452,44 @@ class SplitBlock(nn.Module):
 
 
 def _remat_block(norm, attention, ffn, name: str, split: bool = False,
-                 residual_scale: float = 1.0,
-                 keep: Optional[Tuple[str, ...]] = None) -> nn.Module:
+                 residual_scale: float = 1.0) -> nn.Module:
     """A ``PartsBlock`` recomputed in the backward pass, but for what is
-    named here (the names sit where the values are made; in units of the
-    block's input, bf16 (B, L, dim)): the flash kernel's output and log-sum-
-    exps, 2.5, without which its forward call runs twice a block; the
-    SwiGLU gate and up products, 10 in ``glm4_moe_lite``'s dense block and
-    1.5 in a routed block's shared expert. Each paid on the chip (PERF.md
-    section 6, PR 29: +5.6% and +1.5% of a step). Left to the
-    recomputation: the residual stream after attention (1 a block, +0.7%:
-    under the 1% a name has to pay); q, k, v (7.5 a block, 1.5 GB a step,
-    for under 10 ms); the routed experts' ragged_dot intermediates (1 GB a
-    step for 5 ms, and the benchmark's moe.expert_matmul_roofline counts
-    their recomputation as required work);
-    dots_with_no_batch_dims_saveable (about 3 GB: no room beside AdamW's
-    state). Attention that is not the flash kernel carries no such name
-    and keeps what it kept before; a Gated DeltaNet layer names nothing
-    (its scan keeps a state a chunk across ITS backward, inside the
-    recomputation). ``split`` recomputes the block's two halves apart
-    (``SplitBlock``) and keeps the residual stream between them: for a
-    block whose halves' backward passes do not fit side by side. ``keep``
-    is the list of names kept, by default the two above
-    (``FLASH_RESIDUALS``, ``MLP_GATE_UP``); ``residual_scale`` is the
-    block's. (Imported here: Pallas costs every importer of the zoo over
-    a second.)"""
+    named here: ONE list for every family, because a name that no value of
+    a block carries costs nothing (the names sit where the values are
+    made). In units of the block's input, bf16 (B, L, dim): the flash
+    kernel's output and log-sum-exps, 2.5, without which its forward call
+    runs twice a block; the SwiGLU gate and up products, 10 in
+    ``glm4_moe_lite``'s dense block, 1.5 in a routed block's shared
+    expert, 8 in every ``granite_hybrid`` block (2.68 GB a step: its mark
+    11.77 -> 14.16 GB of the chip's 16.91); the five tiles the gated delta
+    rule's forward call writes, 12 a Gated DeltaNet block, without which
+    ``delta_chunk_fwd`` runs twice a block; that block's input projection
+    (``DELTA_NET_QKVZ``, the ``[q | k | v | z]`` rows), 6. Each paid on
+    the chip (PERF.md section 6; PR 29: +5.6% and +1.5% of a
+    ``glm4_moe_lite`` step; PR 36: the tiles +3.7% and the projection
+    +2.0% of a ``qwen3_next`` step, the products +5.3% of a
+    ``granite_hybrid`` step). Left to the recomputation: the residual
+    stream after attention (1 a block, +0.7%: under the 1% a name has to
+    pay); q, k, v (7.5 a block, 1.5 GB a step, for under 10 ms); the
+    routed experts' ragged_dot intermediates (1 GB a step for 5 ms, and
+    the benchmark's moe.expert_matmul_roofline counts their recomputation
+    as required work); ``granite_hybrid``'s mixer's input projection
+    (1.26 GB a step for 13.7 ms: one run read +3.4%, the next issue's to
+    measure, PERF.md section 7);
+    dots_with_no_batch_dims_saveable (about 3 GB: no room beside
+    AdamW's state). Attention that is not the flash kernel carries no
+    such name and keeps what it kept before; a delta rule that runs XLA's
+    batched form (head widths under 128) names no tiles, and either
+    rule's walk keeps a state a chunk across ITS backward inside the
+    recomputation, where no name reaches. ``split`` recomputes the
+    block's two halves apart (``SplitBlock``) and keeps the residual
+    stream between them: for a block whose halves' backward passes do not
+    fit side by side. ``residual_scale`` is the block's. (Imported here:
+    Pallas costs every importer of the zoo over a second.)"""
     from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
+    from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
     policy = jax.checkpoint_policies.save_only_these_names(
-        *((FLASH_RESIDUALS, MLP_GATE_UP) if keep is None else keep))
+        FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES, DELTA_NET_QKVZ)
     if split:
         return nn.remat(SplitBlock, policy=policy, methods=("mix", "feed"))(
             norm, attention, ffn, residual_scale, name=name)
@@ -699,8 +714,9 @@ class GraniteHybrid(nn.Module):
     ``logits_scaling``, so that ``next_token_loss(out, E^T, tokens)`` with
     ``E = params["token_embedding"]["embedding"]`` is the model's loss;
     ``stats`` is empty (no routed layer). Each block is recomputed in the
-    backward pass in halves and keeps the flash kernel's residuals alone:
-    the gate and up products are 8,192 wide here."""
+    backward pass in halves and keeps what ``_remat_block`` names for
+    every family: here the flash kernel's residuals in the softmax layers
+    and the gate and up products, 8,192 wide, in every layer."""
     vocab: int
     dim: int
     layer_types: Tuple[str, ...]
@@ -723,7 +739,6 @@ class GraniteHybrid(nn.Module):
     attention_fn: Optional[Callable] = None
 
     def _block(self, kind: str, name: str) -> nn.Module:
-        from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
         dt = self.dtype
 
         def attention(n):
@@ -739,8 +754,7 @@ class GraniteHybrid(nn.Module):
         return _remat_block(
             lambda n: RMSNorm(self.eps, name=n), attention,
             lambda n: SwiGluMlp(self.dim, self.mlp_hidden, dt, name=n),
-            name, split=True, residual_scale=self.residual_multiplier,
-            keep=(FLASH_RESIDUALS,))
+            name, split=True, residual_scale=self.residual_multiplier)
 
     @nn.compact
     def __call__(self, tokens, hidden: bool = False):

@@ -18,13 +18,20 @@ half of each; nothing couples the two chunks.
   K``, ``qe = e^G Q`` and ``P = (Q K^T) * D`` in the products' operand
   type (each is only ever read as such an operand) and ``U_0 = T (beta
   V)`` in float32 (the walk subtracts from it).
+  The five carry ONE checkpoint name, ``DELTA_CHUNK_TILES``: a block
+  recomputed under a policy that lists it
+  (``models/zoo/decoder._remat_block``) keeps them and its backward pass
+  holds no second forward call; under no policy, or another, the name
+  changes nothing.
 - ``delta_chunk_bwd`` (``jax.custom_vjp``; same grid, the value heads of
   one key head in turn so that dq and dk are summed over them in the
-  output block): makes the tiles again (nothing chunk-local is kept
-  between the passes) and takes the five cotangents back to q, k, v, g
-  and beta. Through the inverse ``dA = -strict_lower(T^T dT T^T)``;
-  through the decays ``dG_i = sum_j (dD * D)_ij - sum_j (dD * D)_ji`` and
-  the ``e^G``, ``e^(G_C - G)`` terms, then the reverse running sum.
+  output block): gets ``(q, k, v, g, beta)`` alone and makes the tiles
+  again (nothing chunk-local is kept for IT, whatever a policy keeps of
+  the forward's outputs for the walk and the output's backward) and
+  takes the five cotangents back to q, k, v, g and beta. Through the
+  inverse ``dA = -strict_lower(T^T dT T^T)``; through the decays ``dG_i =
+  sum_j (dD * D)_ij - sum_j (dD * D)_ji`` and the ``e^G``, ``e^(G_C - G)``
+  terms, then the reverse running sum.
 - ``delta_chunk_out`` after the walk: ``O = qe S_0 + P U`` straight into
   ``(B, L, Hv * dv)`` rows (the state and the corrections are cast to
   ``dtype`` in VMEM); ``delta_chunk_out_bwd`` is its four products
@@ -48,6 +55,7 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -397,8 +405,16 @@ def delta_chunk(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     return tuple(_forward(q, k, v, g, beta, dims, jnp.dtype(dtype)))
 
 
+# One name for all five: a policy that kept four of them would still run
+# the call. The tiles returned and the tiles saved are the same named
+# values; without a policy that lists the name it changes nothing.
+DELTA_CHUNK_TILES = "delta_chunk_tiles"
+
+
 def _fwd_rule(q, k, v, g, beta, dims, dtype):
-    return delta_chunk(q, k, v, g, beta, dims, dtype), (q, k, v, g, beta)
+    tiles = tuple(checkpoint_name(t, DELTA_CHUNK_TILES)
+                  for t in delta_chunk(q, k, v, g, beta, dims, dtype))
+    return tiles, (q, k, v, g, beta)
 
 
 def _bwd_rule(dims, dtype, res, cts):

@@ -12,9 +12,11 @@ path and the XLA form round the same operands at the same places, 2e-2
 of the norm against the float32 rule (``chip_smoke.BF16_REL_TOL``) and
 against each other.
 """
+import collections
 import sys
 from pathlib import Path
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,10 +26,14 @@ from jax.experimental import pallas as pl
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.references import qwen3_next as ref  # noqa: E402
-from mmlspark_tpu.models.zoo.decoder import GatedDeltaNet  # noqa: E402
+from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
+    GatedDeltaNet, Qwen3Next)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
 from mmlspark_tpu.ops import pallas_delta_rule as pdr  # noqa: E402
+# the flash kernel's twin of the last section here: what ``decoder.py``'s
+# ``nn.remat`` is for the blocks compared with, and a jaxpr's Pallas calls
+from tests.test_glm4_moe_lite import _REMAT, _pallas_calls  # noqa: E402
 
 NAMES = "o q k v g beta".split()
 
@@ -197,3 +203,78 @@ def test_delta_net_layer_on_the_pallas_path_is_the_reference_layer(length):
     want = jax.jit(jax.vmap(lambda row: ref._delta_net(
         d, jnp.einsum, p["params"], row)))(x)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+# -------------------------- what a block keeps of the call's forward tiles
+_DELTA_LAYERS = 3
+
+
+def _block_grads(jaxpr=False):
+    """Gradients of one period of ``qwen3_next`` (three Gated DeltaNet
+    blocks and a softmax one) at head width 128 and chunk 64, where the
+    rule takes the Pallas calls, under ``nn.remat`` as it stands when
+    called, or their jaxpr."""
+    module = Qwen3Next(
+        vocab=48, dim=32, depth=_DELTA_LAYERS + 1, heads=2, kv_heads=1,
+        head_dim=16, rotary_width=4, linear_key_heads=1,
+        linear_value_heads=2, linear_key_dim=128, linear_value_dim=128,
+        conv_width=4, expert_hidden=16, shared_hidden=16, num_experts=4,
+        top_k=2, chunk=64, dtype=jnp.float32)
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, 48, size=(1, 128)).astype(np.int32))
+    params = module.init(jax.random.PRNGKey(3), tokens)
+
+    def loss(p):
+        return jnp.sum(jnp.sin(module.apply(p, tokens, hidden=True)[
+            "hidden"]))
+    if jaxpr:
+        return jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+    return jax.jit(jax.grad(loss))(params)
+
+
+@pytest.fixture(scope="module")
+def kept_grads():
+    """The module's own gradients, once."""
+    return _block_grads()
+
+
+@pytest.mark.parametrize("remat,forward_calls", [
+    ("kept", 1), ("input_only", 2), ("nothing_recomputed", 1)])
+def test_a_recomputed_block_holds_one_forward_call_of_the_tiles(
+        monkeypatch, kept_grads, remat, forward_calls):
+    """Three Gated DeltaNet blocks in the gradient's jaxpr: the backward
+    pass of a block that keeps the five tiles ``delta_chunk`` wrote
+    (``DELTA_CHUNK_TILES``) has no second ``delta_chunk_fwd`` call; the
+    backward call, which makes its tiles again, and the output's two run
+    as often as they did (the walk between them is still recomputed, so
+    ``delta_chunk_out`` is too). The values kept are the ones the
+    recomputation would have made: the gradients are those of a block
+    that keeps its input alone and of one that recomputes nothing."""
+    monkeypatch.setattr(nn, "remat", _REMAT[remat])
+    calls = collections.Counter(_pallas_calls(_block_grads(jaxpr=True)))
+    assert calls[pdr._FWD_NAME] == forward_calls * _DELTA_LAYERS
+    assert calls[pdr._BWD_NAME] == _DELTA_LAYERS
+    assert calls[pdr._OUT_NAME] == _DELTA_LAYERS * (
+        1 if remat == "nothing_recomputed" else 2)
+    assert calls[pdr._OUT_BWD_NAME] == _DELTA_LAYERS
+    if remat == "kept":
+        return
+    for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path(kept_grads),
+            jax.tree_util.tree_leaves(_block_grads())):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-6, atol=1e-6 * float(jnp.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_name_on_the_tiles_is_inert_without_a_policy():
+    """Differentiated outside any recomputation (``chip_smoke.py``'s leg,
+    the tests above), the call's forward runs once and the name is an
+    identity in the program."""
+    args, w = _inputs(128, "init")
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+        la.gated_delta_rule(*a, chunk=64) * w), argnums=(0, 1, 2, 3, 4)))(
+            *args).jaxpr
+    calls = collections.Counter(_pallas_calls(jaxpr))
+    assert calls == {pdr._FWD_NAME: 1, pdr._BWD_NAME: 1, pdr._OUT_NAME: 1,
+                     pdr._OUT_BWD_NAME: 1}

@@ -483,28 +483,32 @@ def test_each_block_recomputed_in_halves_is_the_block_not_recomputed():
             err_msg=jax.tree_util.keystr(path))
 
 
-def test_this_family_keeps_the_flash_residuals_and_not_the_gate_and_up():
-    """``_remat_block``'s list of names: today's two by default, the flash
-    kernel's alone here (the policy is a closure over its names)."""
+@pytest.mark.parametrize("preset", [
+    "glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny"])
+def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
+                                                          preset):
+    """``_remat_block`` has ONE list of names for every family (the policy
+    is a closure over its names): a name that no value of a block carries
+    costs nothing, so the list does not know the family. This family's
+    exception (the flash kernel's residuals alone, for want of a
+    measurement of the room the 8,192-wide gate and up products take) went
+    when the room was measured (PERF.md section 6, PR 36)."""
     from mmlspark_tpu.models.zoo import decoder
     from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
+    from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
     seen = []
     real = jax.checkpoint_policies.save_only_these_names
 
     def spy(*names):
         seen.append(names)
         return real(*names)
-    jax.checkpoint_policies.save_only_these_names = spy
-    try:
-        build_model("granite_hybrid_tiny")["module"].init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-        assert set(seen) == {(FLASH_RESIDUALS,)}
-        del seen[:]
-        build_model("qwen3_next_tiny")["module"].init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-        assert set(seen) == {(FLASH_RESIDUALS, decoder.MLP_GATE_UP)}
-    finally:
-        jax.checkpoint_policies.save_only_these_names = real
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        spy)
+    build_model(preset)["module"].init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    assert seen and set(seen) == {
+        (FLASH_RESIDUALS, decoder.MLP_GATE_UP, DELTA_CHUNK_TILES,
+         decoder.DELTA_NET_QKVZ)}
 
 
 # ------------------------------------------------------------ the parts
