@@ -104,6 +104,9 @@ class Sizes:
     # calls it: (B, L, value heads, head width), chunks of 64
     gated_delta: Tuple[int, int, int, int] = (2, 4096, 32, 128)
     gated_delta_chunk: int = 64
+    # and as cell olmo-hybrid-7b-train-8k calls it: (B, L, heads, key
+    # width, value width), a state that fills no whole lanes, beta up to 2
+    gated_delta_wide: Tuple[int, int, int, int, int] = (1, 8192, 30, 96, 192)
 
 
 FULL = Sizes()
@@ -660,23 +663,19 @@ def kernel_gated_delta(sz: Sizes, rehearsal: bool, record) -> None:
     either way), forward and backward with bfloat16 operands, against its
     token-by-token float32 form: the worst relative error over the output
     and the five gradients, and the path the trace took (the counter
-    ``linear_attention.chunk_calls.*``). Twice: q and k at value-head
-    width, and at key-head width (half the heads), the shape
-    ``GatedDeltaNet`` sends. Decays as the model's init makes them (``-A
+    ``linear_attention.chunk_calls.*``). Three times: q and k at
+    value-head width, at key-head width (half the heads), the shape
+    ``GatedDeltaNet`` sends for ``qwen3_next``, and at a state of 96 x
+    192 a head with ``beta`` up to 2, the shape it sends for
+    ``olmo_hybrid``. Decays as the model's init makes them (``-A
     softplus(1)``, ``A`` up to 16)."""
     import jax
     import jax.numpy as jnp
     from mmlspark_tpu.observability import metrics as obsmetrics
     from mmlspark_tpu.ops import linear_attention as la
+    from mmlspark_tpu.ops import pallas_delta_rule as pdr
 
-    B, L, H, d = shape = sz.gated_delta
-    ks = jax.random.split(jax.random.PRNGKey(0), 6)
-    v, w = (jax.random.normal(kk, shape) for kk in ks[2:4])
-    g = -jnp.linspace(1e-3, 16.0, H) * jax.nn.softplus(
-        1.0 + jax.random.normal(ks[4], (B, L, H)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, L, H)))
-
-    def both(impl, dtype):
+    def both(impl, dtype, w):
         def out(*a):
             return la.gated_delta_rule(
                 *a, chunk=sz.gated_delta_chunk, impl=impl, dtype=dtype)
@@ -687,18 +686,30 @@ def kernel_gated_delta(sz: Sizes, rehearsal: bool, record) -> None:
         return {p: obsmetrics.counter(
             f"linear_attention.chunk_calls.{p}").value
             for p in ("pallas", "xla")}
-    for name, key_heads in (("gated_delta_bf16", H),
-                            ("gated_delta_bf16_key_heads", max(1, H // 2))):
-        q, k = (la.l2_normalize(jax.random.normal(kk, (B, L, key_heads, d)))
+    B, L, H, d = sz.gated_delta
+    wide = sz.gated_delta_wide
+    for name, (B, L, H, dk, dv), key_heads, beta_scale in (
+            ("gated_delta_bf16", (B, L, H, d, d), H, 1.0),
+            ("gated_delta_bf16_key_heads", (B, L, H, d, d),
+             max(1, H // 2), 1.0),
+            ("gated_delta_bf16_wide", wide, wide[2], 2.0)):
+        shape = (B, L, H, dv)
+        ks = jax.random.split(jax.random.PRNGKey(0), 6)
+        v, w = (jax.random.normal(kk, shape) for kk in ks[2:4])
+        g = -jnp.linspace(1e-3, 16.0, H) * jax.nn.softplus(
+            1.0 + jax.random.normal(ks[4], (B, L, H)))
+        beta = beta_scale * jax.nn.sigmoid(
+            jax.random.normal(ks[5], (B, L, H)))
+        q, k = (la.l2_normalize(jax.random.normal(kk, (B, L, key_heads, dk)))
                 for kk in ks[:2])
         args = (q, k, v, g, beta)
         before = paths()
-        chunked = both("chunked", jnp.bfloat16)
-        if d % 128 == 0 and sz.gated_delta_chunk == 64:
+        chunked = both("chunked", jnp.bfloat16, w)
+        if pdr.supports(sz.gated_delta_chunk, key_heads, H, dk, dv):
             _require_mosaic(chunked, args, name, rehearsal, calls=2)
         (got, got_g), c, s = _kernel_run(chunked, args)
         took = [p for p, n in paths().items() if n > before[p]]
-        want, want_g = both("recurrent", jnp.float32)(*args)
+        want, want_g = both("recurrent", jnp.float32, w)(*args)
         err = max(_rel_err(got, want),
                   *(_rel_err(a, b) for a, b in zip(got_g, want_g)))
         check(got.shape == shape and err <= BF16_REL_TOL,

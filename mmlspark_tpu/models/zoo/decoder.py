@@ -1,11 +1,14 @@
-"""Decoder LMs built from parts, and the three families made of them:
+"""Decoder LMs built from parts, and the four families made of them:
 ``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's),
 ``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
-softmax layer, every feed-forward part a routed layer) and
+softmax layer, every feed-forward part a routed layer),
 ``granite_hybrid`` (Granite 4.0-H: Mamba-2 mixers and grouped softmax
 attention without positions in the order of a published list, a dense
 SwiGLU part in every layer, scaled residual additions, one table for the
-embedding and the head).
+embedding and the head) and ``olmo_hybrid`` (Olmo-Hybrid: Gated DeltaNet
+layers whose state's transition may have negative eigenvalues and plain
+softmax attention without positions in the order of a published list, a
+dense SwiGLU part in every layer, OLMo 2's norms on each half's OUTPUT).
 
 ``PartsBlock`` is the pre-norm residual block with nothing fixed: its norm,
 its attention (which owns its projections and its positions) and its
@@ -28,10 +31,12 @@ Parts here:
   flash kernel and its backward run as they are), norms on q and k, a
   rotary slice, and a sigmoid gate on the output;
 - ``GroupedAttention``: the same grouped heads with nothing else: no
-  positions, no norms, no gate, and a softmax scale of its own;
+  positions, no gate, a softmax scale of its own, and if asked an RMS norm
+  over the whole q and the whole k projection;
 - ``GatedDeltaNet``: linear attention with a recurrent state
   (``ops/linear_attention.py``): a short causal convolution, the gated
-  delta rule, a gated norm on the output;
+  delta rule with ``beta`` in (0, ``beta_scale``), a gated norm on the
+  output;
 - ``Mamba2Mixer``: the state-space layer (the same module's ``ssd``): a
   convolution with a bias over ``[x | B | C]``, a scalar decay a head,
   ``B`` and ``C`` shared by groups of heads, a skip, the gate BEFORE the
@@ -52,7 +57,9 @@ recomputation costs more than their bytes (``_remat_block``): the
 flash kernel's output and log-sum-exps and the five tiles the gated delta
 rule's forward call writes, so that each kernel's forward runs once a
 block and not twice, and the SwiGLU gate and up products. The list is one
-for every family: a name that no value of a block carries costs nothing.
+for every family (a name that no value of a block carries costs nothing)
+but where a family's state leaves no room for all of it: that family
+hands ``_remat_block`` the names it lets go.
 """
 from __future__ import annotations
 
@@ -212,16 +219,22 @@ class GatedAttention(nn.Module):
 
 
 class GatedDeltaNet(nn.Module):
-    """Gated DeltaNet (arXiv:2412.06464) as Qwen3-Next lays it out:
+    """Gated DeltaNet (arXiv:2412.06464) in flash-linear-attention's
+    layout, which serves two published ones: Qwen3-Next's (16 key heads
+    under 32 value heads, 128 x 128 a head, ``beta`` in (0, 1)) and
+    Olmo-Hybrid's (as many key as value heads, 96 x 192 a head,
+    ``beta_scale`` 2: ``linear_allow_neg_eigval``, a token's transition
+    ``I - beta k k^T`` then has its eigenvalue ``1 - beta`` in (-1, 1)).
     ``[q | k | v | z] = x W_qkvz`` and ``[b | a] = x W_ba``; ``[q | k | v]``
-    pass a causal depthwise convolution and ``silu``; ``beta = sigmoid(b)``,
-    ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; q and k are
-    L2-normalised over the head, each key head serves ``value_heads /
-    key_heads`` value heads; the gated delta rule
+    pass a causal depthwise convolution and ``silu``; ``beta = beta_scale
+    sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; q
+    and k are L2-normalised over the head, each key head serves
+    ``value_heads / key_heads`` value heads; the gated delta rule
     (``ops/linear_attention.gated_delta_rule``: chunked on whole rows);
     ``o <- rmsnorm(o) * w_n * silu(z)`` over each head; ``y = o W_o``.
     Columns of ``W_qkvz`` are ``[q | k | v | z]``, head-major inside each
-    (a checkpoint's per-key-head interleaving is a permutation of them)."""
+    (a checkpoint's per-key-head interleaving, or its four separate
+    matrices, are a permutation of them)."""
     dim: int
     key_heads: int
     value_heads: int
@@ -231,6 +244,7 @@ class GatedDeltaNet(nn.Module):
     eps: float = 1e-6
     chunk: int = 64
     dtype: Any = jnp.bfloat16
+    beta_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -262,6 +276,8 @@ class GatedDeltaNet(nn.Module):
             k = mixed[..., Hk * dk:2 * Hk * dk].reshape(B, L, Hk, dk)
             v = mixed[..., 2 * Hk * dk:].reshape(B, L, Hv, dv)
             beta = jax.nn.sigmoid(ba[..., :Hv])
+            if self.beta_scale != 1.0:
+                beta = self.beta_scale * beta
             g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)
             o = la.gated_delta_rule(
                 la.l2_normalize(q), la.l2_normalize(k), v, g, beta,
@@ -274,9 +290,12 @@ class GatedDeltaNet(nn.Module):
 
 class GroupedAttention(nn.Module):
     """Causal softmax attention with grouped key/value heads and nothing
-    else: no positions (in ``granite_hybrid`` the state-space layers carry
-    the order), no norm on q or k, no gate, no biases; ``softmax(scale x q
-    k^T) v`` with a published ``scale`` that need not be ``head_dim ** -0.5``.
+    else: no positions (in ``granite_hybrid`` and ``olmo_hybrid`` the
+    recurrent layers carry the order), no gate, no biases; ``softmax(scale
+    x q k^T) v`` with a published ``scale`` that need not be ``head_dim **
+    -0.5``. With ``qk_norm_eps`` an RMS norm over the WHOLE q projection
+    and the whole k projection, before the split into heads (OLMo 2's
+    ``q_norm`` / ``k_norm``; scope ``qk_norm``); without, none.
     ``attention_fn(q, k, v)`` keeps its own ``head_dim ** -0.5``, so ``q``
     is multiplied by ``scale x head_dim ** 0.5`` before the call (0.125 in
     the published model: a power of two, exact in bfloat16). Each
@@ -289,6 +308,7 @@ class GroupedAttention(nn.Module):
     scale: Optional[float] = None       # None: head_dim ** -0.5
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
+    qk_norm_eps: Optional[float] = None     # None: no norm on q and k
 
     @nn.compact
     def __call__(self, x):
@@ -300,9 +320,16 @@ class GroupedAttention(nn.Module):
         attn_fn = self.attention_fn or full_attention
         with jax.named_scope("grouped_attention"):
             x = x.astype(dt)
-            q = _dense(H * d, dt, "attn_query")(x).reshape(B, L, H, d)
-            k = _dense(G * d, dt, "attn_key")(x).reshape(B, L, G, d)
-            v = _dense(G * d, dt, "attn_value")(x).reshape(B, L, G, d)
+
+            def heads_of(name, heads, norm=None):
+                y = _dense(heads * d, dt, name)(x)
+                if norm and self.qk_norm_eps is not None:
+                    with jax.named_scope("qk_norm"):
+                        y = RMSNorm(self.qk_norm_eps, name=norm)(y).astype(dt)
+                return y.reshape(B, L, heads, d)
+            q = heads_of("attn_query", H, "query_norm")
+            k = heads_of("attn_key", G, "key_norm")
+            v = heads_of("attn_value", G)
             if self.scale is not None:
                 q = q * jnp.asarray(self.scale * d ** 0.5, dt)
             k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
@@ -401,19 +428,38 @@ class PartsBlock(nn.Module):
     """``h = x + r attention(norm(x))``, ``y = h + r ffn(norm(h))``, ``r``
     = ``residual_scale`` (1 in most families). A feed-forward part may
     return ``(y, stats)``, ``stats`` a dict of scalars (a routed layer's
-    load); the block returns ``(y, stats)`` always."""
+    load); the block returns ``(y, stats)`` always. With ``norm_output``
+    the norms sit on each half's OUTPUT, OLMo 2's wiring: ``h = x + r
+    norm(attention(x))``, ``y = h + r norm(ffn(h))`` (scope
+    ``post_norm``). It is an argument of the block and not a wrapper
+    around each part, so that ``norm1`` and ``norm2`` stay the block's own
+    in the parameter tree and in the split of device time, as every
+    family's norms of the residual stream are."""
     norm: Callable[[str], nn.Module]
     attention: Callable[[str], nn.Module]
     ffn: Callable[[str], nn.Module]
     residual_scale: float = 1.0
+    norm_output: bool = False
 
     @nn.compact
     def __call__(self, x) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        h = _add(x, self.attention("attn")(self.norm("norm1")(x)),
-                 self.residual_scale)
-        out = self.ffn("ffn")(self.norm("norm2")(h))
+        h = _add(x, _half(self.norm("norm1"), self.attention("attn"), x,
+                          self.norm_output), self.residual_scale)
+        out = _half(self.norm("norm2"), self.ffn("ffn"), h, self.norm_output)
         y, stats = out if isinstance(out, tuple) else (out, {})
         return _add(h, y, self.residual_scale), stats
+
+
+def _half(norm, part, x, norm_output: bool):
+    """One half of a block before its residual addition: ``part(norm(x))``,
+    or with ``norm_output`` ``norm(part(x))``; a part's ``stats`` pass."""
+    if not norm_output:
+        return part(norm(x))
+    out = part(x)
+    y, rest = (out[0], out[1:]) if isinstance(out, tuple) else (out, ())
+    with jax.named_scope("post_norm"):
+        y = norm(y)
+    return (y,) + rest if rest else y
 
 
 def _add(x, y, scale: float):
@@ -425,25 +471,27 @@ def _add(x, y, scale: float):
 class SplitBlock(nn.Module):
     """``PartsBlock`` with its two halves as methods (``mix``: ``x +
     attention(norm(x))``; ``feed``: ``h + ffn(norm(h))``), so that each can
-    be a unit of recomputation of its own (``x + r attention(norm(x))``
-    with ``residual_scale``, as there): what the backward pass of the
-    feed-forward half keeps never lies beside what the mixer's keeps. The
+    be a unit of recomputation of its own (``residual_scale`` and
+    ``norm_output`` as there): what the backward pass of the feed-forward
+    half keeps never lies beside what the mixer's keeps. The
     parts are made in ``setup`` under ``PartsBlock``'s names (``norm1``,
     ``attn``, ``norm2``, ``ffn``); the factories are called with no name."""
     make_norm: Callable[[Optional[str]], nn.Module]
     make_attention: Callable[[Optional[str]], nn.Module]
     make_ffn: Callable[[Optional[str]], nn.Module]
     residual_scale: float = 1.0
+    norm_output: bool = False
 
     def setup(self):
         self.norm1, self.attn = self.make_norm(None), self.make_attention(None)
         self.norm2, self.ffn = self.make_norm(None), self.make_ffn(None)
 
     def mix(self, x):
-        return _add(x, self.attn(self.norm1(x)), self.residual_scale)
+        return _add(x, _half(self.norm1, self.attn, x, self.norm_output),
+                    self.residual_scale)
 
     def feed(self, h) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        out = self.ffn(self.norm2(h))
+        out = _half(self.norm2, self.ffn, h, self.norm_output)
         y, stats = out if isinstance(out, tuple) else (out, {})
         return _add(h, y, self.residual_scale), stats
 
@@ -452,11 +500,14 @@ class SplitBlock(nn.Module):
 
 
 def _remat_block(norm, attention, ffn, name: str, split: bool = False,
-                 residual_scale: float = 1.0) -> nn.Module:
+                 residual_scale: float = 1.0, norm_output: bool = False,
+                 let_go: Tuple[str, ...] = ()) -> nn.Module:
     """A ``PartsBlock`` recomputed in the backward pass, but for what is
     named here: ONE list for every family, because a name that no value of
     a block carries costs nothing (the names sit where the values are
-    made). In units of the block's input, bf16 (B, L, dim): the flash
+    made); ``let_go`` names what a family whose state leaves no room for
+    all of it does without (``OlmoHybrid``; its row is in that class's
+    docstring). In units of the block's input, bf16 (B, L, dim): the flash
     kernel's output and log-sum-exps, 2.5, without which its forward call
     runs twice a block; the SwiGLU gate and up products, 10 in
     ``glm4_moe_lite``'s dense block, 1.5 in a routed block's shared
@@ -479,22 +530,25 @@ def _remat_block(norm, attention, ffn, name: str, split: bool = False,
     dots_with_no_batch_dims_saveable (about 3 GB: no room beside
     AdamW's state). Attention that is not the flash kernel carries no
     such name and keeps what it kept before; a delta rule that runs XLA's
-    batched form (head widths under 128) names no tiles, and either
-    rule's walk keeps a state a chunk across ITS backward inside the
+    batched form (head widths ``pallas_delta_rule.supports`` refuses: the
+    tiny presets') names no tiles, and either rule's walk keeps a state a
+    chunk across ITS backward inside the
     recomputation, where no name reaches. ``split`` recomputes the
     block's two halves apart (``SplitBlock``) and keeps the residual
     stream between them: for a block whose halves' backward passes do not
-    fit side by side. ``residual_scale`` is the block's. (Imported here:
-    Pallas costs every importer of the zoo over a second.)"""
+    fit side by side. ``residual_scale`` and ``norm_output`` are the
+    block's. (Imported here: Pallas costs every importer of the zoo over a
+    second.)"""
     from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
     from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
-    policy = jax.checkpoint_policies.save_only_these_names(
-        FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES, DELTA_NET_QKVZ)
+    policy = jax.checkpoint_policies.save_only_these_names(*(
+        n for n in (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES,
+                    DELTA_NET_QKVZ) if n not in let_go))
     if split:
         return nn.remat(SplitBlock, policy=policy, methods=("mix", "feed"))(
-            norm, attention, ffn, residual_scale, name=name)
+            norm, attention, ffn, residual_scale, norm_output, name=name)
     return nn.remat(PartsBlock, policy=policy)(
-        norm, attention, ffn, residual_scale, name=name)
+        norm, attention, ffn, residual_scale, norm_output, name=name)
 
 
 class Head(nn.Module):
@@ -776,6 +830,89 @@ class GraniteHybrid(nn.Module):
         return {"hidden": h, "stats": {}}
 
 
+class OlmoHybrid(nn.Module):
+    """``olmo_hybrid`` (``model_type: olmo_hybrid``): ``h_0 = E[token]``;
+    layer ``l`` is ``h <- h + norm(mixer_l(h))``, ``h <- h + norm(mlp(h))``
+    (the norm on each half's OUTPUT, none on its input), ``mixer_l`` a
+    ``GatedDeltaNet`` with ``beta`` in (0, 2) where ``layer_types[l]`` is
+    ``"linear_attention"`` and a ``GroupedAttention`` with as many
+    key/value as query heads, an RMS norm over the whole q and the whole k
+    projection and no positions where it is ``"full_attention"``, the
+    feed-forward part a dense ``SwiGluMlp``; plain RMS norms, a final
+    norm, an untied head. ``__call__`` as ``Qwen3Next``'s (``stats`` is
+    empty: no routed layer).
+
+    Each block is recomputed in the backward pass in halves and keeps
+    ``_remat_block``'s names but ``LET_GO``: this family's 928.9M
+    parameters at the benchmark's cut are 11.15 GB of weights and moments
+    on a chip of 16.91, the least room of any family here. The step
+    compiled for a described v5e peaks at 17.19 GB with all four names
+    (refused), 16.41 without the delta net's input projection, 16.35
+    without the SwiGLU gate and up products (11,008 wide: 5.7 units of the
+    block's bf16 input a layer, the cheapest name a byte by PR 36's
+    readings), 15.74 with the flash kernel's residuals alone; on the chip
+    (PERF.md section 6, PR 38) the sub-list without the products ran
+    1.640 rows/s, the one without the projection 1.622, tiles and
+    residuals alone 1.564."""
+    vocab: int
+    dim: int
+    layer_types: Tuple[str, ...]
+    heads: int
+    head_dim: int
+    linear_key_heads: int
+    linear_value_heads: int
+    linear_key_dim: int
+    linear_value_dim: int
+    conv_width: int
+    mlp_hidden: int
+    eps: float = 1e-6
+    chunk: int = 64
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    # of ``_remat_block``'s names, those this family lets go
+    LET_GO = (MLP_GATE_UP,)
+
+    def _block(self, kind: str, name: str) -> nn.Module:
+        dt = self.dtype
+
+        def attention(n):
+            if kind == "full_attention":
+                return GroupedAttention(
+                    self.dim, self.heads, self.heads, self.head_dim, None, dt,
+                    self.attention_fn, self.eps, name=n)
+            return GatedDeltaNet(
+                self.dim, self.linear_key_heads, self.linear_value_heads,
+                self.linear_key_dim, self.linear_value_dim, self.conv_width,
+                self.eps, self.chunk, dt, beta_scale=2.0, name=n)
+
+        return _remat_block(
+            lambda n: RMSNorm(self.eps, name=n), attention,
+            lambda n: SwiGluMlp(self.dim, self.mlp_hidden, dt, name=n),
+            name, split=True, norm_output=True, let_go=self.LET_GO)
+
+    @nn.compact
+    def __call__(self, tokens, hidden: bool = False):
+        if not self.layer_types or set(self.layer_types) - {
+                "linear_attention", "full_attention"}:
+            raise ValueError(f"layer_types {self.layer_types!r}: "
+                             "'linear_attention' or 'full_attention' a layer")
+        embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype,
+                         embedding_init=_INIT, name="token_embedding")
+        head = Head(self.vocab, name="lm_head")
+        x = embed(tokens)
+        for i, kind in enumerate(self.layer_types):
+            x, _ = self._block(kind, f"block{i}")(x)
+        out = {"hidden": RMSNorm(self.eps, name="final_norm")(x)}
+        self.sow("intermediates", "hidden", out["hidden"])
+        if not hidden:
+            return head(out["hidden"])
+        if self.is_initializing():
+            head(out["hidden"][:, :1])
+        out["stats"] = {}
+        return out
+
+
 def _spec(module: nn.Module, max_len: int):
     return dict(
         module=module, input_shape=(max_len,), input_dtype="int32",
@@ -904,3 +1041,41 @@ def granite_hybrid_tiny(**overrides):
     tokens, multipliers that are not 1 and a softmax scale that is not
     ``head_dim ** -0.5``."""
     return granite_hybrid(**{**_GRANITE_TINY, **overrides})
+
+
+OLMO_HYBRID_7B_LAYERS = (("linear_attention",) * 3 + ("full_attention",)) * 8
+
+
+@register_model("olmo_hybrid")
+def olmo_hybrid(vocab: int = 100352, dim: int = 3840,
+                layer_types=OLMO_HYBRID_7B_LAYERS, heads: int = 30,
+                head_dim: int = 128, linear_key_heads: int = 30,
+                linear_value_heads: int = 30, linear_key_dim: int = 96,
+                linear_value_dim: int = 192, conv_width: int = 4,
+                mlp_hidden: int = 11008, eps: float = 1e-6, chunk: int = 64,
+                max_len: int = 8192, dtype=jnp.bfloat16, attention_fn=None):
+    """Olmo-Hybrid-7B as published (huggingface.co/allenai/Olmo-Hybrid-7B
+    ``config.json``, ``model_type: olmo_hybrid``): thirty-two layers, three
+    Gated DeltaNet layers (96 x 192 a head, ``linear_allow_neg_eigval``) to
+    one full-attention layer without positions, a dense SwiGLU part in
+    each, the norms on each half's output, an untied head. ``head_dim`` =
+    ``hidden_size / num_attention_heads`` (the config has no key for it)."""
+    return _spec(OlmoHybrid(
+        vocab, dim, tuple(layer_types), heads, head_dim, linear_key_heads,
+        linear_value_heads, linear_key_dim, linear_value_dim, conv_width,
+        mlp_hidden, eps, chunk, dtype, attention_fn), max_len)
+
+
+_OLMO_TINY = dict(vocab=96, dim=32,
+                  layer_types=("linear_attention",) * 3 + ("full_attention",),
+                  heads=4, head_dim=8, linear_key_heads=4,
+                  linear_value_heads=4, linear_key_dim=8, linear_value_dim=16,
+                  mlp_hidden=48, chunk=8, max_len=64, dtype=jnp.float32)
+
+
+@register_model("olmo_hybrid_tiny")
+def olmo_hybrid_tiny(**overrides):
+    """Test-scale ``olmo_hybrid`` (float32, so CPU parity is tight): one
+    period of the layer pattern, head widths in the published 1 : 2 ratio,
+    chunks of 8 tokens."""
+    return olmo_hybrid(**{**_OLMO_TINY, **overrides})

@@ -31,14 +31,19 @@ mathematics:
   backward pass is that scan's transpose: the state at each chunk's start
   is what it keeps (``(N, B, H, dk, dv)`` float32), never a state a token.
   The batched half has two executors, picked from the call's shapes as
-  ``full_attention`` picks its kernel: where ``chunk`` is 64, both head
-  widths are multiples of 128 and the value heads are whole groups a key
-  head (``pallas_delta_rule.supports``), the Pallas calls of
+  ``full_attention`` picks its kernel: where ``chunk`` is 64 and either
+  both head widths are multiples of 128 and the value heads are whole
+  groups a key head, or there are as many key as value heads and four
+  heads or fewer side by side fill whole lanes (96 x 192: 384 and 768)
+  (``pallas_delta_rule.supports``), the Pallas calls of
   ``ops/pallas_delta_rule.py`` make a chunk's tiles in VMEM from q, k, v
   as ``(B, L, H * d)`` rows (q and k at KEY-head width: a value head
-  reads key head ``h // (Hv / Hk)`` through the block's index map) and
-  hand the walk ``W``, ``Kd`` in ``dtype`` and ``U_0`` in float32, chunk-
-  major; after the walk a third call writes ``O`` as rows. Otherwise
+  reads key head ``h // (Hv / Hk)`` through the block's index map; heads
+  that fill no whole lanes go to a program four at a time and it slices
+  each one's lanes in VMEM) and hand the walk ``W``, ``Kd`` in ``dtype``
+  and ``U_0`` in float32, chunk-major, at the heads' own widths (no
+  parameter and no state is padded); after the walk a third call writes
+  ``O`` as rows. Otherwise
   (``_chunked``) XLA's batched products over float32 head-major copies,
   q and k repeated to the value heads first: the form every test holds
   the calls to, and what other shapes run. The walk is the same scan.

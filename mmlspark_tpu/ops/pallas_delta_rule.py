@@ -10,10 +10,18 @@ read, laid out chunk-major as the scan wants them. A program works on
 TWO chunks side by side, 128 tokens: every ``chunk x chunk`` tile is then
 a 128 x 128 tile that is block-diagonal under the mask ``same chunk``,
 which fills the lanes and the MXU's width where a 64 x 64 tile fills
-half of each; nothing couples the two chunks.
+half of each; nothing couples the two chunks. A program has ONE value head
+where both head widths are multiples of 128, and ``heads_per_program``
+heads where a head's lanes end inside a lane tile (96 x 192: four, 384
+key lanes and 768 value lanes): its blocks of rows are that many heads
+wide, each head's lanes are sliced in VMEM (a load or a store may start
+inside a tile where a block or a view of one may not), its other blocks
+have the heads on an axis of their own, and the last program of a head
+count that is no multiple (30 = 7 x 4 + 2) skips the heads past the end.
+Everything in HBM keeps the heads' own widths.
 
-- ``delta_chunk_fwd`` (grid ``(B, blocks of up to 8 pairs, value
-  heads)``): ``G``, ``D``, ``K K^T``, ``Q K^T``, ``A``, ``T = (I +
+- ``delta_chunk_fwd`` (grid ``(B, blocks of up to 8 pairs, programs of
+  value heads)``): ``G``, ``D``, ``K K^T``, ``Q K^T``, ``A``, ``T = (I +
   A)^-1`` stay in VMEM; out go ``W = T (beta e^G K)``, ``Kd = e^(G_C - G)
   K``, ``qe = e^G Q`` and ``P = (Q K^T) * D`` in the products' operand
   type (each is only ever read as such an operand) and ``U_0 = T (beta
@@ -51,6 +59,7 @@ module a shape. On the CPU they run interpreted.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Tuple
 
 import jax
@@ -69,6 +78,8 @@ PAIRS_PER_PROGRAM = 8
 # pairs a turn of a program's loop: two independent chains of products
 # for the scheduler to interleave (the inverse is ten dependent products)
 _UNROLL = 2
+# heads a program at most, where one head's lanes end inside a lane tile
+_MAX_HEADS = 4
 _NEUMANN_ROWS = 16
 _HIGHEST = jax.lax.Precision.HIGHEST
 _FWD_NAME = "delta_chunk_fwd"
@@ -83,12 +94,23 @@ _f32 = jnp.float32
 _VMEM_LIMIT = 64 << 20
 
 
+def heads_per_program(dk: int, dv: int) -> int:
+    """The fewest heads side by side whose key lanes and whose value lanes
+    both end on a lane tile: 1 where both widths are multiples of 128, 4
+    at 96 x 192 (384 and 768 lanes)."""
+    return math.lcm(LANES // math.gcd(dk, LANES), LANES // math.gcd(dv, LANES))
+
+
 def supports(chunk: int, key_heads: int, value_heads: int, dk: int,
              dv: int) -> bool:
-    """Shapes the calls take: the chunk they are written for, head widths
-    that fill the lanes, whole groups of value heads a key head."""
-    return (chunk == CHUNK and dk % LANES == 0 and dv % LANES == 0
-            and value_heads % key_heads == 0)
+    """Shapes the calls take: the chunk they are written for and either
+    head widths that fill the lanes, with whole groups of value heads a
+    key head, or as many key heads as value heads of widths that fill the
+    lanes ``_MAX_HEADS`` heads or fewer at a time (multiples of 32)."""
+    per = heads_per_program(dk, dv)
+    return (chunk == CHUNK and value_heads % key_heads == 0
+            and (per == 1 or (per <= _MAX_HEADS
+                              and key_heads == value_heads)))
 
 
 def _pairs_per_program(pairs: int) -> int:
@@ -200,37 +222,74 @@ def _pair_rows(p: Any):
     return pl.ds(pl.multiple_of(p * PAIR, PAIR), PAIR)
 
 
-def _chunks_of(ref, p):
-    """The pair's two chunks of a chunk-major block, one under the other."""
-    return jnp.concatenate([ref[2 * p], ref[2 * p + 1]], axis=0)
+def _chunks_of(ref, p, at=()):
+    """The pair's two chunks of a chunk-major block, one under the other
+    (``at``: ``_head_of`` the head, here and below)."""
+    return jnp.concatenate(
+        [ref[(2 * p,) + at], ref[(2 * p + 1,) + at]], axis=0)
 
 
-def _to_chunks(ref, p, tile):
-    ref[2 * p] = tile[:CHUNK].astype(ref.dtype)
-    ref[2 * p + 1] = tile[CHUNK:].astype(ref.dtype)
+def _to_chunks(ref, p, tile, at=()):
+    ref[(2 * p,) + at] = tile[:CHUNK].astype(ref.dtype)
+    ref[(2 * p + 1,) + at] = tile[CHUNK:].astype(ref.dtype)
+
+
+def _lanes_of(ref, j: int, heads: int):
+    """Head ``j``'s lanes of a block of rows that is ``heads`` heads wide
+    (all of them where a program has one head): an index, because no view
+    of a block may start inside a lane tile."""
+    if heads == 1:
+        return slice(None)
+    width = ref.shape[-1] // heads
+    return pl.ds(j * width, width)
+
+
+def _head_of(j: int, heads: int) -> tuple:
+    """Head ``j``'s index on the heads' axis of a block (no such axis
+    where a program has one head)."""
+    return () if heads == 1 else (j,)
+
+
+def _each_head(heads: int, total: int, body):
+    """``body(j)`` for each head of the program; the last program of a
+    ragged grid (``total`` heads are no whole programs) skips the heads
+    past the end, whose blocks lie outside the arrays."""
+    for j in range(heads):
+        if total % heads and j >= total % heads:
+            pl.when(pl.program_id(2) * heads + j < total)(
+                functools.partial(body, j))
+        else:
+            body(j)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
-                w_ref, u0_ref, kd_ref, qe_ref, p_ref, *, dtype):
+                w_ref, u0_ref, kd_ref, qe_ref, p_ref, *, dtype,
+                heads: int = 1, total: int = 1):
     m = _masks(PAIR)
 
-    def pair(p):
-        rows, one = _pair_rows(p), pl.ds(p, 1)
-        t = _tiles(q_ref[rows, :], k_ref[rows, :], v_ref[rows, :],
-                   g_ref[one, :], beta_ref[one, :], dtype, m)
-        _to_chunks(w_ref, p, _dot(t["Tc"], t["kb"]))
-        _to_chunks(u0_ref, p, _dot(t["Tc"], t["vb"]))
-        _to_chunks(kd_ref, p, t["k"] * t["E"])
-        _to_chunks(qe_ref, p, t["q"] * t["eG"])
-        p_ref[p] = (t["qk"] * t["D"]).astype(p_ref.dtype)
+    def head(j):
+        kl, vl = (_lanes_of(r, j, heads) for r in (k_ref, v_ref))
+        at = _head_of(j, heads)
 
-    _pairs_loop(p_ref.shape[0], pair)
+        def pair(p):
+            rows, one = _pair_rows(p), at + (pl.ds(p, 1), slice(None))
+            t = _tiles(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
+                       g_ref[one], beta_ref[one], dtype, m)
+            _to_chunks(w_ref, p, _dot(t["Tc"], t["kb"]), at)
+            _to_chunks(u0_ref, p, _dot(t["Tc"], t["vb"]), at)
+            _to_chunks(kd_ref, p, t["k"] * t["E"], at)
+            _to_chunks(qe_ref, p, t["q"] * t["eG"], at)
+            p_ref[(p,) + at] = (t["qk"] * t["D"]).astype(p_ref.dtype)
+
+        _pairs_loop(p_ref.shape[0], pair)
+
+    _each_head(heads, total, head)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
                 dw_ref, du0_ref, dkd_ref, dqe_ref, dp_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, *, dtype,
-                group: int):
+                group: int, heads: int = 1, total: int = 1):
     # the value heads of one key head follow each other on the grid's last
     # axis: dq and dk of the key head are summed in the resident block
     @pl.when(pl.program_id(2) % group == 0)
@@ -240,104 +299,133 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref,
 
     m = _masks(PAIR)
 
-    def pair(p):
-        rows, one = _pair_rows(p), pl.ds(p, 1)
-        t = _tiles(q_ref[rows, :], k_ref[rows, :], v_ref[rows, :],
-                   g_ref[one, :], beta_ref[one, :], dtype, m)
-        q, k, v, qc, kc = t["q"], t["k"], t["v"], t["qc"], t["kc"]
-        beta, D, eG, E, T, Tc = (t["beta"], t["D"], t["eG"], t["E"],
-                                 t["T"], t["Tc"])
-        dW = _chunks_of(dw_ref, p).astype(dtype)
-        dU0 = _chunks_of(du0_ref, p).astype(dtype)
-        dKd = _chunks_of(dkd_ref, p).astype(_f32)
-        dqe = _chunks_of(dqe_ref, p).astype(_f32)
-        dP = dp_ref[p].astype(_f32)
+    def head(j):
+        kl, vl = (_lanes_of(r, j, heads) for r in (k_ref, v_ref))
+        at = _head_of(j, heads)
 
-        # W = T kb, U0 = T vb; A -> T = (I + A)^-1. What dT and dP hold
-        # outside the chunks' diagonal blocks meets a mask or D = 0
-        dT = _dot(dW, t["kb"], _NT) + _dot(dU0, t["vb"], _NT)
-        dkb = _dot(Tc, dW, _TN)
-        dvb = _dot(Tc, dU0, _TN)
-        dA = jnp.where(m["below"],
-                       -_dot32(_dot32(T, dT, _TN), T, _NT), 0.0)
-        # A = beta_i D_ij kk_ij; P = qk * D
-        dAD, dPD = dA * D, dP * D
-        dAk = dAD * t["kk"]
-        dbeta = jnp.sum(dAk, axis=1, keepdims=True)
-        dkk, dqk = (dAD * beta).astype(dtype), dPD.astype(dtype)
-        dDD = dAk * beta + dPD * t["qk"]
-        dG = jnp.sum(dDD, axis=1, keepdims=True) - _col(
-            jnp.sum(dDD, axis=0, keepdims=True), m["eye"])
+        def pair(p):
+            rows, one = _pair_rows(p), at + (pl.ds(p, 1), slice(None))
+            t = _tiles(q_ref[rows, kl], k_ref[rows, kl], v_ref[rows, vl],
+                       g_ref[one], beta_ref[one], dtype, m)
+            q, k, v, qc, kc = t["q"], t["k"], t["v"], t["qc"], t["kc"]
+            beta, D, eG, E, T, Tc = (t["beta"], t["D"], t["eG"], t["E"],
+                                     t["T"], t["Tc"])
+            dW = _chunks_of(dw_ref, p, at).astype(dtype)
+            dU0 = _chunks_of(du0_ref, p, at).astype(dtype)
+            dKd = _chunks_of(dkd_ref, p, at).astype(_f32)
+            dqe = _chunks_of(dqe_ref, p, at).astype(_f32)
+            dP = dp_ref[(p,) + at].astype(_f32)
 
-        scale = beta * eG                       # kb = k * (beta e^G)
-        along = jnp.sum(dkb * k, axis=1, keepdims=True)
-        dbeta += along * eG + jnp.sum(dvb * v, axis=1, keepdims=True)
-        last = jnp.sum(dKd * k, axis=1, keepdims=True) * E  # Kd = k E
-        dG += along * scale - last \
-            + jnp.sum(dqe * q, axis=1, keepdims=True) * eG
+            # W = T kb, U0 = T vb; A -> T = (I + A)^-1. What dT and dP
+            # hold outside the chunks' diagonal blocks meets a mask or D
+            # = 0
+            dT = _dot(dW, t["kb"], _NT) + _dot(dU0, t["vb"], _NT)
+            dkb = _dot(Tc, dW, _TN)
+            dvb = _dot(Tc, dU0, _TN)
+            dA = jnp.where(m["below"],
+                           -_dot32(_dot32(T, dT, _TN), T, _NT), 0.0)
+            # A = beta_i D_ij kk_ij; P = qk * D
+            dAD, dPD = dA * D, dP * D
+            dAk = dAD * t["kk"]
+            dbeta = jnp.sum(dAk, axis=1, keepdims=True)
+            dkk, dqk = (dAD * beta).astype(dtype), dPD.astype(dtype)
+            dDD = dAk * beta + dPD * t["qk"]
+            dG = jnp.sum(dDD, axis=1, keepdims=True) - _col(
+                jnp.sum(dDD, axis=0, keepdims=True), m["eye"])
 
-        dq_ref[rows, :] += _dot(dqk, kc) + dqe * eG
-        dk_ref[rows, :] += _dot(dqk, qc, _TN) + _dot(dkk, kc) \
-            + _dot(dkk, kc, _TN) + dkb * scale + dKd * E
-        dv_ref[rows, :] = (dvb * beta).astype(dv_ref.dtype)
-        # G = cumsum(g) a chunk: dg_j = sum over i >= j of dG_i; and E's
-        # G_C is the chunk's last row, which is past every j of the chunk
-        dg_ref[one, :] = jnp.sum(
-            jnp.where(m["seen"], dG, 0.0) + jnp.where(m["same"], last, 0.0),
-            axis=0, keepdims=True)
-        dbeta_ref[one, :] = _row(dbeta, m["eye"])
+            scale = beta * eG                       # kb = k * (beta e^G)
+            along = jnp.sum(dkb * k, axis=1, keepdims=True)
+            dbeta += along * eG + jnp.sum(dvb * v, axis=1, keepdims=True)
+            last = jnp.sum(dKd * k, axis=1, keepdims=True) * E  # Kd = k E
+            dG += along * scale - last \
+                + jnp.sum(dqe * q, axis=1, keepdims=True) * eG
 
-    _pairs_loop(dp_ref.shape[0], pair)
+            dq_ref[rows, kl] += _dot(dqk, kc) + dqe * eG
+            dk_ref[rows, kl] += _dot(dqk, qc, _TN) + _dot(dkk, kc) \
+                + _dot(dkk, kc, _TN) + dkb * scale + dKd * E
+            dv_ref[rows, vl] = (dvb * beta).astype(dv_ref.dtype)
+            # G = cumsum(g) a chunk: dg_j = sum over i >= j of dG_i; and
+            # E's G_C is the chunk's last row, which is past every j of
+            # the chunk
+            dg_ref[one] = jnp.sum(
+                jnp.where(m["seen"], dG, 0.0)
+                + jnp.where(m["same"], last, 0.0), axis=0, keepdims=True)
+            dbeta_ref[one] = _row(dbeta, m["eye"])
+
+        _pairs_loop(dp_ref.shape[0], pair)
+
+    _each_head(heads, total, head)
 
 
-def _out_kernel(qe_ref, p_ref, s0_ref, u_ref, o_ref, *, dtype):
-    def pair(p):
-        o_ref[_pair_rows(p), :] = jnp.concatenate(
-            [_dot(qe_ref[c], s0_ref[c].astype(dtype))
-             for c in (2 * p, 2 * p + 1)], axis=0) \
-            + _dot(p_ref[p], _chunks_of(u_ref, p).astype(dtype))
+def _out_kernel(qe_ref, p_ref, s0_ref, u_ref, o_ref, *, dtype,
+                heads: int = 1, total: int = 1):
+    def head(j):
+        at, lanes = _head_of(j, heads), _lanes_of(o_ref, j, heads)
 
-    _pairs_loop(p_ref.shape[0], pair)
+        def pair(p):
+            o_ref[_pair_rows(p), lanes] = jnp.concatenate(
+                [_dot(qe_ref[(c,) + at], s0_ref[(c,) + at].astype(dtype))
+                 for c in (2 * p, 2 * p + 1)], axis=0) \
+                + _dot(p_ref[(p,) + at],
+                       _chunks_of(u_ref, p, at).astype(dtype))
+
+        _pairs_loop(p_ref.shape[0], pair)
+
+    _each_head(heads, total, head)
 
 
 def _out_bwd_kernel(qe_ref, p_ref, s0_ref, u_ref, do_ref,
-                    dqe_ref, dp_ref, ds0_ref, du_ref, *, dtype):
-    def pair(p):
-        do = do_ref[_pair_rows(p), :].astype(dtype)
-        for c, rows in ((2 * p, do[:CHUNK]), (2 * p + 1, do[CHUNK:])):
-            dqe_ref[c] = _dot(rows, s0_ref[c].astype(dtype), _NT).astype(
-                dqe_ref.dtype)
-            ds0_ref[c] = _dot(qe_ref[c], rows, _TN)
-        dp_ref[p] = _dot(do, _chunks_of(u_ref, p).astype(dtype),
-                         _NT).astype(dp_ref.dtype)
-        _to_chunks(du_ref, p, _dot(p_ref[p], do, _TN))
+                    dqe_ref, dp_ref, ds0_ref, du_ref, *, dtype,
+                    heads: int = 1, total: int = 1):
+    def head(j):
+        at, lanes = _head_of(j, heads), _lanes_of(do_ref, j, heads)
 
-    _pairs_loop(p_ref.shape[0], pair)
+        def pair(p):
+            do = do_ref[_pair_rows(p), lanes].astype(dtype)
+            for c, rows in ((2 * p, do[:CHUNK]), (2 * p + 1, do[CHUNK:])):
+                c = (c,) + at
+                dqe_ref[c] = _dot(rows, s0_ref[c].astype(dtype),
+                                  _NT).astype(dqe_ref.dtype)
+                ds0_ref[c] = _dot(qe_ref[c], rows, _TN)
+            dp_ref[(p,) + at] = _dot(
+                do, _chunks_of(u_ref, p, at).astype(dtype),
+                _NT).astype(dp_ref.dtype)
+            _to_chunks(du_ref, p, _dot(p_ref[(p,) + at], do, _TN), at)
+
+        _pairs_loop(p_ref.shape[0], pair)
+
+    _each_head(heads, total, head)
 
 
-def _specs(B: int, N: int, Hk: int, Hv: int):
-    """The grid (row, block of pairs, value head) and its block specs: q,
-    k, v rows (B, L, H * width), q and k at the head's key head; g and
-    beta (B, Hv, N / 2, 128); the chunk-major arrays (N, B, Hv, 64,
-    width); the pair-major ``P`` (N / 2, B, Hv, 128, 128) and the states
-    (N, B, Hv, dk, dv)."""
+def _specs(B: int, N: int, Hk: int, Hv: int, heads: int = 1):
+    """The grid (row, block of pairs, program of ``heads`` value heads)
+    and its block specs: q, k, v rows (B, L, H * width), q and k at the
+    head's key head; g and beta (B, Hv, N / 2, 128); the chunk-major
+    arrays (N, B, Hv, 64, width); the pair-major ``P`` (N / 2, B, Hv,
+    128, 128) and the states (N, B, Hv, dk, dv). With ``heads`` > 1 (``Hk
+    = Hv`` then) a block holds the program's heads: side by side in the
+    rows' lanes, on an axis of their own elsewhere; the last program's
+    may reach past the arrays."""
     group, per = Hv // Hk, _pairs_per_program(N // 2)
+    on_axis = None if heads == 1 else heads
 
     def rows(width, key_head=False):
         return pl.BlockSpec(
-            (None, per * PAIR, width),
+            (None, per * PAIR, heads * width),
             (lambda b, i, h: (b, i, h // group)) if key_head
             else (lambda b, i, h: (b, i, h)))
 
     def major(count, *tile):
-        return pl.BlockSpec((count, None, None) + tile,
+        return pl.BlockSpec((count, None, on_axis) + tile,
                             lambda b, i, h: (i, b, h, 0, 0))
-    scalars = pl.BlockSpec((None, None, per, PAIR),
+    scalars = pl.BlockSpec((None, on_axis, per, PAIR),
                            lambda b, i, h: (b, h, i, 0))
-    return dict(grid=(B, N // 2 // per, Hv), rows=rows, scalars=scalars,
+    return dict(grid=(B, N // 2 // per, -(-Hv // heads)), rows=rows,
+                scalars=scalars,
                 chunks=lambda width: major(2 * per, CHUNK, width),
                 pairs=major(per, PAIR, PAIR),
-                states=lambda dk, dv: major(2 * per, dk, dv))
+                states=lambda dk, dv: major(2 * per, dk, dv),
+                program=dict(heads=heads, total=Hv))
 
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, last_axis,
@@ -364,12 +452,13 @@ def _tile_specs(s, dk, dv):
 def _forward(q, k, v, g, beta, dims, dtype):
     Hk, Hv, dk, dv = dims
     B, N = q.shape[0], q.shape[1] // CHUNK
-    s = _specs(B, N, Hk, Hv)
+    s = _specs(B, N, Hk, Hv, heads_per_program(dk, dv))
 
     def chunks(width, dt):
         return jax.ShapeDtypeStruct((N, B, Hv, CHUNK, width), dt)
     return _call(
-        functools.partial(_fwd_kernel, dtype=dtype), _FWD_NAME, s["grid"],
+        functools.partial(_fwd_kernel, dtype=dtype, **s["program"]),
+        _FWD_NAME, s["grid"],
         [s["rows"](dk, True), s["rows"](dk, True), s["rows"](dv),
          s["scalars"], s["scalars"]], _tile_specs(s, dk, dv),
         [chunks(dk, dtype), chunks(dv, _f32), chunks(dk, dtype),
@@ -381,12 +470,14 @@ def _forward(q, k, v, g, beta, dims, dtype):
 @functools.partial(jax.jit, static_argnames=("dims", "dtype"))
 def _backward(q, k, v, g, beta, cts, dims, dtype):
     Hk, Hv, dk, dv = dims
-    s = _specs(q.shape[0], q.shape[1] // CHUNK, Hk, Hv)
+    s = _specs(q.shape[0], q.shape[1] // CHUNK, Hk, Hv,
+               heads_per_program(dk, dv))
     ins = [s["rows"](dk, True), s["rows"](dk, True), s["rows"](dv),
            s["scalars"], s["scalars"]]
     return _call(
-        functools.partial(_bwd_kernel, dtype=dtype, group=Hv // Hk),
-        _BWD_NAME, s["grid"], ins + _tile_specs(s, dk, dv), ins,
+        functools.partial(_bwd_kernel, dtype=dtype, group=Hv // Hk,
+                          **s["program"]), _BWD_NAME, s["grid"],
+        ins + _tile_specs(s, dk, dv), ins,
         [jax.ShapeDtypeStruct(x.shape, x.dtype)
          for x in (q, k, v, g, beta)], "arbitrary", q, k, v, g, beta, *cts)
 
@@ -427,7 +518,7 @@ delta_chunk.defvjp(_fwd_rule, _bwd_rule)
 def _out_specs(qe, u):
     N, B, Hv, _, dk = qe.shape
     dv = u.shape[-1]
-    s = _specs(B, N, Hv, Hv)
+    s = _specs(B, N, Hv, Hv, heads_per_program(dk, dv))
     return s, [s["chunks"](dk), s["pairs"], s["states"](dk, dv),
                s["chunks"](dv)], s["rows"](dv)
 
@@ -437,7 +528,8 @@ def _out_forward(qe, p, s0, u, dtype):
     N, B, Hv = qe.shape[:3]
     s, ins, rows = _out_specs(qe, u)
     return _call(
-        functools.partial(_out_kernel, dtype=dtype), _OUT_NAME, s["grid"],
+        functools.partial(_out_kernel, dtype=dtype, **s["program"]),
+        _OUT_NAME, s["grid"],
         ins, rows, jax.ShapeDtypeStruct(
             (B, N * CHUNK, Hv * u.shape[-1]), _f32), "parallel",
         qe, p, s0, u)
@@ -447,8 +539,8 @@ def _out_forward(qe, p, s0, u, dtype):
 def _out_backward(qe, p, s0, u, do, dtype):
     s, ins, rows = _out_specs(qe, u)
     return _call(
-        functools.partial(_out_bwd_kernel, dtype=dtype), _OUT_BWD_NAME,
-        s["grid"], ins + [rows], ins,
+        functools.partial(_out_bwd_kernel, dtype=dtype, **s["program"]),
+        _OUT_BWD_NAME, s["grid"], ins + [rows], ins,
         [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (qe, p, s0, u)],
         "parallel", qe, p, s0, u, do)
 

@@ -36,7 +36,7 @@ TOY = chip_smoke.Sizes(
     flash_bf16=(1, 512, 2, 16), flash_bf16_d256=(2, 512, 2, 32),
     flash_fp32=(1, 512, 1, 16),
     short_bf16=(2, 37, 3, 16), gated_delta=(2, 40, 3, 16),
-    gated_delta_chunk=16)
+    gated_delta_chunk=16, gated_delta_wide=(1, 40, 3, 8, 16))
 
 
 @pytest.fixture(autouse=True)
@@ -107,11 +107,14 @@ def test_every_leg_runs_at_toy_size_on_the_cpu(rehearsal):
     assert k["mosaic_lowering_proven"] is False     # interpreted here
     assert {"flash_fwd_bf16", "flash_fwd_fp32", "flash_bwd_bf16",
             "flash_bwd_bf16_d256", "gated_delta_bf16",
-            "gated_delta_bf16_key_heads", "short_fwd_bwd_bf16",
+            "gated_delta_bf16_key_heads", "gated_delta_bf16_wide",
+            "short_fwd_bwd_bf16",
             "flash_fwd_bf16_sharded_x8"} <= set(k["kernels"])
     # head width 16, chunk 16: not the Pallas calls' shape
     assert k["kernels"]["gated_delta_bf16"]["path"] == "xla"
     assert k["kernels"]["gated_delta_bf16_key_heads"]["key_heads"] == 1
+    assert k["kernels"]["gated_delta_bf16_wide"]["path"] == "xla"
+    assert k["kernels"]["gated_delta_bf16_wide"]["shape"] == [1, 40, 3, 16]
 
 
 def test_smoke_writes_only_where_the_environment_placed_the_cache(rehearsal):

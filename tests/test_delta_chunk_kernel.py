@@ -36,19 +36,26 @@ from mmlspark_tpu.ops import pallas_delta_rule as pdr  # noqa: E402
 from tests.test_glm4_moe_lite import _REMAT, _pallas_calls  # noqa: E402
 
 NAMES = "o q k v g beta".split()
+# a state that fills no whole lanes (Olmo-Hybrid's 96 x 192, ``beta`` up to
+# 2): four heads a program, side by side in the rows' lanes; six heads are
+# a program and a half, so the second program's last two heads lie outside
+# the arrays
+WIDE = dict(Hk=6, Hv=6, d=96, dv=192, beta_scale=2.0)
 
 
-def _inputs(L, decay, Hk=1, Hv=2, B=1, d=128, seed=0):
+def _inputs(L, decay, Hk=1, Hv=2, B=1, d=128, seed=0, dv=None,
+            beta_scale=1.0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     q = la.l2_normalize(jax.random.normal(ks[0], (B, L, Hk, d)))
     k = la.l2_normalize(jax.random.normal(ks[1], (B, L, Hk, d)))
-    v = jax.random.normal(ks[2], (B, L, Hv, d))
+    v = jax.random.normal(ks[2], (B, L, Hv, dv or d))
     noise = jax.random.normal(ks[3], (B, L, Hv))
     if decay == "init":         # -A softplus(dt_bias + .), A up to 16
         g = -jnp.linspace(1e-3, 16.0, Hv) * jax.nn.softplus(1.0 + noise)
     else:                       # at least -20 a token: 0 / 0 as a quotient
         g = -20.0 - 20.0 * jax.nn.softplus(noise)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, L, Hv)))
+    beta = beta_scale * jax.nn.sigmoid(
+        jax.random.normal(ks[4], (B, L, Hv)))
     return (q, k, v, g, beta), jax.random.normal(ks[5], v.shape)
 
 
@@ -78,12 +85,13 @@ def xla_form(monkeypatch):
 @pytest.mark.parametrize("decay", ["init", "strong"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("heads", [(2, 2), (1, 2)],
-                         ids=["one_to_one", "grouped"])
+@pytest.mark.parametrize("heads", [(2, 2), (1, 2), WIDE],
+                         ids=["one_to_one", "grouped", "six_of_96x192"])
 @pytest.mark.parametrize("length", [128, 150], ids=["whole", "ragged"])
 def test_pallas_path_is_the_xla_form_and_the_token_by_token_rule(
         length, heads, dtype, decay, xla_form):
-    args, w = _inputs(length, decay, *heads)
+    args, w = _inputs(length, decay, **heads) if isinstance(heads, dict) \
+        else _inputs(length, decay, *heads)
     before = _chunk_calls()
     got = _run(args, w, "chunked", dtype)
     mid = _chunk_calls()
@@ -110,9 +118,11 @@ def test_pallas_path_is_the_xla_form_and_the_token_by_token_rule(
             assert float(jnp.linalg.norm(a - x)) <= tol, name
 
 
-def test_more_chunks_than_one_program_holds_and_two_rows():
+@pytest.mark.parametrize("heads", [dict(Hk=1, Hv=2), WIDE],
+                         ids=["grouped", "six_of_96x192"])
+def test_more_chunks_than_one_program_holds_and_two_rows(heads):
     """Nine chunks: two programs of eight, the second mostly padding."""
-    args, w = _inputs(64 * 9, "init", 1, 2, B=2)
+    args, w = _inputs(64 * 9, "init", B=2, **heads)
     got, want = _run(args, w, "chunked"), _run(args, w, "recurrent")
     for name, a, b in zip(NAMES, got, want):
         np.testing.assert_allclose(
@@ -142,6 +152,24 @@ def test_the_kernels_inverse_is_inv_unit_lower(kind):
     np.testing.assert_allclose(
         got, np.linalg.inv(np.eye(n) + np.asarray(a, np.float64)),
         atol=(1e-5 if kind == "random" else 2e-3) * scale)
+
+
+def test_the_shapes_the_calls_take():
+    """Whole lanes a head (any grouping of value heads under key heads),
+    or as many key as value heads whose lanes end on a tile four heads or
+    fewer at a time; nothing else, and no other chunk."""
+    assert pdr.heads_per_program(128, 128) == pdr.heads_per_program(
+        128, 256) == 1
+    assert pdr.heads_per_program(96, 192) == 4
+    assert pdr.heads_per_program(64, 64) == 2
+    assert pdr.supports(64, 16, 32, 128, 128)
+    assert pdr.supports(64, 30, 30, 96, 192)
+    assert pdr.supports(64, 6, 6, 96, 192)
+    assert not pdr.supports(64, 15, 30, 96, 192)    # grouped, ragged lanes
+    assert not pdr.supports(64, 30, 30, 80, 160)    # 8 heads to a tile
+    assert not pdr.supports(64, 4, 4, 8, 16)        # the tiny presets
+    assert not pdr.supports(32, 30, 30, 96, 192)
+    assert not pdr.supports(64, 3, 4, 128, 128)
 
 
 @pytest.mark.parametrize("case,d,chunk", [
