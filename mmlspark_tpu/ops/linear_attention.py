@@ -61,15 +61,15 @@ state ``S`` of ``(N, P)``::
 = dt_t A`` and ``G``, ``D`` as above, ``O = exp(G) (C S_0) + ((C B^T) * D)
 (dt x)`` and ``S_C = exp(G_C) S_0 + B^T (exp(G_C - G) dt x)``. With no
 correction a chunk's addend to the state does not depend on the state, so
-it too is a batched product over all chunks (per GROUP: ``B^T`` against
-the group's heads side by side), as are ``C B^T`` (per group) and ``C
-S_0``; the decays go on the ``P``-wide side, so that neither ``B`` nor
-``C`` is ever repeated to the heads. The walk from chunk to chunk is ONE
-``lax.scan`` carrying the float32 state as ``(rows, G, (H / G) P, N)`` (a
-head's state transposed, a group's heads one under the other, ``N`` in
-the lanes), a multiply and an add a step (``S <- exp(G_C) S + Z_c``),
-under the scope ``ssd_scan``; it hands out the state each chunk starts
-from, which is also what its transpose needs.
+it too is computed for all chunks at once, as are ``C B^T`` (per group) and
+``C S_0``; neither ``B`` nor ``C`` is ever repeated to the heads. The walk
+is ONE ``lax.scan`` (``_ssd_walk``, scope ``ssd_scan``) carrying the float32
+state as ``(rows, G, (H / G) P, N)`` (a head's state transposed, a group's
+heads one under the other, ``N`` in the lanes), a multiply and an add a
+step; it hands out the state each chunk starts from. The chunk-local half
+has two executors picked from the call's shapes (``pallas_ssd.supports``:
+chunk 256, ``N`` a multiple of 128, sixteen heads of a group filling whole
+lanes): the Pallas calls of ``ops/pallas_ssd.py``, else ``_ssd_chunked``.
 
 Every decay is the exponential of a difference that is not positive
 (``G_i - G_j`` under the causal mask, ``G_i``, ``G_C - G_j``); none is a
@@ -85,10 +85,10 @@ The other products take ``dtype`` operands (bfloat16 on the chip) and
 accumulate in float32; state, decay and sums are float32.
 
 Counters, per TRACE: ``linear_attention.calls.<chunked|recurrent>``,
-``linear_attention.rule_calls.<delta|ssd>``,
-``linear_attention.chunk_calls.<pallas|xla>`` (the executor of the delta
-rule's batched half) and ``linear_attention.fallbacks`` for a trace on an
-accelerator that took the token-by-token form under ``impl="auto"``.
+``linear_attention.rule_calls.<delta|ssd>``, the executor of the batched
+half ``linear_attention.chunk_calls.<pallas|xla>`` (delta rule) and
+``linear_attention.ssd_chunk_calls.<pallas|xla>`` (state-space rule), and
+``linear_attention.fallbacks`` (token-by-token on a chip under "auto").
 """
 from __future__ import annotations
 
@@ -359,6 +359,20 @@ def _ssd_recurrent(x, dt, A, Bm, Cm, block: int):
     return _rows_of_blocks(y, L)
 
 
+def _ssd_walk(Z, last):
+    """The walk of the state from chunk to chunk, ONE ``lax.scan``: the
+    chunks' addends ``Z`` (Nc, B, Gr, rep * P, N) and ``last`` = exp(G_C)
+    (Nc, B, Gr, rep * P, 1); the state each chunk starts from, as ``Z``."""
+    def walk(S, z):
+        Z_c, a_c = z
+        return a_c * S + Z_c, S
+
+    with jax.named_scope("ssd_scan"):
+        _, S0 = jax.lax.scan(walk, jnp.zeros(Z.shape[1:], jnp.float32),
+                             (Z, last))
+    return S0
+
+
 def _ssd_chunked(x, dt, A, Bm, Cm, chunk: int, dtype):
     f32 = jnp.float32
     B, L, H, P = x.shape
@@ -388,22 +402,42 @@ def _ssd_chunked(x, dt, A, Bm, Cm, chunk: int, dtype):
     Z = _mm("bgrnje,bgnjd->bgnred",
             v * heads(jnp.exp(G[..., -1:] - G))[..., None], Bm, dtype)
     last = jnp.repeat(jnp.exp(G[..., -1]), P, axis=1)   # (B, H * P, Nc)
-
-    def walk(S, z):
-        Z_c, a_c = z
-        return a_c * S + Z_c, S
-
-    with jax.named_scope("ssd_scan"):
-        _, S0 = jax.lax.scan(
-            walk, jnp.zeros((B, Gr, rep * P, N), f32),
-            (jnp.moveaxis(Z.reshape(B, Gr, Nc, rep * P, N), 2, 0),
-             jnp.moveaxis(last.reshape(B, Gr, rep * P, 1, Nc), 4, 0)))
+    S0 = _ssd_walk(
+        jnp.moveaxis(Z.reshape(B, Gr, Nc, rep * P, N), 2, 0),
+        jnp.moveaxis(last.reshape(B, Gr, rep * P, 1, Nc), 4, 0))
     S0 = jnp.moveaxis(S0, 0, 2).reshape(B, Gr, Nc, rep, P, N)
     y = inside + heads(jnp.exp(G))[..., None] * _mm(
         "bgnid,bgnred->bgrnie", Cm, S0, dtype)
     y = jnp.moveaxis(y.reshape(B, H, Nc, C, P), 1, 3).reshape(
         B, Nc * C, H, P)
     return y[:, :L]
+
+
+def _ssd_chunked_kernel(x, dt, A, Bm, Cm, dtype):
+    """``_ssd_chunked`` with the chunk-local half in the calls of
+    ``ops/pallas_ssd.py``: x, B and C go in as the rows they are, no
+    float32 head-major copy of anything a head wide is made, the addends
+    come out in the layout the scan carries and the outputs as rows."""
+    from mmlspark_tpu.ops import pallas_ssd as pss
+    B, L, H, P = x.shape
+    Gr, N = Bm.shape[2:]
+    # padding: dt = 0, no decay and no addend
+    (x, dt, Bm, Cm), Nc = _whole_chunks((x, dt, Bm, Cm), pss.CHUNK)
+
+    def rows(a):                # (B, L, H, d) -> (B, L, H * d), no copy
+        return a.reshape(B, Nc * pss.CHUNK, -1)
+    x, Bm, Cm, dims = rows(x), rows(Bm), rows(Cm), (H, P, Gr, N)
+    # a chunk's scalars as rows: float32 (B, Nc, H, 256)
+    dt = jnp.swapaxes(
+        dt.astype(jnp.float32).reshape(B, Nc, pss.CHUNK, H), 2, 3)
+    g = dt * A[:, None]
+    last = jnp.repeat(jnp.exp(jnp.sum(g, -1)), P, axis=2)   # (B, Nc, H * P)
+    S0 = _ssd_walk(
+        pss.ssd_chunk(x, Bm, dt, g, dims, dtype),
+        jnp.moveaxis(last.reshape(B, Nc, Gr, H // Gr * P, 1), 1, 0))
+    # the starting states are only ever read as a product's operand
+    y = pss.ssd_chunk_out(x, Bm, Cm, dt, g, S0.astype(dtype), dims, dtype)
+    return y.reshape(B, Nc * pss.CHUNK, H, P)[:, :L]
 
 
 def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
@@ -417,8 +451,12 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     ``A`` (H,) negative, ``B`` and ``C`` (B, L, G, N) with head ``h``
     reading group ``h // (H / G)``; returns (B, L, H, P) float32, without
     the skip term ``D x``. ``impl`` and ``dtype`` as ``gated_delta_rule``'s.
+    The chunked form takes the Pallas calls where ``pallas_ssd.supports``
+    the shapes, else XLA's batched products: counted as
+    ``linear_attention.ssd_chunk_calls.<pallas|xla>``.
     """
-    H = x.shape[2]
+    from mmlspark_tpu.ops import pallas_ssd as pss
+    H, P = x.shape[2:]
     if dt.shape != x.shape[:3] or A.shape != (H,) or B.shape != C.shape \
             or B.shape[:2] != x.shape[:2] or H % B.shape[2]:
         raise ValueError(f"shapes x {x.shape} dt {dt.shape} A {A.shape} "
@@ -430,4 +468,8 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     with jax.named_scope("ssd"):
         if taken == "recurrent":
             return _ssd_recurrent(x, dt, A, B, C, chunk)
+        if pss.supports(chunk, H, P, *B.shape[2:]):
+            obsmetrics.counter("linear_attention.ssd_chunk_calls.pallas").inc()
+            return _ssd_chunked_kernel(x, dt, A, B, C, dtype or x.dtype)
+        obsmetrics.counter("linear_attention.ssd_chunk_calls.xla").inc()
         return _ssd_chunked(x, dt, A, B, C, chunk, dtype or x.dtype)

@@ -420,3 +420,48 @@ def test_mosaic_compiles_the_delta_rules_chunk_calls(topo, as_on_chip,
                for line in text.splitlines()) == 2
     # no float32 head-major copy of q, k or v, and no repeated key heads
     assert f"f32[{B},{Hv},{L // 64},64,{d}]" not in text
+
+
+def test_mosaic_compiles_the_state_space_rules_chunk_calls(topo, as_on_chip):
+    """Mamba-2's rule, forward and backward, at the shape of benchmark
+    cell ``granite-4.0-h-micro-train-8k`` through Mosaic for a v5e: the
+    four Pallas calls of ``ops/pallas_ssd.py`` under the names
+    ``ssm.chunk_kernel_ms`` finds them by and no accepted pattern does,
+    and the walk still the two ``while``s (the scan and its transpose) that
+    ``ssm.state_walk_ms`` finds by the state they carry first."""
+    from jax.sharding import SingleDeviceSharding
+    from mmlspark_tpu.ops import linear_attention as la
+    one = SingleDeviceSharding(topo.devices[0])
+    B, L, H, P_, N = 1, 8192, 64, 64, 128
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def grads(x, dt, A, Bm, Cm, w):
+        return jax.value_and_grad(lambda *a: (la.ssd(
+            *a, dtype=jnp.bfloat16) * w).sum(), argnums=(0, 1, 2, 3, 4))(
+                x, dt, A, Bm, Cm)
+
+    text = jax.jit(grads).lower(
+        s((B, L, H, P_), jnp.bfloat16), s((B, L, H)), s((H,)),
+        s((B, L, 1, N), jnp.bfloat16), s((B, L, 1, N), jnp.bfloat16),
+        s((B, L, H, P_))).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    names = sorted(re.match(r"(?:ROOT )?%([a-z_]+)", c).group(1)
+                   for c in calls)
+    assert names == ["ssd_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_out",
+                     "ssd_chunk_out_bwd"]
+    mine = _benchmark_pattern("ssm.chunk_kernel_ms")
+    assert all(mine.search(c) for c in calls)
+    for metric in ("kernel.flash_attention_ms", "kernel.flash_fwd_roofline",
+                   "kernel.flash_bwd_ms", "kernel.attention_ms",
+                   "linattn.chunk_kernel_ms", "linattn.delta_rule_ms",
+                   "ssm.state_walk_ms"):
+        assert not any(_benchmark_pattern(metric).search(c) for c in calls)
+    walk = _benchmark_pattern("ssm.state_walk_ms")
+    assert sum(bool(walk.search(line.strip()))
+               for line in text.splitlines()) == 2
+    # neither the masked scores nor a float32 head-major copy of x in HBM
+    assert f"[{H},{L // 256},256,256]" not in text
+    assert f"f32[{B},{L // 256},256,{H},{P_}]" not in text
