@@ -1,4 +1,4 @@
-"""Decoder LMs built from parts, and the four families made of them:
+"""Decoder LMs built from parts, and the five families made of them:
 ``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's),
 ``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
 softmax layer, every feed-forward part a routed layer),
@@ -8,7 +8,10 @@ SwiGLU part in every layer, scaled residual additions, one table for the
 embedding and the head) and ``olmo_hybrid`` (Olmo-Hybrid: Gated DeltaNet
 layers whose state's transition may have negative eigenvalues and plain
 softmax attention without positions in the order of a published list, a
-dense SwiGLU part in every layer, OLMo 2's norms on each half's OUTPUT).
+dense SwiGLU part in every layer, OLMo 2's norms on each half's OUTPUT)
+and ``lfm2_moe`` (LFM2-24B-A2B: gated short convolutions and rotary
+grouped attention in the order of a published list, a dense SwiGLU part in
+the leading layers and a routed layer in every later one, one table).
 
 ``PartsBlock`` is the pre-norm residual block with nothing fixed: its norm,
 its attention (which owns its projections and its positions) and its
@@ -32,7 +35,11 @@ Parts here:
   rotary slice, and a sigmoid gate on the output;
 - ``GroupedAttention``: the same grouped heads with nothing else: no
   positions, no gate, a softmax scale of its own, and if asked an RMS norm
-  over the whole q and the whole k projection;
+  over the whole q and the whole k projection, or (``norm_heads``) over
+  each head's channels, and (``theta``) rotary positions on the whole head;
+- ``ShortConv``: LFM2's gated short convolution, which IS the mixer:
+  ``[B | C | x] = u W_in``, a causal depthwise convolution of three taps
+  over ``B * x`` with no activation, the gate ``C`` on its output;
 - ``GatedDeltaNet``: linear attention with a recurrent state
   (``ops/linear_attention.py``): a short causal convolution, the gated
   delta rule with ``beta`` in (0, ``beta_scale``), a gated norm on the
@@ -46,8 +53,8 @@ Parts here:
 
 Parameter names hit the rules of ``parallel/sharding.DEFAULT_RULES``
 (``attn_query*`` / ``attn_key*`` / ``attn_value`` / ``attn_qkvz`` /
-``attn_gate_value_key_query_dt`` / ``attn_out``, ``mlp_gate`` / ``mlp_up``
-/ ``mlp_down``, ``experts_*``, ``router``, ``lm_head``,
+``attn_gate_value_key_query_dt`` / ``attn_in`` / ``attn_out``, ``mlp_gate``
+/ ``mlp_up`` / ``mlp_down``, ``experts_*``, ``router``, ``lm_head``,
 ``token_embedding``).
 
 Blocks are recomputed in the backward pass one by one (``nn.remat``), which
@@ -72,6 +79,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from mmlspark_tpu.models.zoo import register_model
 from mmlspark_tpu.models.zoo.moe import DroplessMoe
+from mmlspark_tpu.observability import metrics as obsmetrics
 from mmlspark_tpu.parallel.sequence import full_attention
 
 _INIT = nn.initializers.normal(0.02)
@@ -79,6 +87,8 @@ _INIT = nn.initializers.normal(0.02)
 MLP_GATE_UP = "mlp_gate_up"
 # and of ``GatedDeltaNet``'s input projection's output
 DELTA_NET_QKVZ = "delta_net_qkvz"
+# and of ``ShortConv``'s
+SHORT_CONV_IN = "short_conv_in"
 
 
 class RMSNorm(nn.Module):
@@ -289,16 +299,24 @@ class GatedDeltaNet(nn.Module):
 
 
 class GroupedAttention(nn.Module):
-    """Causal softmax attention with grouped key/value heads and nothing
-    else: no positions (in ``granite_hybrid`` and ``olmo_hybrid`` the
-    recurrent layers carry the order), no gate, no biases; ``softmax(scale
-    x q k^T) v`` with a published ``scale`` that need not be ``head_dim **
-    -0.5``. With ``qk_norm_eps`` an RMS norm over the WHOLE q projection
-    and the whole k projection, before the split into heads (OLMo 2's
-    ``q_norm`` / ``k_norm``; scope ``qk_norm``); without, none.
+    """Causal softmax attention with grouped key/value heads and little
+    else: no gate, no biases; ``softmax(scale x q k^T) v`` with a
+    published ``scale`` that need not be ``head_dim ** -0.5``. Without
+    ``theta`` no positions (in ``granite_hybrid`` and ``olmo_hybrid`` the
+    recurrent layers carry the order); with it rotary positions on the
+    whole head of q and k (``lfm2_moe``). With ``qk_norm_eps`` an RMS norm
+    with a plain scale on q and on k (scope ``qk_norm``): over the WHOLE
+    projection before the split into heads (OLMo 2's ``q_norm`` /
+    ``k_norm``), or with ``norm_heads`` over EACH head's ``head_dim``
+    channels, one scale of ``head_dim`` shared by the heads (LFM2's
+    ``q_layernorm`` / ``k_layernorm``), float32 through the rotation;
+    without, none. LFM2's softmax layer is this part with two arguments
+    and not a third part: ``GatedAttention`` would need its ``1 + w``
+    scales, its rotary slice and its gate (which shapes ``W_q``) argued
+    away.
     ``attention_fn(q, k, v)`` keeps its own ``head_dim ** -0.5``, so ``q``
     is multiplied by ``scale x head_dim ** 0.5`` before the call (0.125 in
-    the published model: a power of two, exact in bfloat16). Each
+    the published Granite: a power of two, exact in bfloat16). Each
     key/value head is repeated to the ``heads / kv_heads`` query heads it
     serves at that call, as in ``GatedAttention``."""
     dim: int
@@ -309,6 +327,8 @@ class GroupedAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
     qk_norm_eps: Optional[float] = None     # None: no norm on q and k
+    norm_heads: bool = False            # the norm over each head, not all
+    theta: Optional[float] = None       # None: no positions
 
     @nn.compact
     def __call__(self, x):
@@ -323,10 +343,17 @@ class GroupedAttention(nn.Module):
 
             def heads_of(name, heads, norm=None):
                 y = _dense(heads * d, dt, name)(x)
-                if norm and self.qk_norm_eps is not None:
+                normed = norm and self.qk_norm_eps is not None
+                if normed and not self.norm_heads:
                     with jax.named_scope("qk_norm"):
                         y = RMSNorm(self.qk_norm_eps, name=norm)(y).astype(dt)
-                return y.reshape(B, L, heads, d)
+                y = y.reshape(B, L, heads, d)
+                if normed and self.norm_heads:
+                    with jax.named_scope("qk_norm"):
+                        y = RMSNorm(self.qk_norm_eps, name=norm)(y)
+                if norm and self.theta is not None:
+                    y = rotary(y, self.theta)
+                return y.astype(dt)
             q = heads_of("attn_query", H, "query_norm")
             k = heads_of("attn_key", G, "key_norm")
             v = heads_of("attn_value", G)
@@ -335,6 +362,42 @@ class GroupedAttention(nn.Module):
             k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
             o = attn_fn(q, k, v, causal=True)
             return _dense(self.dim, dt, "attn_out")(o.reshape(B, L, H * d))
+
+
+class ShortConv(nn.Module):
+    """LFM2's gated short convolution (``Lfm2ShortConv``; the ``conv``
+    entries of ``lfm2_moe``'s ``layer_types``), which is the whole mixer
+    and feeds no recurrence: ``[B | C | x] = u W_in`` (``dim -> 3 dim``);
+    ``z = B * x``; ``c_t = sum_j k_j z_{t - (taps-1) + j}``, depthwise and
+    causal with zeros before a row's start, NO activation
+    (``ops/linear_attention.causal_conv1d`` as it is); ``y = (C * c)
+    W_out``. No biases in the published model; ``bias`` adds the
+    convolution's. Scope ``short_conv``, and inside it ``gate_conv`` for
+    everything between the two projections (the split, both gates, the
+    taps): memory-bound, and what ``shortconv.gate_conv_roofline`` reads.
+    Columns of ``W_in`` are ``[B | C | x]``, each ``dim`` wide."""
+    dim: int
+    taps: int = 3
+    bias: bool = False
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from mmlspark_tpu.ops import linear_attention as la
+        dt = self.dtype
+        obsmetrics.counter("short_conv.calls").inc()
+        with jax.named_scope("short_conv"):
+            bcx = checkpoint_name(
+                _dense(3 * self.dim, dt, "attn_in")(u.astype(dt)),
+                SHORT_CONV_IN)
+            kernel = self.param("conv_kernel", _INIT, (self.taps, self.dim),
+                                jnp.float32)
+            conv_bias = self.param("conv_bias", _INIT, (self.dim,),
+                                   jnp.float32) if self.bias else None
+            with jax.named_scope("gate_conv"):
+                b, c, x = jnp.split(bcx, 3, axis=-1)
+                y = c * la.causal_conv1d(b * x, kernel, conv_bias)
+            return _dense(self.dim, dt, "attn_out")(y)
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -515,13 +578,15 @@ def _remat_block(norm, attention, ffn, name: str, split: bool = False,
     11.77 -> 14.16 GB of the chip's 16.91); the five tiles the gated delta
     rule's forward call writes, 12 a Gated DeltaNet block, without which
     ``delta_chunk_fwd`` runs twice a block; that block's input projection
-    (``DELTA_NET_QKVZ``, the ``[q | k | v | z]`` rows), 6. Each paid on
-    the chip (PERF.md section 6; PR 29: +5.6% and +1.5% of a
+    (``DELTA_NET_QKVZ``, the ``[q | k | v | z]`` rows), 6; a
+    ``ShortConv``'s (``SHORT_CONV_IN``, the ``[B | C | x]`` rows), 3. Each
+    paid on the chip (PERF.md section 6; PR 29: +5.6% and +1.5% of a
     ``glm4_moe_lite`` step; PR 36: the tiles +3.7% and the projection
     +2.0% of a ``qwen3_next`` step, the products +5.3% of a
-    ``granite_hybrid`` step). Left to the recomputation: the residual
-    stream after attention (1 a block, +0.7%: under the 1% a name has to
-    pay); q, k, v (7.5 a block, 1.5 GB a step, for under 10 ms); the
+    ``granite_hybrid`` step; PR 40: the short convolution's projection
+    +3.5% of an ``lfm2_moe`` step, whose mark went 13.64 -> 14.43 GB).
+    Left to the recomputation: the residual stream after attention (1 a
+    block, +0.7%: under the 1% a name has to pay); q, k, v (7.5 a block, 1.5 GB a step, for under 10 ms); the
     routed experts' ragged_dot intermediates (1 GB a step for 5 ms, and
     the benchmark's moe.expert_matmul_roofline counts their recomputation
     as required work); ``granite_hybrid``'s mixer's input projection
@@ -543,7 +608,7 @@ def _remat_block(norm, attention, ffn, name: str, split: bool = False,
     from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
     policy = jax.checkpoint_policies.save_only_these_names(*(
         n for n in (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES,
-                    DELTA_NET_QKVZ) if n not in let_go))
+                    DELTA_NET_QKVZ, SHORT_CONV_IN) if n not in let_go))
     if split:
         return nn.remat(SplitBlock, policy=policy, methods=("mix", "feed"))(
             norm, attention, ffn, residual_scale, norm_output, name=name)
@@ -913,6 +978,97 @@ class OlmoHybrid(nn.Module):
         return out
 
 
+class Lfm2Moe(nn.Module):
+    """``lfm2_moe`` (``model_type: lfm2_moe``): ``h_0 = E[token]``; layer
+    ``l`` is ``h <- h + op_l(norm(h))``, ``h <- h + ffn_l(norm(h))``. The
+    mixer's kind and the feed-forward part's kind both depend on the
+    layer's index, each on its own: ``op_l`` is a ``ShortConv`` where
+    ``layer_types[l]`` is ``"conv"`` and a ``GroupedAttention`` with a
+    norm over each q and k head and rotary positions on the whole head
+    where it is ``"full_attention"``; ``ffn_l`` is a dense ``SwiGluMlp``
+    for ``l < dense_layers`` and from there on a ``DroplessMoe`` routed by
+    sigmoid scores over all ``num_experts`` (the choice the top ``top_k``
+    of score plus bias, the weights over their sum plus ``weight_eps``, no
+    shared expert), of which ``experts_held`` live here. Plain RMS norms,
+    a final norm, ONE table read by the embedding's gather and by the head
+    (``GraniteHybrid``'s way, without its multipliers). ``gate_grad`` is
+    the routed layers' (``DroplessMoe``).
+
+    ``__call__(tokens)`` gives ``(B, L, vocab)`` float32 logits.
+    ``__call__(tokens, hidden=True)`` gives ``{"hidden", "stats"}`` for
+    the chunked loss, ``stats`` the routed layers' load (``_load_stats``),
+    so that ``next_token_loss(out, E^T, tokens)`` with ``E =
+    params["token_embedding"]["embedding"]`` is the model's loss. Each
+    block is recomputed in the backward pass and keeps ``_remat_block``'s
+    names: here the flash kernel's residuals in the softmax layers, the
+    gate and up products of the dense part and each short convolution's
+    ``[B | C | x]`` rows."""
+    vocab: int
+    dim: int
+    layer_types: Tuple[str, ...]
+    heads: int
+    kv_heads: int
+    head_dim: int
+    mlp_hidden: int
+    expert_hidden: int
+    num_experts: int
+    top_k: int
+    experts_held: Optional[Tuple[int, int]] = None   # (count, first index)
+    dense_layers: int = 2
+    conv_taps: int = 3
+    conv_bias: bool = False
+    scaling: float = 1.0
+    weight_eps: float = 1e-6
+    gate_grad: bool = True
+    theta: float = 1e6
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    def _block(self, index: int, name: str) -> nn.Module:
+        dt = self.dtype
+
+        def attention(n):
+            if self.layer_types[index] == "full_attention":
+                return GroupedAttention(
+                    self.dim, self.heads, self.kv_heads, self.head_dim, None,
+                    dt, self.attention_fn, self.eps, norm_heads=True,
+                    theta=self.theta, name=n)
+            return ShortConv(self.dim, self.conv_taps, self.conv_bias, dt,
+                             name=n)
+
+        def ffn(n):
+            if index < self.dense_layers:
+                return SwiGluMlp(self.dim, self.mlp_hidden, dt, name=n)
+            return DroplessMoe(
+                self.dim, self.num_experts, self.expert_hidden, self.top_k,
+                experts_held=self.experts_held, scaling=self.scaling,
+                dtype=dt, weight_eps=self.weight_eps,
+                gate_grad=self.gate_grad, name=n)
+
+        return _remat_block(lambda n: RMSNorm(self.eps, name=n), attention,
+                            ffn, name)
+
+    @nn.compact
+    def __call__(self, tokens, hidden: bool = False):
+        if not self.layer_types or set(self.layer_types) - {
+                "conv", "full_attention"}:
+            raise ValueError(f"layer_types {self.layer_types!r}: "
+                             "'conv' or 'full_attention' a layer")
+        embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype,
+                         embedding_init=_INIT, name="token_embedding")
+        x = embed(tokens)
+        loads = []
+        for i in range(len(self.layer_types)):
+            x, stats = self._block(i, f"block{i}")(x)
+            loads.append(stats)
+        normed = RMSNorm(self.eps, name="final_norm")(x)
+        self.sow("intermediates", "hidden", normed)
+        if not hidden:
+            return jnp.dot(normed, embed.embedding.T)
+        return {"hidden": normed, "stats": _load_stats(loads)}
+
+
 def _spec(module: nn.Module, max_len: int):
     return dict(
         module=module, input_shape=(max_len,), input_dtype="int32",
@@ -1079,3 +1235,49 @@ def olmo_hybrid_tiny(**overrides):
     period of the layer pattern, head widths in the published 1 : 2 ratio,
     chunks of 8 tokens."""
     return olmo_hybrid(**{**_OLMO_TINY, **overrides})
+
+
+LFM2_24B_A2B_LAYERS = ("conv", "conv") + (
+    "full_attention", "conv", "conv", "conv") * 9 + ("full_attention", "conv")
+
+
+@register_model("lfm2_moe")
+def lfm2_moe(vocab: int = 65536, dim: int = 2048,
+             layer_types=LFM2_24B_A2B_LAYERS, heads: int = 32,
+             kv_heads: int = 8, head_dim: int = 64, mlp_hidden: int = 11776,
+             expert_hidden: int = 1536, num_experts: int = 64,
+             top_k: int = 4, experts_held=None, dense_layers: int = 2,
+             conv_taps: int = 3, conv_bias: bool = False,
+             scaling: float = 1.0, weight_eps: float = 1e-6,
+             gate_grad: bool = True, theta: float = 1e6, eps: float = 1e-5,
+             max_len: int = 8192, dtype=jnp.bfloat16, attention_fn=None):
+    """LFM2-24B-A2B as published (huggingface.co/LiquidAI/LFM2-24B-A2B
+    ``config.json``, ``model_type: lfm2_moe``): forty layers, a gated
+    short convolution or rotary grouped attention by ``layer_types``
+    (layers 2, 6, ..., 38 attend), a dense SwiGLU part in the first two
+    and 64 sigmoid-routed experts, four a token, in every later one, one
+    table. ``head_dim`` = ``hidden_size / num_attention_heads`` (the
+    config has no key for it). ``experts_held`` = ``(count, first)`` as
+    for ``glm4_moe_lite``; ``gate_grad=False`` for a share trained without
+    its exchange (``DroplessMoe``)."""
+    held = None if experts_held is None else tuple(experts_held)
+    return _spec(Lfm2Moe(
+        vocab, dim, tuple(layer_types), heads, kv_heads, head_dim,
+        mlp_hidden, expert_hidden, num_experts, top_k, held, dense_layers,
+        conv_taps, conv_bias, scaling, weight_eps, gate_grad, theta, eps,
+        dtype, attention_fn), max_len)
+
+
+_LFM2_TINY = dict(vocab=96, dim=32,
+                  layer_types=("conv", "full_attention", "conv", "conv"),
+                  heads=4, kv_heads=2, head_dim=8, mlp_hidden=48,
+                  expert_hidden=16, num_experts=8, top_k=2, dense_layers=1,
+                  max_len=64, dtype=jnp.float32)
+
+
+@register_model("lfm2_moe_tiny")
+def lfm2_moe_tiny(**overrides):
+    """Test-scale ``lfm2_moe`` (float32, so CPU parity is tight): one
+    leading dense layer, then one period's kinds of mixer under routed
+    layers of eight experts, two a token."""
+    return lfm2_moe(**{**_LFM2_TINY, **overrides})

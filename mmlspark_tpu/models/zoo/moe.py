@@ -233,9 +233,10 @@ _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class DroplessMoe(nn.Module):
-    """Routed experts with a shared expert, no capacity and no dropped
-    token. One layer, one dispatch, one set of grouped products; how the
-    scores are made and whether the shared expert is gated are arguments.
+    """Routed experts, with a shared expert or (``shared=None``) without,
+    no capacity and no dropped token. One layer, one dispatch, one set of
+    grouped products; how the scores are made and whether the shared
+    expert is gated are arguments.
 
     ``scores="sigmoid"`` (DeepSeek-V3's layer; ``topk_method: noaux_tc``
     with one group): ``s = sigmoid(x W_r)`` in float32 over ALL
@@ -243,7 +244,20 @@ class DroplessMoe(nn.Module):
     (``router_bias``: no gradient reaches it, it only moves the choice).
     ``scores="softmax"``: ``s = softmax(x W_r)`` in float32 over all of
     them, the choice its top ``top_k``, no bias. Either way the weights are
-    ``s`` at the chosen, normalised to 1, times ``scaling``.
+    ``s`` at the chosen over ``(their sum + weight_eps)``, times
+    ``scaling`` (``weight_eps`` 0 in DeepSeek-V3's and Qwen3-Next's layer,
+    1e-6 in LFM2's).
+    ``gate_grad=False`` makes the weights, and the scores behind them,
+    constants of the backward pass (``jax.lax.stop_gradient``): no
+    gradient reaches the router's kernel, and none reaches the tokens
+    through the scores; the forward pass is the same. It is what a SHARE
+    trained without its exchange can honestly compute: the scores' true
+    gradient needs the outputs of all ``top_k`` chosen experts, of which a
+    chip that holds ``held`` of ``num_experts`` has only its own, so a
+    router trained on that part of the sum alone is pulled onto the held
+    experts and the share's load climbs step by step (PERF.md section 6,
+    PR 40). With the exchange of a routed layer across chips (ROADMAP
+    Queue 2 A5) the argument goes back to ``True``.
     ``shared_gate`` puts the shared expert behind a sigmoid of one more
     output of the token: ``sigmoid(x w_g) * shared(x)``.
     ``experts_held = (count, first)``
@@ -272,6 +286,8 @@ class DroplessMoe(nn.Module):
     dtype: Any = jnp.bfloat16
     scores: str = "sigmoid"
     shared_gate: bool = False
+    weight_eps: float = 0.0
+    gate_grad: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -299,7 +315,12 @@ class DroplessMoe(nn.Module):
                 s = ranked = jax.nn.softmax(logits, axis=-1)
             _, choice = jax.lax.top_k(ranked, K)     # indices: no gradient
             gate = jnp.take_along_axis(s, choice, axis=-1)
-            gate = gate / gate.sum(-1, keepdims=True) * self.scaling
+            total = gate.sum(-1, keepdims=True)
+            if self.weight_eps:
+                total = total + self.weight_eps
+            gate = gate / total * self.scaling
+            if not self.gate_grad:
+                gate = jax.lax.stop_gradient(gate)
             self.sow("intermediates", "router_choice", choice)
 
         with jax.named_scope("moe_dispatch"):

@@ -484,7 +484,8 @@ def test_each_block_recomputed_in_halves_is_the_block_not_recomputed():
 
 
 @pytest.mark.parametrize("preset", [
-    "glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny"])
+    "glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
+    "lfm2_moe_tiny"])
 def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
                                                           preset):
     """``_remat_block`` has ONE list of names for every family (the policy
@@ -508,7 +509,7 @@ def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     assert seen and set(seen) == {
         (FLASH_RESIDUALS, decoder.MLP_GATE_UP, DELTA_CHUNK_TILES,
-         decoder.DELTA_NET_QKVZ)}
+         decoder.DELTA_NET_QKVZ, decoder.SHORT_CONV_IN)}
 
 
 # ------------------------------------------------------------ the parts
