@@ -721,14 +721,17 @@ class Glm4MoeLite(nn.Module):
 
 def _load_stats(loads) -> Dict[str, jax.Array]:
     """The routed layers' load as the trainer's ring carries it:
-    ``moe.slots_here`` summed over them, ``moe.overflow_layers`` (how many
-    of them ran at full size this step), ``moe.load_max_over_mean`` of the
-    worst."""
+    ``moe.slots_here`` and ``moe.rows_moved`` (the rows of the rungs their
+    expert-order buffers took) summed over them, ``moe.overflow_layers``
+    (how many of them ran at full size this step),
+    ``moe.load_max_over_mean`` of the worst."""
     loads = [s for s in loads if s]
     if not loads:
         return {}
     return {"moe.slots_here": sum(
                 s["slots_here"] for s in loads).astype(jnp.float32),
+            "moe.rows_moved": sum(
+                s["rows"] for s in loads).astype(jnp.float32),
             "moe.overflow_layers": sum(
                 s["overflowed"] for s in loads).astype(jnp.float32),
             "moe.load_max_over_mean": jnp.max(jnp.stack(

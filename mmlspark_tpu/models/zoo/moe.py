@@ -109,26 +109,36 @@ class MoeMlp(nn.Module):
 # lying first and group by group, and gate/up/down run as grouped matrix
 # products over those groups (``jax.lax.ragged_dot``; on a TPU XLA lowers it
 # to a grouped Mosaic matmul that walks only the tiles the groups cover).
-# Every array between the sort and the token-order sum has ``R`` rows, a
-# bound on the slots routed here that the layer's shapes give
-# (``_bounded_rows``), not the ``S*K`` slots there are: the first ``R``
-# entries of the sort name the rows, one gather brings them from token order
-# (``_rows_of_tokens``), and each returns as an addend of its token's row
-# (``_sum_by_token``, a scatter-add); the two are each other's transpose, so
-# the backward pass moves ``R`` rows too and no ``(S*K, D)`` array is made.
-# A step that routes more than ``R`` slots here runs the same path at
-# ``S*K`` rows under a ``lax.cond``: no slot is dropped at any load.
+# Every array between the sort and the token-order sum has ``R`` rows, the
+# smallest rung that holds the step's slots on a short ladder of static
+# sizes the layer's shapes give (``_ladder``), not the ``S*K`` slots there
+# are: the first ``R`` entries of the sort name the rows, one gather brings
+# them from token order (``_rows_of_tokens``), and each returns as an addend
+# of its token's row (``_sum_by_token``, a scatter-add); the two are each
+# other's transpose, so the backward pass moves ``R`` rows too and no
+# ``(S*K, D)`` array is made. The rung is a device scalar's choice
+# (``lax.switch``), and the last rung is ``S*K``: no slot is dropped at any
+# load.
 
-# the expert-order buffers hold this many times the slots an even router
-# sends to the held experts, rounded up to whole tiles of rows
-_ROWS_OVER_EVEN_LOAD = 4
+# the ladder's rungs below ``S*K``: the expert-order buffers hold this many
+# times the slots an even router sends to the held experts, rounded up to
+# whole tiles of rows. Gathers, scatter-adds and element-wise passes pay for
+# every row of a rung, filler included (0.4 us a row a layer at width 2,048
+# on a v5e), so the lowest lies close over a steady load; a router trained
+# on one chip's share climbs past it, and past four times it late in a
+# window (PERF.md section 6, PRs 31 and 41)
+_RUNGS_OVER_EVEN_LOAD = (1.25, 4)
 _ROWS_TILE = 512
 
 
-def _bounded_rows(slots: int, held: int, experts: int) -> int:
-    even = _ROWS_OVER_EVEN_LOAD * slots * held
-    tiles = -(-even // (experts * _ROWS_TILE))
-    return min(slots, tiles * _ROWS_TILE)
+def _ladder(slots: int, held: int, experts: int) -> Tuple[int, ...]:
+    """The sizes the expert-order buffers may take, ascending, the last one
+    all ``slots``; one rung where the layer holds every expert."""
+    rungs = {slots}
+    for over in _RUNGS_OVER_EVEN_LOAD:
+        tiles = math.ceil(over * slots * held / (experts * _ROWS_TILE))
+        rungs.add(min(slots, tiles * _ROWS_TILE))
+    return tuple(sorted(rungs))
 
 
 def _sum_by_token(tokens, rows, token):
@@ -196,37 +206,33 @@ def _routed_rows(rows, xf, gate, w_gate, w_up, w_down, order, sizes):
         return _sum_by_token(tokens, ys * weight[:, None], token)
 
 
-def _at_either_size(bound, small, full, sizes, *operands):
-    return jax.lax.cond(sizes.sum() <= bound, small, full, *operands)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(bound, xf, gate, w_gate, w_up, w_down, order, sizes):
-    """``_routed_rows`` at ``bound`` rows while the step's slots fit them,
-    at all ``S*K`` when they do not. Differentiated as a whole: the
-    backward pass makes the same choice and runs the chosen size's forward
-    again, so neither pass hands the other an array of the size it did not
-    run (``lax.cond``'s own derivative keeps both branches' residuals)."""
-    operands = (xf, gate, w_gate, w_up, w_down, order, sizes)
-    return _at_either_size(
-        bound, functools.partial(_routed_rows, bound),
-        functools.partial(_routed_rows, gate.size), sizes, *operands)
+def _routed(rungs, rung, xf, gate, w_gate, w_up, w_down, order, sizes):
+    """``_routed_rows`` at ``rungs[rung]`` rows, ``rung`` a device scalar:
+    the smallest rung that holds the step's slots. Differentiated as a
+    whole: the backward pass takes the same rung and runs its forward
+    again, so neither pass hands the other an array of a size it did not
+    run (``lax.switch``'s own derivative keeps every branch's residuals)."""
+    return jax.lax.switch(
+        rung, [functools.partial(_routed_rows, rows) for rows in rungs],
+        xf, gate, w_gate, w_up, w_down, order, sizes)
 
 
-def _routed_fwd(bound, *operands):
-    return _routed(bound, *operands), operands
+def _routed_fwd(rungs, rung, *operands):
+    return _routed(rungs, rung, *operands), (rung, operands)
 
 
-def _routed_bwd(bound, operands, g):
+def _routed_bwd(rungs, kept, g):
     def pull(rows):
         def back(g, *operands):
             moved, ids = operands[:5], operands[5:]     # order, sizes
             return jax.vjp(lambda *m: _routed_rows(rows, *m, *ids),
                            *moved)[1](g)
         return back
-    sizes, slots = operands[-1], operands[1].size
-    return (*_at_either_size(bound, pull(bound), pull(slots), sizes, g,
-                             *operands), None, None)
+    rung, operands = kept
+    moved = jax.lax.switch(rung, [pull(rows) for rows in rungs], g,
+                           *operands)
+    return (None, *moved, None, None)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -267,13 +273,15 @@ class DroplessMoe(nn.Module):
     dense feed-forward part every chip computes alike.
 
     A layer that holds a share of the experts sizes its expert-order
-    buffers by a bound on the slots routed here (``_bounded_rows``) and
-    runs the same path over all ``tokens x top_k`` rows in a step that
-    routes more to it: the choice is a device scalar's.
+    buffers by the smallest rung that holds the step's slots (``_ladder``:
+    a few static sizes, the last all ``tokens x top_k`` rows) and runs the
+    same path at every rung: the choice is a device scalar's.
 
     Returns ``(y, stats)``: ``slots_here`` (slots routed to held experts),
-    ``overflowed`` (1 if they passed the bound in this call, else 0) and
-    ``load_max_over_mean`` (the fullest held expert over their mean).
+    ``rows`` (the rows of the rung this call ran at), ``overflowed`` (1 if
+    that was all ``tokens x top_k`` of a ladder with smaller rungs, else
+    0) and ``load_max_over_mean`` (the fullest held expert over their
+    mean).
     Sows the choice under ``("intermediates", "router_choice")``.
     """
     dim: int
@@ -332,10 +340,13 @@ class DroplessMoe(nn.Module):
             sizes = jnp.bincount(key, length=held + 1)[:held].astype(
                 jnp.int32)
             slots_here = sizes.sum()
-            bound = _bounded_rows(S * K, held, E)
+            # the smallest rung that holds them: how many of the rungs
+            # below the last they exceed
+            rungs = _ladder(S * K, held, E)
+            rung = jnp.sum(slots_here > jnp.asarray(rungs[:-1], jnp.int32))
 
         # the outer scope names what ``_routed_rows``'s own three leave
-        # out: the weights' casts and the choice between the two sizes
+        # out: the weights' casts and the choice of the rung
         with jax.named_scope("moe_experts"):
             w_gate, w_up, w_down = (
                 self.param(name, init, shape, jnp.float32).astype(self.dtype)
@@ -344,10 +355,10 @@ class DroplessMoe(nn.Module):
                                     ("experts_down", (held, H, D))))
             operands = (xf.astype(self.dtype), gate, w_gate, w_up, w_down,
                         order, sizes)
-            if bound == S * K:  # buffers of every slot: one size, no cond
-                y = _routed_rows(bound, *operands)
+            if len(rungs) == 1:  # buffers of every slot: no conditional
+                y = _routed_rows(S * K, *operands)
             else:
-                y = _routed(bound, *operands)
+                y = _routed(rungs, rung, *operands)
 
         with jax.named_scope("moe_combine"):
             if self.shared is not None:
@@ -361,7 +372,9 @@ class DroplessMoe(nn.Module):
 
         load = sizes.astype(jnp.float32)
         stats = {"slots_here": slots_here,
-                 "overflowed": (slots_here > bound).astype(jnp.int32),
+                 "rows": jnp.asarray(rungs, jnp.int32)[rung],
+                 "overflowed": ((rung > 0) & (rung == len(rungs) - 1)
+                                ).astype(jnp.int32),
                  "load_max_over_mean": load.max() / jnp.maximum(
                      load.mean(), 1e-9)}
         return y.reshape(B, L, D).astype(self.dtype), stats

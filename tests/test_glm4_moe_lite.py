@@ -9,6 +9,7 @@ products of four such numbers), 2e-3 of the largest element on Adam steps
 (``g / (sqrt(v) + eps)`` amplifies a relative gradient error where ``g`` is
 near zero).
 """
+import functools
 import sys
 from pathlib import Path
 
@@ -25,6 +26,7 @@ from benchmark.references import glm47_flash as ref  # noqa: E402
 from mmlspark_tpu.models.zoo import build_model  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
     MlaAttention, SwiGluMlp, rotary)
+from mmlspark_tpu.models.zoo import moe  # noqa: E402
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.train.lm_loss import (  # noqa: E402
     chunked_cross_entropy, next_token_loss)
@@ -162,6 +164,7 @@ def test_three_adamw_steps_match_the_reference():
                                    rtol=2e-3, err_msg=k)
     # every routed slot of the uncut tiny model is held here
     assert float(m["moe.slots_here"]) == 3 * ROWS * LEN * 2
+    assert float(m["moe.rows_moved"]) == 3 * ROWS * LEN * 2
 
 
 # ------------------------------- what a block keeps for its backward
@@ -361,10 +364,14 @@ def test_expert_layer_gradients_match_a_dense_loop():
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-6)
 
 
-# ------------------------------- expert-order buffers of a bounded size
-# 2 of 16 experts over 512 tokens x top-2: the buffers hold 512 of the
-# 1,024 slots, four times what an even router sends here
+# ------------------------------ expert-order buffers on a ladder of sizes
+# 2 of 16 experts over 512 tokens x top-2: an even router sends 128 slots
+# here, and every rung below all 1,024 is one tile of 512 rows
 WIDE = dict(slots=1024, bound=512)
+# 3 of 16 experts over 2,048 tokens x top-2: an even router sends 768 slots
+# here, so the ladder has a rung close over that, one at four times it
+# (3,072) and all 4,096
+TALL = dict(tokens=2048, held=(3, 4))
 
 
 def _wide_params(scores, onto_held):
@@ -380,6 +387,40 @@ def _wide_params(scores, onto_held):
     if onto_held:
         kernel = p["router"]["kernel"]
         p["router"] = {"kernel": kernel.at[0, 4:6].add(12.0)}
+    return layer, {"params": p}, x
+
+
+def _tall_layer(scores):
+    return DroplessMoe(32, 16, 16, 2, experts_held=TALL["held"],
+                       scaling=1.8, dtype=jnp.float32, scores=scores)
+
+
+def _tall_rungs():
+    return moe._ladder(TALL["tokens"] * 2, TALL["held"][0], 16)
+
+
+def _tall_params(scores, slots):
+    """A seeded layer whose router sends exactly ``slots`` slots to the
+    held experts: feature 0 of the input is 1 and its row of the router's
+    matrix pushes the three held experts away for every token; feature 1
+    marks the tokens that send both choices here (experts 4 and 5),
+    feature 2 the one that sends one (expert 4, and the unheld expert 0).
+    Expert 6 is held and chosen by nobody."""
+    layer = _tall_layer(scores)
+    both, one = divmod(slots, 2)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 1024, 32))
+    marks = np.zeros((TALL["tokens"], 3), np.float32)
+    marks[:, 0] = 1.0
+    marks[:both, 1] = 1.0
+    marks[both:both + one, 2] = 1.0
+    # spread the marked tokens over both rows of the batch
+    marks = marks[np.random.default_rng(9).permutation(TALL["tokens"])]
+    x = x.at[..., :3].set(jnp.asarray(marks).reshape(2, 1024, 3))
+    p = dict(layer.init(jax.random.PRNGKey(8), x)["params"])
+    kernel = p["router"]["kernel"]
+    kernel = kernel.at[0, 4:7].add(-12.0).at[1, 4:6].add(24.0)
+    kernel = kernel.at[2, 4].add(24.0).at[2, 0].add(12.0)
+    p["router"] = {"kernel": kernel}
     return layer, {"params": p}, x
 
 
@@ -407,43 +448,93 @@ def _dense_loop(layer, p, x):
     return y.reshape(x.shape)
 
 
-@pytest.mark.parametrize("scores", ["sigmoid", "softmax"])
-@pytest.mark.parametrize("onto_held", [False, True],
-                         ids=["within_the_bound", "past_the_bound"])
-def test_bounded_buffers_give_the_dense_loop_at_either_size(scores,
-                                                            onto_held):
-    """Output and gradients of a layer whose buffers are smaller than its
-    slots, while the step's slots fit them and when every slot is routed
-    here: the second runs the same path at full size and says so."""
-    layer, p, x = _wide_params(scores, onto_held)
-    y, stats = jax.jit(layer.apply)(p, x)
-    slots_here = int(stats["slots_here"])
-    if onto_held:
-        assert slots_here == WIDE["slots"] > WIDE["bound"]
-    else:
-        assert 0 < slots_here <= WIDE["bound"]
-    assert int(stats["overflowed"]) == int(onto_held)
-    np.testing.assert_allclose(y, _dense_loop(layer, p, x), rtol=1e-5,
-                               atol=1e-6)
-
-    def loss(fn):
+def _programs(layer):
+    """The layer's jitted output and gradients, and the dense loop's."""
+    def sin_sum(fn):
         return lambda q, x: jnp.sum(jnp.sin(fn(q, x)))
-    got = jax.jit(jax.grad(loss(lambda q, x: layer.apply(q, x)[0]),
-                           argnums=(0, 1)))(p, x)
-    want = jax.grad(loss(lambda q, x: _dense_loop(layer, q, x)),
-                    argnums=(0, 1))(p, x)
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
-                            jax.tree_util.tree_leaves(want)):
+    own = lambda q, x: layer.apply(q, x)[0]
+    dense = lambda q, x: _dense_loop(layer, q, x)
+    return (jax.jit(layer.apply),
+            jax.jit(jax.grad(sin_sum(own), argnums=(0, 1))),
+            jax.jit(dense),
+            jax.jit(jax.grad(sin_sum(dense), argnums=(0, 1))))
+
+
+@functools.lru_cache(maxsize=None)
+def _tall_programs(scores):
+    """Every load of a kind of score runs one set of programs."""
+    return _programs(_tall_layer(scores))
+
+
+# a load of the three-rung layer, from its ladder: the rung it lands in
+# and its slots (inside the rung, up to its last row, or one slot past the
+# rung below)
+_TALL_LOADS = {"rung0_inside": lambda rungs: (0, 300),
+               "rung0_to_its_edge": lambda rungs: (0, rungs[0]),
+               "rung1_one_more": lambda rungs: (1, rungs[0] + 1),
+               "rung1_to_its_edge": lambda rungs: (1, rungs[1]),
+               "rung2_one_more": lambda rungs: (2, rungs[1] + 1),
+               "rung2_every_slot": lambda rungs: (2, rungs[2])}
+
+
+@pytest.mark.parametrize("scores", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("load", ["within_the_bound", "past_the_bound",
+                                  *_TALL_LOADS])
+def test_bounded_buffers_give_the_dense_loop_at_either_size(scores, load):
+    """Output and gradients of a layer whose buffers are smaller than its
+    slots, at every rung of its ladder: loads inside a rung, up to its
+    last row and one slot more, which runs the same path at the next size;
+    the last rung holds every slot and says so."""
+    if load in _TALL_LOADS:
+        rungs = _tall_rungs()
+        assert len(rungs) == 3 and rungs[-1] == 2 * TALL["tokens"]
+        index, slots = _TALL_LOADS[load](rungs)
+        layer, p, x = _tall_params(scores, slots)
+        apply, grad, dense, dense_grad = _tall_programs(scores)
+    else:       # a seeded router's load, or every token onto the held
+        index, rungs = int(load == "past_the_bound"), (
+            WIDE["bound"], WIDE["slots"])
+        slots = WIDE["slots"] if index else None
+        layer, p, x = _wide_params(scores, bool(index))
+        apply, grad, dense, dense_grad = _programs(layer)
+    y, stats = apply(p, x)
+    if slots is None:
+        assert 0 < int(stats["slots_here"]) <= rungs[0]
+    else:
+        assert int(stats["slots_here"]) == slots
+    assert int(stats["rows"]) == rungs[index]
+    assert int(stats["overflowed"]) == int(index == len(rungs) - 1)
+    np.testing.assert_allclose(y, dense(p, x), rtol=1e-5, atol=1e-6)
+    for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path(grad(p, x)),
+            jax.tree_util.tree_leaves(dense_grad(p, x))):
         np.testing.assert_allclose(
             g, w, rtol=1e-5, atol=1e-5 * float(jnp.abs(w).max()),
             err_msg=jax.tree_util.keystr(path))
 
 
+@pytest.mark.parametrize("slots, held, experts", [
+    (32768, 8, 64), (81920, 32, 512), (131072, 8, 64), (1024, 2, 16)],
+    ids=["glm", "qwen", "lfm2", "wide"])
+def test_the_ladder_of_a_share(slots, held, experts):
+    """Ascending whole tiles, at most four sizes; the lowest within 1.5
+    times an even router's share (rounded up to a tile), one rung at four
+    times it, the last every slot."""
+    rungs = moe._ladder(slots, held, experts)
+    tile = moe._ROWS_TILE
+    tiled = lambda rows: min(slots, -(-rows // tile) * tile)
+    even = slots * held // experts
+    assert list(rungs) == sorted(set(rungs)) and 2 <= len(rungs) <= 4
+    assert all(r % tile == 0 for r in rungs)
+    assert even <= rungs[0] <= tiled(even + even // 2)
+    assert tiled(4 * even) in rungs and rungs[-1] == slots
+
+
 def _slot_sized_arrays(jaxpr, rows, found, inside=()):
     """Every value of ``rows`` rows of the layer's or its experts' width
     that an equation of ``jaxpr`` makes (the sort's keys have one column),
-    with the branches of each ``cond`` on its way (branch 0 is
-    ``lax.cond``'s false side: the overflow path)."""
+    with the branches of each ``cond`` on its way (``lax.switch``'s branch
+    ``i`` is the ladder's rung ``i``)."""
     for eqn in jaxpr.eqns:
         for var in eqn.outvars:
             shape = getattr(var.aval, "shape", ())
@@ -461,22 +552,31 @@ def _slot_sized_arrays(jaxpr, rows, found, inside=()):
 
 
 @pytest.mark.parametrize("what", ["forward", "gradient"])
-def test_no_slot_sized_array_outside_the_overflow_branch(what):
-    """Between the sort and the sum by token every array has the bound's
-    rows: nothing of ``S*K`` rows is made but where a step overflows, in
-    the forward pass or the backward."""
-    layer, p, x = _wide_params("softmax", False)
+@pytest.mark.parametrize("ladder", ["wide", "tall"])
+def test_no_slot_sized_array_outside_the_overflow_branch(ladder, what):
+    """Between the sort and the sum by token every array has its rung's
+    rows: each branch makes arrays of its own size, and nothing of a
+    rung's rows is made outside that rung's branch (so nothing of ``S*K``
+    rows but where a step overflows), in the forward pass or the
+    backward."""
+    if ladder == "wide":
+        layer, p, x = _wide_params("softmax", False)
+        rungs = (WIDE["bound"], WIDE["slots"])
+    else:
+        layer, p, x = _tall_params("softmax", 300)
+        rungs = _tall_rungs()
     fn = lambda q, x: jnp.sum(layer.apply(q, x)[0])
     if what == "gradient":
         fn = jax.grad(fn, argnums=(0, 1))
     jaxpr = jax.make_jaxpr(fn)(p, x).jaxpr
-    found = _slot_sized_arrays(jaxpr, WIDE["slots"], [])
-    assert found, "the overflow branch itself was not seen"
-    assert all("cond[0]" in inside for inside, _, _ in found), [
-        f for f in found if "cond[0]" not in f[0]]
-    bounded = _slot_sized_arrays(jaxpr, WIDE["bound"], [])
-    assert any("cond[1]" in inside and shape == (WIDE["bound"], 32)
-               for inside, _, shape in bounded)
+    tokens = x.shape[0] * x.shape[1]
+    for i, rows in enumerate(rungs):
+        found = _slot_sized_arrays(jaxpr, rows, [])
+        assert any(f"cond[{i}]" in inside and shape == (rows, 32)
+                   for inside, _, shape in found), (i, rows)
+        if rows != tokens:      # the layer's input and output have those
+            assert all(f"cond[{i}]" in inside for inside, _, _ in found), [
+                f for f in found if f"cond[{i}]" not in f[0]]
 
 
 def test_a_layer_that_holds_every_expert_lowers_no_cond():
@@ -486,7 +586,9 @@ def test_a_layer_that_holds_every_expert_lowers_no_cond():
     text = str(jax.make_jaxpr(jax.grad(
         lambda q: jnp.sum(layer.apply(q, x)[0])))(p))
     assert "cond" not in text and "ragged_dot" in text
-    assert int(layer.apply(p, x)[1]["overflowed"]) == 0
+    stats = layer.apply(p, x)[1]
+    assert int(stats["overflowed"]) == 0
+    assert int(stats["rows"]) == int(stats["slots_here"]) == 2 * 256 * 2
 
 
 # --------------------------------------------------- attention's parts

@@ -315,6 +315,7 @@ def test_three_adamw_steps_match_the_reference():
     # every routed slot of the uncut tiny model is held here
     assert float(m["moe.slots_here"]) == 3 * ROWS * LEN * 2
     assert float(m["moe.overflow_layers"]) == 0
+    assert float(m["moe.rows_moved"]) == float(m["moe.slots_here"])
     assert want["mtp"] == [] and len(want["routing"]) == 3
     assert want["routing"][0]["choice"].shape == (ROWS * LEN, 2)
     assert want["routing"][0]["ranked"].shape == (ROWS * LEN, 8)

@@ -238,7 +238,7 @@ def test_losses_and_gradients_match_the_reference(params):
         want_loss = want_loss + part
         want = g if want is None else jax.tree_util.tree_map(
             jnp.add, want, g)
-    assert set(aux) == {"loss.main", "moe.slots_here",
+    assert set(aux) == {"loss.main", "moe.slots_here", "moe.rows_moved",
                         "moe.overflow_layers", "moe.load_max_over_mean"}
     np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
     np.testing.assert_allclose(aux["loss.main"], want_loss, rtol=1e-5)
@@ -295,6 +295,7 @@ def test_three_adamw_steps_match_the_reference():
     # every routed slot of the uncut tiny model is held here
     assert float(m["moe.slots_here"]) == 4 * ROWS * LEN * 3
     assert float(m["moe.overflow_layers"]) == 0     # buffers of every slot
+    assert float(m["moe.rows_moved"]) == float(m["moe.slots_here"])
     assert len(want["routing"]) == 4
     assert want["routing"][0]["choice"].shape == (ROWS * LEN, 3)
     assert want["routing"][0]["ranked"].shape == (ROWS * LEN, 16)

@@ -130,8 +130,8 @@ def test_an_aux_the_ring_cannot_hold_is_refused_at_the_first_step(
 
 
 # ----------------------------------------------- the glm4_moe_lite step
-AUX = ("loss.main", "loss.mtp", "moe.slots_here", "moe.overflow_layers",
-       "moe.load_max_over_mean")
+AUX = ("loss.main", "loss.mtp", "moe.slots_here", "moe.rows_moved",
+       "moe.overflow_layers", "moe.load_max_over_mean")
 
 
 def _lm_trainer(length=16, chunk=8, edit=lambda params: params, **model):
@@ -181,6 +181,7 @@ def test_the_lm_step_counts_its_grouped_products_and_publishes_its_gauges():
     assert 0 < float(m["moe.slots_here"]) < 3 * 32 * 2
     assert float(m["moe.load_max_over_mean"]) >= 1.0
     assert float(m["moe.overflow_layers"]) == 0     # buffers of every slot
+    assert float(m["moe.rows_moved"]) == 3 * 32 * 2
     np.testing.assert_allclose(
         m["loss"], m["loss.main"] + 0.3 * m["loss.mtp"], rtol=1e-6)
 
@@ -190,7 +191,8 @@ def test_the_layers_that_ran_at_full_size_are_counted_in_the_ring(
         onto_held, layers):
     """2 of 16 experts over 512 tokens x top-2: the expert-order buffers
     hold 512 of the 1,024 slots. A router biased onto the held experts
-    sends all of them here, in the two routed blocks and the MTP block."""
+    sends all of them here, in the two routed blocks and the MTP block,
+    and the ring says how many rows each step's buffers held."""
     def bias_onto_held(params):
         return jax.tree_util.tree_map_with_path(
             lambda path, v: v.at[4:6].set(10.0)
@@ -206,6 +208,9 @@ def test_the_layers_that_ran_at_full_size_are_counted_in_the_ring(
     ring = trainer.flush_metrics()
     np.testing.assert_array_equal(ring["moe.overflow_layers"][:2], layers)
     assert obsmetrics.gauge("moe.overflow_layers").value == layers
+    rows = 3 * (1024 if onto_held else 512)
+    np.testing.assert_array_equal(ring["moe.rows_moved"][:2], rows)
+    assert obsmetrics.gauge("moe.rows_moved").value == rows
     if onto_held:
         np.testing.assert_array_equal(ring["moe.slots_here"][:2], 3 * 1024)
     else:
