@@ -217,6 +217,72 @@ def test_flash_attention_matches_reference():
     np.testing.assert_allclose(got, ref, atol=4e-2, rtol=4e-2)
 
 
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("length,blocks", [(2048, (256, 256)),
+                                           (768, (768, 128))],
+                         ids=["L2048", "L768-bq768-bk128"])
+@pytest.mark.parametrize("width", [64, 128, 256])
+def test_flash_forward_matches_reference(width, length, blocks, causal,
+                                         dtype, tol):
+    """The forward alone against the plain reference, at the head widths
+    the benchmark's cells run. At 2,048 tokens the tile rule leaves a
+    query block with K tiles of both kinds under ``causal``: wholly before
+    its first row (the loop, no mask) and crossed by the diagonal (the
+    tiles after it, each with its constant mask). At 768 a caller's 768 x
+    128 become 768 x 256 (nothing larger divides the length): the
+    diagonal crosses three K tiles of the one query block. bfloat16 under
+    the bound the backward's tests use, float32 as tight as they are."""
+    from mmlspark_tpu.ops import pallas_attention
+
+    bq, bk = pallas_attention._fwd_tiles(*blocks, length, width)
+    assert length % bq == 0 and bq % bk == 0
+    if length == 2048:
+        assert length // bq >= 2 and bq > pallas_attention.BLOCK_Q
+    else:
+        assert (bq, bk) == (768, 256)
+    q, k, v, _ = _flash_qkvw((2, length, 2, width), dtype, seed=width + 1)
+    got = pallas_attention.flash_attention(q, k, v, causal, *blocks)
+    assert got.dtype == dtype and got.shape == q.shape
+    # the plain softmax in float32, on the inputs as they are rounded
+    want = full_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                          causal, use_flash="never")
+    assert _rel(got, want) <= tol
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_forward_multiplies_in_the_input_dtype(dtype):
+    """Read from the kernel's jaxpr: both products of a tile take their
+    operands in the input dtype and accumulate in float32, in the loop
+    without a mask and in each tile the diagonal crosses. The test that
+    fails if an upcast of q, k, v or the probabilities comes back."""
+    from mmlspark_tpu.ops import pallas_attention
+
+    def dots(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from dots(sub)
+
+    q, k, v, _ = _flash_qkvw((1, 1024, 1, 64), dtype, seed=2)
+    closed = jax.make_jaxpr(lambda *a: pallas_attention._flash_forward(
+        *a, causal=True, save_lse=True))(q, k, v)
+    found = [(tuple(x.aval.dtype for x in eqn.invars), eqn.outvars[0].aval)
+             for eqn in dots(closed.jaxpr)
+             if eqn.outvars[0].aval.ndim == 2]
+    # the loop's tile and the crossed ones, two products each; the
+    # log-sum-exps' unpacking outside the kernel multiplies nothing
+    bq, bk = pallas_attention._fwd_tiles(256, 256, 1024, 64)
+    assert len(found) == 2 * (1 + bq // bk)
+    for operands, out in found:
+        assert operands == (jnp.dtype(dtype), jnp.dtype(dtype))
+        assert out.dtype == jnp.float32
+
+
 def test_flash_attention_support_gate():
     """Ragged lengths (ViT's 197 tokens) and short sequences are not the
     flash kernel's: ``full_attention`` asks ``supports_short`` next
@@ -314,32 +380,78 @@ def test_flash_attention_grads_with_unequal_blocks(blocks):
         assert _rel(g, r) <= 2e-5
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
-@pytest.mark.parametrize("width", [16, 64, 256, 512])
-def test_flash_forward_saves_the_rows_logsumexp(width, causal, dtype):
-    """What ``_flash_fwd_rule`` keeps for the backward: float32, and exact
-    in bf16 too (three addends carry each through the bf16 output), for
-    head widths that take several lines a query block, one line, and half
-    of one."""
-    from mmlspark_tpu.ops import pallas_attention
-
-    q, k, v, _ = _flash_qkvw((2, 512, 3, width), dtype, seed=11)
-    out, lse = pallas_attention._flash_forward(q, k, v, causal,
-                                               save_lse=True)
-    assert lse.dtype == jnp.float32 and lse.shape == (2, 3, 2, 256)
+def _row_logsumexp(q, k, causal):
+    """float32 (B, H, L) from the plain scores."""
+    length, width = q.shape[1], q.shape[3]
     scores = jnp.einsum("blhd,bkhd->bhlk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) / np.sqrt(width)
     if causal:
-        scores = jnp.where(jnp.arange(512)[None, :]
-                           > jnp.arange(512)[:, None], -jnp.inf, scores)
-    want = jax.nn.logsumexp(scores, axis=-1)                  # (B, H, L)
-    np.testing.assert_allclose(np.asarray(lse).reshape(2, 3, 512),
-                               np.asarray(want), atol=2e-5, rtol=1e-6)
+        scores = jnp.where(jnp.arange(length)[None, :]
+                           > jnp.arange(length)[:, None], -jnp.inf, scores)
+    return jax.nn.logsumexp(scores, axis=-1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("length", [512, 1024])
+@pytest.mark.parametrize("width", [16, 64, 256, 512])
+def test_flash_forward_saves_the_rows_logsumexp(width, length, causal,
+                                                dtype):
+    """What ``_flash_fwd_rule`` keeps for the backward: float32, by the
+    CALLER's rows of 256 whatever tile the forward ran, and exact in bf16
+    too (three addends carry each through the bf16 output), for head
+    widths that take several lines a query block, one line, and half of
+    one."""
+    from mmlspark_tpu.ops import pallas_attention
+
+    q, k, v, _ = _flash_qkvw((2, length, 3, width), dtype, seed=11)
+    out, lse = pallas_attention._flash_forward(q, k, v, causal,
+                                               save_lse=True)
+    assert lse.dtype == jnp.float32
+    assert lse.shape == (2, 3, length // 256, 256)
+    np.testing.assert_allclose(np.asarray(lse).reshape(2, 3, length),
+                               np.asarray(_row_logsumexp(q, k, causal)),
+                               atol=2e-5, rtol=1e-6)
     np.testing.assert_array_equal(
         np.asarray(out, np.float32), np.asarray(
             pallas_attention.flash_attention(q, k, v, causal), np.float32))
+
+
+# (L, head width) of the one flash call each language-model cell of the
+# benchmark makes a block: glm-4.7-flash and qwen3-next, granite-4.0-h and
+# lfm2, olmo-hybrid (benchmark/configs/*.json with their traffic files)
+_CELL_CALLS = [(4096, 256), (8192, 64), (8192, 128)]
+
+
+@pytest.mark.parametrize("length,width", _CELL_CALLS,
+                         ids=[f"L{n}-d{d}" for n, d in _CELL_CALLS])
+def test_flash_forward_tiles_from_the_shape(length, width, monkeypatch):
+    """The tile rule at the shapes the five configurations run: tiles that
+    divide the length and are no smaller than the caller's, a VMEM count
+    inside what the call asks of Mosaic, and the log-sum-exps the
+    caller's own 256 x 256 would have given, in the same rows."""
+    from mmlspark_tpu.ops import pallas_attention as pa
+
+    dtype = jnp.bfloat16
+    bq, bk = pa._fwd_tiles(pa.BLOCK_Q, pa.BLOCK_K, length, width)
+    assert bq >= pa.BLOCK_Q and bk >= pa.BLOCK_K
+    assert length % bq == 0 and bq % bk == 0
+    rows = bq + pa._lse_rows(bq, width, dtype)[1]
+    need = pa._flash_fwd_vmem_bytes(length, width, bq, bk, rows, 2)
+    assert need <= pa._vmem_limit(need) <= pa._VMEM_CAP
+
+    q, k, v, _ = _flash_qkvw((1, length, 1, width), dtype, seed=length)
+    _, lse = pa._flash_forward(q, k, v, True, save_lse=True)
+    assert lse.shape == (1, 1, length // 256, 256)
+    monkeypatch.setattr(pa, "_fwd_tiles", lambda bq, bk, *_: (bq, bk))
+    _, as_called = pa._flash_forward.__wrapped__(q, k, v, True,
+                                                 save_lse=True)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(as_called),
+                               atol=2e-5, rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(lse).reshape(1, 1, length),
+        np.asarray(_row_logsumexp(q, k, True)), atol=2e-5, rtol=1e-6)
 
 
 def test_flash_backward_counts_its_traces():

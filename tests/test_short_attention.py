@@ -302,7 +302,11 @@ def _benchmark_pattern(metric):
     ((1, 8192, 8, 64), jnp.bfloat16),       # chip_smoke, the longctx lane
     ((1, 16384, 2, 64), jnp.float32),       # the edge of supports()
     ((1, 512, 2, 8), jnp.float32),          # its narrowest head
-], ids=["d256-bf16", "d64-bf16", "d64-f32-edge", "d8-f32"])
+    ((4, 8192, 32, 64), jnp.bfloat16),      # lfm2-24b-a2b-train-ep8share-8k
+    ((1, 8192, 30, 128), jnp.bfloat16),     # olmo-hybrid-7b-train-8k: AT
+                                            # the cap, L x d = 2^20
+], ids=["d256-bf16", "d64-bf16", "d64-f32-edge", "d8-f32", "lfm2-d64-bf16",
+        "olmo-d128-bf16"])
 def test_mosaic_compiles_the_flash_kernel_and_its_backward(
         topo, as_on_chip, shape, dtype):
     """The flash kernel forward and backward through Mosaic for a v5e, and
