@@ -1,0 +1,471 @@
+"""The parts the decoder families are made of (``models/zoo/decoder.py``
+holds the block, the one ``Decoder`` skeleton and the registry entries that
+say which part sits at which layer). A part is a flax module ``x -> y`` on
+``(B, L, dim)`` rows that owns its projections and its positions; it knows
+``ops/`` and ``parallel/sequence`` and nothing of a family:
+
+- ``RMSNorm``: float32 in and out, epsilon from the configuration; with
+  ``offset`` the scale is ``1 + w`` and ``w`` starts at zero;
+- ``rotary``: rotary positions on the last axis, half-split pairing
+  (dimension ``i`` turns with ``i + R/2``), float32 angles; with ``width``
+  on the first ``width`` dimensions only, the rest passing through;
+- ``MlaAttention``: multi-head latent attention in its expanded (training)
+  form: a low-rank query, one compressed key/value row per token, a rotary
+  slice on every query head and ONE rotary key shared by all heads;
+- ``GatedAttention``: softmax attention over grouped key/value heads (each
+  repeated to the query heads it serves at the attention call, so the
+  flash kernel and its backward run as they are), norms on q and k, a
+  rotary slice, and a sigmoid gate on the output;
+- ``GroupedAttention``: the same grouped heads with nothing else: no
+  positions, no gate, a softmax scale of its own, and if asked an RMS norm
+  over the whole q and the whole k projection, or (``norm_heads``) over
+  each head's channels, and (``theta``) rotary positions on the whole head;
+- ``ShortConv``: LFM2's gated short convolution, which IS the mixer:
+  ``[B | C | x] = u W_in``, a causal depthwise convolution of three taps
+  over ``B * x`` with no activation, the gate ``C`` on its output;
+- ``GatedDeltaNet``: linear attention with a recurrent state
+  (``ops/linear_attention.py``): a short causal convolution, the gated
+  delta rule with ``beta`` in (0, ``beta_scale``), a gated norm on the
+  output;
+- ``Mamba2Mixer``: the state-space layer (the same module's ``ssd``): a
+  convolution with a bias over ``[x | B | C]``, a scalar decay a head,
+  ``B`` and ``C`` shared by groups of heads, a skip, the gate BEFORE the
+  norm;
+- ``SwiGluMlp``: ``down(silu(gate x) * up x)``, no biases;
+- ``Head``: the untied output matrix;
+- (``zoo/moe.DroplessMoe``, the routed layer, lives in its own module.)
+
+Parameter names hit the rules of ``parallel/sharding.DEFAULT_RULES``
+(``attn_query*`` / ``attn_key*`` / ``attn_value`` / ``attn_qkvz`` /
+``attn_gate_value_key_query_dt`` / ``attn_in`` / ``attn_out``, ``mlp_gate``
+/ ``mlp_up`` / ``mlp_down``, ``lm_head``). The three checkpoint names are
+the values a recomputed block may keep (``decoder._remat_block``); each sits
+where its value is made.
+"""
+from __future__ import annotations
+
+from typing import Any, Callable, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from mmlspark_tpu.observability import metrics as obsmetrics
+from mmlspark_tpu.parallel.sequence import full_attention
+
+_INIT = nn.initializers.normal(0.02)
+# the checkpoint name of ``SwiGluMlp``'s gate and up products
+MLP_GATE_UP = "mlp_gate_up"
+# and of ``GatedDeltaNet``'s input projection's output
+DELTA_NET_QKVZ = "delta_net_qkvz"
+# and of ``ShortConv``'s
+SHORT_CONV_IN = "short_conv_in"
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+    offset: bool = False        # scale = 1 + w, w zero at init
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.offset
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.offset:
+            scale = 1.0 + scale
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+
+
+def rotary(x: jax.Array, theta: float,
+           width: Optional[int] = None) -> jax.Array:
+    """Rotary positions over the last axis of ``(B, L, H, R)``: position
+    ``l`` turns the pair ``(i, i + R/2)`` by ``l * theta**(-2i/R)``. With
+    ``width`` only the first ``width`` dimensions turn (pairs ``(i, i +
+    width/2)``, angles over ``width``) and the rest pass through."""
+    if width is not None and width != x.shape[-1]:
+        return jnp.concatenate(
+            [rotary(x[..., :width], theta), x[..., width:]], -1)
+    L, R = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
+    ang = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def _dense(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    kernel_init=_INIT, name=name)
+
+
+class MlaAttention(nn.Module):
+    """Multi-head latent attention, expanded form. Query/key heads are
+    ``nope + rope`` wide and value heads ``v_dim``; the attention call is
+    the framework's ``(q, k, v, causal)`` on ``(B, L, H, D)``, so the two
+    have to be equally wide (they are, 256, in the published model)."""
+    dim: int
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v_dim: int
+    theta: float = 1e6
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        if self.nope + self.rope != self.v_dim:
+            raise ValueError(
+                f"query/key heads are {self.nope + self.rope} wide and "
+                f"value heads {self.v_dim}: attention_fn(q, k, v) takes "
+                "one head width")
+        B, L, _ = x.shape
+        H, dt = self.heads, self.dtype
+        attn_fn = self.attention_fn or full_attention
+        with jax.named_scope("mla_attention"):
+            x = x.astype(dt)
+            cq = RMSNorm(self.eps, name="query_norm")(
+                _dense(self.q_rank, dt, "attn_query_a")(x)).astype(dt)
+            q = _dense(H * (self.nope + self.rope), dt, "attn_query_b")(
+                cq).reshape(B, L, H, self.nope + self.rope)
+            kva = _dense(self.kv_rank + self.rope, dt, "attn_key_value_a")(x)
+            ckv = RMSNorm(self.eps, name="key_value_norm")(
+                kva[..., :self.kv_rank]).astype(dt)
+            kv = _dense(H * (self.nope + self.v_dim), dt,
+                        "attn_key_value_b")(ckv).reshape(
+                            B, L, H, self.nope + self.v_dim)
+            q_r = rotary(q[..., self.nope:], self.theta)
+            # the one rotary key, shared by every head
+            k_r = rotary(kva[..., None, self.kv_rank:], self.theta)
+            q = jnp.concatenate([q[..., :self.nope], q_r], -1)
+            k = jnp.concatenate(
+                [kv[..., :self.nope],
+                 jnp.broadcast_to(k_r, (B, L, H, self.rope))], -1)
+            o = attn_fn(q, k, kv[..., self.nope:], causal=True)
+            return _dense(self.dim, dt, "attn_out")(
+                o.reshape(B, L, H * self.v_dim))
+
+
+class GatedAttention(nn.Module):
+    """Softmax attention with grouped key/value heads, a norm on every q
+    and k head, rotary positions on the first ``rotary_width`` of each
+    head, and a sigmoid gate on the output: ``[q | gate] = x W_q`` (halves
+    per head), ``o <- o * sigmoid(gate)``, ``y = o W_o``; no biases.
+
+    Each key/value head is repeated to the ``heads / kv_heads`` query
+    heads it serves where ``attention_fn(q, k, v)`` is called, so the
+    fused kernels take it as any equal-headed call; the repeated K/V
+    traffic is the price (a kernel that reads ``kv_heads`` heads for
+    ``heads`` is not there yet)."""
+    dim: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rotary_width: int
+    theta: float = 1e7
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+
+    @nn.compact
+    def __call__(self, x):
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads over "
+                             f"{self.kv_heads} key/value heads")
+        B, L, _ = x.shape
+        H, G, d, dt = self.heads, self.kv_heads, self.head_dim, self.dtype
+        attn_fn = self.attention_fn or full_attention
+        with jax.named_scope("gated_attention"):
+            x = x.astype(dt)
+            qg = _dense(H * 2 * d, dt, "attn_query_gate")(x).reshape(
+                B, L, H, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:]
+            k = _dense(G * d, dt, "attn_key")(x).reshape(B, L, G, d)
+            v = _dense(G * d, dt, "attn_value")(x).reshape(B, L, G, d)
+            q = RMSNorm(self.eps, offset=True, name="query_norm")(q)
+            k = RMSNorm(self.eps, offset=True, name="key_norm")(k)
+            q = rotary(q, self.theta, self.rotary_width).astype(dt)
+            k = rotary(k, self.theta, self.rotary_width).astype(dt)
+            k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
+            o = attn_fn(q, k, v, causal=True)
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
+            return _dense(self.dim, dt, "attn_out")(o.reshape(B, L, H * d))
+
+
+class GatedDeltaNet(nn.Module):
+    """Gated DeltaNet (arXiv:2412.06464) in flash-linear-attention's
+    layout, which serves two published ones: Qwen3-Next's (16 key heads
+    under 32 value heads, 128 x 128 a head, ``beta`` in (0, 1)) and
+    Olmo-Hybrid's (as many key as value heads, 96 x 192 a head,
+    ``beta_scale`` 2: ``linear_allow_neg_eigval``, a token's transition
+    ``I - beta k k^T`` then has its eigenvalue ``1 - beta`` in (-1, 1)).
+    ``[q | k | v | z] = x W_qkvz`` and ``[b | a] = x W_ba``; ``[q | k | v]``
+    pass a causal depthwise convolution and ``silu``; ``beta = beta_scale
+    sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)`` in float32; q
+    and k are L2-normalised over the head, each key head serves
+    ``value_heads / key_heads`` value heads; the gated delta rule
+    (``ops/linear_attention.gated_delta_rule``: chunked on whole rows);
+    ``o <- rmsnorm(o) * w_n * silu(z)`` over each head; ``y = o W_o``.
+    Columns of ``W_qkvz`` are ``[q | k | v | z]``, head-major inside each
+    (a checkpoint's per-key-head interleaving, or its four separate
+    matrices, are a permutation of them)."""
+    dim: int
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int = 4
+    eps: float = 1e-6
+    chunk: int = 64
+    dtype: Any = jnp.bfloat16
+    beta_scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, x):
+        from mmlspark_tpu.ops import linear_attention as la
+        if self.value_heads % self.key_heads:
+            raise ValueError(f"{self.value_heads} value heads over "
+                             f"{self.key_heads} key heads")
+        B, L, _ = x.shape
+        Hk, Hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
+                          self.value_dim)
+        dt, f32 = self.dtype, jnp.float32
+        with jax.named_scope("gated_delta_net"):
+            x = x.astype(dt)
+            qkvz = checkpoint_name(_dense(
+                2 * Hk * dk + 2 * Hv * dv, dt, "attn_qkvz")(x),
+                DELTA_NET_QKVZ)
+            ba = _dense(2 * Hv, dt, "attn_ba")(x).astype(f32)
+            conv = self.param("conv_kernel", _INIT,
+                              (self.conv_width, 2 * Hk * dk + Hv * dv), f32)
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, f32, 1e-3, 16.0)), (Hv,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,), f32)
+            with jax.named_scope("gdn_conv"):
+                mixed = nn.silu(la.causal_conv1d(
+                    qkvz[..., :2 * Hk * dk + Hv * dv], conv))
+            z = qkvz[..., 2 * Hk * dk + Hv * dv:].reshape(B, L, Hv, dv)
+            q = mixed[..., :Hk * dk].reshape(B, L, Hk, dk)
+            k = mixed[..., Hk * dk:2 * Hk * dk].reshape(B, L, Hk, dk)
+            v = mixed[..., 2 * Hk * dk:].reshape(B, L, Hv, dv)
+            beta = jax.nn.sigmoid(ba[..., :Hv])
+            if self.beta_scale != 1.0:
+                beta = self.beta_scale * beta
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., Hv:] + dt_bias)
+            o = la.gated_delta_rule(
+                la.l2_normalize(q), la.l2_normalize(k), v, g, beta,
+                chunk=self.chunk, dtype=dt)
+            o = RMSNorm(self.eps, name="gate_norm")(o) \
+                * nn.silu(z.astype(f32))
+            return _dense(self.dim, dt, "attn_out")(
+                o.astype(dt).reshape(B, L, Hv * dv))
+
+
+class GroupedAttention(nn.Module):
+    """Causal softmax attention with grouped key/value heads and little
+    else: no gate, no biases; ``softmax(scale x q k^T) v`` with a
+    published ``scale`` that need not be ``head_dim ** -0.5``. Without
+    ``theta`` no positions (in ``granite_hybrid`` and ``olmo_hybrid`` the
+    recurrent layers carry the order); with it rotary positions on the
+    whole head of q and k (``lfm2_moe``). With ``qk_norm_eps`` an RMS norm
+    with a plain scale on q and on k (scope ``qk_norm``): over the WHOLE
+    projection before the split into heads (OLMo 2's ``q_norm`` /
+    ``k_norm``), or with ``norm_heads`` over EACH head's ``head_dim``
+    channels, one scale of ``head_dim`` shared by the heads (LFM2's
+    ``q_layernorm`` / ``k_layernorm``), float32 through the rotation;
+    without, none. LFM2's softmax layer is this part with two arguments
+    and not a third part: ``GatedAttention`` would need its ``1 + w``
+    scales, its rotary slice and its gate (which shapes ``W_q``) argued
+    away.
+    ``attention_fn(q, k, v)`` keeps its own ``head_dim ** -0.5``, so ``q``
+    is multiplied by ``scale x head_dim ** 0.5`` before the call (0.125 in
+    the published Granite: a power of two, exact in bfloat16). Each
+    key/value head is repeated to the ``heads / kv_heads`` query heads it
+    serves at that call, as in ``GatedAttention``."""
+    dim: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    scale: Optional[float] = None       # None: head_dim ** -0.5
+    dtype: Any = jnp.bfloat16
+    attention_fn: Optional[Callable] = None
+    qk_norm_eps: Optional[float] = None     # None: no norm on q and k
+    norm_heads: bool = False            # the norm over each head, not all
+    theta: Optional[float] = None       # None: no positions
+
+    @nn.compact
+    def __call__(self, x):
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads over "
+                             f"{self.kv_heads} key/value heads")
+        B, L, _ = x.shape
+        H, G, d, dt = self.heads, self.kv_heads, self.head_dim, self.dtype
+        attn_fn = self.attention_fn or full_attention
+        with jax.named_scope("grouped_attention"):
+            x = x.astype(dt)
+
+            def heads_of(name, heads, norm=None):
+                y = _dense(heads * d, dt, name)(x)
+                normed = norm and self.qk_norm_eps is not None
+                if normed and not self.norm_heads:
+                    with jax.named_scope("qk_norm"):
+                        y = RMSNorm(self.qk_norm_eps, name=norm)(y).astype(dt)
+                y = y.reshape(B, L, heads, d)
+                if normed and self.norm_heads:
+                    with jax.named_scope("qk_norm"):
+                        y = RMSNorm(self.qk_norm_eps, name=norm)(y)
+                if norm and self.theta is not None:
+                    y = rotary(y, self.theta)
+                return y.astype(dt)
+            q = heads_of("attn_query", H, "query_norm")
+            k = heads_of("attn_key", G, "key_norm")
+            v = heads_of("attn_value", G)
+            if self.scale is not None:
+                q = q * jnp.asarray(self.scale * d ** 0.5, dt)
+            k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
+            o = attn_fn(q, k, v, causal=True)
+            return _dense(self.dim, dt, "attn_out")(o.reshape(B, L, H * d))
+
+
+class ShortConv(nn.Module):
+    """LFM2's gated short convolution (``Lfm2ShortConv``; the ``conv``
+    entries of ``lfm2_moe``'s ``layer_types``), which is the whole mixer
+    and feeds no recurrence: ``[B | C | x] = u W_in`` (``dim -> 3 dim``);
+    ``z = B * x``; ``c_t = sum_j k_j z_{t - (taps-1) + j}``, depthwise and
+    causal with zeros before a row's start, NO activation
+    (``ops/linear_attention.causal_conv1d`` as it is); ``y = (C * c)
+    W_out``. No biases in the published model; ``bias`` adds the
+    convolution's. Scope ``short_conv``, and inside it ``gate_conv`` for
+    everything between the two projections (the split, both gates, the
+    taps): memory-bound, and what ``shortconv.gate_conv_roofline`` reads.
+    Columns of ``W_in`` are ``[B | C | x]``, each ``dim`` wide."""
+    dim: int
+    taps: int = 3
+    bias: bool = False
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from mmlspark_tpu.ops import linear_attention as la
+        dt = self.dtype
+        obsmetrics.counter("short_conv.calls").inc()
+        with jax.named_scope("short_conv"):
+            bcx = checkpoint_name(
+                _dense(3 * self.dim, dt, "attn_in")(u.astype(dt)),
+                SHORT_CONV_IN)
+            kernel = self.param("conv_kernel", _INIT, (self.taps, self.dim),
+                                jnp.float32)
+            conv_bias = self.param("conv_bias", _INIT, (self.dim,),
+                                   jnp.float32) if self.bias else None
+            with jax.named_scope("gate_conv"):
+                b, c, x = jnp.split(bcx, 3, axis=-1)
+                y = c * la.causal_conv1d(b * x, kernel, conv_bias)
+            return _dense(self.dim, dt, "attn_out")(y)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias`` such that ``softplus(dt_bias) = exp(U(log 1e-3, log
+    1e-1))`` floored at 1e-4: Mamba-2's own initialiser."""
+    dt = jnp.maximum(1e-4, jnp.exp(jax.random.uniform(
+        key, shape, dtype, jnp.log(1e-3), jnp.log(1e-1))))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 (arXiv:2405.21060) as ``granite_hybrid`` lays it out: ``[z |
+    xBC | dt] = u W_in``; ``xBC <- silu(conv(xBC) + b)`` (causal,
+    depthwise); ``[x | B | C] = xBC`` with ``x`` on ``heads`` heads of
+    ``head_dim`` and ``B``, ``C`` on ``groups`` groups of ``state``, head
+    ``h`` reading group ``h // (heads / groups)``; ``dt = softplus(dt +
+    dt_bias)`` (no clamp) and ``A = -exp(A_log)`` a head, float32; the
+    state-space rule (``ops/linear_attention.ssd``: chunked on whole rows)
+    plus the skip ``D x``; ``y <- rmsnorm(y * silu(z)) * w_n``, the gate
+    first and the mean square over all ``heads x head_dim`` channels; ``out
+    = y W_out``. No biases but the convolution's. Columns of ``W_in`` are
+    ``[z | x | B | C | dt]``, head-major inside each."""
+    dim: int
+    heads: int
+    head_dim: int
+    state: int
+    groups: int = 1
+    conv_width: int = 4
+    eps: float = 1e-5
+    chunk: int = 256
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        from mmlspark_tpu.ops import linear_attention as la
+        if self.heads % self.groups:
+            raise ValueError(f"{self.heads} heads over {self.groups} groups")
+        B, L, _ = u.shape
+        H, P, N, G = self.heads, self.head_dim, self.state, self.groups
+        d_in, mixed, dt_, f32 = H * P, H * P + 2 * G * N, self.dtype, \
+            jnp.float32
+        with jax.named_scope("mamba2_mixer"):
+            zxbcdt = _dense(d_in + mixed + H, dt_,
+                            "attn_gate_value_key_query_dt")(u.astype(dt_))
+            conv = self.param("conv_kernel", _INIT,
+                              (self.conv_width, mixed), f32)
+            conv_bias = self.param("conv_bias", _INIT, (mixed,), f32)
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, f32, 1.0, 16.0)), (H,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (H,), f32)
+            skip = self.param("D_skip", nn.initializers.ones, (H,), f32)
+            z = zxbcdt[..., :d_in]
+            with jax.named_scope("ssm_conv"):
+                xbc = nn.silu(la.causal_conv1d(
+                    zxbcdt[..., d_in:d_in + mixed], conv, conv_bias))
+            x = xbc[..., :d_in].reshape(B, L, H, P)
+            Bm = xbc[..., d_in:d_in + G * N].reshape(B, L, G, N)
+            Cm = xbc[..., d_in + G * N:].reshape(B, L, G, N)
+            dt = jax.nn.softplus(
+                zxbcdt[..., d_in + mixed:].astype(f32) + dt_bias)
+            y = la.ssd(x, dt, -jnp.exp(a_log), Bm, Cm, chunk=self.chunk,
+                       dtype=dt_)
+            y = y + skip[:, None] * x.astype(f32)
+            y = y.reshape(B, L, d_in) * nn.silu(z.astype(f32))
+            y = RMSNorm(self.eps, name="gate_norm")(y)
+            return _dense(self.dim, dt_, "attn_out")(y.astype(dt_))
+
+
+class SwiGluMlp(nn.Module):
+    dim: int
+    hidden: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        # the scope names the dense feed-forward part wherever it runs (a
+        # block's own, a routed layer's shared expert) for the split of
+        # device time by part (``observability/scopes.py``)
+        with jax.named_scope("ffn"):
+            x = x.astype(self.dtype)
+            gate = checkpoint_name(
+                _dense(self.hidden, self.dtype, "mlp_gate")(x), MLP_GATE_UP)
+            up = checkpoint_name(
+                _dense(self.hidden, self.dtype, "mlp_up")(x), MLP_GATE_UP)
+            return _dense(self.dim, self.dtype, "mlp_down")(
+                nn.silu(gate) * up)
+
+
+class Head(nn.Module):
+    """The untied output head; the chunked loss reads ``kernel`` itself
+    (``train/lm_loss.py``) and never calls this on a whole batch."""
+    vocab: int
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param("kernel", _INIT, (x.shape[-1], self.vocab),
+                            jnp.float32)
+        return jnp.dot(x.astype(jnp.float32), kernel)

@@ -216,7 +216,7 @@ def test_lane_serves_an_unseen_block_without_an_edit():
     model is this test's own, not the zoo's."""
     import jax.numpy as jnp
     from mmlspark_tpu.models import zoo
-    from mmlspark_tpu.models.zoo.decoder import SwiGluMlp
+    from mmlspark_tpu.models.zoo.parts import SwiGluMlp
     from mmlspark_tpu.models.zoo.transformer import (
         DecoderBlock, TransformerLM,
     )

@@ -1,0 +1,142 @@
+"""The decoder families' programs against what a named commit gave: the
+check that a change to ``models/zoo/decoder.py`` or ``parts.py`` which is
+meant to move no family's arithmetic has moved none.
+
+Two files under ``tests/data/`` hold, for each of the five tiny presets,
+what THIS module computed on commit 79fac30 (PR 42; float32, the CPU
+backend, every function under ``jax.jit``):
+
+- ``decoder_parent_outputs.npz``: the logits; the ``hidden=True`` outputs
+  the chunked loss reads (``hidden``, GLM's ``mtp_hidden``, every key of
+  ``stats``); for the routed families the gradient of ``mean(logits^2)``
+  at ``block1/ffn``'s router and gate bank;
+- ``decoder_parent_programs.json``: the sha256 of the lowered text of the
+  gradient of ``next_token_loss`` through ``hidden=True``. The text
+  carries no debug information, so neither a class name nor a line number
+  is in it: it moves when an operation, a shape or their order moves.
+
+A PR that changes a family's program on purpose remakes both with
+
+    JAX_PLATFORMS=cpu python tests/test_decoder_programs.py --write
+
+(without ``--write`` it prints the five hashes and writes nothing), names
+its own commit here, and its diff of the JSON then shows which families it
+touched and which it did not.
+"""
+import hashlib
+import json
+import sys
+from pathlib import Path
+
+if __name__ == "__main__":
+    import conftest  # noqa: F401  (the tests' backend, before jax starts)
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+from mmlspark_tpu.models.zoo import build_model  # noqa: E402
+from mmlspark_tpu.train.lm_loss import next_token_loss  # noqa: E402
+
+PRESETS = ("glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
+           "olmo_hybrid_tiny", "lfm2_moe_tiny")
+DATA = Path(__file__).resolve().parent / "data"
+OUTPUTS = DATA / "decoder_parent_outputs.npz"
+PROGRAMS = DATA / "decoder_parent_programs.json"
+
+
+def _built(preset):
+    """The preset's module, its seeded parameters and two rows of tokens."""
+    module = build_model(preset)["module"]
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, 96, size=(2, 24)).astype(np.int32))
+    return module, jax.jit(module.init)(jax.random.PRNGKey(3), tokens), tokens
+
+
+def _head_kernel(params):
+    """``(dim, vocab)``: the untied head's, or the one table transposed."""
+    p = params["params"]
+    return p["lm_head"]["kernel"] if "lm_head" in p \
+        else p["token_embedding"]["embedding"].T
+
+
+def _outputs(module, params, tokens):
+    """What the ``.npz`` holds of one preset, by key."""
+    @jax.jit
+    def run(params):
+        out = module.apply(params, tokens, hidden=True)
+        got = {"logits": module.apply(params, tokens),
+               **{k: v for k, v in out.items() if k != "stats"},
+               **{f"stats.{k}": v for k, v in out["stats"].items()}}
+        if "router" in params["params"]["block1"]["ffn"]:
+            g = jax.grad(lambda p: jnp.mean(jnp.square(module.apply(
+                p, tokens))))(params)["params"]["block1"]["ffn"]
+            got.update(router=g["router"]["kernel"],
+                       experts_gate=g["experts_gate"])
+        return got
+    return {k: np.asarray(v) for k, v in run(params).items()}
+
+
+def _program(module, params, tokens):
+    """sha256 of the lowered loss gradient: traced, never compiled."""
+    def loss(params, tokens):
+        out = module.apply(params, tokens, hidden=True)
+        return next_token_loss(out, _head_kernel(params), tokens, chunk=16,
+                               dtype=jnp.float32)[0]
+    text = jax.jit(jax.grad(loss)).lower(params, tokens).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.fixture(scope="module", params=PRESETS)
+def built(request):
+    return (request.param,) + _built(request.param)
+
+
+def test_the_families_that_share_parts_give_their_parents_outputs(built):
+    """Every family's arithmetic is commit 79fac30's BIT FOR BIT (the
+    module's docstring: what the file holds and the command that remakes
+    it). The parameters come from the module's own ``init``, so the tree's
+    paths and the order of its draws are held too."""
+    preset, module, params, tokens = built
+    golden = np.load(OUTPUTS)
+    want = {k.split(".", 1)[1]: golden[k] for k in golden.files
+            if k.startswith(preset + ".")}
+    got = _outputs(module, params, tokens)
+    assert set(got) == set(want) and "hidden" in got
+    for k in sorted(got):
+        assert got[k].dtype == want[k].dtype, k
+        assert np.array_equal(got[k], want[k]), k
+
+
+def test_the_loss_gradient_lowers_to_its_parents_program(built):
+    """The step the cells run (the chunked loss over ``hidden=True``, its
+    gradient) lowers to the text commit 79fac30 gave, operation for
+    operation."""
+    preset, module, params, tokens = built
+    with open(PROGRAMS) as f:
+        want = json.load(f)
+    assert set(want) == set(PRESETS)
+    assert _program(module, params, tokens) == want[preset], preset
+
+
+def main(write: bool) -> None:
+    outputs, programs = {}, {}
+    for preset in PRESETS:
+        built = _built(preset)
+        programs[preset] = _program(*built)
+        print(preset, programs[preset], flush=True)
+        outputs.update({f"{preset}.{k}": v
+                        for k, v in _outputs(*built).items()})
+    if write:
+        np.savez_compressed(OUTPUTS, **outputs)
+        with open(PROGRAMS, "w") as f:
+            json.dump(programs, f, indent=1)
+            f.write("\n")
+        print("wrote", OUTPUTS.name, sorted(outputs), "and", PROGRAMS.name)
+
+
+if __name__ == "__main__":
+    main("--write" in sys.argv[1:])

@@ -26,8 +26,8 @@ from jax.experimental import pallas as pl
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.references import qwen3_next as ref  # noqa: E402
-from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
-    GatedDeltaNet, Qwen3Next)
+from mmlspark_tpu.models.zoo import build_model  # noqa: E402
+from mmlspark_tpu.models.zoo.parts import GatedDeltaNet  # noqa: E402
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
 from mmlspark_tpu.ops import pallas_delta_rule as pdr  # noqa: E402
@@ -242,12 +242,12 @@ def _block_grads(jaxpr=False):
     blocks and a softmax one) at head width 128 and chunk 64, where the
     rule takes the Pallas calls, under ``nn.remat`` as it stands when
     called, or their jaxpr."""
-    module = Qwen3Next(
-        vocab=48, dim=32, depth=_DELTA_LAYERS + 1, heads=2, kv_heads=1,
-        head_dim=16, rotary_width=4, linear_key_heads=1,
+    module = build_model(
+        "qwen3_next", vocab=48, dim=32, depth=_DELTA_LAYERS + 1, heads=2,
+        kv_heads=1, head_dim=16, rotary_fraction=0.25, linear_key_heads=1,
         linear_value_heads=2, linear_key_dim=128, linear_value_dim=128,
         conv_width=4, expert_hidden=16, shared_hidden=16, num_experts=4,
-        top_k=2, chunk=64, dtype=jnp.float32)
+        top_k=2, chunk=64, dtype=jnp.float32)["module"]
     tokens = jnp.asarray(np.random.default_rng(5).integers(
         0, 48, size=(1, 128)).astype(np.int32))
     params = module.init(jax.random.PRNGKey(3), tokens)

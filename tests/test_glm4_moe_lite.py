@@ -24,7 +24,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.references import glm47_flash as ref  # noqa: E402
 from mmlspark_tpu.models.zoo import build_model  # noqa: E402
-from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
+from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
     MlaAttention, SwiGluMlp, rotary)
 from mmlspark_tpu.models.zoo import moe  # noqa: E402
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402

@@ -17,6 +17,7 @@ chunk stays under a few hundred; 1e-3 on the gradients where it reaches
 10^4 (float32 holds such a sum to 1e-3, and the decays are exponentials of
 its differences).
 """
+import inspect
 import json
 import sys
 from pathlib import Path
@@ -30,10 +31,12 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.references import granite_hybrid as ref  # noqa: E402
-from mmlspark_tpu.models.zoo import build_model  # noqa: E402
+from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
-    GRANITE_4_H_MICRO_LAYERS, GroupedAttention, Mamba2Mixer, PartsBlock,
-    RMSNorm, SplitBlock, SwiGluMlp)
+    GRANITE_4_H_MICRO_LAYERS, PartsBlock)
+from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
+    DELTA_NET_QKVZ, MLP_GATE_UP, SHORT_CONV_IN, GroupedAttention,
+    Mamba2Mixer, RMSNorm, SwiGluMlp)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
 from mmlspark_tpu.train.lm_loss import next_token_loss  # noqa: E402
@@ -50,6 +53,13 @@ OPT = dict(learning_rate=1e-2, beta1=0.9, beta2=0.95, eps=1e-8,
            weight_decay=0.1)
 ROWS, LEN = 2, 20               # two and a half chunks of 8
 REPO = Path(__file__).resolve().parent.parent
+
+
+def _defaults(name):
+    """The zoo entry's own defaults, by keyword: what a family is lives in
+    its entry, the module it builds holds parts."""
+    return {k: p.default for k, p in inspect.signature(
+        getattr(decoder, name)).parameters.items()}
 
 
 def _tokens(seed, steps=1):
@@ -269,7 +279,8 @@ def test_the_gate_comes_before_the_norm_by_hand():
 
 
 # ------------------------------------------------- the model as a whole
-def test_reference_tree_is_the_programs_tree_and_layer_types(params):
+def test_reference_tree_is_the_programs_tree_and_layer_types(monkeypatch,
+                                                             params):
     module = _module()
     own = module.init(jax.random.PRNGKey(0), jnp.zeros((1, LEN), jnp.int32))
     shapes = lambda t: jax.tree_util.tree_map(lambda x: x.shape, t)
@@ -292,14 +303,19 @@ def test_reference_tree_is_the_programs_tree_and_layer_types(params):
     assert GRANITE_4_H_MICRO_LAYERS.count("attention") == 4
     assert [i for i, k in enumerate(GRANITE_4_H_MICRO_LAYERS)
             if k == "attention"] == [5, 15, 25, 35]
-    whole = build_model("granite_hybrid")["module"]
-    assert whole.layer_types == GRANITE_4_H_MICRO_LAYERS
-    assert (whole.embedding_multiplier, whole.attention_multiplier,
-            whole.residual_multiplier, whole.logits_scaling) == (
+    whole, entry = build_model("granite_hybrid")["module"], _defaults(
+        "granite_hybrid")
+    assert entry["layer_types"] == GRANITE_4_H_MICRO_LAYERS
+    assert [i for i in range(40) if whole.mixers[i] is whole.mixers[5]] \
+        == [5, 15, 25, 35] and len(set(whole.ffns)) == 1
+    assert (whole.embedding_multiplier, entry["attention_multiplier"],
+            whole.residual_scale, whole.logits_scaling) == (
                 12.0, 0.015625, 0.22, 8.0)
-    with pytest.raises(ValueError):
-        build_model("granite_hybrid_tiny", layer_types=("mamba", "moe"))[
-            "module"].init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    assert whole.tied and whole.split and whole.mtp is None
+    with pytest.raises(ValueError, match="'mamba' or 'attention' a layer"):
+        build_model("granite_hybrid_tiny", layer_types=("mamba", "moe"))
+    with pytest.raises(ValueError, match="layer_types"):
+        build_model("granite_hybrid_tiny", layer_types=())
     with pytest.raises(ValueError):
         ref.dims(dict(CFG, num_hidden_layers=4))
     # the module's own init: Mamba-2's; the reference's, from its own draws
@@ -314,8 +330,11 @@ def test_reference_tree_is_the_programs_tree_and_layer_types(params):
     assert float(jnp.abs(params["params"]["block0"]["attn"][
         "conv_bias"]).max()) > 0
     # the tiny preset is this file's configuration
-    tiny = build_model("granite_hybrid_tiny")["module"]
-    assert tiny == _module()
+    seen = []
+    monkeypatch.setattr(decoder, "granite_hybrid", lambda **kw: seen.append(kw))
+    build_model("granite_hybrid_tiny")
+    assert [{**entry, **kw} for kw in seen] == [
+        {**entry, **ref.zoo_args(CFG, 64)}]
 
 
 def test_logits_match_the_reference_with_all_four_multipliers(params):
@@ -494,7 +513,6 @@ def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
     exception (the flash kernel's residuals alone, for want of a
     measurement of the room the 8,192-wide gate and up products take) went
     when the room was measured (PERF.md section 6, PR 36)."""
-    from mmlspark_tpu.models.zoo import decoder
     from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
     from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
     seen = []
@@ -508,8 +526,8 @@ def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
     build_model(preset)["module"].init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     assert seen and set(seen) == {
-        (FLASH_RESIDUALS, decoder.MLP_GATE_UP, DELTA_CHUNK_TILES,
-         decoder.DELTA_NET_QKVZ, decoder.SHORT_CONV_IN)}
+        (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES, DELTA_NET_QKVZ,
+         SHORT_CONV_IN)}
 
 
 # ------------------------------------------------------------ the parts
@@ -569,15 +587,18 @@ def test_mixer_layer_is_the_reference_layer(groups):
                                atol=1e-6)
 
 
-@pytest.mark.parametrize("block", [PartsBlock, SplitBlock])
-def test_a_block_with_a_residual_multiplier_is_its_two_equations(block):
+@pytest.mark.parametrize("split", [False, True], ids=["whole", "halves"])
+def test_a_block_with_a_residual_multiplier_is_its_two_equations(split):
     """``h = x + r mixer(norm1(x))``, ``y = h + r mlp(norm2(h))``, by hand
-    from the parts; at ``r`` = 1 the block is the one it was."""
+    from the parts, recomputed whole or in halves; at ``r`` = 1 the block
+    is the one it was."""
     def parts(r):
-        return block(lambda n: RMSNorm(1e-5, name=n),
-                     lambda n: GroupedAttention(16, 4, 2, 4, 0.3,
-                                                jnp.float32, name=n),
-                     lambda n: SwiGluMlp(16, 24, jnp.float32, name=n), r)
+        return decoder._remat_block(
+            lambda n: RMSNorm(1e-5, name=n),
+            lambda n: GroupedAttention(16, 4, 2, 4, 0.3, jnp.float32,
+                                       name=n),
+            lambda n: SwiGluMlp(16, 24, jnp.float32, name=n), None,
+            split=split, residual_scale=r)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, 16))
     p = parts(0.22).init(jax.random.PRNGKey(2), x)
     p = jax.tree_util.tree_map(lambda v: 4.0 * v, p)
@@ -593,7 +614,7 @@ def test_a_block_with_a_residual_multiplier_is_its_two_equations(block):
         want = h + r * sub(mlp, "ffn", sub(RMSNorm(1e-5), "norm2", h))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
         assert stats == {}
-    assert block.__dataclass_fields__["residual_scale"].default == 1.0
+    assert PartsBlock.__dataclass_fields__["residual_scale"].default == 1.0
 
 
 # -------------------------------------------- the benchmark's own counts
@@ -693,12 +714,12 @@ def test_configuration_holds_the_catalogued_numbers():
         traffic = json.load(f)
     assert (traffic["batch_per_chip"], traffic["tokens_per_row"]) == (1, 8192)
     # the zoo entry's defaults are the published numbers
-    whole = build_model("granite_hybrid")["module"]
+    entry = _defaults("granite_hybrid")
     uncut = dict(cfg, **{k: published[k] for k in cfg["reduced"]})
     args = ref.zoo_args(uncut, 8192)
     args.pop("max_len")
     for k, v in args.items():
-        assert getattr(whole, k) == v, k
+        assert entry[k] == v, k
     # attention's scale is NOT head^-1/2, and q's factor is a power of two
     assert cfg["attention_multiplier"] != 64 ** -0.5
     assert cfg["attention_multiplier"] * 64 ** 0.5 == 0.125

@@ -10,9 +10,10 @@ only by the order of additions (grouped products and a chunked loss against
 dense loops and whole logits): 1e-5 relative on logits and losses, 1e-4 on
 gradients, 2e-3 on the norm of three Adam steps (``g / (sqrt(v) + eps)``
 amplifies a relative gradient error where ``g`` is near zero). What has to
-be exact is exact: a frozen gate's zero gradient, and the parent's outputs
-of the families whose parts gained an argument.
+be exact is exact: a frozen gate's zero gradient (the families' outputs
+against a named commit's are ``tests/test_decoder_programs.py``'s).
 """
+import inspect
 import json
 import sys
 from pathlib import Path
@@ -26,9 +27,11 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.references import lfm2_moe as ref  # noqa: E402
-from mmlspark_tpu.models.zoo import build_model  # noqa: E402
+from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
-    LFM2_24B_A2B_LAYERS, GroupedAttention, ShortConv)
+    LFM2_24B_A2B_LAYERS)
+from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
+    SHORT_CONV_IN, GroupedAttention, ShortConv)
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.train.lm_loss import next_token_loss  # noqa: E402
@@ -48,6 +51,13 @@ OPT = dict(learning_rate=1e-2, beta1=0.9, beta2=0.95, eps=1e-8,
            weight_decay=0.1)
 ROWS, LEN = 2, 16
 REPO = Path(__file__).resolve().parent.parent
+
+
+def _defaults(name):
+    """The zoo entry's own defaults, by keyword: what a family is lives in
+    its entry, the module it builds holds parts."""
+    return {k: p.default for k, p in inspect.signature(
+        getattr(decoder, name)).parameters.items()}
 GATES = pytest.mark.parametrize("gate_grad", [False, True],
                                 ids=["frozen_gate", "trained_gate"])
 
@@ -214,10 +224,8 @@ def test_reference_tree_is_the_programs_tree_and_layer_kinds(params):
 
 
 def test_a_layer_type_of_another_name_raises():
-    bad = build_model("lfm2_moe_tiny", layer_types=(
-        "conv", "linear_attention"))["module"]
     with pytest.raises(ValueError, match="'conv' or 'full_attention'"):
-        bad.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+        build_model("lfm2_moe_tiny", layer_types=("conv", "linear_attention"))
     with pytest.raises(ValueError, match="layer_types"):
         ref.dims(dict(CFG, layer_types=["conv", "mamba", "conv", "conv"]))
 
@@ -329,7 +337,6 @@ def test_the_kept_projection_rows_change_no_gradient(monkeypatch, params,
     those of blocks that recompute nothing, and with the name kept a
     recomputed block multiplies by ``W_in`` no second time."""
     import flax.linen as nn
-    from mmlspark_tpu.models.zoo import decoder
     tokens = jnp.asarray(_tokens(6)[0])
 
     def grads():
@@ -340,7 +347,7 @@ def test_the_kept_projection_rows_change_no_gradient(monkeypatch, params,
         real = decoder._remat_block
         monkeypatch.setattr(
             decoder, "_remat_block", lambda *a, **kw: real(
-                *a, **dict(kw, let_go=(decoder.SHORT_CONV_IN,))))
+                *a, **dict(kw, let_go=(SHORT_CONV_IN,))))
     got = jax.jit(grads())(params)
     text = str(jax.make_jaxpr(grads())(params))
     # 3 conv layers: forward, backward's two products, and the
@@ -451,33 +458,6 @@ def test_weight_eps_is_in_the_denominator():
         large.apply(p, x)[0].reshape(8, 32),
         exact.apply(p, x)[0].reshape(8, 32) * (top / (top + 1.0))[:, None],
         rtol=1e-5, atol=1e-7)
-
-
-@pytest.mark.parametrize("preset", [
-    "glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
-    "olmo_hybrid_tiny"])
-def test_the_families_that_share_parts_give_their_parents_outputs(preset):
-    """``weight_eps=0``, ``gate_grad=True``, ``norm_heads=False`` and
-    ``theta=None`` are the defaults, and with them the four older
-    families' arithmetic is the parent commit's BIT FOR BIT:
-    ``tests/data/lfm2_parent_outputs.npz`` holds what this function gave
-    on commit b4d1e76 (PR 39), on this backend (float32, CPU): the logits,
-    and for the routed families the gradient of ``mean(logits^2)`` at the
-    second block's router and gate bank."""
-    golden = np.load(REPO / "tests" / "data" / "lfm2_parent_outputs.npz")
-    module = build_model(preset)["module"]
-    tokens = jnp.asarray(np.random.default_rng(5).integers(
-        0, 96, size=(2, 24)).astype(np.int32))
-    p = module.init(jax.random.PRNGKey(3), tokens)
-    assert np.array_equal(np.asarray(module.apply(p, tokens)),
-                          golden[f"{preset}.logits"])
-    if f"{preset}.router" in golden:
-        g = jax.grad(lambda p: jnp.mean(jnp.square(
-            module.apply(p, tokens))))(p)["params"]["block1"]["ffn"]
-        assert np.array_equal(np.asarray(g["router"]["kernel"]),
-                              golden[f"{preset}.router"])
-        assert np.array_equal(np.asarray(g["experts_gate"]),
-                              golden[f"{preset}.experts_gate"])
 
 
 # -------------------------------------------- the benchmark's own counts
@@ -599,16 +579,23 @@ def test_configuration_holds_the_catalogued_numbers():
             "recomputation", "fit"} <= set(cfg["assumed"])
     assert "no gradient" in cfg["assumed"]["gate_grad"].lower()
     # the zoo entry's defaults are the published numbers
-    whole = build_model("lfm2_moe")["module"]
+    whole, entry = build_model("lfm2_moe")["module"], _defaults("lfm2_moe")
     uncut = dict(cfg, **{k: published[k] for k in cfg["reduced"]},
                  program={"zoo": "lfm2_moe"})
     args = ref.zoo_args(uncut, 8192)
     args.pop("max_len")
-    assert args.pop("experts_held") == (64, 0) and whole.experts_held is None
+    routed = whole.ffns[-1](None)           # the routed layer as built
+    assert args.pop("experts_held") == (64, 0) \
+        and entry["experts_held"] is None and routed.experts_held is None
     for k, v in args.items():
-        assert getattr(whole, k) == v, k
-    assert whole.gate_grad is True
-    assert whole.head_dim * whole.heads == whole.dim
+        assert entry[k] == v, k
+    assert entry["gate_grad"] is True and routed.gate_grad is True
+    assert entry["head_dim"] * entry["heads"] == entry["dim"] == whole.dim
+    # the mixer AND the feed-forward part by the layer's index
+    assert [i for i in range(40) if whole.mixers[i] is whole.mixers[2]] \
+        == list(range(2, 40, 4))
+    assert [i for i in range(40) if whole.ffns[i] is whole.ffns[0]] == [0, 1]
+    assert whole.tied and not whole.split and whole.mtp is None
     # the cell, its traffic and its three metrics
     with open(REPO / "BENCHMARK.json") as f:
         bench = json.load(f)

@@ -19,6 +19,7 @@ inverse entries that grow with the chunk: the products are exact in
 algebra and larger in magnitude, ``tests/test_qwen3_next.py`` holds the
 same form to 2e-5 at ``beta`` under 1).
 """
+import inspect
 import itertools
 import json
 import sys
@@ -36,8 +37,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from benchmark.references import olmo_hybrid as ref  # noqa: E402
 from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
-    OLMO_HYBRID_7B_LAYERS, GatedDeltaNet, GroupedAttention, PartsBlock,
-    RMSNorm, SplitBlock, SwiGluMlp)
+    OLMO_HYBRID_7B_LAYERS, PartsBlock)
+from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
+    DELTA_NET_QKVZ, MLP_GATE_UP, SHORT_CONV_IN, GatedDeltaNet,
+    GroupedAttention, RMSNorm, SwiGluMlp)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
 from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS  # noqa: E402
@@ -57,6 +60,13 @@ OPT = dict(learning_rate=1e-2, beta1=0.9, beta2=0.95, eps=1e-8,
            weight_decay=0.1)
 ROWS, LEN = 2, 20               # two and a half chunks of 8
 REPO = Path(__file__).resolve().parent.parent
+
+
+def _defaults(name):
+    """The zoo entry's own defaults, by keyword: what a family is lives in
+    its entry, the module it builds holds parts."""
+    return {k: p.default for k, p in inspect.signature(
+        getattr(decoder, name)).parameters.items()}
 
 
 def _tokens(seed, steps=1):
@@ -228,16 +238,18 @@ def test_attention_with_a_norm_over_the_whole_projection_and_no_positions():
                                rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("block", [PartsBlock, SplitBlock])
-def test_a_block_that_norms_its_outputs_is_its_two_equations(block):
+@pytest.mark.parametrize("split", [False, True], ids=["whole", "halves"])
+def test_a_block_that_norms_its_outputs_is_its_two_equations(split):
     """``h = x + norm1(mixer(x))``, ``y = h + norm2(mlp(h))``, by hand from
-    the parts; without ``norm_output`` the block is the one it was."""
+    the parts, recomputed whole or in halves; without ``norm_output`` the
+    block is the one it was."""
     def parts(norm_output):
-        return block(lambda n: RMSNorm(1e-6, name=n),
-                     lambda n: GroupedAttention(16, 4, 4, 4, None,
-                                                jnp.float32, name=n),
-                     lambda n: SwiGluMlp(16, 24, jnp.float32, name=n), 1.0,
-                     norm_output)
+        return decoder._remat_block(
+            lambda n: RMSNorm(1e-6, name=n),
+            lambda n: GroupedAttention(16, 4, 4, 4, None, jnp.float32,
+                                       name=n),
+            lambda n: SwiGluMlp(16, 24, jnp.float32, name=n), None,
+            split=split, norm_output=norm_output)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, 16))
     p = jax.tree_util.tree_map_with_path(
         _away, parts(True).init(jax.random.PRNGKey(2), x))
@@ -259,11 +271,12 @@ def test_a_block_that_norms_its_outputs_is_its_two_equations(block):
         before, h + sub(mlp, "ffn", sub(norm, "norm2", h)), rtol=1e-5,
         atol=1e-6)
     assert float(jnp.abs(before - got).max()) > 1e-3
-    assert block.__dataclass_fields__["norm_output"].default is False
+    assert PartsBlock.__dataclass_fields__["norm_output"].default is False
 
 
 # ------------------------------------------------- the model as a whole
-def test_reference_tree_is_the_programs_tree_and_layer_types(params):
+def test_reference_tree_is_the_programs_tree_and_layer_types(monkeypatch,
+                                                             params):
     module = _module()
     own = module.init(jax.random.PRNGKey(0), jnp.zeros((1, LEN), jnp.int32))
     shapes = lambda t: jax.tree_util.tree_map(lambda x: x.shape, t)
@@ -291,11 +304,15 @@ def test_reference_tree_is_the_programs_tree_and_layer_types(params):
     assert [i for i, k in enumerate(OLMO_HYBRID_7B_LAYERS)
             if k == "full_attention"] == list(range(3, 32, 4))
     whole = build_model("olmo_hybrid")["module"]
-    assert whole.layer_types == OLMO_HYBRID_7B_LAYERS
-    with pytest.raises(ValueError):
+    assert _defaults("olmo_hybrid")["layer_types"] == OLMO_HYBRID_7B_LAYERS
+    assert [i for i in range(32) if whole.mixers[i] is whole.mixers[3]] \
+        == list(range(3, 32, 4)) and len(set(whole.ffns)) == 1
+    assert whole.norm_output and whole.split and not whole.tied
+    with pytest.raises(
+            ValueError,
+            match="'linear_attention' or 'full_attention' a layer"):
         build_model("olmo_hybrid_tiny", layer_types=("linear_attention",
-                                                     "mamba"))[
-            "module"].init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+                                                     "mamba"))
     with pytest.raises(ValueError):
         ref.dims(dict(CFG, num_hidden_layers=3))
     with pytest.raises(ValueError):
@@ -311,7 +328,11 @@ def test_reference_tree_is_the_programs_tree_and_layer_types(params):
         assert np.all(np.asarray(tree["block3"]["attn"]["query_norm"][
             "scale"]) == 1)
     # the tiny preset is this file's configuration
-    assert build_model("olmo_hybrid_tiny")["module"] == _module()
+    seen, entry = [], _defaults("olmo_hybrid")
+    monkeypatch.setattr(decoder, "olmo_hybrid", lambda **kw: seen.append(kw))
+    build_model("olmo_hybrid_tiny")
+    assert [{**entry, **kw} for kw in seen] == [
+        {**entry, **ref.zoo_args(CFG, 64)}]
 
 
 def test_logits_match_the_reference(params):
@@ -449,10 +470,10 @@ def nothing_recomputed(params):
         nn.remat = real
 
 
-_NAMES = (FLASH_RESIDUALS, decoder.MLP_GATE_UP, DELTA_CHUNK_TILES,
-          decoder.DELTA_NET_QKVZ)       # ``_remat_block``'s one list, but
+_NAMES = (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES,
+          DELTA_NET_QKVZ)               # ``_remat_block``'s one list, but
 # for the name this family has no value of (``lfm2_moe``'s, last in it)
-_NOT_HERE = (decoder.SHORT_CONV_IN,)
+_NOT_HERE = (SHORT_CONV_IN,)
 
 
 @pytest.mark.parametrize("split", [True, False], ids=["halves", "whole"])
@@ -473,13 +494,11 @@ def test_every_sub_list_of_kept_names_gives_the_same_gradients(
         return real_policy(*names)
     monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
                         spy)
-    monkeypatch.setattr(decoder.OlmoHybrid, "LET_GO",
-                        tuple(n for n in _NAMES if n not in keep))
-    if not split:
-        real = decoder._remat_block
-        monkeypatch.setattr(
-            decoder, "_remat_block",
-            lambda *a, split=False, **kw: real(*a, split=False, **kw))
+    real = decoder._remat_block
+    monkeypatch.setattr(
+        decoder, "_remat_block", lambda *a, **kw: real(*a, **dict(
+            kw, split=split,
+            let_go=tuple(n for n in _NAMES if n not in keep))))
     got = _hidden_grads(params, jnp.asarray(_tokens(4)[0]))
     assert set(seen) == {keep + _NOT_HERE}
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
@@ -490,12 +509,13 @@ def test_every_sub_list_of_kept_names_gives_the_same_gradients(
 
 
 def test_this_family_lets_go_of_names_of_the_one_list():
-    assert decoder.OlmoHybrid.LET_GO
-    assert set(decoder.OlmoHybrid.LET_GO) < set(_NAMES)
+    let_go = build_model("olmo_hybrid")["module"].let_go
+    assert let_go == (MLP_GATE_UP,) and set(let_go) < set(_NAMES)
+    assert build_model("olmo_hybrid_tiny")["module"].let_go == let_go
     # the four other families let go of none and keep the whole list
-    for family in (decoder.Glm4MoeLite, decoder.Qwen3Next,
-                   decoder.GraniteHybrid, decoder.Lfm2Moe):
-        assert not hasattr(family, "LET_GO")
+    for family in ("glm4_moe_lite", "qwen3_next", "granite_hybrid",
+                   "lfm2_moe"):
+        assert build_model(family)["module"].let_go == ()
 
 
 # -------------------------------------------- the benchmark's own counts
@@ -616,13 +636,13 @@ def test_configuration_holds_the_catalogued_numbers():
     assert {"norm_wiring", "qk_norm", "positions", "attention_head_dim",
             "projection_columns", "recomputation"} <= set(cfg["assumed"])
     # the zoo entry's defaults are the published numbers
-    whole = build_model("olmo_hybrid")["module"]
+    entry = _defaults("olmo_hybrid")
     uncut = dict(cfg, **{k: published[k] for k in cfg["reduced"]})
     args = ref.zoo_args(uncut, 8192)
     args.pop("max_len")
     for k, v in args.items():
-        assert getattr(whole, k) == v, k
-    assert whole.head_dim * whole.heads == whole.dim
+        assert entry[k] == v, k
+    assert entry["head_dim"] * entry["heads"] == entry["dim"]
     # the cell, its traffic and its three metrics are data files
     with open(REPO / "BENCHMARK.json") as f:
         bench = json.load(f)

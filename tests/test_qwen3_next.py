@@ -31,7 +31,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from benchmark.references import glm47_flash as glm_ref  # noqa: E402
 from benchmark.references import qwen3_next as ref  # noqa: E402
 from mmlspark_tpu.models.zoo import build_model  # noqa: E402
-from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
+from mmlspark_tpu.models.zoo.decoder import qwen3_next_layers  # noqa: E402
+from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
     GatedAttention, GatedDeltaNet, SwiGluMlp, rotary)
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
@@ -197,11 +198,16 @@ def test_reference_tree_is_the_programs_tree_and_the_layer_pattern(params):
         linear = "attn_qkvz" in p[f"block{i}"]["attn"]
         softmax = "attn_query_gate" in p[f"block{i}"]["attn"]
         assert linear != softmax and softmax == (i == 3)
-        assert module.softmax_layer(i) == softmax
-    # the published pattern: of 48 layers, 3, 7, ..., 47 are softmax
+        assert (qwen3_next_layers(4, 4)[i] == "full_attention") == softmax
+    # the published pattern: of 48 layers, 3, 7, ..., 47 are softmax, in
+    # the rule and in the mixers the entry hands the skeleton
+    assert [i for i, kind in enumerate(qwen3_next_layers(48, 4))
+            if kind == "full_attention"] == list(range(3, 48, 4))
     whole = build_model("qwen3_next")["module"]
-    assert [i for i in range(48) if whole.softmax_layer(i)] == list(
-        range(3, 48, 4))
+    assert len(whole.mixers) == len(whole.ffns) == 48
+    assert [i for i in range(48) if whole.mixers[i] is whole.mixers[3]] \
+        == list(range(3, 48, 4))
+    assert len(set(whole.mixers)) == 2 and len(set(whole.ffns)) == 1
     d = ref.dims(dict(CFG, num_hidden_layers=48))
     assert [i for i in range(48) if ref.softmax_layer(d, i)] == list(
         range(3, 48, 4))

@@ -28,7 +28,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.references import granite_hybrid as ref  # noqa: E402
 from mmlspark_tpu.models.zoo import build_model  # noqa: E402
-from mmlspark_tpu.models.zoo.decoder import Mamba2Mixer  # noqa: E402
+from mmlspark_tpu.models.zoo.parts import Mamba2Mixer  # noqa: E402
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
 from mmlspark_tpu.ops import pallas_ssd as pss  # noqa: E402

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import optax
 import pytest
 
-from mmlspark_tpu.models.zoo.decoder import RMSNorm, SwiGluMlp
+from mmlspark_tpu.models.zoo.parts import RMSNorm, SwiGluMlp
 from mmlspark_tpu.observability import scopes
 from mmlspark_tpu.parallel.mesh import mesh_from_config
 from mmlspark_tpu.parallel.trainer import DistributedTrainer
