@@ -1,5 +1,5 @@
 """Decoder LMs: ONE skeleton (``Decoder``: embed, blocks, final norm,
-head) over the parts of ``models/zoo/parts.py``, and the five families as
+head) over the parts of ``models/zoo/parts.py``, and the six families as
 registry entries that say which part sits at which layer:
 ``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's),
 ``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
@@ -13,7 +13,11 @@ softmax attention without positions in the order of a published list, a
 dense SwiGLU part in every layer, OLMo 2's norms on each half's OUTPUT)
 and ``lfm2_moe`` (LFM2-24B-A2B: gated short convolutions and rotary
 grouped attention in the order of a published list, a dense SwiGLU part in
-the leading layers and a routed layer in every later one, one table).
+the leading layers and a routed layer in every later one, one table) and
+``laguna`` (Laguna-XS.2: window-512 and full softmax layers three to one,
+the same part at two settings with different head counts and rotary rules
+and a sigmoid gate a head, a dense part then small sigmoid-routed experts
+beside a shared one by a published list).
 
 What a family IS lives in its entry, beside the name of the published
 ``config.json`` it reads: the mixer and the feed-forward part of layer
@@ -48,7 +52,8 @@ from mmlspark_tpu.models.zoo.moe import DroplessMoe
 from mmlspark_tpu.models.zoo.parts import (
     _INIT, DELTA_NET_QKVZ, MLP_GATE_UP, SHORT_CONV_IN, GatedAttention,
     GatedDeltaNet, GroupedAttention, Head, Mamba2Mixer, MlaAttention,
-    RMSNorm, ShortConv, SwiGluMlp, _dense)
+    RMSNorm, ShortConv, SwiGluMlp, _dense, plain_frequencies,
+    yarn_frequencies)
 
 # a part's factory: the flax name (None inside a block, whose ``setup``
 # names its parts by attribute) -> the module
@@ -632,3 +637,109 @@ def lfm2_moe_tiny(**overrides):
     leading dense layer, then one period's kinds of mixer under routed
     layers of eight experts, two a token."""
     return lfm2_moe(**{**_LFM2_TINY, **overrides})
+
+
+LAGUNA_XS2_LAYERS = ("full_attention",) + ("sliding_attention",) * 3
+LAGUNA_XS2_HEADS = {"full_attention": 48, "sliding_attention": 64}
+
+
+@register_model("laguna")
+def laguna(vocab: int = 100352, dim: int = 2048,
+           layer_types=LAGUNA_XS2_LAYERS * 10, heads_per_layer=None,
+           mlp_layer_types=("dense",) + ("sparse",) * 39, kv_heads: int = 8,
+           head_dim: int = 128, window: int = 512, mlp_hidden: int = 8192,
+           expert_hidden: int = 512, shared_hidden: int = 512,
+           num_experts: int = 256, top_k: int = 8, experts_held=None,
+           scaling: float = 2.5, gate_grad: bool = True,
+           head_gate: bool = True, full_theta: float = 5e5,
+           full_rotary_fraction: float = 0.5, yarn_factor: float = 64.0,
+           yarn_original: int = 4096, yarn_beta_fast: float = 64.0,
+           yarn_beta_slow: float = 1.0,
+           yarn_attention_factor: float = 1.4158883083359672,
+           window_theta: float = 1e4, eps: float = 1e-6,
+           max_len: int = 8192, dtype=jnp.bfloat16, attention_fn=None):
+    """Laguna-XS.2 as published (huggingface.co/poolside/Laguna-XS.2
+    ``config.json``, ``model_type: laguna``): forty layers whose two kinds
+    of mixer are the SAME part, ``GroupedAttention``, at two settings, in
+    the order of a published list (``layer_types``) with the query heads
+    of a second (``num_attention_heads_per_layer``; ``heads_per_layer``
+    None reads 48 for a full and 64 for a sliding layer, which is that
+    list): ``"full_attention"`` sees the whole causal half and turns the
+    first ``full_rotary_fraction`` of each head by YaRN
+    (``parts.yarn_frequencies``; cos and sin times
+    ``yarn_attention_factor``); ``"sliding_attention"`` sees the ``window``
+    keys ``0 <= i - j < window`` and turns the whole head by plain rotary
+    at ``window_theta`` (both through ``parts.rotary_by_frequencies``).
+    Every layer has ``kv_heads`` key/value heads of ``head_dim``, no norm
+    on q or k, and (``gating``) one sigmoid gate a query head from the
+    layer's input. The feed-forward part by a third
+    list (``mlp_layer_types``): ``"dense"`` a ``SwiGluMlp`` of
+    ``mlp_hidden``, ``"sparse"`` a ``DroplessMoe`` routed by sigmoid
+    scores over all ``num_experts`` (DeepSeek-V3's rule, as
+    ``glm4_moe_lite``: the choice on score plus bias, the weights over
+    their sum times ``scaling``, on the experts' OUTPUT) beside one
+    ungated shared expert of ``shared_hidden``. Plain RMS norms, untied
+    tables. ``experts_held`` = ``(count, first)`` as for ``glm4_moe_lite``;
+    ``gate_grad=False`` for a share trained without its exchange
+    (``DroplessMoe``). Each block is recomputed whole and keeps all of
+    ``_remat_block``'s names."""
+    held = None if experts_held is None else tuple(experts_held)
+    kinds = tuple(layer_types)
+    heads = tuple(LAGUNA_XS2_HEADS.get(k) for k in kinds) \
+        if heads_per_layer is None else tuple(heads_per_layer)
+    feeds = tuple(mlp_layer_types)
+    if not (len(kinds) == len(heads) == len(feeds)):
+        raise ValueError(f"{len(kinds)} layer_types, {len(heads)} head "
+                         f"counts, {len(feeds)} mlp_layer_types")
+    yarn = yarn_frequencies(
+        int(head_dim * full_rotary_fraction), full_theta, yarn_factor,
+        yarn_original, yarn_beta_fast, yarn_beta_slow)
+
+    def attention(h, **positions):
+        return lambda n: GroupedAttention(
+            dim, h, kv_heads, head_dim, None, dtype, attention_fn,
+            head_gate=head_gate, name=n, **positions)
+
+    # the kind's setting, then the layer's head count
+    settings = _by_kind(kinds, {
+        "full_attention": dict(rotary_freqs=yarn,
+                               rotary_factor=yarn_attention_factor),
+        "sliding_attention": dict(
+            rotary_freqs=plain_frequencies(head_dim, window_theta),
+            window=window)})
+
+    def dense(n):
+        return SwiGluMlp(dim, mlp_hidden, dtype, name=n)
+
+    def routed(n):
+        return DroplessMoe(
+            dim, num_experts, expert_hidden, top_k, experts_held=held,
+            scaling=scaling, shared=lambda m: SwiGluMlp(
+                dim, shared_hidden, dtype, name=m), dtype=dtype,
+            gate_grad=gate_grad, name=n)
+
+    return _spec(Decoder(
+        vocab, dim,
+        tuple(attention(h, **kw) for h, kw in zip(heads, settings)),
+        _by_kind(feeds, {"dense": dense, "sparse": routed}), _rms(eps),
+        dtype=dtype), max_len)
+
+
+_LAGUNA_TINY = dict(vocab=96, dim=32,
+                    layer_types=("full_attention", "sliding_attention",
+                                 "sliding_attention", "full_attention"),
+                    heads_per_layer=(2, 4, 4, 2),
+                    mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
+                    kv_heads=2, head_dim=8, window=8, mlp_hidden=48,
+                    expert_hidden=8, shared_hidden=8, num_experts=8, top_k=2,
+                    yarn_factor=4.0, yarn_original=8, yarn_beta_fast=4.0,
+                    max_len=64, dtype=jnp.float32)
+
+
+@register_model("laguna_tiny")
+def laguna_tiny(**overrides):
+    """Test-scale ``laguna`` (float32, so CPU parity is tight): a leading
+    dense layer under a full layer, then sliding, sliding, full under
+    routed layers of eight experts, two a token; unequal head counts, a
+    window shorter than the rows, YaRN past its original length."""
+    return laguna(**{**_LAGUNA_TINY, **overrides})

@@ -9,6 +9,9 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
 - ``rotary``: rotary positions on the last axis, half-split pairing
   (dimension ``i`` turns with ``i + R/2``), float32 angles; with ``width``
   on the first ``width`` dimensions only, the rest passing through;
+  ``rotary_by_frequencies``: the same by given inverse frequencies and a
+  factor (``plain_frequencies``, ``yarn_frequencies``), as one product
+  over the whole head;
 - ``MlaAttention``: multi-head latent attention in its expanded (training)
   form: a low-rank query, one compressed key/value row per token, a rotary
   slice on every query head and ONE rotary key shared by all heads;
@@ -44,11 +47,14 @@ where its value is made.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+import contextlib
+import math
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from mmlspark_tpu.observability import metrics as obsmetrics
@@ -96,6 +102,76 @@ def rotary(x: jax.Array, theta: float,
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            -1).astype(x.dtype)
+
+
+def rotary_by_frequencies(x: jax.Array, inv_freq: Tuple[float, ...],
+                          factor: float = 1.0) -> jax.Array:
+    """Rotary positions by GIVEN inverse frequencies over the last axis of
+    ``(B, L, H, R)``: the first ``2 n`` dimensions turn, ``n`` =
+    ``len(inv_freq)``, position ``l`` turning the pair ``(i, i + n)`` by
+    ``l * inv_freq[i]``, and the rest pass through; ``factor`` multiplies
+    cos and sin both (YaRN's attention factor). The frequencies are made
+    where the rule is known (``plain_frequencies``, ``yarn_frequencies``).
+
+    ``rotary``'s arithmetic in another form: ``x cos + pair(x) sin`` over
+    the WHOLE head, where ``pair(x) = [-x_2 | x_1 | 0]`` is a product with
+    the pairing's signed permutation matrix (each output is plus or minus
+    one input: exact in any dtype) and cos and sin are 1 and 0 on the
+    dimensions that pass. No half of a head is sliced out of the lanes: on
+    the chip the split form of a (2, 8192, 64, 128) bfloat16 tensor ran
+    4.83 ms forward and this one 1.30, with equal bits (PERF.md section 6,
+    PR 44). The families that turn by ``rotary`` keep it: their programs
+    are held to a named commit's (``tests/test_decoder_programs.py``)."""
+    L, R = x.shape[1], x.shape[-1]
+    n = len(inv_freq)
+    if 2 * n > R:
+        raise ValueError(f"{n} frequencies turn {2 * n} dimensions of {R}")
+    ang = jnp.arange(L, dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    rest = (L, R - 2 * n)
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    cos = jnp.concatenate([cos, cos, jnp.ones(rest, jnp.float32)], -1)
+    sin = jnp.concatenate([sin, sin, jnp.zeros(rest, jnp.float32)], -1)
+    pair = np.zeros((R, R), np.float32)
+    i = np.arange(n)
+    pair[i + n, i], pair[i, i + n] = -1.0, 1.0
+    paired = jnp.einsum(
+        "blhr,rs->blhs", x, jnp.asarray(pair, x.dtype),
+        preferred_element_type=jnp.float32,
+        precision=None if x.dtype.itemsize < 4 else jax.lax.Precision.HIGHEST)
+    return (x.astype(jnp.float32) * cos[None, :, None, :]
+            + paired * sin[None, :, None, :]).astype(x.dtype)
+
+
+def plain_frequencies(width: int, theta: float) -> Tuple[float, ...]:
+    """Plain rotary's ``width / 2`` inverse frequencies,
+    ``theta**(-2i/width)``."""
+    return tuple(theta ** (-2.0 * i / width) for i in range(width // 2))
+
+
+def yarn_frequencies(width: int, theta: float, factor: float,
+                     original: int, beta_fast: float = 32.0,
+                     beta_slow: float = 1.0) -> Tuple[float, ...]:
+    """YaRN's ``width / 2`` inverse frequencies (arXiv:2309.00071, as
+    ``transformers``' ``_compute_yarn_parameters`` makes them): pair ``i``
+    of plain rotary turns by ``theta**(-2i/width)``; a pair that turns
+    more than ``beta_fast`` times over the ``original`` positions keeps
+    that, one that turns fewer than ``beta_slow`` times turns ``factor``
+    times slower, and between the two a linear ramp over the pair's index
+    blends them (its ends the floor and the ceiling of the two pairs'
+    indices, kept inside ``[0, width - 1]``). Float64 on the host, once."""
+    def pair_turning(turns):
+        return width * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), width - 1)
+    if low == high:
+        high += 0.001
+    out = []
+    for i, plain in enumerate(plain_frequencies(width, theta)):
+        keep = 1.0 - min(max((i - low) / (high - low), 0.0), 1.0)
+        out.append(plain / factor * (1.0 - keep) + plain * keep)
+    return tuple(out)
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -290,7 +366,23 @@ class GroupedAttention(nn.Module):
     is multiplied by ``scale x head_dim ** 0.5`` before the call (0.125 in
     the published Granite: a power of two, exact in bfloat16). Each
     key/value head is repeated to the ``heads / kv_heads`` query heads it
-    serves at that call, as in ``GatedAttention``."""
+    serves at that call, as in ``GatedAttention``.
+
+    Laguna's two softmax layers are this part at two settings, by four
+    more arguments that each default to nothing: ``window`` (a query at
+    ``i`` sees the keys ``0 <= i - j < window``; handed to
+    ``attention_fn`` as ``window=``, and the whole mixer then lies under
+    the scope ``window_attention_layer``); ``rotary_freqs`` /
+    ``rotary_factor`` (given inverse frequencies in ``theta``'s place,
+    which turn the first ``2 len(rotary_freqs)`` dimensions of a head, and
+    a factor on cos and sin: ``rotary_by_frequencies``, with
+    ``plain_frequencies`` or YaRN's, ``yarn_frequencies``); ``head_gate``
+    (one sigmoid gate a query head from the part's input, float32: ``g =
+    sigmoid(x W_g)``, ``W_g`` ``dim x heads``, ``o_h <- g_h o_h`` before
+    ``W_o``; scope ``head_gate``). It grew and no part was added beside
+    it: the projections, the grouping, the repeat and the call are these
+    to the letter, and ``GatedAttention``'s gate is an element's, shapes
+    ``W_q`` and comes with norms of the ``1 + w`` kind."""
     dim: int
     heads: int
     kv_heads: int
@@ -301,6 +393,10 @@ class GroupedAttention(nn.Module):
     qk_norm_eps: Optional[float] = None     # None: no norm on q and k
     norm_heads: bool = False            # the norm over each head, not all
     theta: Optional[float] = None       # None: no positions
+    window: Optional[int] = None        # None: the whole causal half
+    rotary_freqs: Optional[Tuple[float, ...]] = None    # in theta's place
+    rotary_factor: float = 1.0
+    head_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -310,8 +406,10 @@ class GroupedAttention(nn.Module):
         B, L, _ = x.shape
         H, G, d, dt = self.heads, self.kv_heads, self.head_dim, self.dtype
         attn_fn = self.attention_fn or full_attention
-        with jax.named_scope("grouped_attention"):
-            x = x.astype(dt)
+        banded = contextlib.nullcontext() if self.window is None \
+            else jax.named_scope("window_attention_layer")
+        with jax.named_scope("grouped_attention"), banded:
+            x32, x = x, x.astype(dt)
 
             def heads_of(name, heads, norm=None):
                 y = _dense(heads * d, dt, name)(x)
@@ -323,7 +421,10 @@ class GroupedAttention(nn.Module):
                 if normed and self.norm_heads:
                     with jax.named_scope("qk_norm"):
                         y = RMSNorm(self.qk_norm_eps, name=norm)(y)
-                if norm and self.theta is not None:
+                if norm and self.rotary_freqs is not None:
+                    y = rotary_by_frequencies(y, self.rotary_freqs,
+                                              self.rotary_factor)
+                elif norm and self.theta is not None:
                     y = rotary(y, self.theta)
                 return y.astype(dt)
             q = heads_of("attn_query", H, "query_norm")
@@ -332,7 +433,17 @@ class GroupedAttention(nn.Module):
             if self.scale is not None:
                 q = q * jnp.asarray(self.scale * d ** 0.5, dt)
             k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
-            o = attn_fn(q, k, v, causal=True)
+            if self.window is None:
+                o = attn_fn(q, k, v, causal=True)
+            else:
+                o = attn_fn(q, k, v, causal=True, window=self.window)
+            if self.head_gate:
+                with jax.named_scope("head_gate"):
+                    gate = jax.nn.sigmoid(nn.Dense(
+                        H, use_bias=False, dtype=jnp.float32,
+                        param_dtype=jnp.float32, kernel_init=_INIT,
+                        name="attn_head_gate")(x32.astype(jnp.float32)))
+                    o = o * gate[..., None].astype(dt)
             return _dense(self.dim, dt, "attn_out")(o.reshape(B, L, H * d))
 
 

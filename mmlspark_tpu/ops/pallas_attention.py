@@ -15,6 +15,10 @@ times (scores, softmax, weighted sum), and again in the backward pass.
   (``_flash_bwd_kernel``, grid (B, H, L/block_k)) that recomputes the
   probabilities from them tile by tile in VMEM and makes dq, dk and dv
   with five products a tile.
+  With ``window=W`` both calls compute the causal BAND ``0 <= i - j < W``
+  (a sliding-window layer): tiles outside the band are never visited,
+  the tiles its two edges cross are masked, and the calls carry names of
+  their own (``window_attention_fwd`` / ``window_attention_bwd``).
 - ``short_attention`` (a sequence that fits one VMEM block: ViT's 197
   tokens, an LM's short prompts) needs no online softmax: one program
   holds a batch row's whole sequence and ALL its heads, reads q, k, v
@@ -42,8 +46,9 @@ Interface layout: (B, L, H, D) like every attention_fn in the framework.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -118,7 +123,7 @@ def _lse_from_rows(tail, block_q: int):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
-                  scale: float):
+                  scale: float, window: Optional[int] = None):
     """One program per (batch, head, query block) against the (batch,
     head)'s whole K and V, resident in VMEM. The scores are held
     TRANSPOSED, (bk, bq), as ``_flash_bwd_kernel`` holds them: the running
@@ -133,7 +138,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     ``m``, ``l``, the accumulator, both exponentials and the final
     division are float32 (the backward's convention, and
     ``_short_fwd_kernel``'s). The scale multiplies the float32 score tile,
-    as in the backward, so it rounds nothing at any head width."""
+    as in the backward, so it rounds nothing at any head width.
+
+    With ``window`` (causal only) a query at ``i`` sees the keys ``j`` with
+    ``0 <= i - j < window``: the K tiles before the band of the block's
+    first row are never visited, the tiles the band's LOWER edge crosses
+    are masked as the diagonal's are, and what lies between runs clear."""
     # q/k/v refs are (1, 1, L-block, D): batch and head ride the grid, so
     # the last two dims are the (8, 128)-tileable (rows, lanes) pair Mosaic
     # wants; o_ref is (1, 1, 1, bq [+ lse rows], D)
@@ -142,10 +152,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     assert bq % block_k == 0, (bq, block_k)
     qi = pl.program_id(2)
 
-    def tile(start, carry, diagonal=None):
+    def tile(start, carry, diagonal=None, live=None):
         """One K tile from row ``start``; ``diagonal`` is the tile's
-        offset from the query block's first row where the diagonal
-        crosses it."""
+        offset from the query block's first row where the diagonal (or a
+        band's lower edge) crosses it; ``live`` False for a tile that
+        lies before the row's start (a band's, of the first blocks)."""
         m, l, acc = carry
         rows = pl.ds(pl.multiple_of(start, block_k), block_k)
         k = k_ref[0, 0, rows, :]
@@ -157,7 +168,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
             # compiled kernel
             key = diagonal + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
             query = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-            st = jnp.where(key <= query, st, _NEG_INF)
+            seen = key <= query
+            if window is not None and bq - 1 - diagonal >= window:
+                lower = query - key < window
+                # a tile wholly before the block's first row has no future
+                seen = lower if diagonal + block_k <= 1 else seen & lower
+            if live is not None:
+                seen = seen & live
+            st = jnp.where(seen, st, _NEG_INF)
         m_new = jnp.maximum(m, st.max(axis=0, keepdims=True))
         pt = jnp.exp(st - m_new)                              # (bk, bq)
         corr = jnp.exp(m - m_new)
@@ -177,8 +195,22 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     # 0 is in the first tile run and every row sees it: ``m`` is finite
     # from there on.
     clear = qi * (bq // block_k) if causal else k_ref.shape[2] // block_k
+    first = 0
+    if window is not None:
+        # the block's first row is a multiple of bk, so the tiles' offsets
+        # from it are the same in every block: those from -edge up to
+        # ``inside`` cross the band's lower edge (a wholly masked row of a
+        # tile run before the row's first seen key leaves rubbish in its
+        # ``l`` and accumulator, which that key's correction, exp(-1e30 -
+        # m), multiplies by 0: every row sees itself, in the last tiles)
+        edge = -(-(window - 1) // block_k) * block_k
+        inside = min(0, -((window - bq) // block_k) * block_k)
+        for offset in range(-edge, inside, block_k):
+            start = qi * bq + offset
+            carry = tile(jnp.maximum(start, 0), carry, offset, start >= 0)
+        first = jnp.maximum(clear + inside // block_k, 0)
     carry = jax.lax.fori_loop(
-        0, clear, lambda i, c: tile(i * block_k, c), carry)
+        first, clear, lambda i, c: tile(i * block_k, c), carry)
     if causal:
         for diagonal in range(0, bq, block_k):
             carry = tile(qi * bq + diagonal, carry, diagonal)
@@ -194,10 +226,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
         o_ref[0, 0, 0, bq:, :] = _lse_tile(m + jnp.log(l), d, o_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, block_q: int = BLOCK_Q,
-                    block_k: int = BLOCK_K) -> jax.Array:
+                    block_k: int = BLOCK_K,
+                    window: Optional[int] = None) -> jax.Array:
     """(B, L, H, D) fused attention; requires L divisible by the blocks
     (``supports`` tells callers when to fall back). Differentiable: the
     forward call saves each row's log-sum-exp beside its output, and the
@@ -212,15 +244,44 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     forward pass (``jax.checkpoint`` / ``nn.remat``) names it in
     ``save_only_these_names`` and the recomputation holds no second
     forward call (``models/zoo/decoder.py`` does). With any other policy,
-    or none, the name changes nothing."""
-    return _flash_forward(q, k, v, causal, block_q, block_k)[0]
+    or none, the name changes nothing.
+
+    ``window`` (causal only): a query at ``i`` sees the keys ``j`` with
+    ``0 <= i - j < window``. Both passes visit the tiles of the band alone
+    and choose their tiles for it (``_window_tile``); the two Pallas calls
+    are then named ``window_attention_fwd`` / ``window_attention_bwd``, so
+    that a trace tells a band's calls from a causal half's (the residuals
+    are ``FLASH_RESIDUALS`` either way). A window of the whole row or more
+    is the plain causal call."""
+    return _flash_attention(q, k, v, causal, block_q, block_k,
+                            band(window, causal, q.shape[1]))
+
+
+def band(window: Optional[int], causal: bool, L: int) -> Optional[int]:
+    """The rule of a ``window``, for every caller, and the value as the
+    kernels take it: None where every key of the causal half is inside
+    it."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"window {window!r} with causal={causal}: a window "
+                         "is the causal band 0 <= i - j < window")
+    return None if window >= L else int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, causal, block_q, block_k, window):
+    """``flash_attention`` of a ``window`` that ``band`` has resolved."""
+    return _flash_forward(q, k, v, causal, block_q, block_k,
+                          window=window)[0]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "save_lse"))
+    "causal", "block_q", "block_k", "save_lse", "window"))
 def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool = False, block_q: int = BLOCK_Q,
-                   block_k: int = BLOCK_K, save_lse: bool = False):
+                   block_k: int = BLOCK_K, save_lse: bool = False,
+                   window: Optional[int] = None):
     """(output (B, L, H, D), None), or under differentiation
     (``save_lse``) the rows' log-sum-exps in the None's place: float32
     (B, H, L / block_q, block_q). A call that is not differentiated
@@ -229,35 +290,46 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
     b, L, h, d = q.shape
     scale = 1.0 / float(np.sqrt(d))
     vmem = pl.ANY if _interpret() else pltpu.VMEM
-    bq, bk = _fwd_tiles(block_q, block_k, L, d)
+    bq, bk = _fwd_tiles(block_q, block_k, L, d, window)
     kernel = functools.partial(_flash_kernel, block_k=bk, causal=causal,
                                scale=scale)
+    # a band's call carries a name of its own (as the backward's does, and
+    # for its reason: the benchmark's readers of the causal forward find
+    # theirs by the word `flash`); the causal call is what it was
+    scope, named = contextlib.nullcontext(), {}
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
+        scope, named = jax.named_scope(_WINDOW_FWD_NAME), {
+            "name": _WINDOW_FWD_NAME}
     rows = bq + (_lse_rows(bq, d, q.dtype)[1] if save_lse else 0)
     need = _flash_fwd_vmem_bytes(L, d, bq, bk, rows, q.dtype.itemsize)
     # (B, L, H, D) -> (B, H, L, D): head ahead of length so kernel blocks
     # end in the tileable (rows, lanes) pair; XLA fuses the transposes
     # into the surrounding program
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, h, L // bq),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda bi, hi, qi: (bi, hi, qi, 0),
-                         memory_space=vmem),
-            pl.BlockSpec((1, 1, L, d), lambda bi, hi, qi: (bi, hi, 0, 0),
-                         memory_space=vmem),
-            pl.BlockSpec((1, 1, L, d), lambda bi, hi, qi: (bi, hi, 0, 0),
-                         memory_space=vmem),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, rows, d),
-                               lambda bi, hi, qi: (bi, hi, qi, 0, 0),
-                               memory_space=vmem),
-        out_shape=jax.ShapeDtypeStruct((b, h, L // bq, rows, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(need)),
-        interpret=_interpret(),
-    )(qt, kt, vt)
+    with scope:
+        out = pl.pallas_call(
+            kernel,
+            grid=(b, h, L // bq),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d),
+                             lambda bi, hi, qi: (bi, hi, qi, 0),
+                             memory_space=vmem),
+                pl.BlockSpec((1, 1, L, d), lambda bi, hi, qi: (bi, hi, 0, 0),
+                             memory_space=vmem),
+                pl.BlockSpec((1, 1, L, d), lambda bi, hi, qi: (bi, hi, 0, 0),
+                             memory_space=vmem),
+            ],
+            out_specs=pl.BlockSpec((1, 1, 1, rows, d),
+                                   lambda bi, hi, qi: (bi, hi, qi, 0, 0),
+                                   memory_space=vmem),
+            out_shape=jax.ShapeDtypeStruct((b, h, L // bq, rows, d),
+                                           q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_vmem_limit(need)),
+            interpret=_interpret(),
+            **named,
+        )(qt, kt, vt)
     o = out[:, :, :, :bq].reshape(b, h, L, d).transpose(0, 2, 1, 3)
     if not save_lse:
         return o, None
@@ -296,22 +368,28 @@ def supports(q_shape, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> bool:
 FLASH_RESIDUALS = "flash_attention_residuals"
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k):
+def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
-                              save_lse=True)
+                              save_lse=True, window=window)
     out = checkpoint_name(out, FLASH_RESIDUALS)
     lse = checkpoint_name(lse, FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 
 _BWD_NAME = "long_attention_bwd"
+# a band's calls: no `flash` in either, and the backward's does not start
+# with the causal backward's name (the benchmark's readers of the causal
+# calls find theirs by those words)
+_WINDOW_FWD_NAME = "window_attention_fwd"
+_WINDOW_BWD_NAME = "window_attention_bwd"
 # what a program may ask of a v5e's 128 MiB of VMEM
 _VMEM_CAP = 100 << 20
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      block_q: int, causal: bool, scale: float):
+                      block_q: int, causal: bool, scale: float,
+                      window: Optional[int] = None):
     """One program per (batch, head, K block): q and do of the whole
     sequence are resident (L, D), k and v are this K block (bk, D), the
     log-sum-exps and ``delta = rowsum(do * o)`` are (L / bq, bq) float32
@@ -320,7 +398,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     padded (L, 1) column in VMEM, and of the five products only dq's
     contracts over a leading dimension. dk and dv of the block are summed
     over the query blocks here; dq is summed over the K blocks in a
-    float32 scratch that lives across the grid's last axis."""
+    float32 scratch that lives across the grid's last axis. With
+    ``window`` both of its loops are bounded by the band: the query blocks
+    past the last row that still sees this K block are never visited, and
+    the blocks the band's lower edge crosses are masked."""
     kj = pl.program_id(2)
     bk = k_ref.shape[0]
     nq = q_ref.shape[0] // block_q
@@ -334,18 +415,23 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def step(qi, masked: bool):
+    def step(qi, masked: bool, edge: bool = False):
+        """One query block; ``masked`` where the diagonal crosses it,
+        ``edge`` where a band's lower edge does."""
         rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
         q = q_ref[rows, :]
         do = do_ref[rows, :]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if masked:
+        if masked or edge:
             k_idx = kj * bk + jax.lax.broadcasted_iota(
                 jnp.int32, st.shape, 0)
             q_idx = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, st.shape, 1)
-            st = jnp.where(k_idx <= q_idx, st, _NEG_INF)
+            seen = k_idx <= q_idx if masked else q_idx - k_idx < window
+            if masked and edge:
+                seen = seen & (q_idx - k_idx < window)
+            st = jnp.where(seen, st, _NEG_INF)
         pt = jnp.exp(st - lse_ref[pl.ds(qi, 1), :])           # (bk, bq)
         dpt = jax.lax.dot_general(
             v, do, _NT, preferred_element_type=jnp.float32)
@@ -357,8 +443,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[rows, :] += jax.lax.dot_general(
             dst, k, _TN, preferred_element_type=jnp.float32)
 
-    def loop(lo, hi, masked):
-        jax.lax.fori_loop(lo, hi, lambda qi, _: step(qi, masked), None)
+    def loop(lo, hi, masked, edge=False):
+        jax.lax.fori_loop(lo, hi, lambda qi, _: step(qi, masked, edge), None)
 
     if causal:
         # query blocks wholly before this K block are never visited: no
@@ -366,8 +452,22 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # blocks the diagonal crosses pay for a mask
         first = (kj * bk) // block_q
         clear = jnp.minimum(nq, ((kj + 1) * bk + block_q - 2) // block_q)
-        loop(first, clear, True)
-        loop(clear, nq, False)
+        if window is None:
+            loop(first, clear, True)
+            loop(clear, nq, False)
+        else:
+            # a block the diagonal crosses reaches at most a tile past the
+            # K block's first key (one tile divides the other): the band's
+            # lower edge is in it only where the window is under a tile
+            loop(first, clear, True, window < max(block_q, bk))
+            # the last row that sees this block's last key, and the first
+            # block one of whose rows no longer sees its first; past the
+            # diagonal every key is in a row's past: the edge's mask alone
+            last = jnp.minimum(
+                nq, ((kj + 1) * bk + window - 2) // block_q + 1)
+            edge = jnp.clip((kj * bk + window) // block_q, clear, last)
+            loop(clear, edge, False)
+            loop(edge, last, False, True)
     else:
         loop(0, nq, False)
 
@@ -392,6 +492,24 @@ def _bwd_tile(block: int, L: int) -> int:
     return block
 
 
+# A band's tiles, both passes, query and K alike: the caller's block, doubled
+# while it still divides L and stays within the window and the causal passes'
+# own bound. A block of ``t`` queries computes ``t + window`` keys' scores
+# (rounded up to whole tiles) for the ``window`` it needs, which argues for
+# small tiles, and a tile's fixed costs (the accumulator's rescaling, the
+# statistics, the loop) argue for large ones: they win. Measured on the chip
+# (PR 44, bf16, (2, 8192, 64, 128), window 512, ms a call, forward /
+# backward): tiles of 128 20.57 / 26.55, 256 12.70 / 17.25, 512 9.23 /
+# 16.24; forward at 512 x 256 9.82, 1024 x 512 9.81.
+_WINDOW_TILE = 512
+
+
+def _window_tile(block: int, L: int, window: int) -> int:
+    while 2 * block <= min(_WINDOW_TILE, window) and L % (2 * block) == 0:
+        block *= 2
+    return block
+
+
 # The forward's tiles, from the shape: a K tile of up to 512 rows, and a
 # query tile of up to 1,024 whose (d, bq) float32 accumulator stays within
 # 512 KiB: 1,024 rows at head widths 64 and 128, 512 at 256. Measured on
@@ -405,13 +523,19 @@ _FWD_TILE_Q = 1024
 _FWD_ACC = 1 << 17          # d * bq elements
 
 
-def _fwd_tiles(block_q: int, block_k: int, L: int, d: int) -> Tuple[int, int]:
+def _fwd_tiles(block_q: int, block_k: int, L: int, d: int,
+               window: Optional[int] = None) -> Tuple[int, int]:
     """The (query, K) tile the forward runs for a caller's blocks, which
     are the floor of both: each doubled while it still divides ``L`` and
     stays under its bound, the query tile from the least common multiple
     with the K tile, so that the diagonal crosses whole K tiles. No dtype
     in it: the accumulator and the score tile, which the bounds are for,
-    are float32 whatever the input."""
+    are float32 whatever the input. A band's tiles are ``_window_tile``'s,
+    with the same least common multiple."""
+    if window is not None:
+        block_k = _window_tile(block_k, L, window)
+        return int(np.lcm(_window_tile(block_q, L, window), block_k)), \
+            block_k
     while block_k < _FWD_TILE_K and L % (2 * block_k) == 0:
         block_k *= 2
     block_q = int(np.lcm(block_q, block_k))
@@ -457,10 +581,19 @@ def _flash_bwd_vmem_bytes(L: int, d: int, block_q: int, block_k: int,
 
 # Jitted, as ``_flash_forward`` is: the blocks of a model lower ONE Mosaic
 # module and call it N times.
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
-def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k):
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "window"))
+def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
+                    window=None):
     b, L, h, d = q.shape
-    block_q, block_k = _bwd_tile(block_q, L), _bwd_tile(block_k, L)
+    tile = _bwd_tile if window is None else functools.partial(
+        _window_tile, window=window)
+    block_q, block_k = tile(block_q, L), tile(block_k, L)
+    name = _BWD_NAME if window is None else _WINDOW_BWD_NAME
+    kernel = functools.partial(_flash_bwd_kernel, block_q=block_q,
+                               causal=causal, scale=1.0 / float(np.sqrt(d)))
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
     nq = L // block_q
     lse = lse.reshape(b, h, nq, block_q)    # rows of the backward's tile
     # rowsum(do * o): the softmax jacobian's contraction, once a call, read
@@ -482,11 +615,10 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k):
     # the scope's name is the call's instruction name in the compiled
     # program and so in a device trace: no `flash` in it, because the
     # benchmark's readers of the FORWARD find theirs by that word
-    with jax.named_scope(_BWD_NAME):
+    with jax.named_scope(name):
         grads = pl.pallas_call(
-            functools.partial(_flash_bwd_kernel, block_q=block_q,
-                              causal=causal, scale=1.0 / float(np.sqrt(d))),
-            name=_BWD_NAME,
+            kernel,
+            name=name,
             grid=(b, h, L // block_k),
             in_specs=[whole(L, d), block(), block(), whole(L, d),
                       whole(nq, block_q), whole(nq, block_q)],
@@ -504,13 +636,14 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k):
     return tuple(g.transpose(0, 2, 1, 3) for g in grads)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, res, do):
+def _flash_bwd_rule(causal, block_q, block_k, window, res, do):
     obsmetrics.counter("attention.flash_bwd_calls.pallas").inc()
     q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k)
+    return _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
+                           window=window)
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # ---------------------------------------------------------------------------

@@ -43,16 +43,21 @@ def _on_chip() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _reference_attention(q, k, v, causal: bool):
+def _reference_attention(q, k, v, causal: bool,
+                         window: Optional[int] = None):
     """Plain attention: matmuls in the input dtype (bf16 tiles the MXU);
     scores, softmax and the output accumulation in fp32, cast back once
-    at the end. The L x L scores go through HBM."""
+    at the end. The L x L scores go through HBM. With ``window`` a query
+    at ``i`` sees the keys ``j`` with ``0 <= i - j < window``."""
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("blhd,bkhd->bhlk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         L, K = s.shape[-2], s.shape[-1]
         mask = jnp.arange(K)[None, :] > jnp.arange(L)[:, None]
+        if window is not None:
+            mask = mask | (jnp.arange(L)[:, None] - jnp.arange(K)[None, :]
+                           >= window)
         s = jnp.where(mask, -jnp.inf, s)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhlk,bkhd->blhd", p.astype(v.dtype), v,
@@ -90,7 +95,8 @@ def _on_own_rows(kernel, q, k, v):
 
 def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    causal: bool = True,
-                   use_flash: str = "auto") -> jnp.ndarray:
+                   use_flash: str = "auto",
+                   window: Optional[int] = None) -> jnp.ndarray:
     """Attention (B, L, H, D) with the whole sequence on each device.
 
     The one place that picks the implementation, from the shape alone
@@ -107,19 +113,33 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     tests themselves) | "require" (a fused kernel or a ValueError, on the
     CPU in interpret mode — for callers whose result is only meaningful
     on a kernel: the ``longctx`` bench lane, ``chip_smoke.py``). Every
-    trace increments ``attention.fused_calls.<short|flash|reference>``;
-    under "auto" on an accelerator a trace that takes the reference also
-    increments ``attention.flash_fallbacks``, so the downgrade is visible
-    in metrics and reports.
+    trace increments ``attention.fused_calls.<short|flash|window|
+    reference>``; under "auto" on an accelerator a trace that takes the
+    reference also increments ``attention.flash_fallbacks``, so the
+    downgrade is visible in metrics and reports.
+
+    ``window`` (causal only): a query at ``i`` sees the keys ``j`` with
+    ``0 <= i - j < window``; a window of the whole row or more is the
+    plain causal call. The flash kernel takes a band (its calls are then
+    named ``window_attention_fwd`` / ``_bwd`` and counted under
+    ``.window``); the short kernel does not, so a windowed shape the
+    flash kernel refuses runs the masked reference and counts as a
+    fallback like any other.
     """
     if use_flash not in ("auto", "never", "require"):
         raise ValueError(f"unknown use_flash {use_flash!r}")
+    if window is not None:
+        from mmlspark_tpu.ops.pallas_attention import band
+        window = band(window, causal, q.shape[1])
     if use_flash == "require" or (use_flash == "auto" and _on_chip()):
         from mmlspark_tpu.ops import pallas_attention
         name = kernel = None
         if pallas_attention.supports(q.shape):
             name, kernel = "flash", pallas_attention.flash_attention
-        elif pallas_attention.supports_short(q.shape, q.dtype.itemsize):
+            if window is not None:
+                name, kernel = "window", partial(kernel, window=window)
+        elif window is None and pallas_attention.supports_short(
+                q.shape, q.dtype.itemsize):
             name, kernel = "short", pallas_attention.short_attention
         out = None if kernel is None else _on_own_rows(
             lambda q, k, v: kernel(q, k, v, causal), q, k, v)
@@ -133,7 +153,7 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 f"supports_short; the batch must split over the mesh)")
         obsmetrics.counter("attention.flash_fallbacks").inc()
     obsmetrics.counter("attention.fused_calls.reference").inc()
-    return _reference_attention(q, k, v, causal)
+    return _reference_attention(q, k, v, causal, window)
 
 
 # ---------------------------------------------------------------------------

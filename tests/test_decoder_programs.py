@@ -2,9 +2,11 @@
 check that a change to ``models/zoo/decoder.py`` or ``parts.py`` which is
 meant to move no family's arithmetic has moved none.
 
-Two files under ``tests/data/`` hold, for each of the five tiny presets,
+Two files under ``tests/data/`` hold, for each of the six tiny presets,
 what THIS module computed on commit 79fac30 (PR 42; float32, the CPU
-backend, every function under ``jax.jit``):
+backend, every function under ``jax.jit``; ``laguna_tiny``'s on PR 44's
+commit, which added the family: that PR's diff of the JSON shows the five
+older hashes unmoved):
 
 - ``decoder_parent_outputs.npz``: the logits; the ``hidden=True`` outputs
   the chunked loss reads (``hidden``, GLM's ``mtp_hidden``, every key of
@@ -19,7 +21,7 @@ A PR that changes a family's program on purpose remakes both with
 
     JAX_PLATFORMS=cpu python tests/test_decoder_programs.py --write
 
-(without ``--write`` it prints the five hashes and writes nothing), names
+(without ``--write`` it prints the six hashes and writes nothing), names
 its own commit here, and its diff of the JSON then shows which families it
 touched and which it did not.
 """
@@ -42,7 +44,7 @@ from mmlspark_tpu.models.zoo import build_model  # noqa: E402
 from mmlspark_tpu.train.lm_loss import next_token_loss  # noqa: E402
 
 PRESETS = ("glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
-           "olmo_hybrid_tiny", "lfm2_moe_tiny")
+           "olmo_hybrid_tiny", "lfm2_moe_tiny", "laguna_tiny")
 DATA = Path(__file__).resolve().parent / "data"
 OUTPUTS = DATA / "decoder_parent_outputs.npz"
 PROGRAMS = DATA / "decoder_parent_programs.json"

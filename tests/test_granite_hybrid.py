@@ -504,7 +504,7 @@ def test_each_block_recomputed_in_halves_is_the_block_not_recomputed():
 
 @pytest.mark.parametrize("preset", [
     "glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
-    "lfm2_moe_tiny"])
+    "lfm2_moe_tiny", "laguna_tiny"])
 def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
                                                           preset):
     """``_remat_block`` has ONE list of names for every family (the policy

@@ -343,6 +343,48 @@ def test_mosaic_compiles_the_flash_kernel_and_its_backward(
     assert " while(" not in text
 
 
+@pytest.mark.parametrize("shape,window", [
+    ((2, 8192, 64, 128), 512),      # laguna-xs.2-train-ep8share-8k: AT the
+                                    # cap, L x d = 2^20
+    ((1, 4096, 4, 64), 1280),       # a tile clear between edge and diagonal
+    ((1, 1024, 2, 128), 100),       # a window under a tile
+], ids=["laguna-d128-W512", "d64-W1280", "d128-W100"])
+def test_mosaic_compiles_the_windowed_kernels_under_names_of_their_own(
+        topo, as_on_chip, shape, window):
+    """A band's forward and backward through Mosaic for a v5e, and the
+    names they carry: the accepted patterns of the CAUSAL calls
+    (``kernel.flash_attention_ms`` / ``kernel.flash_fwd_roofline`` /
+    ``kernel.flash_bwd_ms``) must match neither, the ``attn.window_*``
+    patterns each its own, so that a cell with both kinds of layer reads
+    one shape of call under each name."""
+    from jax.sharding import SingleDeviceSharding
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: (
+            full_attention(q, k, v, causal=True, window=window).astype(
+                jnp.float32) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(grads).lower(x, x, x, x).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 2
+    causal = [_benchmark_pattern(m) for m in (
+        "kernel.flash_attention_ms", "kernel.flash_fwd_roofline",
+        "kernel.flash_bwd_ms")]
+    assert not [c for c in calls for rx in causal if rx.search(c)]
+    mine = [_benchmark_pattern(m) for m in (
+        "attn.window_fwd_ms", "attn.window_fwd_roofline",
+        "attn.window_bwd_ms", "attn.window_bwd_roofline")]
+    hits = [[bool(rx.search(c)) for rx in mine] for c in calls]
+    assert sorted(hits) == [[False, False, True, True],
+                            [True, True, False, False]]
+    b, L, h, _ = shape
+    assert f"[{b},{h},{L},{L}]" not in text
+    assert " while(" not in text
+
+
 @pytest.mark.parametrize("width", [64, 256], ids=["d64", "d256"])
 def test_a_recomputed_decoder_block_runs_the_flash_forward_once(
         topo, as_on_chip, width):
