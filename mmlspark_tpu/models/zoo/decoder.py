@@ -601,7 +601,8 @@ def lfm2_moe(vocab: int = 65536, dim: int = 2048,
     def attention(n):
         return GroupedAttention(dim, heads, kv_heads, head_dim, None, dtype,
                                 attention_fn, eps, norm_heads=True,
-                                theta=theta, name=n)
+                                rotary_freqs=plain_frequencies(
+                                    head_dim, theta), name=n)
 
     def conv(n):
         return ShortConv(dim, conv_taps, conv_bias, dtype, name=n)
@@ -669,7 +670,7 @@ def laguna(vocab: int = 100352, dim: int = 2048,
     (``parts.yarn_frequencies``; cos and sin times
     ``yarn_attention_factor``); ``"sliding_attention"`` sees the ``window``
     keys ``0 <= i - j < window`` and turns the whole head by plain rotary
-    at ``window_theta`` (both through ``parts.rotary_by_frequencies``).
+    at ``window_theta`` (both through ``parts.rotary``).
     Every layer has ``kv_heads`` key/value heads of ``head_dim``, no norm
     on q or k, and (``gating``) one sigmoid gate a query head from the
     layer's input. The feed-forward part by a third

@@ -6,12 +6,11 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
 
 - ``RMSNorm``: float32 in and out, epsilon from the configuration; with
   ``offset`` the scale is ``1 + w`` and ``w`` starts at zero;
-- ``rotary``: rotary positions on the last axis, half-split pairing
-  (dimension ``i`` turns with ``i + R/2``), float32 angles; with ``width``
-  on the first ``width`` dimensions only, the rest passing through;
-  ``rotary_by_frequencies``: the same by given inverse frequencies and a
-  factor (``plain_frequencies``, ``yarn_frequencies``), as one product
-  over the whole head;
+- ``rotary``: rotary positions on the last axis by given inverse
+  frequencies (``plain_frequencies``, ``yarn_frequencies``) and a factor:
+  ``n`` frequencies turn the first ``2 n`` dimensions, ``i`` with ``i +
+  n``, the rest passing through; float32 angles, one product over the
+  whole head. The one function that turns, in every family that turns;
 - ``MlaAttention``: multi-head latent attention in its expanded (training)
   form: a low-rank query, one compressed key/value row per token, a rotary
   slice on every query head and ONE rotary key shared by all heads;
@@ -22,7 +21,7 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
 - ``GroupedAttention``: the same grouped heads with nothing else: no
   positions, no gate, a softmax scale of its own, and if asked an RMS norm
   over the whole q and the whole k projection, or (``norm_heads``) over
-  each head's channels, and (``theta``) rotary positions on the whole head;
+  each head's channels, and (``rotary_freqs``) rotary positions;
 - ``ShortConv``: LFM2's gated short convolution, which IS the mixer:
   ``[B | C | x] = u W_in``, a causal depthwise convolution of three taps
   over ``B * x`` with no activation, the gate ``C`` on its output;
@@ -85,27 +84,8 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
-def rotary(x: jax.Array, theta: float,
-           width: Optional[int] = None) -> jax.Array:
-    """Rotary positions over the last axis of ``(B, L, H, R)``: position
-    ``l`` turns the pair ``(i, i + R/2)`` by ``l * theta**(-2i/R)``. With
-    ``width`` only the first ``width`` dimensions turn (pairs ``(i, i +
-    width/2)``, angles over ``width``) and the rest pass through."""
-    if width is not None and width != x.shape[-1]:
-        return jnp.concatenate(
-            [rotary(x[..., :width], theta), x[..., width:]], -1)
-    L, R = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, R, 2, dtype=jnp.float32) / R)
-    ang = jnp.arange(L, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def rotary_by_frequencies(x: jax.Array, inv_freq: Tuple[float, ...],
-                          factor: float = 1.0) -> jax.Array:
+def rotary(x: jax.Array, inv_freq: Tuple[float, ...],
+           factor: float = 1.0) -> jax.Array:
     """Rotary positions by GIVEN inverse frequencies over the last axis of
     ``(B, L, H, R)``: the first ``2 n`` dimensions turn, ``n`` =
     ``len(inv_freq)``, position ``l`` turning the pair ``(i, i + n)`` by
@@ -113,15 +93,15 @@ def rotary_by_frequencies(x: jax.Array, inv_freq: Tuple[float, ...],
     cos and sin both (YaRN's attention factor). The frequencies are made
     where the rule is known (``plain_frequencies``, ``yarn_frequencies``).
 
-    ``rotary``'s arithmetic in another form: ``x cos + pair(x) sin`` over
-    the WHOLE head, where ``pair(x) = [-x_2 | x_1 | 0]`` is a product with
-    the pairing's signed permutation matrix (each output is plus or minus
-    one input: exact in any dtype) and cos and sin are 1 and 0 on the
-    dimensions that pass. No half of a head is sliced out of the lanes: on
-    the chip the split form of a (2, 8192, 64, 128) bfloat16 tensor ran
-    4.83 ms forward and this one 1.30, with equal bits (PERF.md section 6,
-    PR 44). The families that turn by ``rotary`` keep it: their programs
-    are held to a named commit's (``tests/test_decoder_programs.py``)."""
+    Computed as ``x cos + pair(x) sin`` over the WHOLE head, where
+    ``pair(x) = [-x_2 | x_1 | 0]`` is a product with the pairing's signed
+    permutation matrix (each output is plus or minus one input: exact in
+    any dtype, float32 rows at ``Precision.HIGHEST``) and cos and sin are 1
+    and 0 on the dimensions that pass. No half of a head is sliced out of
+    the lanes: on the chip the split form (``[x_1 cos - x_2 sin | x_2 cos +
+    x_1 sin]`` of the two halves) of a (2, 8192, 64, 128) bfloat16 tensor
+    ran 4.83 ms forward and this one 1.30, with equal bits (PERF.md
+    section 6, PR 44; at the other families' shapes, PR 45)."""
     L, R = x.shape[1], x.shape[-1]
     n = len(inv_freq)
     if 2 * n > R:
@@ -218,9 +198,10 @@ class MlaAttention(nn.Module):
             kv = _dense(H * (self.nope + self.v_dim), dt,
                         "attn_key_value_b")(ckv).reshape(
                             B, L, H, self.nope + self.v_dim)
-            q_r = rotary(q[..., self.nope:], self.theta)
+            freqs = plain_frequencies(self.rope, self.theta)
+            q_r = rotary(q[..., self.nope:], freqs)
             # the one rotary key, shared by every head
-            k_r = rotary(kva[..., None, self.kv_rank:], self.theta)
+            k_r = rotary(kva[..., None, self.kv_rank:], freqs)
             q = jnp.concatenate([q[..., :self.nope], q_r], -1)
             k = jnp.concatenate(
                 [kv[..., :self.nope],
@@ -268,8 +249,9 @@ class GatedAttention(nn.Module):
             v = _dense(G * d, dt, "attn_value")(x).reshape(B, L, G, d)
             q = RMSNorm(self.eps, offset=True, name="query_norm")(q)
             k = RMSNorm(self.eps, offset=True, name="key_norm")(k)
-            q = rotary(q, self.theta, self.rotary_width).astype(dt)
-            k = rotary(k, self.theta, self.rotary_width).astype(dt)
+            freqs = plain_frequencies(self.rotary_width, self.theta)
+            q = rotary(q, freqs).astype(dt)
+            k = rotary(k, freqs).astype(dt)
             k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
             o = attn_fn(q, k, v, causal=True)
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(dt)
@@ -350,39 +332,39 @@ class GroupedAttention(nn.Module):
     """Causal softmax attention with grouped key/value heads and little
     else: no gate, no biases; ``softmax(scale x q k^T) v`` with a
     published ``scale`` that need not be ``head_dim ** -0.5``. Without
-    ``theta`` no positions (in ``granite_hybrid`` and ``olmo_hybrid`` the
-    recurrent layers carry the order); with it rotary positions on the
-    whole head of q and k (``lfm2_moe``). With ``qk_norm_eps`` an RMS norm
-    with a plain scale on q and on k (scope ``qk_norm``): over the WHOLE
-    projection before the split into heads (OLMo 2's ``q_norm`` /
-    ``k_norm``), or with ``norm_heads`` over EACH head's ``head_dim``
-    channels, one scale of ``head_dim`` shared by the heads (LFM2's
-    ``q_layernorm`` / ``k_layernorm``), float32 through the rotation;
-    without, none. LFM2's softmax layer is this part with two arguments
-    and not a third part: ``GatedAttention`` would need its ``1 + w``
-    scales, its rotary slice and its gate (which shapes ``W_q``) argued
-    away.
+    ``rotary_freqs`` no positions (in ``granite_hybrid`` and
+    ``olmo_hybrid`` the recurrent layers carry the order); with them q and
+    k turn by ``rotary`` (``lfm2_moe``: the whole head, by
+    ``plain_frequencies``). With ``qk_norm_eps`` an RMS norm with a plain
+    scale on q and on k (scope ``qk_norm``): over the WHOLE projection
+    before the split into heads (OLMo 2's ``q_norm`` / ``k_norm``), or
+    with ``norm_heads`` over EACH head's ``head_dim`` channels, one scale
+    of ``head_dim`` shared by the heads (LFM2's ``q_layernorm`` /
+    ``k_layernorm``), float32 through the rotation; without, none. LFM2's
+    softmax layer is this part with two arguments and not a third part:
+    ``GatedAttention`` would need its ``1 + w`` scales, its rotary slice
+    and its gate (which shapes ``W_q``) argued away.
     ``attention_fn(q, k, v)`` keeps its own ``head_dim ** -0.5``, so ``q``
     is multiplied by ``scale x head_dim ** 0.5`` before the call (0.125 in
     the published Granite: a power of two, exact in bfloat16). Each
     key/value head is repeated to the ``heads / kv_heads`` query heads it
     serves at that call, as in ``GatedAttention``.
 
-    Laguna's two softmax layers are this part at two settings, by four
+    Laguna's two softmax layers are this part at two settings, by three
     more arguments that each default to nothing: ``window`` (a query at
     ``i`` sees the keys ``0 <= i - j < window``; handed to
     ``attention_fn`` as ``window=``, and the whole mixer then lies under
-    the scope ``window_attention_layer``); ``rotary_freqs`` /
-    ``rotary_factor`` (given inverse frequencies in ``theta``'s place,
-    which turn the first ``2 len(rotary_freqs)`` dimensions of a head, and
-    a factor on cos and sin: ``rotary_by_frequencies``, with
-    ``plain_frequencies`` or YaRN's, ``yarn_frequencies``); ``head_gate``
-    (one sigmoid gate a query head from the part's input, float32: ``g =
-    sigmoid(x W_g)``, ``W_g`` ``dim x heads``, ``o_h <- g_h o_h`` before
-    ``W_o``; scope ``head_gate``). It grew and no part was added beside
-    it: the projections, the grouping, the repeat and the call are these
-    to the letter, and ``GatedAttention``'s gate is an element's, shapes
-    ``W_q`` and comes with norms of the ``1 + w`` kind."""
+    the scope ``window_attention_layer``); ``rotary_factor`` (on cos and
+    sin, beside ``rotary_freqs`` that turn the first ``2
+    len(rotary_freqs)`` dimensions of a head: ``plain_frequencies`` in the
+    sliding layers, YaRN's ``yarn_frequencies`` in the full ones);
+    ``head_gate`` (one sigmoid gate a query head from the part's input,
+    float32: ``g = sigmoid(x W_g)``, ``W_g`` ``dim x heads``, ``o_h <- g_h
+    o_h`` before ``W_o``; scope ``head_gate``). It grew and no part was
+    added beside it: the projections, the grouping, the repeat and the
+    call are these to the letter, and ``GatedAttention``'s gate is an
+    element's, shapes ``W_q`` and comes with norms of the ``1 + w``
+    kind."""
     dim: int
     heads: int
     kv_heads: int
@@ -392,9 +374,8 @@ class GroupedAttention(nn.Module):
     attention_fn: Optional[Callable] = None
     qk_norm_eps: Optional[float] = None     # None: no norm on q and k
     norm_heads: bool = False            # the norm over each head, not all
-    theta: Optional[float] = None       # None: no positions
     window: Optional[int] = None        # None: the whole causal half
-    rotary_freqs: Optional[Tuple[float, ...]] = None    # in theta's place
+    rotary_freqs: Optional[Tuple[float, ...]] = None    # None: no positions
     rotary_factor: float = 1.0
     head_gate: bool = False
 
@@ -422,10 +403,7 @@ class GroupedAttention(nn.Module):
                     with jax.named_scope("qk_norm"):
                         y = RMSNorm(self.qk_norm_eps, name=norm)(y)
                 if norm and self.rotary_freqs is not None:
-                    y = rotary_by_frequencies(y, self.rotary_freqs,
-                                              self.rotary_factor)
-                elif norm and self.theta is not None:
-                    y = rotary(y, self.theta)
+                    y = rotary(y, self.rotary_freqs, self.rotary_factor)
                 return y.astype(dt)
             q = heads_of("attn_query", H, "query_norm")
             k = heads_of("attn_key", G, "key_norm")

@@ -25,7 +25,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from benchmark.references import glm47_flash as ref  # noqa: E402
 from mmlspark_tpu.models.zoo import build_model  # noqa: E402
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    MlaAttention, SwiGluMlp, rotary)
+    MlaAttention, SwiGluMlp, plain_frequencies, rotary)
 from mmlspark_tpu.models.zoo import moe  # noqa: E402
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.train.lm_loss import (  # noqa: E402
@@ -594,7 +594,8 @@ def test_a_layer_that_holds_every_expert_lowers_no_cond():
 # --------------------------------------------------- attention's parts
 def test_rotary_turns_pairs_by_position_and_keeps_norms():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 5, 3, 8))
-    y = rotary(x, 1e4)
+    freqs = plain_frequencies(8, 1e4)
+    y = rotary(x, freqs)
     np.testing.assert_allclose(y[:, 0], x[:, 0], atol=1e-6)  # position 0
     np.testing.assert_allclose(jnp.linalg.norm(y, axis=-1),
                                jnp.linalg.norm(x, axis=-1), rtol=1e-5)
@@ -608,8 +609,8 @@ def test_rotary_turns_pairs_by_position_and_keeps_norms():
     q, k = x[:, :1, :1], x[:, 1:2, :1]
     def dot(lq, lk):
         pad = lambda v, l: jnp.pad(v, ((0, 0), (l, 0), (0, 0), (0, 0)))
-        return jnp.sum(rotary(pad(q, lq), 1e4)[:, lq]
-                       * rotary(pad(k, lk), 1e4)[:, lk])
+        return jnp.sum(rotary(pad(q, lq), freqs)[:, lq]
+                       * rotary(pad(k, lk), freqs)[:, lk])
     np.testing.assert_allclose(dot(4, 1), dot(7, 4), rtol=1e-5)
 
 

@@ -30,7 +30,7 @@ from mmlspark_tpu.models.zoo import build_model  # noqa: E402
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
     GroupedAttention, SwiGluMlp, plain_frequencies, rotary,
-    rotary_by_frequencies, yarn_frequencies)
+    yarn_frequencies)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import pallas_attention as pa  # noqa: E402
 from mmlspark_tpu.parallel import sequence  # noqa: E402
@@ -140,16 +140,32 @@ def test_yarn_frequencies_keep_the_fast_pairs_and_slow_the_slow_ones():
     assert toy[0] == 1.0 and 0 < toy[1] < 5e5 ** -0.5
 
 
+def _split_form(x, inv_freq, factor=1.0):
+    """The turn as the published models write it, in NumPy: the two halves
+    of the first ``2 n`` dimensions sliced out, ``[x_1 cos - x_2 sin | x_2
+    cos + x_1 sin]``, the rest put back behind them; float32, then the
+    input's dtype. (cos and sin from jax: NumPy's differ in a last bit.)"""
+    n = len(inv_freq)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos, sin = (np.asarray(f(ang) * factor)[None, :, None, :]
+                for f in (jnp.cos, jnp.sin))
+    x32 = np.asarray(x.astype(jnp.float32))
+    x1, x2 = x32[..., :n], x32[..., n:2 * n]
+    return jnp.asarray(np.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x32[..., 2 * n:]],
+        -1)).astype(x.dtype)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_rotary_by_frequencies_turns_the_pairs_it_is_given(dtype):
+def test_rotary_turns_the_pairs_it_is_given(dtype):
     """The first ``2 n`` dimensions turn by the given frequencies, pair
-    ``(i, i + n)``, cos and sin times the factor; the rest pass; with
-    plain rotary's own frequencies and factor 1 it is ``rotary`` to the
-    bit (the product with the pairing's matrix moves values, it rounds
-    none)."""
+    ``(i, i + n)``, cos and sin times the factor; the rest pass; it is the
+    split form to the bit (the product with the pairing's matrix moves
+    values, it rounds none)."""
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 12, 2, 8), dtype)
     freqs = (1.0, 0.25)
-    got = rotary_by_frequencies(x, freqs, 1.5)
+    got = rotary(x, freqs, 1.5)
     assert got.dtype == dtype
     assert np.array_equal(np.asarray(got[..., 4:]), np.asarray(x[..., 4:]))
     x32 = np.asarray(x.astype(jnp.float32))
@@ -162,18 +178,51 @@ def test_rotary_by_frequencies_turns_the_pairs_it_is_given(dtype):
                                x1 * cos - x2 * sin, **tol)
     np.testing.assert_allclose(got[..., 2:4].astype(jnp.float32),
                                x2 * cos + x1 * sin, **tol)
-    whole = rotary_by_frequencies(x, plain_frequencies(8, 1e4))
-    if dtype == jnp.bfloat16:       # the frequencies' float32 agree there
-        assert np.array_equal(np.asarray(whole), np.asarray(rotary(x, 1e4)))
-    np.testing.assert_allclose(whole.astype(jnp.float32),
-                               rotary(x, 1e4).astype(jnp.float32), **tol)
+    assert np.array_equal(np.asarray(got),
+                          np.asarray(_split_form(x, freqs, 1.5)))
+    whole = plain_frequencies(8, 1e4)
+    assert np.array_equal(np.asarray(rotary(x, whole)),
+                          np.asarray(_split_form(x, whole)))
     # and it differentiates as the split form does
-    g = jax.grad(lambda x: (rotary_by_frequencies(x, freqs, 1.5).astype(
+    g = jax.grad(lambda x: (rotary(x, freqs, 1.5).astype(
         jnp.float32) ** 2).sum())(x)
     assert g.shape == x.shape and bool(jnp.isfinite(
         g.astype(jnp.float32)).all())
     with pytest.raises(ValueError, match="frequencies"):
-        rotary_by_frequencies(x, (1.0,) * 5)
+        rotary(x, (1.0,) * 5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shape,width,theta", [
+    ((2, 16, 4, 64), 64, 1e6),      # lfm2: the whole head
+    ((2, 16, 3, 256), 64, 1e7),     # qwen: the first 64 of 256
+    ((2, 16, 1, 64), 64, 1e6),      # GLM: the one shared 64-wide key
+], ids=["whole-head", "leading-slice", "one-shared-key"])
+def test_the_one_turn_is_the_split_form_at_every_familys_shape(
+        shape, width, theta, dtype):
+    """Every family that turns does so through ``rotary``; at each one's
+    head width, turned width and ``theta`` its values are the split form's
+    BIT FOR BIT in float32 and in bfloat16, and its gradient (the turn by
+    the opposite angle, of the cotangent) to a few units in the last place:
+    the backward of a product sums in another order than a slice's, and a
+    bfloat16 cotangent's two terms are rounded before they are added."""
+    freqs = plain_frequencies(width, theta)
+    x = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32
+                          ).astype(dtype)
+    got = rotary(x, freqs)
+    assert got.dtype == dtype and got.shape == shape
+    # (op by op: under ``jit`` the CPU backend contracts a multiply and an
+    # add into one rounding, which NumPy does not)
+    assert np.array_equal(np.asarray(got), np.asarray(_split_form(x, freqs)))
+    w = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
+    g = jax.grad(lambda x: (rotary(x, freqs).astype(jnp.float32) * w).sum()
+                 )(x)
+    assert g.dtype == dtype
+    want = _split_form(w, tuple(-f for f in freqs)).astype(dtype)
+    ulp = float(jnp.finfo(dtype).eps)
+    np.testing.assert_allclose(
+        np.asarray(g.astype(jnp.float32)),
+        np.asarray(want.astype(jnp.float32)), rtol=4 * ulp, atol=4 * ulp)
 
 
 # ------------------------------------------------------- the softmax part

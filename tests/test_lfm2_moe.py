@@ -31,7 +31,7 @@ from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
     LFM2_24B_A2B_LAYERS)
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    SHORT_CONV_IN, GroupedAttention, ShortConv)
+    SHORT_CONV_IN, GroupedAttention, ShortConv, plain_frequencies)
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.train.lm_loss import next_token_loss  # noqa: E402
@@ -173,7 +173,8 @@ def test_attention_with_a_norm_a_head_and_rotary_positions(params):
     reference's layer, one sequence at a time."""
     d = ref.dims(CFG)
     layer = GroupedAttention(32, 4, 2, 8, None, jnp.float32, None, 1e-5,
-                             norm_heads=True, theta=1e6)
+                             norm_heads=True,
+                             rotary_freqs=plain_frequencies(8, 1e6))
     p = params["params"]["block1"]["attn"]
     assert p["query_norm"]["scale"].shape == (8,)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 12, 32))
@@ -186,7 +187,7 @@ def test_attention_with_a_norm_a_head_and_rotary_positions(params):
     assert not np.allclose(rolled[:, 2:], got[:, 1:-1], atol=1e-3)
     # ... and the norm is over a head, not over the whole projection
     whole = GroupedAttention(32, 4, 2, 8, None, jnp.float32, None, 1e-5,
-                             theta=1e6)
+                             rotary_freqs=plain_frequencies(8, 1e6))
     assert whole.init(jax.random.PRNGKey(0), x)["params"]["query_norm"][
         "scale"].shape == (32,)
 

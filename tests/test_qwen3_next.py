@@ -33,7 +33,7 @@ from benchmark.references import qwen3_next as ref  # noqa: E402
 from mmlspark_tpu.models.zoo import build_model  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import qwen3_next_layers  # noqa: E402
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    GatedAttention, GatedDeltaNet, SwiGluMlp, rotary)
+    GatedAttention, GatedDeltaNet, SwiGluMlp, plain_frequencies, rotary)
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
@@ -372,13 +372,13 @@ def test_delta_net_layer_is_the_reference_layer():
 
 def test_rotary_turns_only_the_first_quarter():
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 3, 16))
-    got = rotary(x, 1e7, 4)
+    freqs = plain_frequencies(4, 1e7)
+    got = rotary(x, freqs)
     np.testing.assert_array_equal(got[..., 4:], x[..., 4:])
-    np.testing.assert_allclose(got[..., :4], rotary(x[..., :4], 1e7),
-                               rtol=1e-6)
+    # the lanes that pass add nothing to the ones that turn, to the bit
+    np.testing.assert_array_equal(got[..., :4], rotary(x[..., :4], freqs))
     np.testing.assert_array_equal(got[:, 0], x[:, 0])       # position 0
     assert np.abs(np.asarray(got[:, 1:, :, :4] - x[:, 1:, :, :4])).min() > 0
-    np.testing.assert_array_equal(rotary(x, 1e7, 16), rotary(x, 1e7))
     for b in range(2):
         np.testing.assert_allclose(ref._rotary(x[b], 1e7, 4), got[b],
                                    rtol=1e-5, atol=1e-6)
