@@ -50,9 +50,9 @@ import jax.numpy as jnp
 from mmlspark_tpu.models.zoo import register_model
 from mmlspark_tpu.models.zoo.moe import DroplessMoe
 from mmlspark_tpu.models.zoo.parts import (
-    _INIT, DELTA_NET_QKVZ, MLP_GATE_UP, SHORT_CONV_IN, GatedAttention,
-    GatedDeltaNet, GroupedAttention, Head, Mamba2Mixer, MlaAttention,
-    RMSNorm, ShortConv, SwiGluMlp, _dense, plain_frequencies,
+    _INIT, ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SHORT_CONV_IN,
+    GatedAttention, GatedDeltaNet, GroupedAttention, Head, Mamba2Mixer,
+    MlaAttention, RMSNorm, ShortConv, SwiGluMlp, _dense, plain_frequencies,
     yarn_frequencies)
 
 # a part's factory: the flax name (None inside a block, whose ``setup``
@@ -137,37 +137,47 @@ def _remat_block(norm, attention, ffn, name: str, split: bool = False,
     rule's forward call writes, 12 a Gated DeltaNet block, without which
     ``delta_chunk_fwd`` runs twice a block; that block's input projection
     (``DELTA_NET_QKVZ``, the ``[q | k | v | z]`` rows), 6; a
-    ``ShortConv``'s (``SHORT_CONV_IN``, the ``[B | C | x]`` rows), 3. Each
-    paid on the chip (PERF.md section 6; PR 29: +5.6% and +1.5% of a
-    ``glm4_moe_lite`` step; PR 36: the tiles +3.7% and the projection
-    +2.0% of a ``qwen3_next`` step, the products +5.3% of a
-    ``granite_hybrid`` step; PR 40: the short convolution's projection
-    +3.5% of an ``lfm2_moe`` step, whose mark went 13.64 -> 14.43 GB).
+    ``ShortConv``'s (``SHORT_CONV_IN``, the ``[B | C | x]`` rows), 3; a
+    ``Mamba2Mixer``'s (``MAMBA2_IN``, the ``[z | x | B | C | dt]`` rows),
+    4.2 (1.26 GB a step over ``granite_hybrid``'s nine mixers); a
+    ``GroupedAttention``'s q, k and v projections' outputs (``ATTN_QKV``:
+    named before the norm a head, the turn, the scale and the repeat of K
+    and V, which are bytes and no product and stay recomputed), 5 in
+    ``laguna``'s sliding blocks and 4 in its full ones (1.54 GB a step),
+    1.5 in ``granite_hybrid``'s and ``lfm2_moe``'s. Each paid on the chip
+    (PERF.md section 6; PR 29: +5.6% and +1.5% of a ``glm4_moe_lite``
+    step; PR 36: the tiles +3.7% and the projection +2.0% of a
+    ``qwen3_next`` step, the products +5.3% of a ``granite_hybrid`` step;
+    PR 40: the short convolution's projection +3.5% of an ``lfm2_moe``
+    step, whose mark went 13.64 -> 14.43 GB; PR 46: the mixer's rows, with
+    the one softmax layer's q, k, v, +3.8% of a ``granite_hybrid`` step,
+    13.8 + 0.6 ms of a recompute column of 44.0, mark 14.04 -> 15.09 GB;
+    q, k, v +3.5% of a ``laguna`` step, 18.9 ms of 91.9, 15.10 -> 16.02
+    GB, and +0.4% of an ``lfm2_moe`` step, 14.51 -> 14.71 GB).
     Left to the recomputation: the residual stream after attention (1 a
-    block, +0.7%: under the 1% a name has to pay); q, k, v (7.5 a block,
-    1.5 GB a step, for under 10 ms); the
-    routed experts' ragged_dot intermediates (1 GB a step for 5 ms, and
-    the benchmark's moe.expert_matmul_roofline counts their recomputation
-    as required work); ``granite_hybrid``'s mixer's input projection
-    (1.26 GB a step for 13.7 ms: one run read +3.4%, the next issue's to
-    measure, PERF.md section 7);
-    dots_with_no_batch_dims_saveable (about 3 GB: no room beside
+    block, +0.7%: under the 1% a name has to pay); ``MlaAttention``'s q,
+    k, v (7.5 a block, 1.5 GB a step, for under 10 ms: PR 29, so that part
+    carries no name, nor does ``GatedAttention``); the routed experts'
+    ragged_dot intermediates (1 GB a step for 5 ms, and the benchmark's
+    moe.expert_matmul_roofline counts their recomputation as required
+    work); dots_with_no_batch_dims_saveable (about 3 GB: no room beside
     AdamW's state). Attention that is not the flash kernel carries no
-    such name and keeps what it kept before; a delta rule that runs XLA's
-    batched form (head widths ``pallas_delta_rule.supports`` refuses: the
-    tiny presets') names no tiles, and either rule's walk keeps a state a
-    chunk across ITS backward inside the
-    recomputation, where no name reaches. ``split`` recomputes the
-    block's two halves apart (``mix``, ``feed``) and keeps the residual
-    stream between them: for a block whose halves' backward passes do not
-    fit side by side. ``residual_scale`` and ``norm_output`` are the
-    block's. (Imported here: Pallas costs every importer of the zoo over a
-    second.)"""
+    residuals' name and keeps of its core what it kept before; a delta
+    rule that runs XLA's batched form (head widths
+    ``pallas_delta_rule.supports`` refuses: the tiny presets') names no
+    tiles, and either rule's walk keeps a state a chunk across ITS
+    backward inside the recomputation, where no name reaches. ``split``
+    recomputes the block's two halves apart (``mix``, ``feed``) and keeps
+    the residual stream between them: for a block whose halves' backward
+    passes do not fit side by side. ``residual_scale`` and ``norm_output``
+    are the block's. (Imported here: Pallas costs every importer of the
+    zoo over a second.)"""
     from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS
     from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
     policy = jax.checkpoint_policies.save_only_these_names(*(
         n for n in (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES,
-                    DELTA_NET_QKVZ, SHORT_CONV_IN) if n not in let_go))
+                    DELTA_NET_QKVZ, SHORT_CONV_IN, MAMBA2_IN, ATTN_QKV)
+        if n not in let_go))
     return nn.remat(PartsBlock, policy=policy,
                     methods=("mix", "feed") if split else None)(
         norm, attention, ffn, residual_scale, norm_output, name=name)
@@ -458,7 +468,8 @@ def granite_hybrid(vocab: int = 100352, dim: int = 2048,
     = (h E^T) / logits_scaling``), ONE table read by the embedding's
     gather and by the head. Each block is recomputed in halves and keeps
     what ``_remat_block`` names for every family: here the flash kernel's
-    residuals in the softmax layers and the gate and up products, 8,192
+    residuals and the q, k and v rows in the softmax layers, each mixer's
+    ``[z | x | B | C | dt]`` rows and the gate and up products, 8,192
     wide, in every layer."""
     def attention(n):
         return GroupedAttention(dim, heads, kv_heads, head_dim,
@@ -519,16 +530,20 @@ def olmo_hybrid(vocab: int = 100352, dim: int = 3840,
     num_attention_heads`` (the config has no key for it).
 
     Each block is recomputed in halves and keeps ``_remat_block``'s names
-    but the SwiGLU products (``let_go``): this family's 928.9M parameters
-    at the benchmark's cut are 11.15 GB of weights and moments on a chip
-    of 16.91, the least room of any family here. The step compiled for a
-    described v5e peaks at 17.19 GB with all four names (refused), 16.41
+    but the SwiGLU products and the softmax layer's q, k and v rows
+    (``let_go``): this family's 928.9M parameters at the benchmark's cut
+    are 11.15 GB of weights and moments on a chip of 16.91, the least room
+    of any family here. The step compiled for a described v5e peaks at
+    17.19 GB with the four names a block of PR 38 carried (refused), 16.41
     without the delta net's input projection, 16.35 without the SwiGLU
     gate and up products (11,008 wide: 5.7 units of the block's bf16 input
     a layer, the cheapest name a byte by PR 36's readings), 15.74 with the
     flash kernel's residuals alone; on the chip (PERF.md section 6, PR 38)
     the sub-list without the products ran 1.640 rows/s, the one without
-    the projection 1.622, tiles and residuals alone 1.564."""
+    the projection 1.622, tiles and residuals alone 1.564. ``ATTN_QKV``
+    (PR 46) would be 0.19 GB of the 0.38 the step's mark, 16.53 GB, leaves:
+    let go unmeasured, and the step lowers to the text it had (PERF.md
+    section 6, PR 46)."""
     def attention(n):
         return GroupedAttention(dim, heads, heads, head_dim, None, dtype,
                                 attention_fn, eps, name=n)
@@ -544,8 +559,8 @@ def olmo_hybrid(vocab: int = 100352, dim: int = 3840,
     return _spec(Decoder(
         vocab, dim, mixers,
         (lambda n: SwiGluMlp(dim, mlp_hidden, dtype, name=n),) * len(mixers),
-        _rms(eps), split=True, norm_output=True, let_go=(MLP_GATE_UP,),
-        dtype=dtype), max_len)
+        _rms(eps), split=True, norm_output=True,
+        let_go=(MLP_GATE_UP, ATTN_QKV), dtype=dtype), max_len)
 
 
 _OLMO_TINY = dict(vocab=96, dim=32,
@@ -594,8 +609,9 @@ def lfm2_moe(vocab: int = 65536, dim: int = 2048,
     = ``(count, first)`` as for ``glm4_moe_lite``; ``gate_grad=False`` for
     a share trained without its exchange (``DroplessMoe``). Each block is
     recomputed whole and keeps ``_remat_block``'s names: here the flash
-    kernel's residuals in the softmax layers, the gate and up products of
-    the dense part and each short convolution's ``[B | C | x]`` rows."""
+    kernel's residuals and the q, k and v rows in the softmax layers, the
+    gate and up products of the dense part and each short convolution's
+    ``[B | C | x]`` rows."""
     held = None if experts_held is None else tuple(experts_held)
 
     def attention(n):
@@ -682,8 +698,16 @@ def laguna(vocab: int = 100352, dim: int = 2048,
     ungated shared expert of ``shared_hidden``. Plain RMS norms, untied
     tables. ``experts_held`` = ``(count, first)`` as for ``glm4_moe_lite``;
     ``gate_grad=False`` for a share trained without its exchange
-    (``DroplessMoe``). Each block is recomputed whole and keeps all of
-    ``_remat_block``'s names."""
+    (``DroplessMoe``).
+
+    Each block is recomputed whole and keeps all of ``_remat_block``'s
+    names: here the kernels' residuals, the SwiGLU products of the dense
+    part and the shared experts, and every layer's q, k and v rows before
+    the turn and the repeat (1.54 GB a step at the benchmark's cut, for
+    18.9 ms of products not made twice: the step's mark 15.10 -> 16.02 GB
+    of 16.91, PERF.md section 6, PR 46; what a block still makes again is
+    the gate a head, the turn, the repeat and the output projection's
+    input)."""
     held = None if experts_held is None else tuple(experts_held)
     kinds = tuple(layer_types)
     heads = tuple(LAGUNA_XS2_HEADS.get(k) for k in kinds) \

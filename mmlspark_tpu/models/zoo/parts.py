@@ -40,7 +40,7 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
 Parameter names hit the rules of ``parallel/sharding.DEFAULT_RULES``
 (``attn_query*`` / ``attn_key*`` / ``attn_value`` / ``attn_qkvz`` /
 ``attn_gate_value_key_query_dt`` / ``attn_in`` / ``attn_out``, ``mlp_gate``
-/ ``mlp_up`` / ``mlp_down``, ``lm_head``). The three checkpoint names are
+/ ``mlp_up`` / ``mlp_down``, ``lm_head``). The five checkpoint names are
 the values a recomputed block may keep (``decoder._remat_block``); each sits
 where its value is made.
 """
@@ -66,6 +66,10 @@ MLP_GATE_UP = "mlp_gate_up"
 DELTA_NET_QKVZ = "delta_net_qkvz"
 # and of ``ShortConv``'s
 SHORT_CONV_IN = "short_conv_in"
+# and of ``Mamba2Mixer``'s
+MAMBA2_IN = "mamba2_in"
+# and of ``GroupedAttention``'s q, k and v projections' outputs
+ATTN_QKV = "attn_qkv"
 
 
 class RMSNorm(nn.Module):
@@ -393,7 +397,7 @@ class GroupedAttention(nn.Module):
             x32, x = x, x.astype(dt)
 
             def heads_of(name, heads, norm=None):
-                y = _dense(heads * d, dt, name)(x)
+                y = checkpoint_name(_dense(heads * d, dt, name)(x), ATTN_QKV)
                 normed = norm and self.qk_norm_eps is not None
                 if normed and not self.norm_heads:
                     with jax.named_scope("qk_norm"):
@@ -501,8 +505,10 @@ class Mamba2Mixer(nn.Module):
         d_in, mixed, dt_, f32 = H * P, H * P + 2 * G * N, self.dtype, \
             jnp.float32
         with jax.named_scope("mamba2_mixer"):
-            zxbcdt = _dense(d_in + mixed + H, dt_,
-                            "attn_gate_value_key_query_dt")(u.astype(dt_))
+            zxbcdt = checkpoint_name(
+                _dense(d_in + mixed + H, dt_,
+                       "attn_gate_value_key_query_dt")(u.astype(dt_)),
+                MAMBA2_IN)
             conv = self.param("conv_kernel", _INIT,
                               (self.conv_width, mixed), f32)
             conv_bias = self.param("conv_bias", _INIT, (mixed,), f32)

@@ -3,16 +3,20 @@ check that a change to ``models/zoo/decoder.py`` or ``parts.py`` which is
 meant to move no family's arithmetic has moved none.
 
 Two files under ``tests/data/`` hold, for each of the six tiny presets,
-what THIS module computed on PR 45's commit (float32, the CPU backend,
-every function under ``jax.jit``). That PR moved GLM's, qwen's and lfm2's
-positions from the split form of the turn to ``parts.rotary``'s product
-and remade both files: its diff of the JSON shows those three hashes moved
-and granite's, olmo's and laguna's not (they are commit 79fac30's, PR 42,
-and PR 44's, which added laguna); of the ``.npz`` every forward key is
-the parent's bit for bit and two gradients moved in their last digits,
-``qwen3_next_tiny.experts_gate`` and ``.router`` (the backward of a
-product sums in another order than a slice's; by at most 3.7e-12 where the
-largest element is 3.4e-5):
+what THIS module computed on PR 46's commit (float32, the CPU backend,
+every function under ``jax.jit``). That PR put two names on
+``_remat_block``'s one list (``MAMBA2_IN``, ``ATTN_QKV``) and remade the
+JSON: its diff shows granite's, lfm2's and laguna's hashes moved (their
+blocks keep rows they made again before), GLM's and qwen's not (no value
+of theirs carries either name), and olmo's moved although it lets
+``ATTN_QKV`` go: its text differs from the parent's ONLY in the numbers
+jax appends to private functions' names (``@_where_178`` ->
+``@_where_179``: one more function is traced and none more is called;
+with the symbols renamed in order of appearance the two texts are equal).
+The ``.npz`` did not move: every key is the parent's bit for bit, the
+gradients too (PR 45, which moved GLM's, qwen's and lfm2's positions to
+``parts.rotary``'s product, made it; granite's, olmo's and laguna's
+outputs are commit 79fac30's, PR 42, and PR 44's):
 
 - ``decoder_parent_outputs.npz``: the logits; the ``hidden=True`` outputs
   the chunked loss reads (``hidden``, GLM's ``mtp_hidden``, every key of

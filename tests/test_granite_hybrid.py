@@ -35,8 +35,8 @@ from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
     GRANITE_4_H_MICRO_LAYERS, PartsBlock)
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    DELTA_NET_QKVZ, MLP_GATE_UP, SHORT_CONV_IN, GroupedAttention,
-    Mamba2Mixer, RMSNorm, SwiGluMlp)
+    ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SHORT_CONV_IN,
+    GroupedAttention, Mamba2Mixer, RMSNorm, SwiGluMlp)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
 from mmlspark_tpu.train.lm_loss import next_token_loss  # noqa: E402
@@ -527,7 +527,41 @@ def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     assert seen and set(seen) == {
         (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES, DELTA_NET_QKVZ,
-         SHORT_CONV_IN)}
+         SHORT_CONV_IN, MAMBA2_IN, ATTN_QKV)}
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "let_go"])
+def test_the_kept_mixer_rows_change_no_gradient(monkeypatch, params, kept):
+    """``MAMBA2_IN`` names the ``[z | x | B | C | dt]`` rows for
+    ``_remat_block``'s one list: kept or made again, the gradients are
+    those of blocks that recompute nothing, and with the name kept a
+    recomputed block multiplies by ``W_in`` no second time."""
+    import flax.linen as nn
+    tokens = jnp.asarray(_tokens(6)[0])
+
+    def grads():
+        module = _module()
+        return jax.grad(lambda p: jnp.sum(module.apply(
+            p, tokens, hidden=True)["hidden"] ** 2))
+    if not kept:
+        real = decoder._remat_block
+        monkeypatch.setattr(
+            decoder, "_remat_block", lambda *a, **kw: real(
+                *a, **dict(kw, let_go=(MAMBA2_IN,))))
+    got = jax.jit(grads())(params)
+    text = str(jax.make_jaxpr(grads())(params))
+    # 4 mixers, 32 + 48 + 4 columns: the forward's product, and the
+    # recomputation's only where the rows are let go (the backward's two
+    # products give (2, 20, 32) and (32, 84))
+    wide = text.count("f32[2,20,84] = dot_general")
+    assert wide == (4 if kept else 8), wide
+    monkeypatch.setattr(nn, "remat", lambda cls, **kw: cls)
+    for (path, g), w in zip(
+            jax.tree_util.tree_leaves_with_path(got),
+            jax.tree_util.tree_leaves(jax.jit(grads())(params))):
+        np.testing.assert_allclose(
+            g, w, rtol=1e-5, atol=1e-5 * float(jnp.abs(w).max()) + 1e-9,
+            err_msg=jax.tree_util.keystr(path))
 
 
 # ------------------------------------------------------------ the parts

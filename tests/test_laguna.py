@@ -14,6 +14,7 @@ in interpret mode against the masked dense product 2e-5 absolute on unit
 normal inputs. What has to be exact is exact: the band's edge, a frozen
 gate's zero gradient, ``window=None`` against today's call.
 """
+import re
 import sys
 from pathlib import Path
 
@@ -26,10 +27,10 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmark.references import laguna as ref  # noqa: E402
-from mmlspark_tpu.models.zoo import build_model  # noqa: E402
+from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.moe import DroplessMoe  # noqa: E402
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    GroupedAttention, SwiGluMlp, plain_frequencies, rotary,
+    ATTN_QKV, GroupedAttention, SwiGluMlp, plain_frequencies, rotary,
     yarn_frequencies)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import pallas_attention as pa  # noqa: E402
@@ -665,3 +666,35 @@ def test_the_sliding_layers_name_their_scopes_and_residuals():
     assert pa.FLASH_RESIDUALS in jaxpr
     assert jaxpr.count(pa._WINDOW_FWD_NAME) == 1
     assert jaxpr.count(pa._WINDOW_BWD_NAME) == 1
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "let_go"])
+def test_the_kept_q_k_v_rows_change_no_gradient(monkeypatch, params, kept):
+    """``ATTN_QKV`` names the q, k and v projections' outputs (before the
+    turn, the scale and the repeat of K and V) for ``_remat_block``'s one
+    list: kept or made again, the gradients are those of blocks that
+    recompute nothing, and with the name kept a recomputed block
+    multiplies by ``W_q``, ``W_k`` and ``W_v`` no second time."""
+    import flax.linen as nn
+    tokens = jnp.asarray(_tokens(6)[0])
+
+    def grads():
+        module = _module()
+        return jax.grad(lambda p: jnp.sum(module.apply(
+            p, tokens, hidden=True)["hidden"] ** 2))
+    if not kept:
+        real = decoder._remat_block
+        monkeypatch.setattr(
+            decoder, "_remat_block", lambda *a, **kw: real(
+                *a, **dict(kw, let_go=(ATTN_QKV,))))
+    got = jax.jit(grads())(params)
+    text = str(jax.make_jaxpr(grads())(params))
+    # rows times a (32, 16) weight: k and v of all four layers and q of
+    # the two full ones (2 heads of 8), ten products a pass; the backward's
+    # contract over the weight's columns or over the tokens
+    narrow = len(re.findall(
+        r"f32\[2,16,16\] = dot_general\[\s*dimension_numbers="
+        r"\(\(\[2\], \[0\]\)", text))
+    assert narrow == (10 if kept else 20), narrow
+    monkeypatch.setattr(nn, "remat", lambda cls, **kw: cls)
+    _close(got, jax.jit(grads())(params), rtol=1e-5)

@@ -39,8 +39,8 @@ from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
     OLMO_HYBRID_7B_LAYERS, PartsBlock)
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    DELTA_NET_QKVZ, MLP_GATE_UP, SHORT_CONV_IN, GatedDeltaNet,
-    GroupedAttention, RMSNorm, SwiGluMlp)
+    ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SHORT_CONV_IN,
+    GatedDeltaNet, GroupedAttention, RMSNorm, SwiGluMlp)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
 from mmlspark_tpu.ops.pallas_attention import FLASH_RESIDUALS  # noqa: E402
@@ -471,9 +471,10 @@ def nothing_recomputed(params):
 
 
 _NAMES = (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES,
-          DELTA_NET_QKVZ)               # ``_remat_block``'s one list, but
-# for the name this family has no value of (``lfm2_moe``'s, last in it)
-_NOT_HERE = (SHORT_CONV_IN,)
+          DELTA_NET_QKVZ, ATTN_QKV)     # ``_remat_block``'s one list, but
+# for the names this family has no value of (``lfm2_moe``'s and
+# ``granite_hybrid``'s mixers')
+_NOT_HERE = (SHORT_CONV_IN, MAMBA2_IN)
 
 
 @pytest.mark.parametrize("split", [True, False], ids=["halves", "whole"])
@@ -500,7 +501,8 @@ def test_every_sub_list_of_kept_names_gives_the_same_gradients(
             kw, split=split,
             let_go=tuple(n for n in _NAMES if n not in keep))))
     got = _hidden_grads(params, jnp.asarray(_tokens(4)[0]))
-    assert set(seen) == {keep + _NOT_HERE}
+    assert {tuple(sorted(names)) for names in seen} == {
+        tuple(sorted(keep + _NOT_HERE))}
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
                             jax.tree_util.tree_leaves(nothing_recomputed)):
         np.testing.assert_allclose(
@@ -510,11 +512,11 @@ def test_every_sub_list_of_kept_names_gives_the_same_gradients(
 
 def test_this_family_lets_go_of_names_of_the_one_list():
     let_go = build_model("olmo_hybrid")["module"].let_go
-    assert let_go == (MLP_GATE_UP,) and set(let_go) < set(_NAMES)
+    assert let_go == (MLP_GATE_UP, ATTN_QKV) and set(let_go) < set(_NAMES)
     assert build_model("olmo_hybrid_tiny")["module"].let_go == let_go
-    # the four other families let go of none and keep the whole list
+    # the five other families let go of none and keep the whole list
     for family in ("glm4_moe_lite", "qwen3_next", "granite_hybrid",
-                   "lfm2_moe"):
+                   "lfm2_moe", "laguna"):
         assert build_model(family)["module"].let_go == ()
 
 
