@@ -1,5 +1,5 @@
 """Decoder LMs: ONE skeleton (``Decoder``: embed, blocks, final norm,
-head) over the parts of ``models/zoo/parts.py``, and the six families as
+head) over the parts of ``models/zoo/parts.py``, and the seven families as
 registry entries that say which part sits at which layer:
 ``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's),
 ``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
@@ -17,7 +17,10 @@ the leading layers and a routed layer in every later one, one table) and
 ``laguna`` (Laguna-XS.2: window-512 and full softmax layers three to one,
 the same part at two settings with different head counts and rotary rules
 and a sigmoid gate a head, a dense part then small sigmoid-routed experts
-beside a shared one by a published list).
+beside a shared one by a published list) and ``sdar_moe``
+(SDAR-30B-A3B-Chat: equal layers of rotary grouped attention with a norm a
+head under softmax-routed experts with no shared one, called on rows of
+``[noised copy | clean copy]`` under the block-diffusion mask).
 
 What a family IS lives in its entry, beside the name of the published
 ``config.json`` it reads: the mixer and the feed-forward part of layer
@@ -768,3 +771,63 @@ def laguna_tiny(**overrides):
     routed layers of eight experts, two a token; unequal head counts, a
     window shorter than the rows, YaRN past its original length."""
     return laguna(**{**_LAGUNA_TINY, **overrides})
+
+
+@register_model("sdar_moe")
+def sdar_moe(vocab: int = 151936, dim: int = 2048, depth: int = 48,
+             heads: int = 32, kv_heads: int = 4, head_dim: int = 128,
+             expert_hidden: int = 768, num_experts: int = 128,
+             top_k: int = 8, experts_held=None, block_length: int = 4,
+             gate_grad: bool = True, theta: float = 1e6, eps: float = 1e-6,
+             max_len: int = 8192, dtype=jnp.bfloat16, attention_fn=None):
+    """SDAR-30B-A3B-Chat as published (huggingface.co/JetLM/
+    SDAR-30B-A3B-Chat ``config.json``, ``model_type: sdar_moe``; SDAR,
+    arXiv:2510.06303), on the TRAINING path of block diffusion (BD3-LM,
+    arXiv:2503.09573): ``depth`` equal layers, each a ``GroupedAttention``
+    (``heads`` query heads over ``kv_heads`` of ``head_dim``, an RMS norm
+    over each q and k head, plain rotary on the whole head) under a
+    ``DroplessMoe`` routed by softmax over all ``num_experts``, the top
+    ``top_k`` weighted by their scores over their sum, NO shared expert;
+    plain RMS norms, untied tables. It is Qwen3-MoE's block; what makes it
+    this family is the row: the ``Decoder`` is called on ``(rows, 2 L)``
+    ids ``[noised copy | clean copy]`` (``max_len`` = ``2 L``), both copies
+    of a token at its position, and every mixer sees the block-diffusion
+    mask at ``block_length`` (``GroupedAttention(block_diffusion=)``,
+    which turns by ``parts.rotary(positions=)`` and calls
+    ``parallel/sequence.full_attention(block_diffusion=(L, B))``: within a
+    block the noised copy attends in both directions, across blocks the
+    model is autoregressive over the clean copy). The loss is
+    ``train/lm_loss.masked_diffusion_loss`` on the noised half's rows; the
+    noise is the input pipeline's. ``experts_held`` = ``(count, first)`` as
+    for ``glm4_moe_lite``; ``gate_grad=False`` for a share trained without
+    its exchange (``DroplessMoe``). Each block is recomputed whole and
+    keeps all of ``_remat_block``'s names: here the kernels' residuals
+    and the q, k and v rows."""
+    held = None if experts_held is None else tuple(experts_held)
+
+    def attention(n):
+        return GroupedAttention(
+            dim, heads, kv_heads, head_dim, None, dtype, attention_fn, eps,
+            norm_heads=True, rotary_freqs=plain_frequencies(head_dim, theta),
+            block_diffusion=block_length, name=n)
+
+    def routed(n):
+        return DroplessMoe(
+            dim, num_experts, expert_hidden, top_k, experts_held=held,
+            dtype=dtype, scores="softmax", gate_grad=gate_grad, name=n)
+
+    return _spec(Decoder(vocab, dim, (attention,) * depth, (routed,) * depth,
+                         _rms(eps), dtype=dtype), max_len)
+
+
+_SDAR_TINY = dict(vocab=96, dim=32, depth=2, heads=4, kv_heads=2, head_dim=8,
+                  expert_hidden=16, num_experts=8, top_k=2, block_length=4,
+                  max_len=64, dtype=jnp.float32)
+
+
+@register_model("sdar_moe_tiny")
+def sdar_moe_tiny(**overrides):
+    """Test-scale ``sdar_moe`` (float32, so CPU parity is tight): two
+    layers of eight experts, two a token, rows of two copies of 32
+    positions in blocks of 4."""
+    return sdar_moe(**{**_SDAR_TINY, **overrides})

@@ -21,7 +21,8 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
 - ``GroupedAttention``: the same grouped heads with nothing else: no
   positions, no gate, a softmax scale of its own, and if asked an RMS norm
   over the whole q and the whole k projection, or (``norm_heads``) over
-  each head's channels, and (``rotary_freqs``) rotary positions;
+  each head's channels, (``rotary_freqs``) rotary positions, and
+  (``block_diffusion``) a row of two copies under the block-diffusion mask;
 - ``ShortConv``: LFM2's gated short convolution, which IS the mixer:
   ``[B | C | x] = u W_in``, a causal depthwise convolution of three taps
   over ``B * x`` with no activation, the gate ``C`` on its output;
@@ -89,13 +90,16 @@ class RMSNorm(nn.Module):
 
 
 def rotary(x: jax.Array, inv_freq: Tuple[float, ...],
-           factor: float = 1.0) -> jax.Array:
+           factor: float = 1.0,
+           positions: Optional[jax.Array] = None) -> jax.Array:
     """Rotary positions by GIVEN inverse frequencies over the last axis of
     ``(B, L, H, R)``: the first ``2 n`` dimensions turn, ``n`` =
     ``len(inv_freq)``, position ``l`` turning the pair ``(i, i + n)`` by
     ``l * inv_freq[i]``, and the rest pass through; ``factor`` multiplies
     cos and sin both (YaRN's attention factor). The frequencies are made
     where the rule is known (``plain_frequencies``, ``yarn_frequencies``).
+    ``positions`` ``(L,)`` gives row ``l`` another position than ``l`` (a
+    row that holds two copies of a sequence repeats them).
 
     Computed as ``x cos + pair(x) sin`` over the WHOLE head, where
     ``pair(x) = [-x_2 | x_1 | 0]`` is a product with the pairing's signed
@@ -110,7 +114,9 @@ def rotary(x: jax.Array, inv_freq: Tuple[float, ...],
     n = len(inv_freq)
     if 2 * n > R:
         raise ValueError(f"{n} frequencies turn {2 * n} dimensions of {R}")
-    ang = jnp.arange(L, dtype=jnp.float32)[:, None] \
+    if positions is None:
+        positions = jnp.arange(L, dtype=jnp.float32)
+    ang = positions.astype(jnp.float32)[:, None] \
         * jnp.asarray(inv_freq, jnp.float32)[None, :]
     rest = (L, R - 2 * n)
     cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
@@ -368,7 +374,16 @@ class GroupedAttention(nn.Module):
     added beside it: the projections, the grouping, the repeat and the
     call are these to the letter, and ``GatedAttention``'s gate is an
     element's, shapes ``W_q`` and comes with norms of the ``1 + w``
-    kind."""
+    kind.
+
+    ``block_diffusion`` = ``B`` makes it ``sdar_moe``'s mixer by one more
+    argument of the same kind: the part's rows are ``[noised copy | clean
+    copy]`` of a sequence, ``L`` positions each in blocks of ``B``. Row
+    ``l`` then turns by position ``l mod L`` (both copies of a token stand
+    at its position) and the call is handed ``block_diffusion=(L, B)`` in
+    ``causal``'s company (``parallel/sequence.full_attention`` has the
+    mask), under the scope ``block_diffusion_attention``. Projections,
+    norms, grouping and repeat are a position's own and do not change."""
     dim: int
     heads: int
     kv_heads: int
@@ -382,6 +397,7 @@ class GroupedAttention(nn.Module):
     rotary_freqs: Optional[Tuple[float, ...]] = None    # None: no positions
     rotary_factor: float = 1.0
     head_gate: bool = False
+    block_diffusion: Optional[int] = None   # None: one copy, causal
 
     @nn.compact
     def __call__(self, x):
@@ -391,6 +407,13 @@ class GroupedAttention(nn.Module):
         B, L, _ = x.shape
         H, G, d, dt = self.heads, self.kv_heads, self.head_dim, self.dtype
         attn_fn = self.attention_fn or full_attention
+        positions = None
+        if self.block_diffusion is not None:
+            if L % 2 or self.window is not None:
+                raise ValueError(
+                    f"block_diffusion on rows of {L} with window "
+                    f"{self.window}: [noised | clean] halves, no window")
+            positions = jnp.arange(L, dtype=jnp.float32) % (L // 2)
         banded = contextlib.nullcontext() if self.window is None \
             else jax.named_scope("window_attention_layer")
         with jax.named_scope("grouped_attention"), banded:
@@ -407,7 +430,8 @@ class GroupedAttention(nn.Module):
                     with jax.named_scope("qk_norm"):
                         y = RMSNorm(self.qk_norm_eps, name=norm)(y)
                 if norm and self.rotary_freqs is not None:
-                    y = rotary(y, self.rotary_freqs, self.rotary_factor)
+                    y = rotary(y, self.rotary_freqs, self.rotary_factor,
+                               positions)
                 return y.astype(dt)
             q = heads_of("attn_query", H, "query_norm")
             k = heads_of("attn_key", G, "key_norm")
@@ -415,7 +439,11 @@ class GroupedAttention(nn.Module):
             if self.scale is not None:
                 q = q * jnp.asarray(self.scale * d ** 0.5, dt)
             k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
-            if self.window is None:
+            if self.block_diffusion is not None:
+                with jax.named_scope("block_diffusion_attention"):
+                    o = attn_fn(q, k, v, causal=True, block_diffusion=(
+                        L // 2, self.block_diffusion))
+            elif self.window is None:
                 o = attn_fn(q, k, v, causal=True)
             else:
                 o = attn_fn(q, k, v, causal=True, window=self.window)

@@ -122,8 +122,20 @@ def _lse_from_rows(tail, block_q: int):
     return parts.sum(axis=3)[..., :block_q]
 
 
+def _blocks_seen(key, query, length: int, low, high):
+    """Where a block-diffusion edge crosses a tile: a query sees the keys
+    from its block's first position plus ``low`` (None: no lower edge) to
+    that plus ``high``; ``key`` and ``query`` are positions in their own
+    halves, blocks are ``length`` long."""
+    own = query & -length if length & (length - 1) == 0 \
+        else query - jax.lax.rem(query, length)
+    seen = key < own + high
+    return seen if low is None else seen & (key >= own + low)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
-                  scale: float, window: Optional[int] = None):
+                  scale: float, window: Optional[int] = None,
+                  block_diffusion: Optional[Tuple[int, int]] = None):
     """One program per (batch, head, query block) against the (batch,
     head)'s whole K and V, resident in VMEM. The scores are held
     TRANSPOSED, (bk, bq), as ``_flash_bwd_kernel`` holds them: the running
@@ -143,7 +155,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     With ``window`` (causal only) a query at ``i`` sees the keys ``j`` with
     ``0 <= i - j < window``: the K tiles before the band of the block's
     first row are never visited, the tiles the band's LOWER edge crosses
-    are masked as the diagonal's are, and what lies between runs clear."""
+    are masked as the diagonal's are, and what lies between runs clear.
+
+    With ``block_diffusion`` = ``(L, B)`` the row is ``[noised | clean]``,
+    ``L`` positions each in blocks of ``B``: a noised query sees the clean
+    keys of the blocks BEFORE its own and the noised keys of its own
+    block, a clean query the clean keys up to its own block's end, and no
+    query a noised key of another block (``block_diffusion_mask``). A
+    noised query block runs clear over the clean K tiles before its rows,
+    masked over the clean tiles of its rows and, last, masked over the
+    noised tiles of its rows; a clean one the first two; no other tile is
+    visited."""
     # q/k/v refs are (1, 1, L-block, D): batch and head ride the grid, so
     # the last two dims are the (8, 128)-tileable (rows, lanes) pair Mosaic
     # wants; o_ref is (1, 1, 1, bq [+ lse rows], D)
@@ -152,18 +174,25 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     assert bq % block_k == 0, (bq, block_k)
     qi = pl.program_id(2)
 
-    def tile(start, carry, diagonal=None, live=None):
+    def tile(start, carry, diagonal=None, live=None, blocks=None):
         """One K tile from row ``start``; ``diagonal`` is the tile's
         offset from the query block's first row where the diagonal (or a
         band's lower edge) crosses it; ``live`` False for a tile that
-        lies before the row's start (a band's, of the first blocks)."""
+        lies before the row's start (a band's, of the first blocks);
+        ``blocks`` = ``(low, high)`` for a tile a block-diffusion edge
+        crosses (``_blocks_seen``)."""
         m, l, acc = carry
         rows = pl.ds(pl.multiple_of(start, block_k), block_k)
         k = k_ref[0, 0, rows, :]
         v = v_ref[0, 0, rows, :]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if diagonal is not None:
+        if blocks is not None:
+            st = jnp.where(_blocks_seen(
+                diagonal + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0),
+                jax.lax.broadcasted_iota(jnp.int32, st.shape, 1),
+                block_diffusion[1], *blocks), st, _NEG_INF)
+        elif diagonal is not None:
             # no program index in it: the mask is a constant of the
             # compiled kernel
             key = diagonal + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
@@ -194,26 +223,50 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     # the masked future, about half of a decoder's, are never visited. Key
     # 0 is in the first tile run and every row sees it: ``m`` is finite
     # from there on.
-    clear = qi * (bq // block_k) if causal else k_ref.shape[2] // block_k
-    first = 0
-    if window is not None:
-        # the block's first row is a multiple of bk, so the tiles' offsets
-        # from it are the same in every block: those from -edge up to
-        # ``inside`` cross the band's lower edge (a wholly masked row of a
-        # tile run before the row's first seen key leaves rubbish in its
-        # ``l`` and accumulator, which that key's correction, exp(-1e30 -
-        # m), multiplies by 0: every row sees itself, in the last tiles)
-        edge = -(-(window - 1) // block_k) * block_k
-        inside = min(0, -((window - bq) // block_k) * block_k)
-        for offset in range(-edge, inside, block_k):
-            start = qi * bq + offset
-            carry = tile(jnp.maximum(start, 0), carry, offset, start >= 0)
-        first = jnp.maximum(clear + inside // block_k, 0)
-    carry = jax.lax.fori_loop(
-        first, clear, lambda i, c: tile(i * block_k, c), carry)
-    if causal:
+    if block_diffusion is not None:
+        half, length = block_diffusion
+        # the block's first row in its half's own positions; every clean
+        # key before it is in an earlier block. A noised row of the half's
+        # first block sees no clean key: the tiles run before its own
+        # noised block leave rubbish in its ``l`` and accumulator, which
+        # that block's correction, exp(-1e30 - m), multiplies by 0 (every
+        # noised row sees itself there, every clean row in the tile before)
+        noised = qi < half // bq
+        first_row = (qi - jnp.where(noised, 0, half // bq)) * bq
+        carry = jax.lax.fori_loop(
+            0, first_row // block_k,
+            lambda i, c: tile(half + i * block_k, c), carry)
         for diagonal in range(0, bq, block_k):
-            carry = tile(qi * bq + diagonal, carry, diagonal)
+            carry = tile(half + first_row + diagonal, carry, diagonal,
+                         blocks=(None, jnp.where(noised, 0, length)))
+        carry = jax.lax.fori_loop(
+            0, jnp.where(noised, bq // block_k, 0),
+            lambda i, c: tile(first_row + i * block_k, c, i * block_k,
+                              blocks=(0, length)), carry)
+    else:
+        clear = qi * (bq // block_k) if causal \
+            else k_ref.shape[2] // block_k
+        first = 0
+        if window is not None:
+            # the block's first row is a multiple of bk, so the tiles'
+            # offsets from it are the same in every block: those from -edge
+            # up to ``inside`` cross the band's lower edge (a wholly masked
+            # row of a tile run before the row's first seen key leaves
+            # rubbish in its ``l`` and accumulator, which that key's
+            # correction, exp(-1e30 - m), multiplies by 0: every row sees
+            # itself, in the last tiles)
+            edge = -(-(window - 1) // block_k) * block_k
+            inside = min(0, -((window - bq) // block_k) * block_k)
+            for offset in range(-edge, inside, block_k):
+                start = qi * bq + offset
+                carry = tile(jnp.maximum(start, 0), carry, offset,
+                             start >= 0)
+            first = jnp.maximum(clear + inside // block_k, 0)
+        carry = jax.lax.fori_loop(
+            first, clear, lambda i, c: tile(i * block_k, c), carry)
+        if causal:
+            for diagonal in range(0, bq, block_k):
+                carry = tile(qi * bq + diagonal, carry, diagonal)
     m, l, acc = carry
     l = jnp.maximum(l, 1e-30)
     o_ref[0, 0, 0, :bq, :] = (acc / l).T.astype(o_ref.dtype)
@@ -229,7 +282,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, block_q: int = BLOCK_Q,
                     block_k: int = BLOCK_K,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[Tuple[int, int]] = None
+                    ) -> jax.Array:
     """(B, L, H, D) fused attention; requires L divisible by the blocks
     (``supports`` tells callers when to fall back). Differentiable: the
     forward call saves each row's log-sum-exp beside its output, and the
@@ -252,9 +307,60 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     are then named ``window_attention_fwd`` / ``window_attention_bwd``, so
     that a trace tells a band's calls from a causal half's (the residuals
     are ``FLASH_RESIDUALS`` either way). A window of the whole row or more
-    is the plain causal call."""
+    is the plain causal call.
+
+    ``block_diffusion`` = ``(L, B)`` (causal only, no window): the row is
+    ``[noised copy | clean copy]`` of ``L`` positions each in blocks of
+    ``B``, and a query sees the keys ``block_diffusion_mask`` says
+    (``supports_block_diffusion`` says which shapes). Both passes visit
+    the tiles that hold a live pair and no other, a quarter of the
+    square's; the two Pallas calls are named
+    ``block_diffusion_attention_fwd`` / ``_bwd``."""
+    if block_diffusion is not None:
+        block_diffusion = diffusion_blocks(block_diffusion, causal, window,
+                                           q.shape[1])
+        if not supports_block_diffusion(q.shape, block_diffusion, block_q,
+                                        block_k):
+            raise ValueError(f"block_diffusion {block_diffusion} on q shape "
+                             f"{tuple(q.shape)}: "
+                             "supports_block_diffusion refuses it")
     return _flash_attention(q, k, v, causal, block_q, block_k,
-                            band(window, causal, q.shape[1]))
+                            band(window, causal, q.shape[1]),
+                            block_diffusion)
+
+
+def diffusion_blocks(block_diffusion, causal: bool, window: Optional[int],
+                     row: int) -> Tuple[int, int]:
+    """The rule of a ``block_diffusion``, for every caller: ``(L, B)``
+    with ``2 L`` the row's length and ``B`` dividing ``L``, under
+    ``causal`` (blocks are causal among themselves) and no window."""
+    half, length = (int(n) for n in block_diffusion)
+    if not causal or window is not None or length < 1 or 2 * half != row \
+            or half % length:
+        raise ValueError(
+            f"block_diffusion {(half, length)} on a row of {row} with "
+            f"causal={causal}, window={window}: (L, B) with 2 L the row's "
+            "length and B dividing L, causal, no window")
+    return half, length
+
+
+def block_diffusion_mask(half: int, length: int) -> jax.Array:
+    """(2 L, 2 L) bool, True where query ``i`` sees key ``j`` of a row
+    ``[noised | clean]``, ``L`` = ``half`` positions each in blocks of
+    ``length`` (BD3-LM's ``M_BD | M_OBC | M_BC``, arXiv:2503.09573): with
+    ``blk(i) = (i mod L) // B``, noised on noised within a block, noised
+    on clean of the blocks before, clean on clean up to its own block; a
+    clean query never sees a noised key. ``L^2 + L B`` pairs are live. The
+    one statement of the mask: the reference path applies it, the kernels
+    are tested against it. Made of iotas, so that a traced caller holds no
+    table of it."""
+    i = jnp.arange(2 * half)
+    noised, blk = i < half, (i % half) // length
+    q_noised, k_noised = noised[:, None], noised[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return (q_noised & k_noised & (k_blk == q_blk)) \
+        | (q_noised & ~k_noised & (k_blk < q_blk)) \
+        | (~q_noised & ~k_noised & (k_blk <= q_blk))
 
 
 def band(window: Optional[int], causal: bool, L: int) -> Optional[int]:
@@ -269,19 +375,22 @@ def band(window: Optional[int], causal: bool, L: int) -> Optional[int]:
     return None if window >= L else int(window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, block_q, block_k, window):
-    """``flash_attention`` of a ``window`` that ``band`` has resolved."""
-    return _flash_forward(q, k, v, causal, block_q, block_k,
-                          window=window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, block_q, block_k, window,
+                     block_diffusion):
+    """``flash_attention`` of a ``window`` that ``band`` and a
+    ``block_diffusion`` that ``diffusion_blocks`` have resolved."""
+    return _flash_forward(q, k, v, causal, block_q, block_k, window=window,
+                          block_diffusion=block_diffusion)[0]
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "save_lse", "window"))
+    "causal", "block_q", "block_k", "save_lse", "window", "block_diffusion"))
 def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool = False, block_q: int = BLOCK_Q,
                    block_k: int = BLOCK_K, save_lse: bool = False,
-                   window: Optional[int] = None):
+                   window: Optional[int] = None,
+                   block_diffusion: Optional[Tuple[int, int]] = None):
     """(output (B, L, H, D), None), or under differentiation
     (``save_lse``) the rows' log-sum-exps in the None's place: float32
     (B, H, L / block_q, block_q). A call that is not differentiated
@@ -290,17 +399,22 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
     b, L, h, d = q.shape
     scale = 1.0 / float(np.sqrt(d))
     vmem = pl.ANY if _interpret() else pltpu.VMEM
-    bq, bk = _fwd_tiles(block_q, block_k, L, d, window)
+    bq, bk = _fwd_tiles(block_q, block_k, L, d, window, block_diffusion)
     kernel = functools.partial(_flash_kernel, block_k=bk, causal=causal,
                                scale=scale)
     # a band's call carries a name of its own (as the backward's does, and
     # for its reason: the benchmark's readers of the causal forward find
-    # theirs by the word `flash`); the causal call is what it was
+    # theirs by the word `flash`), and so does a block-diffusion row's;
+    # the causal call is what it was
     scope, named = contextlib.nullcontext(), {}
     if window is not None:
         kernel = functools.partial(kernel, window=window)
         scope, named = jax.named_scope(_WINDOW_FWD_NAME), {
             "name": _WINDOW_FWD_NAME}
+    if block_diffusion is not None:
+        kernel = functools.partial(kernel, block_diffusion=block_diffusion)
+        scope, named = jax.named_scope(_DIFFUSION_FWD_NAME), {
+            "name": _DIFFUSION_FWD_NAME}
     rows = bq + (_lse_rows(bq, d, q.dtype)[1] if save_lse else 0)
     need = _flash_fwd_vmem_bytes(L, d, bq, bk, rows, q.dtype.itemsize)
     # (B, L, H, D) -> (B, H, L, D): head ahead of length so kernel blocks
@@ -362,15 +476,31 @@ def supports(q_shape, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> bool:
         and d % 8 == 0 and L * d <= _VMEM_KV_LIMIT
 
 
+def supports_block_diffusion(q_shape, block_diffusion: Tuple[int, int],
+                             block_q: int = BLOCK_Q,
+                             block_k: int = BLOCK_K) -> bool:
+    """Whether the flash kernels take the row ``[noised | clean]`` of
+    ``block_diffusion`` = ``(L, B)``: a shape they take at all
+    (``supports``), halves of whole tiles, and ``B`` dividing the tiles
+    both passes run (``_diffusion_tile``), so that a block never straddles
+    two of them."""
+    half, length = block_diffusion
+    return supports(q_shape, block_q, block_k) and q_shape[1] == 2 * half \
+        and all(half % b == 0 and _diffusion_tile(b, half) % length == 0
+                for b in (block_q, block_k))
+
+
 # One name for both: the backward pass needs the output AND the log-sum-
 # exps, so a policy that saved one of them would still run the call again.
 # The output returned and the output saved are the same named value.
 FLASH_RESIDUALS = "flash_attention_residuals"
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window):
+def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window,
+                    block_diffusion):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
-                              save_lse=True, window=window)
+                              save_lse=True, window=window,
+                              block_diffusion=block_diffusion)
     out = checkpoint_name(out, FLASH_RESIDUALS)
     lse = checkpoint_name(lse, FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
@@ -382,6 +512,9 @@ _BWD_NAME = "long_attention_bwd"
 # calls find theirs by those words)
 _WINDOW_FWD_NAME = "window_attention_fwd"
 _WINDOW_BWD_NAME = "window_attention_bwd"
+# and a block-diffusion row's, for the same reason
+_DIFFUSION_FWD_NAME = "block_diffusion_attention_fwd"
+_DIFFUSION_BWD_NAME = "block_diffusion_attention_bwd"
 # what a program may ask of a v5e's 128 MiB of VMEM
 _VMEM_CAP = 100 << 20
 
@@ -389,7 +522,8 @@ _VMEM_CAP = 100 << 20
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       block_q: int, causal: bool, scale: float,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None,
+                      block_diffusion: Optional[Tuple[int, int]] = None):
     """One program per (batch, head, K block): q and do of the whole
     sequence are resident (L, D), k and v are this K block (bk, D), the
     log-sum-exps and ``delta = rowsum(do * o)`` are (L / bq, bq) float32
@@ -401,7 +535,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     float32 scratch that lives across the grid's last axis. With
     ``window`` both of its loops are bounded by the band: the query blocks
     past the last row that still sees this K block are never visited, and
-    the blocks the band's lower edge crosses are masked."""
+    the blocks the band's lower edge crosses are masked. With
+    ``block_diffusion`` = ``(L, B)`` (``_flash_kernel`` has the mask) a
+    noised K block meets the noised query blocks over its own rows alone,
+    masked to a query's own block; a clean one meets the noised and the
+    clean query blocks over its rows, masked, and those after them in
+    either half, clear."""
     kj = pl.program_id(2)
     bk = k_ref.shape[0]
     nq = q_ref.shape[0] // block_q
@@ -415,15 +554,23 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def step(qi, masked: bool, edge: bool = False):
+    def step(qi, masked: bool, edge: bool = False, blocks=None):
         """One query block; ``masked`` where the diagonal crosses it,
-        ``edge`` where a band's lower edge does."""
+        ``edge`` where a band's lower edge does, ``blocks`` = ``(low,
+        high)`` where a block-diffusion edge does (``_blocks_seen``)."""
         rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
         q = q_ref[rows, :]
         do = do_ref[rows, :]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if masked or edge:
+        if blocks is not None:
+            half, length = block_diffusion
+            st = jnp.where(_blocks_seen(
+                jax.lax.rem(kj * bk, half) + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 0),
+                jax.lax.rem(qi * block_q, half) + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 1), length, *blocks), st, _NEG_INF)
+        elif masked or edge:
             k_idx = kj * bk + jax.lax.broadcasted_iota(
                 jnp.int32, st.shape, 0)
             q_idx = qi * block_q + jax.lax.broadcasted_iota(
@@ -443,10 +590,31 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[rows, :] += jax.lax.dot_general(
             dst, k, _TN, preferred_element_type=jnp.float32)
 
-    def loop(lo, hi, masked, edge=False):
-        jax.lax.fori_loop(lo, hi, lambda qi, _: step(qi, masked, edge), None)
+    def loop(lo, hi, masked, edge=False, blocks=None):
+        jax.lax.fori_loop(
+            lo, hi, lambda qi, _: step(qi, masked, edge, blocks), None)
 
-    if causal:
+    if block_diffusion is not None:
+        half, length = block_diffusion
+        # query blocks a half, the first over this K block's rows (in its
+        # half's own positions) and the first wholly after them
+        per_half = half // block_q
+        first = jax.lax.rem(kj * bk, half) // block_q
+        after = jnp.minimum(
+            per_half, (jax.lax.rem(kj * bk, half) + bk - 1) // block_q + 1)
+
+        @pl.when(kj * bk < half)
+        def _():
+            loop(first, after, False, blocks=(0, length))
+
+        @pl.when(kj * bk >= half)
+        def _():
+            loop(first, after, False, blocks=(None, 0))
+            loop(after, per_half, False)
+            loop(per_half + first, per_half + after, False,
+                 blocks=(None, length))
+            loop(per_half + after, 2 * per_half, False)
+    elif causal:
         # query blocks wholly before this K block are never visited: no
         # product, and no fetch either (q and do are resident); only the
         # blocks the diagonal crosses pay for a mask
@@ -510,6 +678,21 @@ def _window_tile(block: int, L: int, window: int) -> int:
     return block
 
 
+# A block-diffusion row's tiles, both passes, query and K alike: the caller's
+# block, doubled while it still divides a HALF of the row (a tile lies in one
+# half) and stays within the bound. A tile an edge crosses is computed whole
+# for the part of it that is live: under a half of the clean tile over a
+# query block's own rows, ``B`` keys a row of the noised one. Of the row's
+# ``2 L / t`` K tiles a query block visits ``L / (2 t) + 1`` on average.
+_DIFFUSION_TILE = 512
+
+
+def _diffusion_tile(block: int, half: int) -> int:
+    while 2 * block <= _DIFFUSION_TILE and half % (2 * block) == 0:
+        block *= 2
+    return block
+
+
 # The forward's tiles, from the shape: a K tile of up to 512 rows, and a
 # query tile of up to 1,024 whose (d, bq) float32 accumulator stays within
 # 512 KiB: 1,024 rows at head widths 64 and 128, 512 at 256. Measured on
@@ -524,14 +707,21 @@ _FWD_ACC = 1 << 17          # d * bq elements
 
 
 def _fwd_tiles(block_q: int, block_k: int, L: int, d: int,
-               window: Optional[int] = None) -> Tuple[int, int]:
+               window: Optional[int] = None,
+               block_diffusion: Optional[Tuple[int, int]] = None
+               ) -> Tuple[int, int]:
     """The (query, K) tile the forward runs for a caller's blocks, which
     are the floor of both: each doubled while it still divides ``L`` and
     stays under its bound, the query tile from the least common multiple
     with the K tile, so that the diagonal crosses whole K tiles. No dtype
     in it: the accumulator and the score tile, which the bounds are for,
     are float32 whatever the input. A band's tiles are ``_window_tile``'s,
-    with the same least common multiple."""
+    with the same least common multiple, a block-diffusion row's
+    ``_diffusion_tile``'s."""
+    if block_diffusion is not None:
+        block_k = _diffusion_tile(block_k, block_diffusion[0])
+        return int(np.lcm(_diffusion_tile(block_q, block_diffusion[0]),
+                          block_k)), block_k
     if window is not None:
         block_k = _window_tile(block_k, L, window)
         return int(np.lcm(_window_tile(block_q, L, window), block_k)), \
@@ -582,18 +772,24 @@ def _flash_bwd_vmem_bytes(L: int, d: int, block_q: int, block_k: int,
 # Jitted, as ``_flash_forward`` is: the blocks of a model lower ONE Mosaic
 # module and call it N times.
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "window"))
+                                             "window", "block_diffusion"))
 def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
-                    window=None):
+                    window=None, block_diffusion=None):
     b, L, h, d = q.shape
-    tile = _bwd_tile if window is None else functools.partial(
-        _window_tile, window=window)
-    block_q, block_k = tile(block_q, L), tile(block_k, L)
-    name = _BWD_NAME if window is None else _WINDOW_BWD_NAME
+    tile, name = functools.partial(_bwd_tile, L=L), _BWD_NAME
+    if window is not None:
+        tile = functools.partial(_window_tile, L=L, window=window)
+        name = _WINDOW_BWD_NAME
+    if block_diffusion is not None:
+        tile = functools.partial(_diffusion_tile, half=block_diffusion[0])
+        name = _DIFFUSION_BWD_NAME
+    block_q, block_k = tile(block_q), tile(block_k)
     kernel = functools.partial(_flash_bwd_kernel, block_q=block_q,
                                causal=causal, scale=1.0 / float(np.sqrt(d)))
     if window is not None:
         kernel = functools.partial(kernel, window=window)
+    if block_diffusion is not None:
+        kernel = functools.partial(kernel, block_diffusion=block_diffusion)
     nq = L // block_q
     lse = lse.reshape(b, h, nq, block_q)    # rows of the backward's tile
     # rowsum(do * o): the softmax jacobian's contraction, once a call, read
@@ -636,11 +832,12 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
     return tuple(g.transpose(0, 2, 1, 3) for g in grads)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, window, res, do):
+def _flash_bwd_rule(causal, block_q, block_k, window, block_diffusion, res,
+                    do):
     obsmetrics.counter("attention.flash_bwd_calls.pallas").inc()
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
-                           window=window)
+                           window=window, block_diffusion=block_diffusion)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,15 +44,20 @@ def _on_chip() -> bool:
 
 
 def _reference_attention(q, k, v, causal: bool,
-                         window: Optional[int] = None):
+                         window: Optional[int] = None,
+                         block_diffusion: Optional[Tuple[int, int]] = None):
     """Plain attention: matmuls in the input dtype (bf16 tiles the MXU);
     scores, softmax and the output accumulation in fp32, cast back once
     at the end. The L x L scores go through HBM. With ``window`` a query
-    at ``i`` sees the keys ``j`` with ``0 <= i - j < window``."""
+    at ``i`` sees the keys ``j`` with ``0 <= i - j < window``; with
+    ``block_diffusion`` those of ``pallas_attention.block_diffusion_mask``."""
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("blhd,bkhd->bhlk", q, k,
                    preferred_element_type=jnp.float32) * scale
-    if causal:
+    if block_diffusion is not None:
+        from mmlspark_tpu.ops.pallas_attention import block_diffusion_mask
+        s = jnp.where(block_diffusion_mask(*block_diffusion), s, -jnp.inf)
+    elif causal:
         L, K = s.shape[-2], s.shape[-1]
         mask = jnp.arange(K)[None, :] > jnp.arange(L)[:, None]
         if window is not None:
@@ -96,7 +101,9 @@ def _on_own_rows(kernel, q, k, v):
 def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    causal: bool = True,
                    use_flash: str = "auto",
-                   window: Optional[int] = None) -> jnp.ndarray:
+                   window: Optional[int] = None,
+                   block_diffusion: Optional[Tuple[int, int]] = None
+                   ) -> jnp.ndarray:
     """Attention (B, L, H, D) with the whole sequence on each device.
 
     The one place that picks the implementation, from the shape alone
@@ -114,8 +121,8 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     CPU in interpret mode — for callers whose result is only meaningful
     on a kernel: the ``longctx`` bench lane, ``chip_smoke.py``). Every
     trace increments ``attention.fused_calls.<short|flash|window|
-    reference>``; under "auto" on an accelerator a trace that takes the
-    reference also increments ``attention.flash_fallbacks``, so the
+    block_diffusion|reference>``; under "auto" on an accelerator a trace that
+    takes the reference also increments ``attention.flash_fallbacks``, so the
     downgrade is visible in metrics and reports.
 
     ``window`` (causal only): a query at ``i`` sees the keys ``j`` with
@@ -125,16 +132,36 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``.window``); the short kernel does not, so a windowed shape the
     flash kernel refuses runs the masked reference and counts as a
     fallback like any other.
+
+    ``block_diffusion`` = ``(L, B)`` (causal only, no window): the row is
+    ``[noised copy | clean copy]`` of ``L`` positions each in blocks of
+    ``B``; a noised query sees the clean keys of the blocks before its own
+    and the noised keys of its own block, a clean query the clean keys up
+    to its own block's end (``pallas_attention.block_diffusion_mask``).
+    The flash kernel takes it where ``supports_block_diffusion`` says (its
+    calls are then named ``block_diffusion_attention_fwd`` / ``_bwd`` and
+    counted under ``.block_diffusion``); elsewhere the reference masks the
+    dense product, a fallback like any other.
     """
     if use_flash not in ("auto", "never", "require"):
         raise ValueError(f"unknown use_flash {use_flash!r}")
     if window is not None:
         from mmlspark_tpu.ops.pallas_attention import band
         window = band(window, causal, q.shape[1])
+    if block_diffusion is not None:
+        from mmlspark_tpu.ops.pallas_attention import diffusion_blocks
+        block_diffusion = diffusion_blocks(block_diffusion, causal, window,
+                                           q.shape[1])
     if use_flash == "require" or (use_flash == "auto" and _on_chip()):
         from mmlspark_tpu.ops import pallas_attention
         name = kernel = None
-        if pallas_attention.supports(q.shape):
+        if block_diffusion is not None:
+            if pallas_attention.supports_block_diffusion(
+                    q.shape, block_diffusion):
+                name, kernel = "block_diffusion", partial(
+                    pallas_attention.flash_attention,
+                    block_diffusion=block_diffusion)
+        elif pallas_attention.supports(q.shape):
             name, kernel = "flash", pallas_attention.flash_attention
             if window is not None:
                 name, kernel = "window", partial(kernel, window=window)
@@ -153,7 +180,7 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 f"supports_short; the batch must split over the mesh)")
         obsmetrics.counter("attention.flash_fallbacks").inc()
     obsmetrics.counter("attention.fused_calls.reference").inc()
-    return _reference_attention(q, k, v, causal, window)
+    return _reference_attention(q, k, v, causal, window, block_diffusion)
 
 
 # ---------------------------------------------------------------------------

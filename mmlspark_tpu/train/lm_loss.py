@@ -1,4 +1,5 @@
-"""Next-token loss of a decoder LM without the whole logits in memory.
+"""The losses of a decoder LM without the whole logits in memory: next
+token, and masked diffusion over a row's noised copy.
 
 ``(tokens, vocab)`` float32 logits and their gradient are the largest
 arrays of an LM step (8,192 x 19,360 x 4 B = 634 MB each, per head) and
@@ -11,6 +12,13 @@ predicts token ``i + 1``; a multi-token-prediction head's row ``i``
 (DeepSeek-V3 section 2.2, depth 1) predicts token ``i + 2`` through the
 same output matrix. Rows that have no target count nothing. No document
 mask: a packed row is one sequence.
+
+``masked_diffusion_loss`` is block diffusion's (BD3-LM, arXiv:2503.09573;
+the linear schedule of MDLM, arXiv:2406.07524): of a row ``[noised copy |
+clean copy]`` the NOISED half's row ``i`` predicts the clean token AT
+``i``, no shift, and counts ``weights[i]``: ``1 / t`` of its block where
+the noised copy holds the mask token, 0 elsewhere. The clean half's rows
+reach no loss.
 """
 from __future__ import annotations
 
@@ -85,3 +93,28 @@ def next_token_loss(out: Dict[str, jax.Array], kernel: jax.Array,
     if mtp is not None:
         aux["loss.mtp"] = mtp
     return loss, aux
+
+
+def masked_diffusion_loss(out: Dict[str, jax.Array], kernel: jax.Array,
+                          targets: jax.Array, weights: jax.Array, *,
+                          chunk: int = 2048, dtype=jnp.bfloat16
+                          ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``(loss, {"loss.main", "diffusion.masked_share"})`` from a model's
+    ``{"hidden"}`` rows over ``[noised | clean]`` (``(B, 2 L, D)``, already
+    normed), the output matrix, the ``(B, L)`` clean tokens and the ``(B,
+    L)`` float32 weights of the noised positions: ``loss = sum_i w_i
+    CE(hidden_i W, target_i) / (B L)`` over the noised half alone.
+    ``diffusion.masked_share`` is the share of noised positions that carry
+    a weight."""
+    B, L = targets.shape
+    if out["hidden"].shape[1] != 2 * L:
+        raise ValueError(f"hidden rows of {out['hidden'].shape[1]} for "
+                         f"{L} clean tokens: [noised | clean] is {2 * L}")
+    with jax.named_scope("lm_loss"):
+        total = chunked_cross_entropy(
+            out["hidden"][:, :L].reshape(B * L, -1), kernel,
+            targets.reshape(B * L),
+            weights.reshape(B * L).astype(jnp.float32), chunk, dtype)
+        loss = total / (B * L)
+    return loss, {"loss.main": loss, "diffusion.masked_share": jnp.mean(
+        (weights > 0).astype(jnp.float32))}

@@ -2,9 +2,15 @@
 check that a change to ``models/zoo/decoder.py`` or ``parts.py`` which is
 meant to move no family's arithmetic has moved none.
 
-Two files under ``tests/data/`` hold, for each of the six tiny presets,
-what THIS module computed on PR 46's commit (float32, the CPU backend,
-every function under ``jax.jit``). That PR put two names on
+Two files under ``tests/data/`` hold, for each of the seven tiny presets,
+what THIS module computed on PR 47's commit (float32, the CPU backend,
+every function under ``jax.jit``). PR 47 added the seventh
+(``sdar_moe_tiny``: rows of ``[noised | clean]``, its program the gradient
+of ``masked_diffusion_loss`` with every noised position weighed 1) after
+giving ``parts.rotary`` and ``GroupedAttention`` one more argument each,
+and remade both files: the JSON's diff shows the six older hashes unmoved,
+and the six older presets' keys of the ``.npz`` are the parent's bit for
+bit. PR 46 put two names on
 ``_remat_block``'s one list (``MAMBA2_IN``, ``ATTN_QKV``) and remade the
 JSON: its diff shows granite's, lfm2's and laguna's hashes moved (their
 blocks keep rows they made again before), GLM's and qwen's not (no value
@@ -31,7 +37,7 @@ A PR that changes a family's program on purpose remakes both with
 
     JAX_PLATFORMS=cpu python tests/test_decoder_programs.py --write
 
-(without ``--write`` it prints the six hashes and writes nothing), names
+(without ``--write`` it prints the seven hashes and writes nothing), names
 its own commit here, and its diff of the JSON then shows which families it
 touched and which it did not.
 """
@@ -51,10 +57,13 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from mmlspark_tpu.models.zoo import build_model  # noqa: E402
-from mmlspark_tpu.train.lm_loss import next_token_loss  # noqa: E402
+from mmlspark_tpu.train.lm_loss import (  # noqa: E402
+    masked_diffusion_loss, next_token_loss)
 
 PRESETS = ("glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
-           "olmo_hybrid_tiny", "lfm2_moe_tiny", "laguna_tiny")
+           "olmo_hybrid_tiny", "lfm2_moe_tiny", "laguna_tiny",
+           "sdar_moe_tiny")
+DIFFUSION = ("sdar_moe_tiny",)
 DATA = Path(__file__).resolve().parent / "data"
 OUTPUTS = DATA / "decoder_parent_outputs.npz"
 PROGRAMS = DATA / "decoder_parent_programs.json"
@@ -92,10 +101,17 @@ def _outputs(module, params, tokens):
     return {k: np.asarray(v) for k, v in run(params).items()}
 
 
-def _program(module, params, tokens):
-    """sha256 of the lowered loss gradient: traced, never compiled."""
+def _program(module, params, tokens, diffusion=False):
+    """sha256 of the lowered loss gradient: traced, never compiled. With
+    ``diffusion`` the rows are ``[noised | clean]`` and the loss the
+    masked-diffusion one, every noised position weighed 1."""
     def loss(params, tokens):
         out = module.apply(params, tokens, hidden=True)
+        if diffusion:
+            clean = tokens[:, tokens.shape[1] // 2:]
+            return masked_diffusion_loss(
+                out, _head_kernel(params), clean, jnp.ones(clean.shape),
+                chunk=16, dtype=jnp.float32)[0]
         return next_token_loss(out, _head_kernel(params), tokens, chunk=16,
                                dtype=jnp.float32)[0]
     text = jax.jit(jax.grad(loss)).lower(params, tokens).as_text()
@@ -131,14 +147,15 @@ def test_the_loss_gradient_lowers_to_its_parents_program(built):
     with open(PROGRAMS) as f:
         want = json.load(f)
     assert set(want) == set(PRESETS)
-    assert _program(module, params, tokens) == want[preset], preset
+    assert _program(module, params, tokens, preset in DIFFUSION) \
+        == want[preset], preset
 
 
 def main(write: bool) -> None:
     outputs, programs = {}, {}
     for preset in PRESETS:
         built = _built(preset)
-        programs[preset] = _program(*built)
+        programs[preset] = _program(*built, preset in DIFFUSION)
         print(preset, programs[preset], flush=True)
         outputs.update({f"{preset}.{k}": v
                         for k, v in _outputs(*built).items()})
