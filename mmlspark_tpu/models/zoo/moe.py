@@ -4,11 +4,13 @@ The one parallelism family SURVEY.md §2.6 lists that the reference era never
 had — built the TPU way (GShard/Switch style):
 
 - the router is a tiny fp32 Dense; each token picks its top-k experts;
-- dispatch/combine are EINSUMS against one-hot capacity tensors — no
-  gather/scatter, so the whole layer stays MXU-shaped and XLA lowers the
-  token movement to an all-to-all over the ``expert`` mesh axis (the
-  sharding rules place the leading E dim of ``experts_up``/``experts_down``
-  on ``expert``, ``parallel/sharding.DEFAULT_RULES``);
+- in ``MoeMlp`` (and in it alone: ``DroplessMoe`` below sorts its slots,
+  gathers rows and scatter-adds them) dispatch/combine are EINSUMS against
+  one-hot capacity tensors — no gather/scatter, so the whole layer stays
+  MXU-shaped and XLA lowers the token movement to an all-to-all over the
+  ``expert`` mesh axis (the sharding rules place the leading E dim of
+  ``experts_up``/``experts_down`` on ``expert``,
+  ``parallel/sharding.DEFAULT_RULES``);
 - per-expert capacity C = ceil(capacity_factor * S * k / E); overflow
   tokens fall through the residual (standard GShard drop policy);
 - the load-balancing auxiliary loss (Shazeer et al.: E * mean_e(frac
@@ -153,6 +155,37 @@ def _sum_by_token(tokens, rows, token):
         rows.astype(jnp.float32), token, num_segments=tokens)
 
 
+def _at_choice(s, choice):
+    """``s (S, E)`` at ``choice (S, K)`` along the expert axis: ``out[t, k]
+    = s[t, choice[t, k]]``, read as a comparison against that axis and a
+    sum over it. One addend a slot is not zero, so the result is the
+    gather's to the bit; so is the gradient, the same comparison times the
+    cotangent summed over ``K`` (a token's choices are distinct: one
+    addend an expert). XLA fuses the ``K`` sums into one pass over ``s`` at
+    the VPU's width; its ``gather`` walks the slots one by one, about 10 ns
+    each on a v5e whatever the bytes (20.5 ms of a 622 ms step at 32,768
+    tokens x 8). A choice at a time over ``(S, E)``, not one comparison
+    over ``(S, K, E)``: the bits are the same, but around that form XLA
+    arranges the routed conditionals otherwise, for 0.3 GB more
+    temporaries and slower grouped products (PERF.md section 6, PR 48)."""
+    experts = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[-1]), 1)
+    return jnp.stack(
+        [jnp.where(choice[:, k:k + 1] == experts, s, 0).sum(-1)
+         for k in range(choice.shape[-1])], axis=-1)
+
+
+def _slots_by_expert(local, held):
+    """int32 ``(held,)``: how many of ``local (S, K)`` (a token's choices,
+    counted from the first held expert) name each held expert. The same
+    comparison, a choice at a time, summed over the tokens: exact, and
+    one fused pass where a ``bincount`` of the ``S*K`` slots is a
+    scatter-add of a scalar a slot, walked like the gather (18 ms of that
+    step)."""
+    experts = jax.lax.broadcasted_iota(jnp.int32, (1, held), 1)
+    return sum((local[:, k:k + 1] == experts).sum(0, dtype=jnp.int32)
+               for k in range(local.shape[-1]))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _rows_of_tokens(tokens, x, token, live):
     """``(tokens, D)`` -> ``(R, D)``: row ``r`` is ``x[token[r]]``; the
@@ -252,7 +285,10 @@ class DroplessMoe(nn.Module):
     them, the choice its top ``top_k``, no bias. Either way the weights are
     ``s`` at the chosen over ``(their sum + weight_eps)``, times
     ``scaling`` (``weight_eps`` 0 in DeepSeek-V3's and Qwen3-Next's layer,
-    1e-6 in LFM2's).
+    1e-6 in LFM2's). ``s`` at the chosen is read by ``_at_choice``, a
+    comparison against the expert axis and a sum over it, not a gather:
+    the same bits, and XLA's gather of scalars costs about 10 ns each on
+    a v5e whatever their bytes.
     ``gate_grad=False`` makes the weights, and the scores behind them,
     constants of the backward pass (``jax.lax.stop_gradient``): no
     gradient reaches the router's kernel, and none reaches the tokens
@@ -322,7 +358,7 @@ class DroplessMoe(nn.Module):
             else:
                 s = ranked = jax.nn.softmax(logits, axis=-1)
             _, choice = jax.lax.top_k(ranked, K)     # indices: no gradient
-            gate = jnp.take_along_axis(s, choice, axis=-1)
+            gate = _at_choice(s, choice)
             total = gate.sum(-1, keepdims=True)
             if self.weight_eps:
                 total = total + self.weight_eps
@@ -337,8 +373,7 @@ class DroplessMoe(nn.Module):
             # expert order: held experts by index, then everything else
             key = jnp.where(here, local, held).reshape(S * K)
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            sizes = jnp.bincount(key, length=held + 1)[:held].astype(
-                jnp.int32)
+            sizes = _slots_by_expert(local, held)
             slots_here = sizes.sum()
             # the smallest rung that holds them: how many of the rungs
             # below the last they exceed

@@ -10,7 +10,12 @@ of ``masked_diffusion_loss`` with every noised position weighed 1) after
 giving ``parts.rotary`` and ``GroupedAttention`` one more argument each,
 and remade both files: the JSON's diff shows the six older hashes unmoved,
 and the six older presets' keys of the ``.npz`` are the parent's bit for
-bit. PR 46 put two names on
+bit. PR 48 read the routed layers' weights and group sizes by comparison
+(``zoo/moe._at_choice``, ``_slots_by_expert``: no ``gather`` after
+``top_k``, no ``bincount``) and remade the JSON: the five routed families'
+hashes moved, granite's and olmo's did not, and every key of the ``.npz``
+is the parent's bit for bit, the router's and the gate bank's gradients
+among them. PR 46 put two names on
 ``_remat_block``'s one list (``MAMBA2_IN``, ``ATTN_QKV``) and remade the
 JSON: its diff shows granite's, lfm2's and laguna's hashes moved (their
 blocks keep rows they made again before), GLM's and qwen's not (no value

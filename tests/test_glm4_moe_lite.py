@@ -364,6 +364,105 @@ def test_expert_layer_gradients_match_a_dense_loop():
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-6)
 
 
+# ------------------------------------- the weights, read without a gather
+def _scores_and_choice(scores, experts, top_k, tokens=96):
+    """Seeded scores of either kind and the choice their rule makes (the
+    sigmoid's bias moves the ranking, never the scores)."""
+    key = jax.random.PRNGKey(experts + top_k)
+    logits = 3.0 * jax.random.normal(key, (tokens, experts))
+    if scores == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        ranked = s + 0.3 * jax.random.normal(
+            jax.random.fold_in(key, 1), (experts,))
+    else:
+        s = ranked = jax.nn.softmax(logits, -1)
+    return s, jax.lax.top_k(ranked, top_k)[1]
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+def _equations(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of what it calls, with the calls on
+    its way (``lax.switch``'s branch ``i`` is ``cond[i]``)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        name = eqn.primitive.name
+        for value in eqn.params.values():
+            for i, sub in enumerate(
+                    value if isinstance(value, (tuple, list)) else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, inside + (
+                        (f"cond[{i}]" if name == "cond" else name),))
+
+
+_ROUTERS = [(64, 4), (128, 8), (256, 8), (512, 10)]   # the cells' four
+
+
+@pytest.mark.parametrize("scores", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("experts, top_k", _ROUTERS)
+def test_the_weights_are_the_gathers_to_the_bit(scores, experts, top_k):
+    s, choice = _scores_and_choice(scores, experts, top_k)
+    got = jax.jit(moe._at_choice)(s, choice)
+    want = jnp.take_along_axis(s, choice, -1)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("scores", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("experts, top_k", _ROUTERS)
+def test_the_weights_gradient_is_the_scatter_adds_to_the_bit(
+        scores, experts, top_k):
+    """What reaches ``s`` where ``gate_grad=True``: a token's choices are
+    distinct, so every ``(token, expert)`` gets at most one addend."""
+    s, choice = _scores_and_choice(scores, experts, top_k)
+    g = jax.random.normal(jax.random.PRNGKey(11), choice.shape)
+    pull = lambda read: jax.jit(
+        lambda s, g: jax.vjp(lambda s: read(s, choice), s)[1](g)[0])(s, g)
+    got = pull(moe._at_choice)
+    want = pull(lambda s, c: jnp.take_along_axis(s, c, -1))
+    assert np.count_nonzero(np.asarray(want)) == choice.size
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("what", ["forward", "vjp"])
+def test_the_weights_are_read_with_no_gather_and_no_scatter(what):
+    s, choice = _scores_and_choice("softmax", 128, 8)
+    fn = lambda s, g: moe._at_choice(s, choice)
+    if what == "vjp":
+        fn = lambda s, g: jax.vjp(lambda s: moe._at_choice(s, choice),
+                                  s)[1](g)[0]
+    names = [eqn.primitive.name for eqn, _ in _equations(
+        jax.make_jaxpr(fn)(s, jnp.ones(choice.shape)).jaxpr)]
+    assert "reduce_sum" in names
+    assert not [n for n in names if "gather" in n or "scatter" in n], names
+
+
+@pytest.mark.parametrize("held, first", [(2, 5), (2, 0), (8, 0)], ids=[
+    "the_two_every_token_chose", "none_of_the_chosen", "every_expert"])
+def test_group_sizes_are_the_bincount_of_the_slots(held, first):
+    """A router biased onto experts 5 and 6 (every slot in two groups),
+    seen from the share that holds them, from one that holds neither and
+    from a layer that holds all eight."""
+    whole, p, x = _layer_params(jax.random.PRNGKey(4), shared=False)
+    bias = jnp.zeros((8,)).at[jnp.array([5, 6])].set(10.0)
+    p = {"params": dict(p["params"], router_bias=bias)}
+    part = _layer(held, first)
+    (_, stats), sown = part.apply(_share(p, first, held), x,
+                                  mutable=["intermediates"])
+    choice = sown["intermediates"]["router_choice"][0]
+    local = choice - first
+    key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    want = jnp.bincount(key, length=held + 1)[:held]
+    got = jax.jit(moe._slots_by_expert, static_argnums=1)(local, held)
+    assert got.dtype == jnp.int32 and got.shape == (held,)
+    np.testing.assert_array_equal(got, want)
+    assert int(stats["slots_here"]) == int(want.sum()) == (
+        0 if (held, first) == (2, 0) else choice.size)
+
+
 # ------------------------------ expert-order buffers on a ladder of sizes
 # 2 of 16 experts over 512 tokens x top-2: an even router sends 128 slots
 # here, and every rung below all 1,024 is one tile of 512 rows
@@ -577,6 +676,52 @@ def test_no_slot_sized_array_outside_the_overflow_branch(ladder, what):
         if rows != tokens:      # the layer's input and output have those
             assert all(f"cond[{i}]" in inside for inside, _, _ in found), [
                 f for f in found if f"cond[{i}]" not in f[0]]
+
+
+@pytest.mark.parametrize("gate_grad", [True, False])
+def test_the_layers_gathers_and_scatters_are_the_ones_it_names(gate_grad):
+    """What a ``DroplessMoe`` forward-and-gradient jaxpr still moves by
+    index, each by what it moves: a rung's rows of the layer's width
+    between token order and expert order (``x[token]`` and
+    ``segment_sum``, each other's transposes), a rung's scalars of the
+    slot axis (the combine's ``weight`` and its transpose). Nothing picks
+    or counts scalars by an index over the expert axis: a gather or
+    scatter of ``tokens x top_k`` scalars walks them one by one on the
+    chip (PERF.md section 6, PR 48)."""
+    layer = DroplessMoe(32, 16, 16, 2, experts_held=TALL["held"],
+                        scaling=1.8, dtype=jnp.float32, scores="softmax",
+                        gate_grad=gate_grad)
+    _, p, x = _tall_params("softmax", 300)
+    tokens, width, slots = TALL["tokens"], 32, 2 * TALL["tokens"]
+    rungs = _tall_rungs()
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda q, x: jnp.sum(layer.apply(q, x)[0]), argnums=(0, 1)))(
+            p, x).jaxpr
+
+    def kind(name, table, moved):
+        rows_of_a_rung = moved[0] in rungs
+        if table == (tokens, width) and moved[1:] == (width,):
+            return rows_of_a_rung and {
+                "gather": "x[token]", "scatter-add": "segment_sum"}.get(name)
+        if table == (slots,) and len(moved) == 1:
+            return rows_of_a_rung and {
+                "gather": "weight",
+                "scatter-add": "weight, transposed"}.get(name)
+        return None
+
+    kinds = set()
+    for eqn, inside in _equations(jaxpr):
+        name = eqn.primitive.name
+        if "gather" not in name and "scatter" not in name:
+            continue
+        # what is read or added into, and what moves
+        shapes = [v.aval.shape for v in eqn.invars]
+        moved = shapes[2] if "scatter" in name else eqn.outvars[0].aval.shape
+        what = kind(name, shapes[0], moved)
+        assert what, (name, shapes[0], moved, inside)
+        kinds.add(what)
+    assert kinds == {"x[token]", "segment_sum", "weight",
+                     "weight, transposed"}
 
 
 def test_a_layer_that_holds_every_expert_lowers_no_cond():
