@@ -51,7 +51,6 @@ import itertools
 import json
 import os
 import sys
-import threading
 import time
 from typing import Any, Dict, List, Tuple
 
@@ -125,48 +124,25 @@ def say(msg: str) -> None:
     print(f"# chip_smoke: {msg}", file=sys.stderr, flush=True)
 
 
-class CompileMeter:
-    """What jax compiled, from its own monitoring events: every program it
-    had to build or load (``compiles``, with the seconds spent) and the
-    persistent cache's hits and misses. Server lanes compile on their own
-    threads, hence the lock."""
+# what jax compiled, from the program's own ledger of its compile events
+# (``mmlspark_tpu/observability/compiles.py``, listening since
+# ``compile_cache.enable``): every program built or loaded with the seconds
+# of its backend stage, and the persistent cache's hits and misses
+_COMPILED = {"compiles": "compile.programs", "compile_s": "compile.backend_s",
+             "cache_hits": "compile.cache_hits",
+             "cache_misses": "compile.cache_misses"}
 
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _MISS = "/jax/compilation_cache/cache_misses"
 
-    def __init__(self):
-        import jax
-        self._lock = threading.Lock()
-        self._n = {"compiles": 0, "compile_s": 0.0, "cache_hits": 0,
-                   "cache_misses": 0}
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
+def compiled() -> Dict[str, float]:
+    from mmlspark_tpu.observability import metrics
+    now = {key: metrics.counter(name).value
+           for key, name in _COMPILED.items()}
+    return {k: v if k == "compile_s" else int(v) for k, v in now.items()}
 
-    def _duration(self, name: str, secs: float, **_kw) -> None:
-        if name == self._COMPILE:
-            with self._lock:
-                self._n["compiles"] += 1
-                self._n["compile_s"] += secs
 
-    def _event(self, name: str, **_kw) -> None:
-        key = {self._HIT: "cache_hits", self._MISS: "cache_misses"}.get(name)
-        if key:
-            with self._lock:
-                self._n[key] += 1
-
-    def snapshot(self) -> Dict[str, float]:
-        with self._lock:
-            return dict(self._n)
-
-    def since(self, before: Dict[str, float]) -> Dict[str, float]:
-        now = self.snapshot()
-        return {"xla_" + k: round(now[k] - before[k], 3) for k in now}
-
-    def close(self) -> None:
-        import jax
-        jax.monitoring.unregister_event_duration_listener(self._duration)
-        jax.monitoring.unregister_event_listener(self._event)
+def compiled_since(before: Dict[str, float]) -> Dict[str, float]:
+    now = compiled()
+    return {"xla_" + k: round(now[k] - before[k], 3) for k in now}
 
 
 def _rel_err(got, ref) -> float:
@@ -195,7 +171,7 @@ def _timed(fn):
 
 # -- leg 1: the trainer -------------------------------------------------------
 
-def leg_trainer(sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
+def leg_trainer(sz: Sizes) -> Dict[str, Any]:
     """bench.config_train_large's framework side, plus the checks a bench
     never makes: sharding coverage, step count, params moved, zero syncs
     and zero compiles in the steady window."""
@@ -260,14 +236,14 @@ def leg_trainer(sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
         return m["loss"]
     loss_first, compile_s = _timed(first)
 
-    before, syncs0 = meter.snapshot(), obssyncs.total()
+    before, syncs0 = compiled(), obssyncs.total()
     t0 = time.perf_counter()
     for _ in range(steps):
         state, m = trainer.train_step(state, next(it), rng)
     syncs_in_window = obssyncs.total() - syncs0   # before the closing wait
     jax.block_until_ready(m["loss"])
     steady_s = time.perf_counter() - t0
-    steady = meter.since(before)
+    steady = compiled_since(before)
 
     ring = trainer.flush_metrics()
     losses = ring["loss"][:steps + 1]
@@ -302,7 +278,7 @@ def leg_trainer(sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
 
 # -- leg 2: the server --------------------------------------------------------
 
-def _score_part(server, sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
+def _score_part(server, sz: Sizes) -> Dict[str, Any]:
     import numpy as np
     from mmlspark_tpu.serve.batcher import bucket_for
 
@@ -323,12 +299,12 @@ def _score_part(server, sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
     first = one_pass()                      # each new bucket compiles here
     compile_s = time.perf_counter() - t0
     built = entry.compile_count + entry.cache_hits
-    before = meter.snapshot()
+    before = compiled()
     t0 = time.perf_counter()
     outs = one_pass()
     steady_s = time.perf_counter() - t0
     steady_programs = entry.compile_count + entry.cache_hits - built
-    check(steady_programs == 0 and meter.since(before)["xla_compiles"] == 0,
+    check(steady_programs == 0 and compiled_since(before)["xla_compiles"] == 0,
           "the scoring server compiled in steady state")
 
     # reference: the direct jitted apply on the same bucket-padded rows
@@ -358,7 +334,7 @@ def _score_part(server, sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
             "digest": _digest(*outs)}
 
 
-def _generate_part(server, sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
+def _generate_part(server, sz: Sizes) -> Dict[str, Any]:
     import numpy as np
     from mmlspark_tpu.serve.batcher import bucket_for
 
@@ -447,7 +423,7 @@ def _generate_part(server, sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
             "digest": _digest(*[r["tokens"] for r in warm + steady])}
 
 
-def leg_server(sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
+def leg_server(sz: Sizes) -> Dict[str, Any]:
     from mmlspark_tpu import compile_cache
     from mmlspark_tpu.models.jax_model import JaxModel
     from mmlspark_tpu.serve import Server
@@ -467,8 +443,8 @@ def leg_server(sz: Sizes, meter: CompileMeter) -> Dict[str, Any]:
                     max_batch=sz.score_max_batch)
     load_s = time.perf_counter() - t0
     try:
-        scoring = _score_part(server, sz, meter)
-        generate = _generate_part(server, sz, meter)
+        scoring = _score_part(server, sz)
+        generate = _generate_part(server, sz)
     finally:
         server.close()
         for k, v in prior.items():
@@ -758,8 +734,7 @@ KERNEL_CHECKS = (kernel_normalize, kernel_crop, kernel_flash_forward,
                  kernel_short_attention, kernel_flash_sharded)
 
 
-def leg_kernels(sz: Sizes, meter: CompileMeter,
-                rehearsal: bool) -> Dict[str, Any]:
+def leg_kernels(sz: Sizes, rehearsal: bool) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     compile_s = steady_s = 0.0
 
@@ -801,22 +776,19 @@ def run(sizes: Sizes = FULL, *, rehearsal: bool = False,
     cache_dir = compile_cache.enable(default_cache_dir)
     device = jax.devices()[0]
     t_start = time.perf_counter()
-    meter = CompileMeter()
+    started = compiled()
     legs: Dict[str, Any] = {}
-    try:
-        for name, leg in (
-                ("trainer", lambda: leg_trainer(sizes, meter)),
-                ("server", lambda: leg_server(sizes, meter)),
-                ("kernels", lambda: leg_kernels(sizes, meter, rehearsal))):
-            say(f"{name} ...")
-            before, t0 = meter.snapshot(), time.perf_counter()
-            legs[name] = leg()
-            legs[name].update(meter.since(before),
-                              wall_s=round(time.perf_counter() - t0, 3))
-            say(f"{name} ok: {json.dumps(legs[name])}")
-        totals = meter.snapshot()
-    finally:
-        meter.close()
+    for name, leg in (
+            ("trainer", lambda: leg_trainer(sizes)),
+            ("server", lambda: leg_server(sizes)),
+            ("kernels", lambda: leg_kernels(sizes, rehearsal))):
+        say(f"{name} ...")
+        before, t0 = compiled(), time.perf_counter()
+        legs[name] = leg()
+        legs[name].update(compiled_since(before),
+                          wall_s=round(time.perf_counter() - t0, 3))
+        say(f"{name} ok: {json.dumps(legs[name])}")
+    totals = compiled_since(started)
     return {
         "ok": True,
         "device": {"platform": device.platform, "kind": device.device_kind,
@@ -827,9 +799,9 @@ def run(sizes: Sizes = FULL, *, rehearsal: bool = False,
                      "flax": flax.__version__},
         "cache": {"dir": cache_dir,
                   "from_env": bool(os.environ.get(compile_cache.ENV_VAR)),
-                  "xla_hits": totals["cache_hits"],
-                  "xla_misses": totals["cache_misses"],
-                  "xla_compile_s": round(totals["compile_s"], 3),
+                  "xla_hits": totals["xla_cache_hits"],
+                  "xla_misses": totals["xla_cache_misses"],
+                  "xla_compile_s": totals["xla_compile_s"],
                   "aot": compile_cache.stats()},
         "wall_s": round(time.perf_counter() - t_start, 3),
         "legs": legs,

@@ -48,7 +48,7 @@ import shutil
 import threading
 from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Tuple
 
-from mmlspark_tpu.observability import events, metrics
+from mmlspark_tpu.observability import compiles, events, metrics
 from mmlspark_tpu.utils import config as mmlconfig
 from mmlspark_tpu.utils.logging import get_logger
 
@@ -110,7 +110,10 @@ def enable(default_dir: str = "") -> Optional[str]:
     environment nor the config names a directory (it becomes
     ``runtime.compile_cache_dir``, so the AOT layer follows). Idempotent;
     call before the first compile — jax binds its cache to one directory
-    for the life of the process."""
+    for the life of the process. Whether or not a cache is on, the
+    program's ledger of what jax compiles (``observability/compiles.py``,
+    the ``compile.*`` counters) listens from here."""
+    compiles.install()
     root = cache_dir()
     if not root and default_dir:
         root = os.path.abspath(default_dir)

@@ -28,6 +28,12 @@ A program is published as a thunk (:func:`publish`) and parsed when
 somebody asks (:func:`table`): asked by nobody, it costs nothing. The
 trainer publishes its step program under the hot-span gate
 (``DistributedTrainer.step_scopes`` is the same table).
+
+The same executable knows what the program occupies on a device:
+:func:`memory` gives its ``memory_analysis()`` in bytes (``argument``,
+``output``, ``alias``, ``temp``, ``generated_code``, ``peak_memory``).
+One call of the thunk serves both, whichever is asked first; neither the
+executable nor a buffer is kept.
 """
 from __future__ import annotations
 
@@ -128,9 +134,14 @@ def parse(compiled: Any) -> Table:
 
 # -- programs, published lazily -------------------------------------------
 
+Memory = Dict[str, int]
+
+_MEMORY_PARTS = ("argument", "output", "alias", "temp", "generated_code")
+
 _lock = threading.Lock()
 _thunks: Dict[str, Callable[[], Any]] = {}
-_tables: Dict[str, Table] = {}
+# program -> (table, memory) of the one compile its thunk made
+_made: Dict[str, Tuple[Table, Optional[Memory]]] = {}
 
 
 def publish(program: str, compile_thunk: Callable[[], Any]) -> None:
@@ -140,28 +151,58 @@ def publish(program: str, compile_thunk: Callable[[], Any]) -> None:
     publication under one name takes the first one's place."""
     with _lock:
         _thunks[program] = compile_thunk
-        _tables.pop(program, None)
+        _made.pop(program, None)
 
 
-def table(program: str) -> Optional[Table]:
-    """The table of a published program, made on the first call (the
-    thunk's compile, a load where the persistent cache holds the program,
-    and the parse) and kept; ``None`` for a name nobody published."""
+def memory_of(compiled: Any) -> Optional[Memory]:
+    """A compiled executable's ``memory_analysis()`` as plain bytes, a
+    device's share of a sharded program; ``None`` from a backend, or an
+    executable, that gives none."""
+    analysis = getattr(compiled, "memory_analysis", None)
+    stats = analysis() if analysis else None
+    if stats is None:
+        return None
+    found = {part: int(getattr(stats, part + "_size_in_bytes"))
+             for part in _MEMORY_PARTS}
+    found["peak_memory"] = int(stats.peak_memory_in_bytes)
+    return found
+
+
+def _make(program: str) -> Optional[Tuple[Table, Optional[Memory]]]:
+    """``(table, memory)`` of a published program, made on the first call
+    (the thunk's compile, a load where the persistent cache holds the
+    program, and the parse) and kept; the executable is dropped here."""
     with _lock:
-        if program in _tables:
-            return _tables[program]
+        if program in _made:
+            return _made[program]
         thunk = _thunks.get(program)
     if thunk is None:
         return None
-    made = parse(thunk())
+    compiled = thunk()
+    made = (parse(compiled), memory_of(compiled))
     with _lock:
         if _thunks.get(program) is thunk:
-            _tables[program] = made
+            _made[program] = made
     return made
+
+
+def table(program: str) -> Optional[Table]:
+    """The table of a published program; ``None`` for a name nobody
+    published."""
+    made = _make(program)
+    return made and made[0]
+
+
+def memory(program: str) -> Optional[Memory]:
+    """What a published program occupies on a device, in bytes by part;
+    its buffers while it runs are ``argument + output - alias + temp``
+    beside its ``generated_code``. ``None`` for a name nobody published."""
+    made = _make(program)
+    return made and made[1]
 
 
 def clear() -> None:
     """Forget every published program (tests)."""
     with _lock:
         _thunks.clear()
-        _tables.clear()
+        _made.clear()

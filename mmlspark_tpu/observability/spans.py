@@ -172,3 +172,18 @@ def current_span() -> Optional[Tuple[str, int]]:
     """(name, span_id) of the innermost open span, or None at the root."""
     stack = _STACK.get()
     return stack[-1] if stack else None
+
+
+def emit_retroactive(name: str, start: float, dur_s: float,
+                     **attrs: Any) -> None:
+    """One span event for a region that is over by the time it is known
+    to have been one (a program jax compiled): ``start`` on
+    ``events.wall()``, a child of the innermost span open HERE, with an
+    id of its own. No ``TraceAnnotation``: there is nothing left to open
+    one around."""
+    stack = _STACK.get()
+    parent = stack[-1] if stack else None
+    events.emit("span", name, span_id=next_span_id(), pid=os.getpid(),
+                parent_id=parent[1] if parent else None,
+                parent=parent[0] if parent else "", depth=len(stack),
+                start=round(start, 6), dur_s=round(dur_s, 9), attrs=attrs)

@@ -31,6 +31,7 @@ from jax.sharding import Mesh
 from mmlspark_tpu.data.pipeline import Dataset
 from mmlspark_tpu.data.prefetch import DevicePrefetcher  # noqa: F401
 from mmlspark_tpu.parallel.mesh import mesh_from_config
+from mmlspark_tpu.observability import compiles as obscompiles
 from mmlspark_tpu.observability import events as obsevents
 from mmlspark_tpu.observability import metrics as obsmetrics
 from mmlspark_tpu.observability import scopes as obsscopes
@@ -224,6 +225,34 @@ class _AuxKeys(Exception):
         self.keys = keys
 
 
+class _StartSpan:
+    """A part of the trainer's start: the cold span ``trainer:<detail>``,
+    inside ``outer`` (the first step's ``trainer:dispatch``, or nothing),
+    its seconds also set on the always-on gauge ``gauge``, so that a
+    reader needs neither the event log nor the flight recorder's ring."""
+
+    __slots__ = ("_span", "_outer", "_gauge", "_start")
+
+    def __init__(self, detail: str, gauge: Optional[str],
+                 outer=obsspans.NOOP, **attrs: Any):
+        self._span = obsspans.span("trainer", detail, **attrs)
+        self._outer, self._gauge = outer, gauge
+
+    def __enter__(self) -> "_StartSpan":
+        self._outer.__enter__()
+        self._start = obsevents.perf()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._span.__exit__(exc_type, exc, tb)
+        if self._gauge:
+            obsmetrics.gauge(self._gauge).set(
+                obsevents.perf() - self._start)
+        self._outer.__exit__(exc_type, exc, tb)
+        return False
+
+
 class DistributedTrainer:
     """Builds sharded init/train/eval steps for a pure loss function.
 
@@ -266,6 +295,11 @@ class DistributedTrainer:
         # donated (fit's streaming path donates; direct callers feeding
         # reused device batches — DeviceEpochCache epochs — must not)
         self._train_steps: Dict[bool, Any] = {}
+        # set where a variant is built, taken back by its first call: that
+        # call is a ``trainer:first_step`` span (the step's traces, its
+        # lowering, its build or load); every later one tests this and
+        # goes on
+        self._starting = False
         self._eval_step = None
         # Device-resident metrics ring (ROADMAP item 4, "kill the overhead
         # floor"): per-step scalars (loss, step counter) accumulate in a
@@ -294,6 +328,9 @@ class DistributedTrainer:
         # (under the same gate, at their first dispatch)
         self._scoped: set = set()
         self._scope_program: Optional[str] = None
+        # what jax compiles from here on has a row in the program's ledger
+        # (listeners on compile events, none per step)
+        obscompiles.install()
 
     # -- state -------------------------------------------------------------
     def _full_init_fn(self, init_params_fn: Callable[[], Any]):
@@ -321,9 +358,13 @@ class DistributedTrainer:
         """Initialize sharded state; params materialize directly into their
         shards (no host-side full copy on any single device)."""
         full_init = self._full_init_fn(init_params_fn)
-        self._abstract_state(full_init)
-        with self.mesh:
-            return jax.jit(full_init, out_shardings=self._state_shardings)()
+        # host time to trace (``eval_shape`` does, the jitted call finds
+        # the jaxpr made), build or load and dispatch the state's program
+        with _StartSpan("init", "trainer.init_s"):
+            self._abstract_state(full_init)
+            with self.mesh:
+                return jax.jit(full_init,
+                               out_shardings=self._state_shardings)()
 
     def state_sharding_spec(self) -> Any:
         return self._state_shardings
@@ -453,6 +494,7 @@ class DistributedTrainer:
                 raise RuntimeError("call init() before train_step()")
             fn = self._build_train_step(donate_batch)
             self._train_steps[donate_batch] = fn
+            self._starting = True
         return fn
 
     def train_step(self, state, batch, rng, *,
@@ -479,6 +521,15 @@ class DistributedTrainer:
                 self._publish_scopes(fn, donate_batch, state, batch, rng)
         else:
             dispatch = obsspans.NOOP
+        if self._starting:
+            # the variant's first call, the ``_AuxKeys`` retrace included,
+            # as the one child of its ``trainer:dispatch``; the gauge is
+            # the first variant's
+            self._starting = False
+            dispatch = _StartSpan(
+                "first_step", "trainer.first_step_s"
+                if len(self._train_steps) == 1 else None,
+                outer=dispatch, donate=donate_batch)
 
         def call():
             try:
@@ -599,6 +650,17 @@ class DistributedTrainer:
         if self._scope_program is None:
             return None
         return obsscopes.table(self._scope_program)
+
+    def step_memory(self) -> Optional[obsscopes.Memory]:
+        """What the step program last published occupies on a device, in
+        bytes: ``argument``, ``output``, ``alias``, ``temp``,
+        ``generated_code`` and ``peak_memory`` of its
+        ``memory_analysis()``. From the same one compile as
+        :meth:`step_scopes`, whichever is asked first; ``None`` where that
+        gives ``None``."""
+        if self._scope_program is None:
+            return None
+        return obsscopes.memory(self._scope_program)
 
     def _estimate_flops(self, state, batch, rng) -> float:
         """FLOPs of one compiled train step via XLA cost analysis (a

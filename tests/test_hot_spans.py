@@ -266,3 +266,136 @@ def test_fit_with_metrics_on_traces_and_compiles_the_step_once():
         jax.monitoring.unregister_event_duration_listener(listener)
     assert "/jax/core/compile/backend_compile_duration" in off
     assert on == off
+
+
+# ---------------------------------------------- the start: init, first step
+def _start_spans(path):
+    return [e for e in _spans(path)[0]
+            if e["name"] in ("trainer:init", "trainer:first_step")
+            or e["name"].startswith("compile:jit_")]
+
+
+def test_init_once_and_first_step_once_a_variant_and_on_no_later_step(
+        events_file):
+    from mmlspark_tpu.observability import compiles
+    compiles.clear()
+    for name in ("trainer.init_s", "trainer.first_step_s"):
+        obsmetrics.gauge(name).set(-1.0)
+    trainer, state = _trainer()
+    batch = trainer.put_batch(_batches(1)[0])
+    for _ in range(3):
+        state, _ = trainer.train_step(state, batch, jax.random.PRNGKey(0))
+    first_variant_s = obsmetrics.gauge("trainer.first_step_s").value
+    state, _ = trainer.fit(state, iter(_batches(3)))    # the donating one
+    found = _start_spans(events_file)
+    (init,) = [e for e in found if e["name"] == "trainer:init"]
+    firsts = [e for e in found if e["name"] == "trainer:first_step"]
+    assert [e["attrs"] for e in firsts] == [{"donate": False},
+                                            {"donate": True}]
+    assert init["depth"] == 0 and "attrs" not in init
+    # each the one child of its variant's first trainer:dispatch
+    dispatches = {e["span_id"]: e for e in _spans(events_file)[0]
+                  if e["name"] == "trainer:dispatch"}
+    parents = [dispatches[e["parent_id"]]["attrs"] for e in firsts]
+    assert parents == [{"step": 0, "donate": False},
+                       {"step": 0, "donate": True}]
+    # and the programs jax built lie under the span that waited for them
+    under = {e["name"]: e["parent"] for e in found
+             if e["name"].startswith("compile:")}
+    assert under["compile:jit_full_init"] == "trainer:init"
+    assert under["compile:jit_step"] == "trainer:first_step"
+    steps = [e for e in found if e["name"] == "compile:jit_step"]
+    assert [e["parent_id"] for e in steps] == \
+        [e["span_id"] for e in firsts]
+    assert compiles.first("jit_step").parent == "trainer:first_step"
+    # both gauges set, the first step's by the first variant alone
+    assert obsmetrics.gauge("trainer.init_s").value == pytest.approx(
+        init["dur_s"], abs=5e-3)
+    assert first_variant_s == pytest.approx(firsts[0]["dur_s"], abs=5e-3)
+    assert obsmetrics.gauge("trainer.first_step_s").value == \
+        first_variant_s
+    assert first_variant_s >= compiles.first("jit_step").backend_s > 0
+
+
+def test_first_step_spans_the_aux_keys_retrace_once(events_file):
+    from mmlspark_tpu.observability import compiles
+    compiles.clear()
+    traces = []
+
+    def listener(name, secs, fun_name="", **_kw):
+        if name.endswith("jaxpr_trace_duration") and fun_name == "step":
+            traces.append(secs)
+
+    def loss_fn(params, batch, rng):
+        loss = jnp.mean((batch["x"] @ params["w"] - batch["y"]) ** 2)
+        return loss, {"test.half_loss": loss / 2}
+
+    trainer = DistributedTrainer(loss_fn, optax.sgd(0.1))
+    state = trainer.init(lambda: {"w": jnp.zeros((3,), jnp.float32)})
+    batch = trainer.put_batch(_batches(1)[0])
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        for _ in range(3):
+            state, m = trainer.train_step(state, batch,
+                                          jax.random.PRNGKey(0))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert "test.half_loss" in m and len(traces) == 2     # _AuxKeys, again
+    found = _start_spans(events_file)
+    (first,) = [e for e in found if e["name"] == "trainer:first_step"]
+    (step,) = [e for e in found if e["name"] == "compile:jit_step"]
+    assert step["parent_id"] == first["span_id"]
+    row = compiles.first("jit_step")
+    assert row.trace_s == pytest.approx(sum(traces))      # both traces
+    assert first["dur_s"] >= row.total_s * 0.99
+    assert first["start"] <= step["start"]
+
+
+def test_gauges_are_set_with_every_sink_off():
+    config.set("observability.flight_recorder_size", 0)
+    try:
+        assert spans.span("trainer", "init") is spans.NOOP
+        for name in ("trainer.init_s", "trainer.first_step_s"):
+            obsmetrics.gauge(name).set(-1.0)
+        trainer, state = _trainer()
+        assert obsmetrics.gauge("trainer.init_s").value > 0
+        assert obsmetrics.gauge("trainer.first_step_s").value == -1.0
+        batch = trainer.put_batch(_batches(1)[0])
+        trainer.train_step(state, batch, jax.random.PRNGKey(0))
+        assert obsmetrics.gauge("trainer.first_step_s").value > 0
+    finally:
+        config.unset("observability.flight_recorder_size")
+
+
+def test_a_later_step_pays_one_boolean_and_builds_nothing_of_the_start(
+        monkeypatch):
+    from mmlspark_tpu.parallel import trainer as trainer_module
+    config.set("train.metrics_flush_steps", 512)
+    try:
+        trainer, state = _trainer(jax.devices()[:1])
+        batch = trainer.put_batch(_batches(1)[0])
+        rng = jax.random.PRNGKey(0)
+        assert trainer._starting is False        # nothing built yet
+        state, _ = trainer.train_step(state, batch, rng)
+        assert trainer._starting is False        # taken back by that call
+
+        def never(*_a, **_kw):
+            raise AssertionError("a later step looked something up")
+
+        # everything the start's spans are made of, and every lookup a
+        # per-step path may not make: the config, the registry, the clock
+        monkeypatch.setattr(trainer_module, "_StartSpan", never)
+        monkeypatch.setattr(spans, "span", never)
+        monkeypatch.setattr(spans, "hot_spans", never)
+        monkeypatch.setattr(events, "perf", never)
+        monkeypatch.setattr(events, "wall", never)
+        monkeypatch.setattr(obsmetrics, "gauge", never)
+        monkeypatch.setattr(obsmetrics, "counter", never)
+        monkeypatch.setattr(config, "get", never)
+        ids = spans.next_span_id()
+        for _ in range(50):
+            state, _ = trainer.train_step(state, batch, rng)
+        assert spans.next_span_id() == ids + 1
+        jax.block_until_ready(state)
+    finally:
+        config.unset("train.metrics_flush_steps")

@@ -324,3 +324,82 @@ def test_the_published_thunk_keeps_shapes_and_no_buffer(stepped):
         [h for h in held if isinstance(h, (tuple, dict, list))])
     assert leaves and all(isinstance(x, jax.ShapeDtypeStruct)
                           for x in leaves)
+
+
+# ------------------------------------------- the step program's bytes
+class _Stats:
+    generated_code_size_in_bytes = 5
+    argument_size_in_bytes = 400
+    output_size_in_bytes = 300
+    alias_size_in_bytes = 200
+    temp_size_in_bytes = 100
+    peak_memory_in_bytes = 650
+
+
+class _Measured(_Compiled):
+    def memory_analysis(self):
+        return _Stats()
+
+
+@pytest.mark.parametrize("order", [("table", "memory"), ("memory", "table"),
+                                   ("memory", "memory", "table")])
+def test_memory_and_table_share_one_thunk_call_and_keep_no_executable(
+        registry, order):
+    import gc
+    import weakref
+    made = []
+
+    def thunk():
+        compiled = _Measured()
+        made.append(weakref.ref(compiled))
+        return compiled
+
+    scopes.publish("jit_f", thunk)
+    got = {ask: getattr(scopes, ask)("jit_f") for ask in order}
+    assert len(made) == 1                           # whichever came first
+    assert got["table"]["add_fusion"].path == PRODUCT
+    assert got["memory"] == {"argument": 400, "output": 300, "alias": 200,
+                             "temp": 100, "generated_code": 5,
+                             "peak_memory": 650}
+    assert scopes.memory("jit_f") is got["memory"]
+    gc.collect()
+    assert made[0]() is None                        # the executable went
+    assert scopes.memory("jit_g") is None
+
+
+def test_an_executable_without_an_analysis_has_a_table_and_no_memory(
+        registry):
+    scopes.publish("jit_f", _Compiled)
+    assert scopes.memory("jit_f") is None and scopes.table("jit_f")
+    scopes.publish("jit_f", _Measured)              # a second publication
+    assert scopes.memory("jit_f")["temp"] == 100
+
+
+def test_step_memory_is_the_compiled_steps_own_analysis(stepped,
+                                                        monkeypatch):
+    trainer, state, batch = stepped
+    real, calls = trainer._compile_step, []
+
+    def counted(fn, *args):
+        calls.append(fn)
+        return real(fn, *args)
+
+    monkeypatch.setattr(trainer, "_compile_step", counted)
+    memory = trainer.step_memory()
+    assert trainer.step_scopes() and len(calls) == 1    # one compile, both
+    assert memory is scopes.memory("jit_step")
+    stats = real(trainer._train_steps[False], state, batch,
+                 jax.random.PRNGKey(1)).memory_analysis()
+    assert memory["argument"] == stats.argument_size_in_bytes > 0
+    assert memory["temp"] == stats.temp_size_in_bytes
+    assert memory["alias"] == stats.alias_size_in_bytes > 0   # the donation
+    assert set(memory) == {"argument", "output", "alias", "temp",
+                           "generated_code", "peak_memory"}
+    assert all(isinstance(v, int) for v in memory.values())
+
+
+def test_step_memory_is_none_until_a_step_is_published(registry):
+    trainer, state, batch = _trainer()
+    assert trainer.step_memory() is None
+    trainer.train_step(state, batch, jax.random.PRNGKey(1))   # gate off
+    assert trainer.step_memory() is None
