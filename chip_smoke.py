@@ -106,6 +106,11 @@ class Sizes:
     # and as cell olmo-hybrid-7b-train-8k calls it: (B, L, heads, key
     # width, value width), a state that fills no whole lanes, beta up to 2
     gated_delta_wide: Tuple[int, int, int, int, int] = (1, 8192, 30, 96, 192)
+    # a head's norm and turn in one pass: ((B, L, H, d), frequencies,
+    # normed) as cells sdar-30b-a3b-train-ep8share-4k (q) and
+    # laguna-xs.2-train-ep8share-8k (a full layer's q: half the head) call it
+    head_norm_turn: Tuple[Tuple[Tuple[int, int, int, int], int, bool], ...] \
+        = (((4, 8192, 32, 128), 64, True), ((2, 8192, 64, 128), 32, False))
 
 
 FULL = Sizes()
@@ -694,6 +699,56 @@ def kernel_gated_delta(sz: Sizes, rehearsal: bool, record) -> None:
         record(name, shape, c, s, err, path=took[0], key_heads=key_heads)
 
 
+def kernel_head_norm_turn(sz: Sizes, rehearsal: bool, record) -> None:
+    """``ops/pallas_head_norm_turn`` forward and backward against the form
+    other shapes keep (``parts.RMSNorm``'s arithmetic, then
+    ``parts.rotary``, float32 through both): the worst relative error over
+    the turned rows, the rows' gradient and the scale's."""
+    import jax
+    import jax.numpy as jnp
+    from mmlspark_tpu.models.zoo import parts
+    from mmlspark_tpu.ops import pallas_head_norm_turn as hnt
+
+    eps = 1e-6
+    for shape, n, normed in sz.head_norm_turn:
+        L, d = shape[1], shape[3]
+        freqs = parts.plain_frequencies(2 * n, 1e6)
+        y, w = (x.astype(jnp.bfloat16)
+                for x in _qkvw(shape, jnp.float32)[:2])
+        scale = jnp.linspace(0.5, 1.5, d) if normed else None
+        cos, sin = parts.rotary_tables(L, d, freqs)
+
+        def fused(y, scale):
+            return hnt.head_norm_turn(y, cos, sin, n, scale,
+                                      eps if normed else None)
+
+        def plain(y, scale):
+            y = y.astype(jnp.float32)
+            if normed:
+                y = y * jax.lax.rsqrt(jnp.mean(
+                    jnp.square(y), -1, keepdims=True) + eps) * scale
+            return parts.rotary(y, freqs)
+
+        def both(f):
+            # (``w`` an argument: a turn's derivative without a norm reads
+            # the cotangent alone, and a program of constants is folded by
+            # XLA's evaluator, not run)
+            return jax.jit(lambda y, scale, w: (f(y, scale), jax.grad(
+                lambda *a: (f(*a).astype(jnp.float32) * w).sum(),
+                argnums=(0, 1) if normed else 0)(y, scale)))
+        name = f"head_norm_turn_d{d}_n{n}" + ("_normed" if normed else "")
+        check(hnt.supports(shape, n), f"{name}: supports() declines {shape}")
+        _require_mosaic(both(fused), (y, scale, w), name, rehearsal, calls=2)
+        (got, got_g), c, s = _kernel_run(both(fused), (y, scale, w))
+        want, want_g = both(plain)(y, scale, w)
+        err = max(_rel_err(a, b) for a, b in zip(
+            jax.tree_util.tree_leaves((got, got_g)),
+            jax.tree_util.tree_leaves((want, want_g))))
+        check(got.shape == shape and err <= BF16_REL_TOL,
+              f"{name}: rel err {err:.3g}")
+        record(name, shape, c, s, err)
+
+
 def kernel_flash_sharded(sz: Sizes, rehearsal: bool, record) -> None:
     """Data-parallel flash on a multi-device host: each device runs the
     kernel on its own batch row (nothing to do on one device)."""
@@ -731,7 +786,8 @@ def kernel_flash_sharded(sz: Sizes, rehearsal: bool, record) -> None:
 
 KERNEL_CHECKS = (kernel_normalize, kernel_crop, kernel_flash_forward,
                  kernel_flash_backward, kernel_gated_delta,
-                 kernel_short_attention, kernel_flash_sharded)
+                 kernel_short_attention, kernel_head_norm_turn,
+                 kernel_flash_sharded)
 
 
 def leg_kernels(sz: Sizes, rehearsal: bool) -> Dict[str, Any]:

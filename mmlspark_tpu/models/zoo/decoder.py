@@ -145,7 +145,10 @@ def _remat_block(norm, attention, ffn, name: str, split: bool = False,
     4.2 (1.26 GB a step over ``granite_hybrid``'s nine mixers); a
     ``GroupedAttention``'s q, k and v projections' outputs (``ATTN_QKV``:
     named before the norm a head, the turn, the scale and the repeat of K
-    and V, which are bytes and no product and stay recomputed), 5 in
+    and V, which are bytes and no product and stay recomputed; where
+    ``ops/pallas_head_norm_turn`` turns a layer's q and k with no norm,
+    ``laguna``'s, the TURNED rows carry the name: the turn's derivative
+    reads no rows, so as many bytes are kept and no layer turns twice), 5 in
     ``laguna``'s sliding blocks and 4 in its full ones (1.54 GB a step),
     1.5 in ``granite_hybrid``'s and ``lfm2_moe``'s. Each paid on the chip
     (PERF.md section 6; PR 29: +5.6% and +1.5% of a ``glm4_moe_lite``

@@ -10,7 +10,10 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
   frequencies (``plain_frequencies``, ``yarn_frequencies``) and a factor:
   ``n`` frequencies turn the first ``2 n`` dimensions, ``i`` with ``i +
   n``, the rest passing through; float32 angles, one product over the
-  whole head. The one function that turns, in every family that turns;
+  whole head. The one function that turns, in every family that turns
+  (but ``GroupedAttention``'s heads of 128 channels, turned and
+  normed in one pass by ``ops/pallas_head_norm_turn`` from the same
+  ``rotary_tables``);
 - ``MlaAttention``: multi-head latent attention in its expanded (training)
   form: a low-rank query, one compressed key/value row per token, a rotary
   slice on every query head and ONE rotary key shared by all heads;
@@ -58,7 +61,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from mmlspark_tpu.observability import metrics as obsmetrics
-from mmlspark_tpu.parallel.sequence import full_attention
+from mmlspark_tpu.parallel.sequence import (
+    full_attention, on_own_rows, own_shape)
 
 _INIT = nn.initializers.normal(0.02)
 # the checkpoint name of ``SwiGluMlp``'s gate and up products
@@ -69,7 +73,8 @@ DELTA_NET_QKVZ = "delta_net_qkvz"
 SHORT_CONV_IN = "short_conv_in"
 # and of ``Mamba2Mixer``'s
 MAMBA2_IN = "mamba2_in"
-# and of ``GroupedAttention``'s q, k and v projections' outputs
+# and of ``GroupedAttention``'s q, k and v projections' outputs (turned,
+# where a kernel turns q and k without a norm)
 ATTN_QKV = "attn_qkv"
 
 
@@ -78,12 +83,14 @@ class RMSNorm(nn.Module):
     offset: bool = False        # scale = 1 + w, w zero at init
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, apply: bool = True):
         scale = self.param(
             "scale", nn.initializers.zeros if self.offset
             else nn.initializers.ones, (x.shape[-1],), jnp.float32)
         if self.offset:
             scale = 1.0 + scale
+        if not apply:       # the scale alone, for a kernel that norms
+            return scale
         x = x.astype(jnp.float32)
         return x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
@@ -109,19 +116,17 @@ def rotary(x: jax.Array, inv_freq: Tuple[float, ...],
     the lanes: on the chip the split form (``[x_1 cos - x_2 sin | x_2 cos +
     x_1 sin]`` of the two halves) of a (2, 8192, 64, 128) bfloat16 tensor
     ran 4.83 ms forward and this one 1.30, with equal bits (PERF.md
-    section 6, PR 44; at the other families' shapes, PR 45)."""
-    L, R = x.shape[1], x.shape[-1]
-    n = len(inv_freq)
-    if 2 * n > R:
-        raise ValueError(f"{n} frequencies turn {2 * n} dimensions of {R}")
-    if positions is None:
-        positions = jnp.arange(L, dtype=jnp.float32)
-    ang = positions.astype(jnp.float32)[:, None] \
-        * jnp.asarray(inv_freq, jnp.float32)[None, :]
-    rest = (L, R - 2 * n)
-    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
-    cos = jnp.concatenate([cos, cos, jnp.ones(rest, jnp.float32)], -1)
-    sin = jnp.concatenate([sin, sin, jnp.zeros(rest, jnp.float32)], -1)
+    section 6, PR 44; at the other families' shapes, PR 45).
+
+    This is XLA's form, and it runs wherever it is called: on
+    ``MlaAttention``'s and ``GatedAttention``'s slices, and on the q and k
+    of a ``GroupedAttention`` whose heads
+    ``ops/pallas_head_norm_turn.supports`` declines. Heads of 128
+    channels there take that module's one Pallas pass instead (the norm a
+    head, where there is one, and the turn, from the same
+    ``rotary_tables``), against which this function is the reference."""
+    R, n = x.shape[-1], len(inv_freq)
+    cos, sin = rotary_tables(x.shape[1], R, inv_freq, factor, positions)
     pair = np.zeros((R, R), np.float32)
     i = np.arange(n)
     pair[i + n, i], pair[i, i + n] = -1.0, 1.0
@@ -131,6 +136,44 @@ def rotary(x: jax.Array, inv_freq: Tuple[float, ...],
         precision=None if x.dtype.itemsize < 4 else jax.lax.Precision.HIGHEST)
     return (x.astype(jnp.float32) * cos[None, :, None, :]
             + paired * sin[None, :, None, :]).astype(x.dtype)
+
+
+def rotary_tables(L: int, R: int, inv_freq: Tuple[float, ...],
+                  factor: float = 1.0,
+                  positions: Optional[jax.Array] = None):
+    """``rotary``'s cos and sin, ``(L, R)`` float32 each: ``factor cos(l
+    f_i)`` on channels ``i`` and ``i + n``, 1 and 0 on those past ``2 n``."""
+    n = len(inv_freq)
+    if 2 * n > R:
+        raise ValueError(f"{n} frequencies turn {2 * n} dimensions of {R}")
+    if positions is None:
+        positions = jnp.arange(L, dtype=jnp.float32)
+    ang = positions.astype(jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    rest = (L, R - 2 * n)
+    cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+    return (jnp.concatenate([cos, cos, jnp.ones(rest, jnp.float32)], -1),
+            jnp.concatenate([sin, sin, jnp.zeros(rest, jnp.float32)], -1))
+
+
+def _norm_turn(shape, n: int) -> Optional[Callable]:
+    """A head's norm and turn as ``ops/pallas_head_norm_turn``'s one pass,
+    on each device's own rows: ``(y, cos, sin[, scale, eps]) -> y``, or None
+    for ``(B, L, H, d)`` rows turned by ``n`` frequencies whose part on a
+    device it does not take (``supports``, by the shape alone) or that do
+    not split over the mesh. (Imported here: Pallas costs every importer
+    of the zoo over a second.)"""
+    from mmlspark_tpu.ops import pallas_head_norm_turn as kernel
+    local = own_shape(shape)
+    if local is None or not kernel.supports(local, n):
+        return None
+
+    def call(y, cos, sin, scale=None, eps=None):
+        return on_own_rows(
+            lambda y, cos, sin, scale=None: kernel.head_norm_turn(
+                y, cos, sin, n, scale, eps),
+            y, whole=(cos, sin) if scale is None else (cos, sin, scale))
+    return call
 
 
 def plain_frequencies(width: int, theta: float) -> Tuple[float, ...]:
@@ -383,7 +426,22 @@ class GroupedAttention(nn.Module):
     at its position) and the call is handed ``block_diffusion=(L, B)`` in
     ``causal``'s company (``parallel/sequence.full_attention`` has the
     mask), under the scope ``block_diffusion_attention``. Projections,
-    norms, grouping and repeat are a position's own and do not change."""
+    norms, grouping and repeat are a position's own and do not change.
+
+    Which form makes a q or k head's norm and its turn is read from the
+    shape alone (``_norm_turn``; every trace counts under
+    ``attn.norm_turn_calls.pallas`` or ``.xla``): with ``rotary_freqs`` and
+    heads of 128 channels (``sdar_moe``, ``laguna``) ONE Pallas pass over the projection's rows
+    (``ops/pallas_head_norm_turn``: the norm a head where ``norm_heads``,
+    the turn, float32 in registers, one rounding; under the scope
+    ``qk_norm`` where it norms) and one more for the derivative, whose
+    residuals are the projection's rows that ``ATTN_QKV`` names (without a
+    norm the derivative reads no rows, and ``ATTN_QKV`` names the TURNED
+    ones: a recomputed block then makes no turn again); any other
+    head width (``lfm2_moe``'s 64, the tiny presets'), a layer without
+    positions
+    (``granite_hybrid``, ``olmo_hybrid``) and a norm over the whole
+    projection keep XLA's form, ``RMSNorm`` then ``rotary``."""
     dim: int
     heads: int
     kv_heads: int
@@ -420,13 +478,37 @@ class GroupedAttention(nn.Module):
             x32, x = x, x.astype(dt)
 
             def heads_of(name, heads, norm=None):
-                y = checkpoint_name(_dense(heads * d, dt, name)(x), ATTN_QKV)
+                y = _dense(heads * d, dt, name)(x)
                 normed = norm and self.qk_norm_eps is not None
+                a_head = normed and self.norm_heads
+                fused = None
+                if norm:
+                    if self.rotary_freqs is not None:
+                        fused = _norm_turn((B, L, heads, d),
+                                           len(self.rotary_freqs))
+                    obsmetrics.counter("attn.norm_turn_calls."
+                                       + ("pallas" if fused else "xla")).inc()
+                if fused:
+                    tables = rotary_tables(L, d, self.rotary_freqs,
+                                           self.rotary_factor, positions)
+                    if not normed:
+                        # the turn's derivative reads no rows, so the TURNED
+                        # ones are what the block keeps: not made again
+                        return checkpoint_name(fused(
+                            y.reshape(B, L, heads, d), *tables), ATTN_QKV)
+                y = checkpoint_name(y, ATTN_QKV)
                 if normed and not self.norm_heads:
                     with jax.named_scope("qk_norm"):
                         y = RMSNorm(self.qk_norm_eps, name=norm)(y).astype(dt)
                 y = y.reshape(B, L, heads, d)
-                if normed and self.norm_heads:
+                if fused and not a_head:
+                    return fused(y, *tables)
+                if fused:
+                    with jax.named_scope("qk_norm"):
+                        return fused(y, *tables, RMSNorm(
+                            self.qk_norm_eps, name=norm)(y, apply=False),
+                            self.qk_norm_eps)
+                if a_head:
                     with jax.named_scope("qk_norm"):
                         y = RMSNorm(self.qk_norm_eps, name=norm)(y)
                 if norm and self.rotary_freqs is not None:

@@ -70,32 +70,60 @@ def _reference_attention(q, k, v, causal: bool,
     return out.astype(q.dtype)
 
 
-def _per_device(fn, mesh: Mesh, n_heads: int):
-    """``fn(q, k, v)`` shard_mapped so that each device runs it on its
-    own batch rows (and its own heads on a tensor axis). Attention is
-    independent across batch and heads, so this needs no collective — but
+def _per_device(fn, mesh: Mesh, rows, whole):
+    """``fn(*rows, *whole)`` shard_mapped so that each device runs it on
+    its own batch rows (and its own heads on a tensor axis) of every ``(B,
+    L, H, D)`` array of ``rows``, each array of ``whole`` whole on every
+    device. Attention, and what is made of a head before it, is
+    independent across batch and heads, so this needs no collective, but
     a Pallas kernel is an opaque custom call the SPMD partitioner cannot
     split: bare inside a multi-device jit it is refused, or all-gathered
     to run every (batch, head) on every chip."""
-    spec = _qkv_spec(mesh, None, n_heads)
-    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)
+    specs = tuple(_qkv_spec(mesh, None, r.shape[2]) for r in rows)
+    everywhere = P()  # lint: allow-spec (shard_map spec)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=specs + (everywhere,) * len(whole),
+        out_specs=specs[0], check_vma=False)
 
 
-def _on_own_rows(kernel, q, k, v):
-    """``kernel(q, k, v)`` per device (``_per_device``) over the mesh of
-    the enclosing ``with mesh:`` block, or None when the batch does not
-    split over that mesh. Outside a mesh, on one device, and inside a
-    ``shard_map`` body (the operands are one device's already) it is the
-    bare call."""
+def _mesh_to_map() -> Optional[Mesh]:
+    """The mesh of the enclosing ``with mesh:`` block where a kernel has
+    to be shard_mapped over it; None outside a mesh, on one device, and
+    inside a ``shard_map`` body (the operands are one device's already)."""
     mesh = ambient_mesh()
     if mesh is None or mesh.size == 1 \
             or jax.sharding.get_abstract_mesh().manual_axes:
-        return kernel(q, k, v)
-    if q.shape[0] % math.prod(
-            mesh.shape[a] for a in active_batch_axes(mesh) or ()):
         return None
-    return _per_device(kernel, mesh, q.shape[2])(q, k, v)
+    return mesh
+
+
+def own_shape(shape) -> Optional[Tuple[int, ...]]:
+    """The part of ``(B, L, H, D)`` rows a device holds where
+    ``on_own_rows`` runs a kernel on them (all of it, for a bare call);
+    None when the batch does not split over the mesh's batch axes."""
+    mesh = _mesh_to_map()
+    if mesh is None:
+        return tuple(shape)
+    batch = math.prod(
+        mesh.shape[a] for a in active_batch_axes(mesh) or ())
+    if shape[0] % batch:
+        return None
+    heads = mesh.shape["tensor"] if _qkv_spec(mesh, None, shape[2])[2] \
+        else 1
+    return (shape[0] // batch, shape[1], shape[2] // heads, shape[3])
+
+
+def on_own_rows(kernel, *rows, whole=()):
+    """``kernel(*rows, *whole)`` per device (``_per_device``) over the
+    mesh of the enclosing ``with mesh:`` block, or None when the batch
+    does not split over that mesh (``own_shape``); where no mesh is to be
+    mapped over (``_mesh_to_map``), the bare call."""
+    mesh = _mesh_to_map()
+    if mesh is None:
+        return kernel(*rows, *whole)
+    if own_shape(rows[0].shape) is None:
+        return None
+    return _per_device(kernel, mesh, rows, whole)(*rows, *whole)
 
 
 def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -114,7 +142,7 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     and one backward; in both the L x L scores never touch HBM. Whatever
     neither takes, and every shape on the CPU, runs the jnp reference.
     Under a multi-device ``with mesh:`` the kernel runs shard_mapped on
-    each device's own batch rows (``_on_own_rows``).
+    each device's own batch rows (``on_own_rows``).
 
     ``use_flash``: "auto" | "never" (reference path, used by the parity
     tests themselves) | "require" (a fused kernel or a ValueError, on the
@@ -168,7 +196,7 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         elif window is None and pallas_attention.supports_short(
                 q.shape, q.dtype.itemsize):
             name, kernel = "short", pallas_attention.short_attention
-        out = None if kernel is None else _on_own_rows(
+        out = None if kernel is None else on_own_rows(
             lambda q, k, v: kernel(q, k, v, causal), q, k, v)
         if out is not None:
             obsmetrics.counter(f"attention.fused_calls.{name}").inc()
@@ -299,7 +327,7 @@ def sharded_full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     mesh:`` block, for callers that hold the mesh and no such block."""
     return _per_device(
         partial(full_attention, causal=causal, use_flash=use_flash),
-        mesh, q.shape[2])(q, k, v)
+        mesh, (q, k, v), ())(q, k, v)
 
 
 def make_attention_fn(mesh: Optional[Mesh], impl: str = "auto",

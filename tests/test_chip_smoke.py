@@ -36,7 +36,8 @@ TOY = chip_smoke.Sizes(
     flash_bf16=(1, 512, 2, 16), flash_bf16_d256=(2, 512, 2, 32),
     flash_fp32=(1, 512, 1, 16),
     short_bf16=(2, 37, 3, 16), gated_delta=(2, 40, 3, 16),
-    gated_delta_chunk=16, gated_delta_wide=(1, 40, 3, 8, 16))
+    gated_delta_chunk=16, gated_delta_wide=(1, 40, 3, 8, 16),
+    head_norm_turn=(((2, 48, 2, 128), 64, True), ((1, 48, 2, 128), 16, False)))
 
 
 @pytest.fixture(autouse=True)
@@ -108,7 +109,8 @@ def test_every_leg_runs_at_toy_size_on_the_cpu(rehearsal):
     assert {"flash_fwd_bf16", "flash_fwd_fp32", "flash_bwd_bf16",
             "flash_bwd_bf16_d256", "gated_delta_bf16",
             "gated_delta_bf16_key_heads", "gated_delta_bf16_wide",
-            "short_fwd_bwd_bf16",
+            "short_fwd_bwd_bf16", "head_norm_turn_d128_n64_normed",
+            "head_norm_turn_d128_n16",
             "flash_fwd_bf16_sharded_x8"} <= set(k["kernels"])
     # head width 16, chunk 16: not the Pallas calls' shape
     assert k["kernels"]["gated_delta_bf16"]["path"] == "xla"

@@ -15,7 +15,11 @@ bit. PR 48 read the routed layers' weights and group sizes by comparison
 ``top_k``, no ``bincount``) and remade the JSON: the five routed families'
 hashes moved, granite's and olmo's did not, and every key of the ``.npz``
 is the parent's bit for bit, the router's and the gate bank's gradients
-among them. PR 46 put two names on
+among them. PR 50 gave ``GroupedAttention``'s q and k heads of 128
+channels one Pallas pass for norm and turn
+(``ops/pallas_head_norm_turn``) and moved neither file: the presets' heads
+have 8 and 16 channels, ``supports`` declines them, and what they lower is
+the parent's text, hash for hash and bit for bit. PR 46 put two names on
 ``_remat_block``'s one list (``MAMBA2_IN``, ``ATTN_QKV``) and remade the
 JSON: its diff shows granite's, lfm2's and laguna's hashes moved (their
 blocks keep rows they made again before), GLM's and qwen's not (no value

@@ -511,3 +511,56 @@ def test_mosaic_compiles_the_state_space_rules_chunk_calls(topo, as_on_chip):
     # neither the masked scores nor a float32 head-major copy of x in HBM
     assert f"[{H},{L // 256},256,256]" not in text
     assert f"f32[{B},{L // 256},256,{H},{P_}]" not in text
+
+
+@pytest.mark.parametrize("shape,n,normed", [
+    ((4, 8192, 32, 128), 64, True),     # sdar-30b-a3b-train-ep8share-4k, q
+    ((4, 8192, 4, 128), 64, True),      # and k
+    ((2, 8192, 48, 128), 64, False),    # laguna-xs.2-train-ep8share-8k,
+    ((2, 8192, 64, 128), 32, False),    # sliding q and full q (half a head)
+    ((2, 8192, 8, 128), 32, False),     # and k
+], ids=["sdar-q", "sdar-k", "laguna-sliding-q", "laguna-full-q", "laguna-k"])
+def test_mosaic_compiles_a_heads_norm_and_turn_under_names_of_their_own(
+        topo, as_on_chip, shape, n, normed):
+    """``ops/pallas_head_norm_turn.py`` forward and backward at the cells'
+    shapes through Mosaic for a v5e: two calls, found by
+    ``attn.norm_turn_ms`` and by no other reader's pattern, and no float32
+    array of the rows' size outside them (the norm's and the turn's
+    temporaries are what the calls take out of the program)."""
+    from jax.sharding import SingleDeviceSharding
+    from mmlspark_tpu.ops import pallas_head_norm_turn as hnt
+    assert hnt.supports(shape, n)
+    one = SingleDeviceSharding(topo.devices[0])
+    B, L, H, d = shape
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    eps = 1e-6 if normed else None
+
+    def grads(y, cos, sin, scale, ct):
+        out, pull = jax.vjp(lambda y, scale: hnt.head_norm_turn(
+            y, cos, sin, n, scale, eps), y, scale)
+        return out, pull(ct)
+
+    text = jax.jit(grads).lower(
+        s(shape, jnp.bfloat16), s((L, d)), s((L, d)),
+        s((d,)) if normed else None, s(shape, jnp.bfloat16)
+    ).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    names = sorted(re.match(r"(?:ROOT )?%([a-z_]+)", c).group(1)
+                   for c in calls)
+    assert names == ["head_norm_turn_bwd", "head_norm_turn_fwd"]
+    mine = _benchmark_pattern("attn.norm_turn_ms")
+    assert all(mine.search(c) for c in calls)
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics")
+    others = [m[:-5] for m in sorted(os.listdir(metrics))
+              if m != "attn.norm_turn_ms.json" and "pattern" in open(
+                  os.path.join(metrics, m)).read()]
+    assert len(others) > 10
+    for metric in others:
+        assert not any(_benchmark_pattern(metric).search(c) for c in calls), \
+            metric
+    assert f"f32[{B},{L},{H},{d}]" not in text
+    assert f"f32[{B},{L},{H * d}]" not in text
