@@ -1,5 +1,5 @@
 """Decoder LMs: ONE skeleton (``Decoder``: embed, blocks, final norm,
-head) over the parts of ``models/zoo/parts.py``, and the seven families as
+head) over the parts of ``models/zoo/parts.py``, and the eight families as
 registry entries that say which part sits at which layer:
 ``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's),
 ``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
@@ -20,7 +20,12 @@ and a sigmoid gate a head, a dense part then small sigmoid-routed experts
 beside a shared one by a published list) and ``sdar_moe``
 (SDAR-30B-A3B-Chat: equal layers of rotary grouped attention with a norm a
 head under softmax-routed experts with no shared one, called on rows of
-``[noised copy | clean copy]`` under the block-diffusion mask).
+``[noised copy | clean copy]`` under the block-diffusion mask) and
+``kimi_linear`` (Kimi-Linear-48B-A3B: Kimi Delta Attention, the delta rule
+with a decay a key channel, three to one with latent attention that has
+no query rank, no positions and keys wider than its values, by two
+published lists; a dense part, then sigmoid-routed experts beside a shared
+one).
 
 What a family IS lives in its entry, beside the name of the published
 ``config.json`` it reads: the mixer and the feed-forward part of layer
@@ -54,9 +59,9 @@ from mmlspark_tpu.models.zoo import register_model
 from mmlspark_tpu.models.zoo.moe import DroplessMoe
 from mmlspark_tpu.models.zoo.parts import (
     _INIT, ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SHORT_CONV_IN,
-    GatedAttention, GatedDeltaNet, GroupedAttention, Head, Mamba2Mixer,
-    MlaAttention, RMSNorm, ShortConv, SwiGluMlp, _dense, plain_frequencies,
-    yarn_frequencies)
+    GatedAttention, GatedDeltaNet, GroupedAttention, Head,
+    KimiDeltaAttention, Mamba2Mixer, MlaAttention, RMSNorm, ShortConv,
+    SwiGluMlp, _dense, plain_frequencies, yarn_frequencies)
 
 # a part's factory: the flax name (None inside a block, whose ``setup``
 # names its parts by attribute) -> the module
@@ -834,3 +839,109 @@ def sdar_moe_tiny(**overrides):
     layers of eight experts, two a token, rows of two copies of 32
     positions in blocks of 4."""
     return sdar_moe(**{**_SDAR_TINY, **overrides})
+
+
+KIMI_LINEAR_FULL_LAYERS = (4, 8, 12, 16, 20, 24, 27)
+KIMI_LINEAR_KDA_LAYERS = tuple(
+    l for l in range(1, 28) if l not in KIMI_LINEAR_FULL_LAYERS)
+
+
+@register_model("kimi_linear")
+def kimi_linear(vocab: int = 163840, dim: int = 2304,
+                kda_layers=KIMI_LINEAR_KDA_LAYERS,
+                full_attn_layers=KIMI_LINEAR_FULL_LAYERS, heads: int = 32,
+                kv_rank: int = 512, nope: int = 128, rope: int = 64,
+                v_dim: int = 128, linear_heads: int = 32,
+                linear_head_dim: int = 128, conv_width: int = 4,
+                mlp_hidden: int = 9216, expert_hidden: int = 1024,
+                shared_hidden: int = 1024, num_experts: int = 256,
+                top_k: int = 8, experts_held=None, scaling: float = 2.446,
+                dense_layers: int = 1, gate_grad: bool = True,
+                eps: float = 1e-5, chunk: int = 64, max_len: int = 16384,
+                dtype=jnp.bfloat16, attention_fn=None):
+    """Kimi-Linear-48B-A3B-Instruct as published (huggingface.co/moonshotai/
+    Kimi-Linear-48B-A3B-Instruct ``config.json``, ``model_type:
+    kimi_linear``; "Kimi Linear", arXiv:2510.26692): twenty-seven layers
+    numbered from 1 in two published lists, a ``KimiDeltaAttention`` (the
+    delta rule with a decay a key channel, 32 heads of 128 x 128) at
+    ``kda_layers`` and at ``full_attn_layers`` a ``MlaAttention`` with no
+    query rank, no rotary turn (``mla_use_nope``: the KDA layers carry the
+    order) and keys of ``nope + rope`` = 192 over values of 128: three to
+    one. The feed-forward part is a dense ``SwiGluMlp`` in the first
+    ``dense_layers`` layers and from there on a ``DroplessMoe`` routed by
+    sigmoid scores over all ``num_experts`` (DeepSeek-V3's rule, as
+    ``glm4_moe_lite`` and ``laguna``: the choice on score plus bias, the
+    weights over their sum times ``scaling``, on the experts' OUTPUT)
+    beside one ungated shared expert of ``shared_hidden``. Plain RMS
+    norms, untied tables, no multi-token-prediction module
+    (``num_nextn_predict_layers`` 0). ``experts_held`` = ``(count, first)``
+    as for ``glm4_moe_lite``; ``gate_grad=False`` for a share trained
+    without its exchange (``DroplessMoe``).
+
+    Each block is recomputed in halves and keeps of ``_remat_block``'s
+    names the flash kernel's residuals alone; let go (``let_go``, as
+    ``olmo_hybrid`` does): the KDA chunk calls' tiles, the KDA layers'
+    three projections and the SwiGLU products. At the benchmark's cut,
+    602M parameters (7.23 GB of weights and moments) under ONE row of
+    16,384 tokens, the step's
+    high-water mark is the backward pass of the FIRST routed layer, whose
+    full-size rung holds 131,072 slots of 2,304 in float32 three times
+    over (5.3 GB) beside every later layer's gradient, and what the blocks
+    before it keep lies under it. Compiled for a described v5e (16.91 GB):
+    23.46 GB with every name kept (refused), 17.11 without the tiles
+    (refused), 16.94 without the SwiGLU products too (refused), 16.27
+    without the projections as well, 16.14 with no name at all (PERF.md
+    section 6, PR 51)."""
+    from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
+    held = None if experts_held is None else tuple(experts_held)
+    kda, full = set(kda_layers), set(full_attn_layers)
+    depth = len(kda) + len(full)
+    if kda & full or kda | full != set(range(1, depth + 1)):
+        raise ValueError(
+            f"kda_layers {tuple(kda_layers)!r} and full_attn_layers "
+            f"{tuple(full_attn_layers)!r}: each of the layers 1 to "
+            f"{depth} in one of them")
+
+    def attention(n):
+        return MlaAttention(dim, heads, None, kv_rank, nope, rope, v_dim,
+                            eps=eps, dtype=dtype, attention_fn=attention_fn,
+                            turn=False, name=n)
+
+    def linear(n):
+        return KimiDeltaAttention(dim, linear_heads, linear_head_dim,
+                                  conv_width, eps, chunk, dtype, name=n)
+
+    def dense(n):
+        return SwiGluMlp(dim, mlp_hidden, dtype, name=n)
+
+    def routed(n):
+        return DroplessMoe(
+            dim, num_experts, expert_hidden, top_k, experts_held=held,
+            scaling=scaling, shared=lambda m: SwiGluMlp(
+                dim, shared_hidden, dtype, name=m), dtype=dtype,
+            gate_grad=gate_grad, name=n)
+
+    return _spec(Decoder(
+        vocab, dim, _by_kind(
+            ("kda" if l in kda else "mla" for l in range(1, depth + 1)),
+            {"kda": linear, "mla": attention}),
+        tuple(dense if l < dense_layers else routed for l in range(depth)),
+        _rms(eps), split=True,
+        let_go=(DELTA_CHUNK_TILES, DELTA_NET_QKVZ, MLP_GATE_UP),
+        dtype=dtype), max_len)
+
+
+_KIMI_TINY = dict(vocab=96, dim=32, kda_layers=(1, 2, 3, 5),
+                  full_attn_layers=(4,), heads=2, kv_rank=16, nope=8, rope=4,
+                  v_dim=8, linear_heads=2, linear_head_dim=8, mlp_hidden=48,
+                  expert_hidden=8, shared_hidden=8, num_experts=8, top_k=2,
+                  chunk=8, max_len=64, dtype=jnp.float32)
+
+
+@register_model("kimi_linear_tiny")
+def kimi_linear_tiny(**overrides):
+    """Test-scale ``kimi_linear`` (float32, so CPU parity is tight): the
+    benchmark's cut at toy widths, a leading dense layer under a KDA mixer,
+    then KDA, KDA, latent, KDA under routed layers of eight experts, two a
+    token; keys of 12 over values of 8, chunks of 8 tokens."""
+    return kimi_linear(**{**_KIMI_TINY, **overrides})

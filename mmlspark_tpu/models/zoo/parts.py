@@ -15,8 +15,9 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
   normed in one pass by ``ops/pallas_head_norm_turn`` from the same
   ``rotary_tables``);
 - ``MlaAttention``: multi-head latent attention in its expanded (training)
-  form: a low-rank query, one compressed key/value row per token, a rotary
-  slice on every query head and ONE rotary key shared by all heads;
+  form: a low-rank query (or, without a rank, a plain one), one compressed
+  key/value row per token, a rotary slice on every query head and ONE
+  rotary key shared by all heads (or, without the turn, no positions);
 - ``GatedAttention``: softmax attention over grouped key/value heads (each
   repeated to the query heads it serves at the attention call, so the
   flash kernel and its backward run as they are), norms on q and k, a
@@ -33,6 +34,10 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
   (``ops/linear_attention.py``): a short causal convolution, the gated
   delta rule with ``beta`` in (0, ``beta_scale``), a gated norm on the
   output;
+- ``KimiDeltaAttention``: the same rule with a decay a key CHANNEL (Kimi
+  Delta Attention): q, k and v each by its own projection and its own
+  short convolution, a low-rank decay gate with a bias a channel, a
+  low-rank sigmoid gate on the normed output;
 - ``Mamba2Mixer``: the state-space layer (the same module's ``ssd``): a
   convolution with a bias over ``[x | B | C]``, a scalar decay a head,
   ``B`` and ``C`` shared by groups of heads, a skip, the gate BEFORE the
@@ -214,12 +219,17 @@ def _dense(features: int, dtype, name: str) -> nn.Dense:
 
 class MlaAttention(nn.Module):
     """Multi-head latent attention, expanded form. Query/key heads are
-    ``nope + rope`` wide and value heads ``v_dim``; the attention call is
-    the framework's ``(q, k, v, causal)`` on ``(B, L, H, D)``, so the two
-    have to be equally wide (they are, 256, in the published model)."""
+    ``nope + rope`` wide and value heads ``v_dim``, which may be narrower
+    (the attention call is the framework's ``(q, k, v, causal)`` on ``(B,
+    L, H, D)`` with v's own last axis; the flash kernels take the two
+    widths as they are). ``q_rank`` None: no low-rank query and no query
+    norm, ``q = x W_q`` (``attn_query``). ``turn`` False: no rotary turn on
+    the ``rope`` channels of q or of the one shared key, which then carry
+    no positions (``mla_use_nope``). GLM-4.7-Flash has a rank, the turn and
+    256 / 256; Kimi-Linear none, none and 192 / 128."""
     dim: int
     heads: int
-    q_rank: int
+    q_rank: Optional[int]
     kv_rank: int
     nope: int
     rope: int
@@ -228,34 +238,37 @@ class MlaAttention(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     attention_fn: Optional[Callable] = None
+    turn: bool = True
 
     @nn.compact
     def __call__(self, x):
-        if self.nope + self.rope != self.v_dim:
-            raise ValueError(
-                f"query/key heads are {self.nope + self.rope} wide and "
-                f"value heads {self.v_dim}: attention_fn(q, k, v) takes "
-                "one head width")
         B, L, _ = x.shape
         H, dt = self.heads, self.dtype
         attn_fn = self.attention_fn or full_attention
         with jax.named_scope("mla_attention"):
             x = x.astype(dt)
-            cq = RMSNorm(self.eps, name="query_norm")(
-                _dense(self.q_rank, dt, "attn_query_a")(x)).astype(dt)
-            q = _dense(H * (self.nope + self.rope), dt, "attn_query_b")(
-                cq).reshape(B, L, H, self.nope + self.rope)
+            if self.q_rank is None:
+                q = _dense(H * (self.nope + self.rope), dt, "attn_query")(x)
+            else:
+                cq = RMSNorm(self.eps, name="query_norm")(
+                    _dense(self.q_rank, dt, "attn_query_a")(x)).astype(dt)
+                q = _dense(H * (self.nope + self.rope), dt,
+                           "attn_query_b")(cq)
+            q = q.reshape(B, L, H, self.nope + self.rope)
             kva = _dense(self.kv_rank + self.rope, dt, "attn_key_value_a")(x)
             ckv = RMSNorm(self.eps, name="key_value_norm")(
                 kva[..., :self.kv_rank]).astype(dt)
             kv = _dense(H * (self.nope + self.v_dim), dt,
                         "attn_key_value_b")(ckv).reshape(
                             B, L, H, self.nope + self.v_dim)
-            freqs = plain_frequencies(self.rope, self.theta)
-            q_r = rotary(q[..., self.nope:], freqs)
-            # the one rotary key, shared by every head
-            k_r = rotary(kva[..., None, self.kv_rank:], freqs)
-            q = jnp.concatenate([q[..., :self.nope], q_r], -1)
+            if self.turn:
+                freqs = plain_frequencies(self.rope, self.theta)
+                q_r = rotary(q[..., self.nope:], freqs)
+                # the one rotary key, shared by every head
+                k_r = rotary(kva[..., None, self.kv_rank:], freqs)
+                q = jnp.concatenate([q[..., :self.nope], q_r], -1)
+            else:
+                k_r = kva[..., None, self.kv_rank:]
             k = jnp.concatenate(
                 [kv[..., :self.nope],
                  jnp.broadcast_to(k_r, (B, L, H, self.rope))], -1)
@@ -379,6 +392,74 @@ class GatedDeltaNet(nn.Module):
                 * nn.silu(z.astype(f32))
             return _dense(self.dim, dt, "attn_out")(
                 o.astype(dt).reshape(B, L, Hv * dv))
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention (KDA; "Kimi Linear", arXiv:2510.26692): the
+    gated delta rule with a decay a key CHANNEL, ``heads`` heads of
+    ``head_dim`` for keys and values alike. ``q = silu(conv_q(x W_q))``
+    and so ``k`` and ``v``: three projections (``attn_query``,
+    ``attn_key``, ``attn_value``), each through a causal depthwise
+    convolution of its own (``conv_query``, ...); q and k L2-normalised
+    over the head; the decay, float32, ``g = -exp(A_log_h) softplus((x
+    W_fa) W_fb + dt_bias)`` through a rank of ``head_dim``
+    (``attn_decay_a`` / ``_b``), ``dt_bias`` a channel and ``A_log`` a
+    head: (B, L, H, head_dim), every entry <= 0; ``beta = sigmoid(x W_b)``
+    a head; the rule (``ops/linear_attention.gated_delta_rule``, which
+    reads the decay's kind from ``g``'s shape); ``o <- rmsnorm(o) w_n
+    sigmoid((x W_ga) W_gb)`` over each head (``attn_gate_a`` / ``_b``, the
+    same rank; ``w_n`` shared by the heads); ``y = o W_o``. No biases but
+    ``dt_bias``. Scope ``kimi_delta_attention``; inside it ``kda_conv`` and
+    ``kda_decay``, and the rule's walk under ``kda_state_walk``. The three
+    projections' outputs carry ``DELTA_NET_QKVZ`` and the chunk calls'
+    tiles ``DELTA_CHUNK_TILES``, as ``GatedDeltaNet``'s do."""
+    dim: int
+    heads: int
+    head_dim: int
+    conv_width: int = 4
+    eps: float = 1e-5
+    chunk: int = 64
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        from mmlspark_tpu.ops import linear_attention as la
+        B, L, _ = x.shape
+        H, d = self.heads, self.head_dim
+        dt, f32 = self.dtype, jnp.float32
+        with jax.named_scope("kimi_delta_attention"):
+            x = x.astype(dt)
+
+            def mixed(name):
+                y = checkpoint_name(
+                    _dense(H * d, dt, f"attn_{name}")(x), DELTA_NET_QKVZ)
+                taps = self.param(f"conv_{name}", _INIT,
+                                  (self.conv_width, H * d), f32)
+                with jax.named_scope("kda_conv"):
+                    return nn.silu(la.causal_conv1d(y, taps)).reshape(
+                        B, L, H, d)
+
+            def low_rank(name):
+                return _dense(H * d, dt, f"attn_{name}_b")(
+                    _dense(d, dt, f"attn_{name}_a")(x)).astype(f32).reshape(
+                        B, L, H, d)
+            q, k, v = mixed("query"), mixed("key"), mixed("value")
+            a_log = self.param(
+                "A_log", lambda key, shape: jnp.log(jax.random.uniform(
+                    key, shape, f32, 1e-3, 16.0)), (H,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (H * d,),
+                                 f32)
+            with jax.named_scope("kda_decay"):
+                g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                    low_rank("decay") + dt_bias.reshape(H, d))
+            beta = jax.nn.sigmoid(_dense(H, dt, "attn_beta")(x).astype(f32))
+            o = la.gated_delta_rule(
+                la.l2_normalize(q), la.l2_normalize(k), v, g, beta,
+                chunk=self.chunk, dtype=dt)
+            o = RMSNorm(self.eps, name="gate_norm")(o) \
+                * jax.nn.sigmoid(low_rank("gate"))
+            return _dense(self.dim, dt, "attn_out")(
+                o.astype(dt).reshape(B, L, H * d))
 
 
 class GroupedAttention(nn.Module):

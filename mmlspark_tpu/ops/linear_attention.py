@@ -1,5 +1,6 @@
 """Linear attention with a recurrent state: two rules over one state a
-head, and the short causal convolution in front of them.
+head (the first with a decay a head or a key channel), and the short causal
+convolution in front of them.
 
 **The gated delta rule** (Gated DeltaNet, Yang et al. 2024,
 arXiv:2412.06464). Per head, with a state ``S`` of ``(dk, dv)`` that is
@@ -48,6 +49,28 @@ mathematics:
   q and k repeated to the value heads first: the form every test holds
   the calls to, and what other shapes run. The walk is the same scan.
 
+**The same rule with a decay a key CHANNEL** (Kimi Delta Attention, "Kimi
+Linear", arXiv:2510.26692): ``g_t`` is a vector over the ``dk`` key channels
+and the first line reads ``S <- Diag(exp(g_t)) S``, row ``c`` of the state
+times ``exp(g_t[c])``. ``gated_delta_rule`` takes it by ``g``'s shape, ``(B,
+L, H, dk)`` for ``(B, L, H)`` (``_kda``; as many key as value heads), in
+the same three forms. Chunked, ``G`` is a vector a token and every ``exp(G)``
+above a scaling of the key channels (``W = T (beta (exp(G) * K))``, ``O =
+(exp(G) * Q) S_0 + P U``, ``S_C = Diag(exp(G_C)) S_0 + (exp(G_C - G) * K)^T
+U``: the walk is ``_delta_walk`` with ``last`` a vector, under the scope
+``kda_state_walk``), but the decay of ``K K^T`` and ``Q K^T`` lies INSIDE
+their contraction, ``A_ij = beta_i sum_c k_i[c] k_j[c] exp(G_i[c] -
+G_j[c])``: no matrix ``D`` multiplies the product afterwards. The causal
+triangle of a chunk is therefore split by the highest bit in which ``i``
+and ``j`` differ (``_kda_products``): at level ``s`` row ``i`` lies in the
+upper and ``j`` in the lower half of one block of ``2 s`` rows, and with
+``R`` = ``G`` at the upper half's first row ``exp(G_i - G_j) = exp(G_i - R)
+exp(R - G_j)``, both exponents <= 0, so the level is ONE product of two
+scaled operands under its mask: six products for a chunk of 64 where the
+scalar rule has one. The executors: the Pallas calls of
+``ops/pallas_kda.py`` at chunks of 64 and heads of 128 x 128
+(``pallas_kda.supports``; XLA makes the running sum), else ``_chunked_kda``.
+
 **The state-space rule of Mamba-2** (SSD; Dao and Gu 2024,
 arXiv:2405.21060). The same skeleton with the correction taken out (``T =
 I``, ``W = 0``, ``U = V`` above), a scalar decay a head, and keys and
@@ -85,8 +108,9 @@ The other products take ``dtype`` operands (bfloat16 on the chip) and
 accumulate in float32; state, decay and sums are float32.
 
 Counters, per TRACE: ``linear_attention.calls.<chunked|recurrent>``,
-``linear_attention.rule_calls.<delta|ssd>``, the executor of the batched
-half ``linear_attention.chunk_calls.<pallas|xla>`` (delta rule) and
+``linear_attention.rule_calls.<delta|kda|ssd>``, the executor of the batched
+half ``linear_attention.chunk_calls.<pallas|xla>`` (delta rule),
+``linear_attention.kda_chunk_calls.<pallas|xla>`` (a decay a key channel) and
 ``linear_attention.ssd_chunk_calls.<pallas|xla>`` (state-space rule), and
 ``linear_attention.fallbacks`` (token-by-token on a chip under "auto").
 """
@@ -175,7 +199,8 @@ def _recurrent(q, k, v, g, beta, block: int):
 
     def step(S, x):
         q_t, k_t, v_t, g_t, b_t = x
-        S = jnp.exp(g_t)[..., None, None] * S
+        # a decay a head, or (B, H, dk) a key channel: row c of S times it
+        S = jnp.exp(g_t)[(...,) + (None,) * (S.ndim - g_t.ndim)] * S
         u = b_t[..., None] * (v_t - jnp.einsum(
             "bhkv,bhk->bhv", S, k_t, precision=_HIGHEST))
         S = S + k_t[..., :, None] * u[..., None, :]
@@ -206,19 +231,20 @@ def _chunks(x, N: int):
     return jnp.moveaxis(x, 3, 1)
 
 
-def _delta_walk(W, U0, Kd, last, dtype):
+def _delta_walk(W, U0, Kd, last, dtype, scope: str = "gated_delta_rule"):
     """The walk of the state from chunk to chunk, ONE ``lax.scan``: ``W``,
-    ``U0``, ``Kd`` (N, B, H, C, width) and ``last`` = exp(G_C) (N, B, H);
-    the state each chunk starts from, (N, B, H, dk, dv), and the chunks'
-    corrections ``U`` (N, B, H, C, dv), float32."""
+    ``U0``, ``Kd`` (N, B, H, C, width) and ``last`` = exp(G_C) (N, B, H), or
+    (N, B, H, dk) where the decay is a key channel's (row c of the state
+    times ``last[c]``); the state each chunk starts from, (N, B, H, dk,
+    dv), and the chunks' corrections ``U`` (N, B, H, C, dv), float32."""
     def walk(S, x):
         W_c, U0_c, Kd_c, a_c = x
         U_c = U0_c - _mm("bhid,bhde->bhie", W_c, S, dtype)
-        nxt = a_c[..., None, None] * S \
+        nxt = a_c[(...,) + (None,) * (S.ndim - a_c.ndim)] * S \
             + _mm("bhid,bhie->bhde", Kd_c, U_c, dtype)
         return nxt, (S, U_c)
 
-    with jax.named_scope("gated_delta_rule"):
+    with jax.named_scope(scope):
         _, (S0, U) = jax.lax.scan(
             walk, jnp.zeros(W.shape[1:3] + (W.shape[-1], U0.shape[-1]),
                             jnp.float32), (W, U0, Kd, last))
@@ -286,6 +312,122 @@ def _chunked_kernel(q, k, v, g, beta, dtype):
     return o.reshape(B, Lp, Hv, dv)[:, :L]
 
 
+# ------------------------------------------------- a decay a key channel
+KDA_WALK_SCOPE = "kda_state_walk"
+
+
+def _kda_levels(chunk: int):
+    if chunk & (chunk - 1):
+        raise ValueError(f"chunk {chunk}: a decay a key channel splits the "
+                         "chunk in halves, a power of two")
+    return tuple(1 << b for b in range(chunk.bit_length() - 1))
+
+
+def _kda_products(q, k, G, chunk: int, dtype):
+    """``sum_c x_i[c] k_j[c] exp(G_i[c] - G_j[c])`` for x = k (``j < i``)
+    and x = q (``j <= i``) over chunks (..., C, dk): the decay lies inside
+    the contraction, so the causal triangle is split by the highest bit in
+    which ``i`` and ``j`` differ. At level ``s`` row ``i`` is in the upper
+    half of a block of ``2 s`` rows and ``j`` in its lower half; with ``R``
+    = ``G`` at the upper half's first row ``exp(G_i - G_j) = exp(G_i - R)
+    exp(R - G_j)``, both exponents <= 0: one product of two scaled
+    operands a level, under the level's mask."""
+    C = chunk
+    rows = jnp.arange(C)
+    differ = rows[:, None] ^ rows[None, :]
+    kk = jnp.zeros(G.shape[:-1] + (C,), jnp.float32)
+    qk = jnp.where(rows[:, None] == rows[None, :], _mm(
+        "...id,...jd->...ij", q, k, dtype), 0.0)
+    for s in _kda_levels(C):
+        # G at the first row of each block of s rows, and of the next block
+        first = G.reshape(G.shape[:-2] + (C // s, s, -1))[..., :1, :]
+        own = jnp.broadcast_to(first, G.shape[:-2] + (C // s, s, G.shape[-1]))
+        nxt = jnp.broadcast_to(jnp.roll(first, -1, axis=-3), own.shape)
+        lhs = jnp.exp(G - own.reshape(G.shape))
+        # the chunk's last block has no next one, and no column under a mask
+        rhs = jnp.exp(jnp.where((rows < C - s)[:, None],
+                                nxt.reshape(G.shape) - G, 0.0))
+        mask = ((differ >> (s.bit_length() - 1)) == 1) \
+            & (rows[:, None] > rows[None, :])
+        kr = k * rhs
+        kk += jnp.where(mask, _mm("...id,...jd->...ij", k * lhs, kr, dtype),
+                        0.0)
+        qk += jnp.where(mask, _mm("...id,...jd->...ij", q * lhs, kr, dtype),
+                        0.0)
+    return kk, qk
+
+
+def _chunked_kda(q, k, v, g, beta, chunk: int, dtype):
+    """``_chunked`` with ``g`` (B, L, H, dk): every ``exp(G)`` is a vector
+    over the key channels, ``K K^T`` and ``Q K^T`` hold the decay inside
+    (``_kda_products``) and the walk's ``last`` is a vector."""
+    f32 = jnp.float32
+    B, L, H, dk = q.shape
+    dv = v.shape[-1]
+    # padding: g = 0, beta = 0, k = 0
+    (q, k, v, g, beta), N = _whole_chunks((q, k, v, g, beta), chunk)
+    C = chunk
+    q, k, v, g = (_chunks(x.astype(f32), N) for x in (q, k, v, g))
+    beta = _chunks(beta.astype(f32), N)[..., None]
+
+    G = jnp.cumsum(g, axis=-2)                          # (B, H, N, C, dk)
+    kk, P = _kda_products(q, k, G, C, dtype)
+    T = inv_unit_lower(beta * kk)
+    eG = jnp.exp(G)
+    W = _mm("bhnij,bhnjd->bhnid", T, k * (beta * eG), dtype)
+    U0 = _mm("bhnij,bhnjd->bhnid", T, v * beta, dtype)
+    Kd = k * jnp.exp(G[..., -1:, :] - G)
+    last = jnp.exp(G[..., -1, :])                       # (B, H, N, dk)
+
+    S0, U = (jnp.moveaxis(x, 0, 2) for x in _delta_walk(
+        *(jnp.moveaxis(x, 2, 0) for x in (W, U0, Kd, last)), dtype,
+        KDA_WALK_SCOPE))
+    o = _mm("bhnid,bhnde->bhnie", q * eG, S0, dtype) \
+        + _mm("bhnij,bhnje->bhnie", P, U, dtype)
+    o = jnp.moveaxis(o, 1, 3).reshape(B, N * C, H, dv)
+    return o[:, :L]
+
+
+def _chunked_kda_kernel(q, k, v, g, beta, dtype):
+    """``_chunked_kda`` with the chunk-local half in the calls of
+    ``ops/pallas_kda.py``; XLA makes the running sum of the decay inside
+    each chunk (one cumulative sum over the rows) and the walk."""
+    from mmlspark_tpu.ops import pallas_kda
+    B, L, H, dk = q.shape
+    dv = v.shape[-1]
+    (q, k, v, g, beta), _ = _whole_chunks(
+        (q, k, v, g, beta), pdr.padded_length(L))
+    Lp = q.shape[1]
+    G = jnp.cumsum(g.astype(jnp.float32).reshape(B, -1, pdr.CHUNK, H, dk),
+                   axis=2)
+    beta = jnp.moveaxis(beta.astype(jnp.float32), 2, 1).reshape(
+        B, H, -1, pdr.PAIR)
+    W, U0, Kd, qe, P = pallas_kda.kda_chunk(
+        q.reshape(B, Lp, -1), k.astype(jnp.float32).reshape(B, Lp, -1),
+        v.reshape(B, Lp, -1), G.reshape(B, Lp, -1), beta, dtype)
+    last = jnp.exp(jnp.moveaxis(G[:, :, -1], 1, 0))     # (N, B, H, dk)
+    S0, U = _delta_walk(W, U0, Kd, last, dtype, KDA_WALK_SCOPE)
+    o = pallas_kda.kda_chunk_out(qe, P, S0, U, dtype)
+    return o.reshape(B, Lp, H, dv)[:, :L]
+
+
+def _kda(q, k, v, g, beta, chunk: int, impl: str, dtype):
+    """``gated_delta_rule`` for ``g`` (B, L, H, dk): as many key as value
+    heads; the executor of the chunked form's batched half counted as
+    ``linear_attention.kda_chunk_calls.<pallas|xla>``."""
+    from mmlspark_tpu.ops import pallas_kda
+    taken = _form("kda", impl, q.shape[1], chunk)
+    q = q.astype(jnp.float32) * q.shape[-1] ** -0.5
+    if taken == "recurrent":
+        return _recurrent(q, k, v, g, beta, chunk)
+    if pallas_kda.supports(chunk, q.shape[2], v.shape[2], q.shape[-1],
+                           v.shape[-1]):
+        obsmetrics.counter("linear_attention.kda_chunk_calls.pallas").inc()
+        return _chunked_kda_kernel(q, k, v, g, beta, dtype)
+    obsmetrics.counter("linear_attention.kda_chunk_calls.xla").inc()
+    return _chunked_kda(q, k, v, g, beta, chunk, dtype)
+
+
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                      g: jax.Array, beta: jax.Array, *, chunk: int = CHUNK,
                      impl: str = "auto", dtype: Any = None) -> jax.Array:
@@ -293,7 +435,11 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``q``, ``k`` (B, L, Hk, dk), ``v`` (B, L, Hv, dv), ``g`` (log decay, <=
     0) and ``beta`` (B, L, Hv); value head ``h`` reads key head ``h // (Hv /
-    Hk)``; returns (B, L, Hv, dv) float32. ``q`` is scaled by ``dk **
+    Hk)``; returns (B, L, Hv, dv) float32. The form is picked from ``g``'s
+    shape: (B, L, Hv, dk) is a decay a key channel (Kimi Delta Attention;
+    as many key as value heads), which takes the forms of its own (``_kda``:
+    the same three, the Pallas calls of ``ops/pallas_kda.py`` at heads of
+    128 x 128). ``q`` is scaled by ``dk **
     -0.5``. ``impl``: "auto" (chunked from one whole chunk up, else token
     by token) | "chunked" | "recurrent". ``dtype``: the matrix products'
     operand type in the chunked form (default: ``q``'s own); the recurrent
@@ -302,14 +448,18 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     products: counted as ``linear_attention.chunk_calls.<pallas|xla>``.
     """
     Hk, Hv = q.shape[2], v.shape[2]
-    if g.shape != v.shape[:3] or beta.shape != v.shape[:3] \
+    channels = g.ndim == 4      # a decay a key channel: as many key heads
+    if g.shape != (q.shape if channels else v.shape[:3]) \
+            or beta.shape != v.shape[:3] \
             or k.shape != q.shape or v.shape[:2] != q.shape[:2] \
-            or Hv % Hk:
+            or Hv % Hk or (channels and Hk != Hv):
         raise ValueError(
             f"shapes q {q.shape} k {k.shape} v {v.shape} g {g.shape} "
             f"beta {beta.shape}")
-    taken = _form("delta", impl, q.shape[1], chunk)
     dtype = dtype or q.dtype
+    if channels:
+        return _kda(q, k, v, g, beta, chunk, impl, dtype)
+    taken = _form("delta", impl, q.shape[1], chunk)
     q = q.astype(jnp.float32) * q.shape[-1] ** -0.5
     if taken == "chunked" and pdr.supports(
             chunk, Hk, Hv, q.shape[-1], v.shape[-1]):

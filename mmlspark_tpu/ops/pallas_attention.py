@@ -137,7 +137,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
                   scale: float, window: Optional[int] = None,
                   block_diffusion: Optional[Tuple[int, int]] = None):
     """One program per (batch, head, query block) against the (batch,
-    head)'s whole K and V, resident in VMEM. The scores are held
+    head)'s whole K and V, resident in VMEM; q and k are one width and v
+    and the output another (equal in most callers; latent attention
+    without positions has keys of 192 over values of 128, and no operand
+    is padded to the other's width). The scores are held
     TRANSPOSED, (bk, bq), as ``_flash_bwd_kernel`` holds them: the running
     maximum ``m``, the running sum ``l`` and the correction are (1, bq)
     rows with the query index in the lanes, which broadcast along the
@@ -169,8 +172,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     # q/k/v refs are (1, 1, L-block, D): batch and head ride the grid, so
     # the last two dims are the (8, 128)-tileable (rows, lanes) pair Mosaic
     # wants; o_ref is (1, 1, 1, bq [+ lse rows], D)
-    q = q_ref[0, 0, :, :]                                     # (bq, d)
-    bq, d = q.shape
+    q = q_ref[0, 0, :, :]                                     # (bq, dk)
+    bq, d = q.shape[0], v_ref.shape[3]      # the output is v's width
     assert bq % block_k == 0, (bq, block_k)
     qi = pl.program_id(2)
 
@@ -396,8 +399,9 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
     (B, H, L / block_q, block_q). A call that is not differentiated
     writes none and is the kernel it was before it could. The caller's
     blocks are the floor of the tiles the call runs (``_fwd_tiles``)."""
-    b, L, h, d = q.shape
-    scale = 1.0 / float(np.sqrt(d))
+    b, L, h, dk = q.shape
+    d = v.shape[3]              # the output's, the accumulator's
+    scale = 1.0 / float(np.sqrt(dk))
     vmem = pl.ANY if _interpret() else pltpu.VMEM
     bq, bk = _fwd_tiles(block_q, block_k, L, d, window, block_diffusion)
     kernel = functools.partial(_flash_kernel, block_k=bk, causal=causal,
@@ -416,7 +420,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
         scope, named = jax.named_scope(_DIFFUSION_FWD_NAME), {
             "name": _DIFFUSION_FWD_NAME}
     rows = bq + (_lse_rows(bq, d, q.dtype)[1] if save_lse else 0)
-    need = _flash_fwd_vmem_bytes(L, d, bq, bk, rows, q.dtype.itemsize)
+    need = _flash_fwd_vmem_bytes(L, d, bq, bk, rows, q.dtype.itemsize, dk)
     # (B, L, H, D) -> (B, H, L, D): head ahead of length so kernel blocks
     # end in the tileable (rows, lanes) pair; XLA fuses the transposes
     # into the surrounding program
@@ -426,10 +430,10 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
             kernel,
             grid=(b, h, L // bq),
             in_specs=[
-                pl.BlockSpec((1, 1, bq, d),
+                pl.BlockSpec((1, 1, bq, dk),
                              lambda bi, hi, qi: (bi, hi, qi, 0),
                              memory_space=vmem),
-                pl.BlockSpec((1, 1, L, d), lambda bi, hi, qi: (bi, hi, 0, 0),
+                pl.BlockSpec((1, 1, L, dk), lambda bi, hi, qi: (bi, hi, 0, 0),
                              memory_space=vmem),
                 pl.BlockSpec((1, 1, L, d), lambda bi, hi, qi: (bi, hi, 0, 0),
                              memory_space=vmem),
@@ -453,27 +457,42 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
 
 # per-(batch, head) K and V stay fully VMEM-resident in the kernel, and
 # Mosaic must fit them in its scoped VMEM: 16 MiB by default on a v5e (of
-# 128 MiB physical). L * d <= 2^20 elements keeps K + V at 8 MiB in fp32
-# (4 MiB in bf16) — half that scope. (Both passes now ask Mosaic for their
-# own count, 32 MiB at least: ``_vmem_limit``; the cap stays where it was
-# measured, and lifting it is a K/V ``BlockSpec`` that tiles.) Measured on
-# the chip (PR 21, d = 64, the default scope):
+# 128 MiB physical). Up to PR 50 the cap was L * d <= 2^20 elements, which
+# kept K + V at 8 MiB in fp32 (4 MiB in bf16), half that scope. (Both
+# passes now ask Mosaic for their own count, 32 MiB at least:
+# ``_vmem_limit``.) Measured on the chip (PR 21, d = 64, the default scope):
 # fp32 still compiles at K + V = 16 MiB (L = 32768) and bf16 at L = 65536;
 # twice that is refused ("Scoped allocation with size 32.00M and limit
 # 16.00M"), as is fp32 d = 128 at L = 16384 (16.50M).
-_VMEM_KV_LIMIT = 1 << 20   # L * d elements
+#
+# Since PR 51 the cap counts what the forward really holds, in BYTES: K and V
+# of one (batch, head), each row padded to whole lane tiles, in the operands'
+# dtype. 16 MiB of them is fp32 at L = 16384 with d = 64 (a row of 64 fills a
+# tile of 128: the edge that was measured) and bf16 at twice that; a caller
+# that names no dtype is counted at 4 bytes. Both passes ask Mosaic for
+# their own count of blocks and scratch (``_vmem_limit``), and at this cap
+# the backward, which holds q, do and dq whole, stays under what a program
+# may ask (``_VMEM_CAP``). Compiled for a v5e and run on the chip at this
+# cap's largest caller, keys of 192 over values of 128 at L = 16384 in
+# bf16 (12 MiB; PERF.md section 6, PR 51). Past it: a K/V ``BlockSpec``
+# that tiles (ROADMAP Queue 2 A10).
+_VMEM_KV_BYTES = 16 << 20
 
 
-def supports(q_shape, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> bool:
+def supports(q_shape, block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
+             v_dim: Optional[int] = None, itemsize: int = 4) -> bool:
     """Whether the flash kernel applies: block-divisible length of at
-    least two query blocks, a sublane-friendly head dim, and K + V of at
-    most 2 * 2^20 elements (8 MiB in fp32) to hold per (batch, head) in
-    Mosaic's 16 MiB scoped VMEM. ``full_attention`` asks this first, then
-    ``supports_short``, and counts, or under ``use_flash="require"``
+    least two query blocks, sublane-friendly head widths (``v_dim``: the
+    values' where it is not the keys'), and K + V of one (batch, head), a
+    row padded to whole lane tiles, within ``_VMEM_KV_BYTES`` at
+    ``itemsize`` bytes an element. ``full_attention`` asks this first,
+    then ``supports_short``, and counts, or under ``use_flash="require"``
     refuses, a shape neither takes."""
     _, L, _, d = q_shape
+    dv = d if v_dim is None else v_dim
+    held = L * (_padded_len(d) + _padded_len(dv)) * itemsize
     return L % block_q == 0 and L % block_k == 0 and L >= 2 * block_q \
-        and d % 8 == 0 and L * d <= _VMEM_KV_LIMIT
+        and d % 8 == 0 and dv % 8 == 0 and held <= _VMEM_KV_BYTES
 
 
 def supports_block_diffusion(q_shape, block_diffusion: Tuple[int, int],
@@ -736,14 +755,17 @@ def _fwd_tiles(block_q: int, block_k: int, L: int, d: int,
 
 
 def _flash_fwd_vmem_bytes(L: int, d: int, block_q: int, block_k: int,
-                          out_rows: int, itemsize: int) -> int:
-    """VMEM one forward program holds, lanes padded to the tile: k and v
-    whole and the q and output blocks, each double-buffered; the (d, bq)
-    float32 accumulator, its rescaled copy and its transpose; the (1, bq)
+                          out_rows: int, itemsize: int,
+                          dk: Optional[int] = None) -> int:
+    """VMEM one forward program holds, lanes padded to the tile: k (and q's
+    block) ``dk`` wide (``d`` where none is given) and v (and the output's
+    block) ``d`` wide, whole, each double-buffered; the (d, bq) float32
+    accumulator, its rescaled copy and its transpose; the (1, bq)
     statistics, a sublane tile each; about six score-sized float32
     temporaries (scores, mask, probabilities and their cast)."""
     lanes = _padded_len(d)
-    blocks = 2 * (2 * L + block_q + out_rows) * lanes * itemsize
+    blocks = 2 * ((L + block_q) * _padded_len(dk or d)
+                  + (L + out_rows) * lanes) * itemsize
     acc = (2 * max(d, 8) + lanes + 4 * 8) * block_q * 4
     return blocks + acc + 6 * block_q * block_k * 4
 
@@ -756,16 +778,18 @@ def _vmem_limit(need: int) -> int:
 
 
 def _flash_bwd_vmem_bytes(L: int, d: int, block_q: int, block_k: int,
-                          itemsize: int) -> int:
-    """VMEM one backward program holds, lanes padded to the tile: q, do
-    and dq whole and k, v, dk, dv by the block, each double-buffered; the
-    statistics; the three float32 accumulators; about eight score-sized
-    float32 temporaries."""
-    lanes = _padded_len(d)
-    whole = L * lanes
-    blocks = 2 * (3 * whole + 4 * block_k * lanes) * itemsize
+                          itemsize: int, dk: Optional[int] = None) -> int:
+    """VMEM one backward program holds, lanes padded to the tile: q and dq
+    (``dk`` wide; ``d`` where none is given) and do (``d`` wide) whole and
+    k, v, dk, dv by the block, each double-buffered; the statistics; the
+    three float32 accumulators; about eight score-sized float32
+    temporaries."""
+    keys, lanes = _padded_len(dk or d), _padded_len(d)
+    whole = L * keys
+    blocks = 2 * (2 * whole + L * lanes
+                  + 2 * block_k * (keys + lanes)) * itemsize
     stats = 2 * 2 * max(L // block_q, 8) * _padded_len(block_q) * 4
-    acc = (whole + 2 * block_k * lanes) * 4
+    acc = (whole + block_k * (keys + lanes)) * 4
     return blocks + stats + acc + 8 * block_q * block_k * 4
 
 
@@ -776,6 +800,7 @@ def _flash_bwd_vmem_bytes(L: int, d: int, block_q: int, block_k: int,
 def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
                     window=None, block_diffusion=None):
     b, L, h, d = q.shape
+    dv = v.shape[3]
     tile, name = functools.partial(_bwd_tile, L=L), _BWD_NAME
     if window is not None:
         tile = functools.partial(_window_tile, L=L, window=window)
@@ -803,11 +828,12 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
         return pl.BlockSpec((None, None, rows, lanes),
                             lambda bi, hi, kj: (bi, hi, 0, 0))
 
-    def block():
-        return pl.BlockSpec((None, None, block_k, d),
+    def block(lanes):
+        return pl.BlockSpec((None, None, block_k, lanes),
                             lambda bi, hi, kj: (bi, hi, kj, 0))
 
-    need = _flash_bwd_vmem_bytes(L, d, block_q, block_k, q.dtype.itemsize)
+    need = _flash_bwd_vmem_bytes(L, dv, block_q, block_k, q.dtype.itemsize,
+                                 d)
     # the scope's name is the call's instruction name in the compiled
     # program and so in a device trace: no `flash` in it, because the
     # benchmark's readers of the FORWARD find theirs by that word
@@ -816,14 +842,14 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
             kernel,
             name=name,
             grid=(b, h, L // block_k),
-            in_specs=[whole(L, d), block(), block(), whole(L, d),
+            in_specs=[whole(L, d), block(d), block(dv), whole(L, dv),
                       whole(nq, block_q), whole(nq, block_q)],
-            out_specs=[whole(L, d), block(), block()],
-            out_shape=[jax.ShapeDtypeStruct(qt.shape, x.dtype)
-                       for x in (q, k, v)],
+            out_specs=[whole(L, d), block(d), block(dv)],
+            out_shape=[jax.ShapeDtypeStruct(xt.shape, x.dtype)
+                       for xt, x in ((qt, q), (kt, k), (vt, v))],
             scratch_shapes=[pltpu.VMEM((L, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+                            pltpu.VMEM((block_k, dv), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=_vmem_limit(need)),

@@ -523,43 +523,46 @@ def _out_specs(qe, u):
                s["chunks"](dv)], s["rows"](dv)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _out_forward(qe, p, s0, u, dtype):
-    N, B, Hv = qe.shape[:3]
-    s, ins, rows = _out_specs(qe, u)
-    return _call(
-        functools.partial(_out_kernel, dtype=dtype, **s["program"]),
-        _OUT_NAME, s["grid"],
-        ins, rows, jax.ShapeDtypeStruct(
-            (B, N * CHUNK, Hv * u.shape[-1]), _f32), "parallel",
-        qe, p, s0, u)
+def chunk_out_call(name: str, bwd_name: str):
+    """``delta_chunk_out`` under ``name`` and its backward under
+    ``bwd_name``: the rule with a decay a key channel (``ops/pallas_kda``)
+    runs these kernels as they are, under names of its own."""
+    @functools.partial(jax.jit, static_argnames=("dtype",))
+    def _out_forward(qe, p, s0, u, dtype):
+        N, B, Hv = qe.shape[:3]
+        s, ins, rows = _out_specs(qe, u)
+        return _call(
+            functools.partial(_out_kernel, dtype=dtype, **s["program"]),
+            name, s["grid"],
+            ins, rows, jax.ShapeDtypeStruct(
+                (B, N * CHUNK, Hv * u.shape[-1]), _f32), "parallel",
+            qe, p, s0, u)
+
+    @functools.partial(jax.jit, static_argnames=("dtype",))
+    def _out_backward(qe, p, s0, u, do, dtype):
+        s, ins, rows = _out_specs(qe, u)
+        return _call(
+            functools.partial(_out_bwd_kernel, dtype=dtype, **s["program"]),
+            bwd_name, s["grid"], ins + [rows], ins,
+            [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (qe, p, s0, u)],
+            "parallel", qe, p, s0, u, do)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+    def delta_chunk_out(qe: jax.Array, p: jax.Array, s0: jax.Array,
+                        u: jax.Array, dtype: Any) -> jax.Array:
+        """``O = qe S_0 + P U`` chunk by chunk, written as (B, L, Hv * dv)
+        float32 rows: ``qe`` and ``P`` from ``delta_chunk``, ``S_0`` (N, B,
+        Hv, dk, dv) and ``U`` (N, B, Hv, 64, dv) float32 from the walk."""
+        return _out_forward(qe, p, s0, u, jnp.dtype(dtype))
+
+    def _out_fwd_rule(qe, p, s0, u, dtype):
+        return delta_chunk_out(qe, p, s0, u, dtype), (qe, p, s0, u)
+
+    def _out_bwd_rule(dtype, res, do):
+        return tuple(_out_backward(*res, do, jnp.dtype(dtype)))
+
+    delta_chunk_out.defvjp(_out_fwd_rule, _out_bwd_rule)
+    return delta_chunk_out
 
 
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _out_backward(qe, p, s0, u, do, dtype):
-    s, ins, rows = _out_specs(qe, u)
-    return _call(
-        functools.partial(_out_bwd_kernel, dtype=dtype, **s["program"]),
-        _OUT_BWD_NAME, s["grid"], ins + [rows], ins,
-        [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (qe, p, s0, u)],
-        "parallel", qe, p, s0, u, do)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def delta_chunk_out(qe: jax.Array, p: jax.Array, s0: jax.Array,
-                    u: jax.Array, dtype: Any) -> jax.Array:
-    """``O = qe S_0 + P U`` chunk by chunk, written as (B, L, Hv * dv)
-    float32 rows: ``qe`` and ``P`` from ``delta_chunk``, ``S_0`` (N, B,
-    Hv, dk, dv) and ``U`` (N, B, Hv, 64, dv) float32 from the walk."""
-    return _out_forward(qe, p, s0, u, jnp.dtype(dtype))
-
-
-def _out_fwd_rule(qe, p, s0, u, dtype):
-    return delta_chunk_out(qe, p, s0, u, dtype), (qe, p, s0, u)
-
-
-def _out_bwd_rule(dtype, res, do):
-    return tuple(_out_backward(*res, do, jnp.dtype(dtype)))
-
-
-delta_chunk_out.defvjp(_out_fwd_rule, _out_bwd_rule)
+delta_chunk_out = chunk_out_call(_OUT_NAME, _OUT_BWD_NAME)

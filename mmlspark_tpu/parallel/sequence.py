@@ -189,7 +189,8 @@ def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 name, kernel = "block_diffusion", partial(
                     pallas_attention.flash_attention,
                     block_diffusion=block_diffusion)
-        elif pallas_attention.supports(q.shape):
+        elif pallas_attention.supports(q.shape, v_dim=v.shape[-1],
+                                       itemsize=q.dtype.itemsize):
             name, kernel = "flash", pallas_attention.flash_attention
             if window is not None:
                 name, kernel = "window", partial(kernel, window=window)

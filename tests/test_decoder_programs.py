@@ -2,9 +2,16 @@
 check that a change to ``models/zoo/decoder.py`` or ``parts.py`` which is
 meant to move no family's arithmetic has moved none.
 
-Two files under ``tests/data/`` hold, for each of the seven tiny presets,
-what THIS module computed on PR 47's commit (float32, the CPU backend,
-every function under ``jax.jit``). PR 47 added the seventh
+Two files under ``tests/data/`` hold, for each of the eight tiny presets,
+what THIS module computed on the commit that last remade them (float32,
+the CPU backend, every function under ``jax.jit``). PR 51 added the eighth
+(``kimi_linear_tiny``) after giving ``MlaAttention`` two arguments whose
+defaults are GLM's (no query rank, no turn), ``flash_attention`` a second
+head width and ``gated_delta_rule`` a decay a key channel, picked from
+``g``'s shape: the JSON's diff is ONE added line, the seven older hashes
+unmoved, and the seven older presets' keys of the ``.npz`` are the parent's
+bit for bit (checked against the parent's file before it was remade). PR
+47 added the seventh
 (``sdar_moe_tiny``: rows of ``[noised | clean]``, its program the gradient
 of ``masked_diffusion_loss`` with every noised position weighed 1) after
 giving ``parts.rotary`` and ``GroupedAttention`` one more argument each,
@@ -46,7 +53,7 @@ A PR that changes a family's program on purpose remakes both with
 
     JAX_PLATFORMS=cpu python tests/test_decoder_programs.py --write
 
-(without ``--write`` it prints the seven hashes and writes nothing), names
+(without ``--write`` it prints the eight hashes and writes nothing), names
 its own commit here, and its diff of the JSON then shows which families it
 touched and which it did not.
 """
@@ -71,7 +78,7 @@ from mmlspark_tpu.train.lm_loss import (  # noqa: E402
 
 PRESETS = ("glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
            "olmo_hybrid_tiny", "lfm2_moe_tiny", "laguna_tiny",
-           "sdar_moe_tiny")
+           "sdar_moe_tiny", "kimi_linear_tiny")
 DIFFUSION = ("sdar_moe_tiny",)
 DATA = Path(__file__).resolve().parent / "data"
 OUTPUTS = DATA / "decoder_parent_outputs.npz"

@@ -790,10 +790,27 @@ def test_mla_rotates_only_its_slice_and_shares_one_rotary_key():
     np.testing.assert_allclose(y[0], want, rtol=1e-5, atol=1e-6)
 
 
-def test_unequal_query_and_value_heads_are_refused():
+def test_unequal_query_and_value_heads_are_taken_as_they_are():
+    """Up to PR 50 the part refused values narrower than its keys
+    (``attention_fn(q, k, v)`` took one head width); since PR 51 the
+    attention call takes v's own last axis: keys of 16 over values of 8,
+    the output projection reading ``heads x v_dim`` channels, and the
+    result is the masked dense product's."""
     attn = MlaAttention(32, 2, 24, 16, 12, 4, 8, dtype=jnp.float32)
-    with pytest.raises(ValueError, match="one head width"):
-        attn.init(jax.random.PRNGKey(0), jnp.zeros((1, 4, 32)))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 6, 32))
+    p = attn.init(jax.random.PRNGKey(0), x)
+    assert p["params"]["attn_out"]["kernel"].shape == (2 * 8, 32)
+    assert p["params"]["attn_key_value_b"]["kernel"].shape == (16, 2 * 20)
+    seen = []
+
+    def spy(q, k, v, causal):
+        seen.append((q.shape, k.shape, v.shape))
+        s = jnp.einsum("blhd,bkhd->bhlk", q, k) / np.sqrt(q.shape[-1])
+        s = jnp.where(jnp.tril(jnp.ones((6, 6), bool)), s, -jnp.inf)
+        return jnp.einsum("bhlk,bkhd->blhd", jax.nn.softmax(s, -1), v)
+    want = attn.clone(attention_fn=spy).apply(p, x)
+    assert seen == [((1, 6, 2, 16), (1, 6, 2, 16), (1, 6, 2, 8))]
+    np.testing.assert_allclose(attn.apply(p, x), want, rtol=1e-5, atol=1e-6)
 
 
 # ------------------------------------------------------------ the loss

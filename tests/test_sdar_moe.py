@@ -538,10 +538,12 @@ def test_the_tiles_lie_in_one_half_and_hold_whole_blocks():
     assert pa.supports_block_diffusion((4, 8192, 32, 128), (4096, 4))
     assert pa.supports_block_diffusion((1, 1024, 2, 64), (512, 512))
     # a block that straddles two tiles, a half that is no whole tile, a
-    # row the flash kernel does not take at all
+    # row the flash kernel does not take at all (since PR 51 its cap
+    # counts K + V in bytes, 16 MiB: float32 at 16,384 x 128 is its edge)
     assert not pa.supports_block_diffusion((1, 1536, 2, 64), (768, 3))
     assert not pa.supports_block_diffusion((1, 768, 2, 64), (384, 4))
-    assert not pa.supports_block_diffusion((1, 16384, 2, 128), (8192, 4))
+    assert pa.supports_block_diffusion((1, 16384, 2, 128), (8192, 4))
+    assert not pa.supports_block_diffusion((1, 32768, 2, 128), (16384, 4))
     with pytest.raises(ValueError, match="supports_block_diffusion"):
         pa.flash_attention(*_qkvd(192)[:3], True, block_diffusion=(192, 4))
     # the causal calls' tiles are what they were

@@ -304,7 +304,7 @@ def _benchmark_pattern(metric):
     ((1, 512, 2, 8), jnp.float32),          # its narrowest head
     ((4, 8192, 32, 64), jnp.bfloat16),      # lfm2-24b-a2b-train-ep8share-8k
     ((1, 8192, 30, 128), jnp.bfloat16),     # olmo-hybrid-7b-train-8k: AT
-                                            # the cap, L x d = 2^20
+                                            # PR 50's cap, L x d = 2^20
 ], ids=["d256-bf16", "d64-bf16", "d64-f32-edge", "d8-f32", "lfm2-d64-bf16",
         "olmo-d128-bf16"])
 def test_mosaic_compiles_the_flash_kernel_and_its_backward(
@@ -345,7 +345,7 @@ def test_mosaic_compiles_the_flash_kernel_and_its_backward(
 
 @pytest.mark.parametrize("shape,window", [
     ((2, 8192, 64, 128), 512),      # laguna-xs.2-train-ep8share-8k: AT the
-                                    # cap, L x d = 2^20
+                                    # cap up to PR 50
     ((1, 4096, 4, 64), 1280),       # a tile clear between edge and diagonal
     ((1, 1024, 2, 128), 100),       # a window under a tile
 ], ids=["laguna-d128-W512", "d64-W1280", "d128-W100"])
@@ -466,6 +466,90 @@ def test_mosaic_compiles_the_delta_rules_chunk_calls(topo, as_on_chip,
                for line in text.splitlines()) == 2
     # no float32 head-major copy of q, k or v, and no repeated key heads
     assert f"f32[{B},{Hv},{L // 64},64,{d}]" not in text
+
+
+def test_mosaic_compiles_the_vector_decay_rules_chunk_calls(topo,
+                                                            as_on_chip):
+    """The delta rule under a decay a key channel, forward and backward,
+    at the shape of benchmark cell ``kimi-linear-48b-a3b-train-ep32share-
+    16k`` through Mosaic for a v5e: the four Pallas calls of
+    ``ops/pallas_kda.py`` (sublane rolls, six masked products a tile, the
+    triangular inverse) under the names ``kda.chunk_kernel_ms`` finds them
+    by and no accepted pattern does, and the walk the two ``while``s (the
+    scan and its transpose) that ``kda.state_walk_ms`` finds by the state
+    they carry first."""
+    from jax.sharding import SingleDeviceSharding
+    from mmlspark_tpu.ops import linear_attention as la
+    one = SingleDeviceSharding(topo.devices[0])
+    B, L, H, d = 1, 16384, 32, 128
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def grads(q, k, v, g, beta, w):
+        return jax.value_and_grad(lambda *a: (la.gated_delta_rule(
+            *a, dtype=jnp.bfloat16) * w).sum(), argnums=(0, 1, 2, 3, 4))(
+                q, k, v, g, beta)
+
+    text = jax.jit(grads).lower(
+        s((B, L, H, d)), s((B, L, H, d)), s((B, L, H, d), jnp.bfloat16),
+        s((B, L, H, d)), s((B, L, H)), s((B, L, H, d))).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    names = sorted(re.match(r"(?:ROOT )?%([a-z_]+)", c).group(1)
+                   for c in calls)
+    assert names == ["kda_chunk_bwd", "kda_chunk_fwd", "kda_chunk_out",
+                     "kda_chunk_out_bwd"]
+    mine = _benchmark_pattern("kda.chunk_kernel_ms")
+    assert all(mine.search(c) for c in calls)
+    for metric in ("kernel.flash_attention_ms", "kernel.flash_fwd_roofline",
+                   "kernel.flash_bwd_ms", "kernel.attention_ms",
+                   "linattn.chunk_kernel_ms", "linattn.delta_rule_ms",
+                   "linattn.state_walk_ms", "ssm.state_walk_ms",
+                   "ssm.chunk_kernel_ms"):
+        assert not any(_benchmark_pattern(metric).search(c) for c in calls)
+    walk = _benchmark_pattern("kda.state_walk_ms")
+    assert sum(bool(walk.search(line.strip()))
+               for line in text.splitlines()) == 2
+    # no float32 head-major copy of q, k, v or the decay
+    assert f"f32[{B},{H},{L // 64},64,{d}]" not in text
+
+
+def test_mosaic_compiles_the_flash_kernels_at_two_head_widths(topo,
+                                                              as_on_chip):
+    """The latent layer's call of cell ``kimi-linear-48b-a3b-train-
+    ep32share-16k``, keys of 192 over values of 128 at 16,384 tokens in
+    bfloat16 (K + V of a (batch, head) 12 MiB in VMEM, lanes padded), forward
+    and backward through Mosaic for a v5e under the causal calls' names, no
+    operand padded to the other's width; float32 at this shape is past
+    ``supports``' bytes and is refused."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one)
+    assert pallas_attention.supports(q.shape, v_dim=128, itemsize=2)
+    assert not pallas_attention.supports(q.shape, v_dim=128, itemsize=4)
+
+    def grads(q, k, v, w):
+        return jax.grad(lambda q, k, v: (
+            full_attention(q, k, v, causal=True).astype(jnp.float32)
+            * w).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(q, q, v, v).compile()
+    text = compiled.as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 2
+    forward = [_benchmark_pattern(m) for m in (
+        "kernel.flash_attention_ms", "mla.flash_fwd_roofline")]
+    backward = _benchmark_pattern("kernel.flash_bwd_ms")
+    hits = [[bool(rx.search(c)) for rx in forward + [backward]]
+            for c in calls]
+    assert sorted(hits) == [[False, False, True], [True, True, False]]
+    assert "[1,32,16384,16384]" not in text and " while(" not in text
+    # the values stay 128 wide into the call: nothing of (.., 16384, 192)
+    # but q, k and their gradients
+    assert "bf16[1,32,16384,128]" in text
 
 
 def test_mosaic_compiles_the_state_space_rules_chunk_calls(topo, as_on_chip):
