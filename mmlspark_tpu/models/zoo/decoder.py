@@ -891,7 +891,13 @@ def kimi_linear(vocab: int = 163840, dim: int = 2304,
     23.46 GB with every name kept (refused), 17.11 without the tiles
     (refused), 16.94 without the SwiGLU products too (refused), 16.27
     without the projections as well, 16.14 with no name at all (PERF.md
-    section 6, PR 51)."""
+    section 6, PR 51). What a recomputed KDA block therefore makes AGAIN in
+    its backward pass: the three projections, each one's pass through
+    ``linear_attention.conv_silu_norm`` (at these widths one Pallas call a
+    projection, ``ops/pallas_conv_norm.py``: 24 forward calls a step of
+    four layers and 12 backward ones, whose residuals are the projections'
+    rows and so made again too), the decay's gate and running sum, and
+    ``kda_chunk_fwd`` (PERF.md section 6, PR 52)."""
     from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
     held = None if experts_held is None else tuple(experts_held)
     kda, full = set(kda_layers), set(full_attn_layers)

@@ -412,7 +412,22 @@ class KimiDeltaAttention(nn.Module):
     ``dt_bias``. Scope ``kimi_delta_attention``; inside it ``kda_conv`` and
     ``kda_decay``, and the rule's walk under ``kda_state_walk``. The three
     projections' outputs carry ``DELTA_NET_QKVZ`` and the chunk calls'
-    tiles ``DELTA_CHUNK_TILES``, as ``GatedDeltaNet``'s do."""
+    tiles ``DELTA_CHUNK_TILES``, as ``GatedDeltaNet``'s do.
+
+    What lies between a projection and the rule (the convolution, ``silu``,
+    and for q and k the L2 norm) is ONE entry,
+    ``linear_attention.conv_silu_norm``, under ``kda_conv``: which form
+    makes it is read from the shape alone and counted a trace as
+    ``linear_attention.conv_norm_calls.<pallas|xla>``. Heads of whole
+    registers and rows in whole tiles (``kimi_linear`` at 32 heads of 128)
+    take one Pallas pass from the projection's rows to what the chunk call
+    reads, float32 for q and k and the rows' type for v, and one more for
+    the derivative, whose residuals are the projection's rows
+    (``DELTA_NET_QKVZ``) and the taps; any other shape (the tiny presets'
+    heads of 8) takes ``causal_conv1d``, ``silu`` and ``l2_normalize`` as
+    XLA fuses them. q's ``head_dim ** -0.5`` is that entry's last multiply
+    in either form, and the rule is told so (``q_scaled``): no float32 pass
+    over q stands between the two."""
     dim: int
     heads: int
     head_dim: int
@@ -430,20 +445,21 @@ class KimiDeltaAttention(nn.Module):
         with jax.named_scope("kimi_delta_attention"):
             x = x.astype(dt)
 
-            def mixed(name):
+            def mixed(name, norm=False, scale=1.0):
                 y = checkpoint_name(
                     _dense(H * d, dt, f"attn_{name}")(x), DELTA_NET_QKVZ)
                 taps = self.param(f"conv_{name}", _INIT,
                                   (self.conv_width, H * d), f32)
                 with jax.named_scope("kda_conv"):
-                    return nn.silu(la.causal_conv1d(y, taps)).reshape(
+                    return la.conv_silu_norm(y, taps, H, norm, scale).reshape(
                         B, L, H, d)
 
             def low_rank(name):
                 return _dense(H * d, dt, f"attn_{name}_b")(
                     _dense(d, dt, f"attn_{name}_a")(x)).astype(f32).reshape(
                         B, L, H, d)
-            q, k, v = mixed("query"), mixed("key"), mixed("value")
+            q, k, v = (mixed("query", True, d ** -0.5), mixed("key", True),
+                       mixed("value"))
             a_log = self.param(
                 "A_log", lambda key, shape: jnp.log(jax.random.uniform(
                     key, shape, f32, 1e-3, 16.0)), (H,))
@@ -453,9 +469,8 @@ class KimiDeltaAttention(nn.Module):
                 g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
                     low_rank("decay") + dt_bias.reshape(H, d))
             beta = jax.nn.sigmoid(_dense(H, dt, "attn_beta")(x).astype(f32))
-            o = la.gated_delta_rule(
-                la.l2_normalize(q), la.l2_normalize(k), v, g, beta,
-                chunk=self.chunk, dtype=dt)
+            o = la.gated_delta_rule(q, k, v, g, beta, chunk=self.chunk,
+                                    dtype=dt, q_scaled=True)
             o = RMSNorm(self.eps, name="gate_norm")(o) \
                 * jax.nn.sigmoid(low_rank("gate"))
             return _dense(self.dim, dt, "attn_out")(

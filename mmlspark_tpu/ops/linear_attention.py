@@ -107,7 +107,28 @@ fifteenth is formed.
 The other products take ``dtype`` operands (bfloat16 on the chip) and
 accumulate in float32; state, decay and sums are float32.
 
-Counters, per TRACE: ``linear_attention.calls.<chunked|recurrent>``,
+**What lies in front of the rules.** ``causal_conv1d`` (the short causal
+depthwise convolution), ``l2_normalize`` (a head's L2 norm) and, for a layer
+whose q, k and v each pass a convolution of their own and ``silu`` (Kimi
+Delta Attention), ``conv_silu_norm``: the convolution, ``silu``, and for q
+and k the norm and a scale, from a projection's rows to what the chunk call
+reads. It has two forms picked from the shape alone
+(``pallas_conv_norm.supports`` on a device's own part of the rows): heads
+of whole registers (a multiple of 128 channels), rows of bfloat16 or float32
+in whole tiles of 512 and at most nine taps take ONE Pallas pass and one
+more for the derivative (``ops/pallas_conv_norm.py``: float32 in registers
+from the load to the store, the taps as the parameter holds them, one
+rounding; q and k leave as float32 rows, v in the rows' type; residuals the
+rows and the taps); any other shape takes the first two functions and
+``silu`` as XLA fuses them, bfloat16 products included. ``gated_delta_rule``
+scales q by ``dk ** -0.5`` itself unless q comes ``q_scaled``, which
+``conv_silu_norm``'s does in either form (the scale is its last multiply:
+the value that enters the rule is the same float32 to the last bit, and no
+float32 pass over q stands between the two).
+
+Counters, per TRACE: ``linear_attention.conv_norm_calls.<pallas|xla>`` (the
+form ``conv_silu_norm`` took),
+``linear_attention.calls.<chunked|recurrent>``,
 ``linear_attention.rule_calls.<delta|kda|ssd>``, the executor of the batched
 half ``linear_attention.chunk_calls.<pallas|xla>`` (delta rule),
 ``linear_attention.kda_chunk_calls.<pallas|xla>`` (a decay a key channel) and
@@ -146,6 +167,43 @@ def l2_normalize(x: jax.Array) -> jax.Array:
     """``x / sqrt(sum(x^2) + 1e-6)`` over the last axis, float32."""
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def conv_silu_norm(y: jax.Array, taps: jax.Array, heads: int,
+                   norm: bool = False, scale: float = 1.0) -> jax.Array:
+    """What lies between a projection and the delta rule's chunk calls:
+    ``y`` (B, L, H * d) through ``causal_conv1d`` under ``taps`` (W, H * d)
+    and ``silu``; with ``norm`` each of the ``heads`` heads through
+    ``l2_normalize`` and times ``scale``, float32, else in ``y``'s type.
+    Two forms, picked from the shape alone (``pallas_conv_norm.supports``
+    on a device's own part of the rows under a ``with mesh:`` block, as
+    ``parallel/sequence.on_own_rows`` splits them): heads of whole
+    registers, rows of bfloat16 or float32 in whole tiles and at most nine
+    taps take ONE Pallas pass from the projection's rows to what the chunk
+    call reads and one more for the derivative
+    (``ops/pallas_conv_norm.py``: float32 from the load to the store, one
+    rounding); any other shape takes the three functions above, as XLA
+    fuses them. Counted a trace as
+    ``linear_attention.conv_norm_calls.<pallas|xla>``."""
+    from mmlspark_tpu.ops import pallas_conv_norm as kernel
+    from mmlspark_tpu.parallel.sequence import on_own_rows, own_shape
+    B, L, C = y.shape
+    local = own_shape((B, L, heads, C // heads))
+    if local is not None and kernel.supports(local, taps.shape[0], y.dtype):
+        obsmetrics.counter("linear_attention.conv_norm_calls.pallas").inc()
+        return on_own_rows(
+            lambda y, taps: kernel.conv_silu_norm(
+                y.reshape(y.shape[:2] + (-1,)),
+                taps.reshape(taps.shape[0], -1), y.shape[2], norm,
+                scale).reshape(y.shape),
+            y.reshape(B, L, heads, -1),
+            heads=(taps.reshape(taps.shape[0], heads, -1),)).reshape(B, L, C)
+    obsmetrics.counter("linear_attention.conv_norm_calls.xla").inc()
+    x = jax.nn.silu(causal_conv1d(y, taps))
+    if not norm:
+        return x
+    x = l2_normalize(x.reshape(B, L, heads, -1))
+    return (x if scale == 1.0 else x * scale).reshape(B, L, C)
 
 
 def _mm(eq: str, a, b, dtype):
@@ -412,12 +470,11 @@ def _chunked_kda_kernel(q, k, v, g, beta, dtype):
 
 
 def _kda(q, k, v, g, beta, chunk: int, impl: str, dtype):
-    """``gated_delta_rule`` for ``g`` (B, L, H, dk): as many key as value
-    heads; the executor of the chunked form's batched half counted as
-    ``linear_attention.kda_chunk_calls.<pallas|xla>``."""
+    """``gated_delta_rule`` for ``g`` (B, L, H, dk) and ``q`` scaled: as
+    many key as value heads; the executor of the chunked form's batched
+    half counted as ``linear_attention.kda_chunk_calls.<pallas|xla>``."""
     from mmlspark_tpu.ops import pallas_kda
     taken = _form("kda", impl, q.shape[1], chunk)
-    q = q.astype(jnp.float32) * q.shape[-1] ** -0.5
     if taken == "recurrent":
         return _recurrent(q, k, v, g, beta, chunk)
     if pallas_kda.supports(chunk, q.shape[2], v.shape[2], q.shape[-1],
@@ -430,7 +487,8 @@ def _kda(q, k, v, g, beta, chunk: int, impl: str, dtype):
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                      g: jax.Array, beta: jax.Array, *, chunk: int = CHUNK,
-                     impl: str = "auto", dtype: Any = None) -> jax.Array:
+                     impl: str = "auto", dtype: Any = None,
+                     q_scaled: bool = False) -> jax.Array:
     """The gated delta rule over whole rows, state zero at each row's start.
 
     ``q``, ``k`` (B, L, Hk, dk), ``v`` (B, L, Hv, dv), ``g`` (log decay, <=
@@ -439,9 +497,10 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     shape: (B, L, Hv, dk) is a decay a key channel (Kimi Delta Attention;
     as many key as value heads), which takes the forms of its own (``_kda``:
     the same three, the Pallas calls of ``ops/pallas_kda.py`` at heads of
-    128 x 128). ``q`` is scaled by ``dk **
-    -0.5``. ``impl``: "auto" (chunked from one whole chunk up, else token
-    by token) | "chunked" | "recurrent". ``dtype``: the matrix products'
+    128 x 128). ``q`` is scaled by ``dk ** -0.5`` here, unless it comes
+    ``q_scaled`` (``conv_silu_norm``'s last multiply: no float32 pass of
+    its own over q). ``impl``: "auto" (chunked from one whole chunk up,
+    else token by token) | "chunked" | "recurrent". ``dtype``: the matrix products'
     operand type in the chunked form (default: ``q``'s own); the recurrent
     form is float32 throughout. The chunked form takes the Pallas calls
     where ``pallas_delta_rule.supports`` the shapes, else XLA's batched
@@ -457,10 +516,12 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
             f"shapes q {q.shape} k {k.shape} v {v.shape} g {g.shape} "
             f"beta {beta.shape}")
     dtype = dtype or q.dtype
+    q = q.astype(jnp.float32)
+    if not q_scaled:
+        q = q * q.shape[-1] ** -0.5
     if channels:
         return _kda(q, k, v, g, beta, chunk, impl, dtype)
     taken = _form("delta", impl, q.shape[1], chunk)
-    q = q.astype(jnp.float32) * q.shape[-1] ** -0.5
     if taken == "chunked" and pdr.supports(
             chunk, Hk, Hv, q.shape[-1], v.shape[-1]):
         obsmetrics.counter("linear_attention.chunk_calls.pallas").inc()

@@ -70,19 +70,25 @@ def _reference_attention(q, k, v, causal: bool,
     return out.astype(q.dtype)
 
 
-def _per_device(fn, mesh: Mesh, rows, whole):
-    """``fn(*rows, *whole)`` shard_mapped so that each device runs it on
-    its own batch rows (and its own heads on a tensor axis) of every ``(B,
-    L, H, D)`` array of ``rows``, each array of ``whole`` whole on every
-    device. Attention, and what is made of a head before it, is
-    independent across batch and heads, so this needs no collective, but
-    a Pallas kernel is an opaque custom call the SPMD partitioner cannot
-    split: bare inside a multi-device jit it is refused, or all-gathered
-    to run every (batch, head) on every chip."""
+def _per_device(fn, mesh: Mesh, rows, whole, heads=()):
+    """``fn(*rows, *whole, *heads)`` shard_mapped so that each device runs
+    it on its own batch rows (and its own heads on a tensor axis) of every
+    ``(B, L, H, D)`` array of ``rows``, each array of ``whole`` whole on
+    every device, and of each ``(..., H, D)`` array of ``heads`` (a
+    parameter a head: no batch) the heads the device holds of the rows.
+    Attention, and what is made of a head before it, is independent across
+    batch and heads, so this needs no collective, but a Pallas kernel is an
+    opaque custom call the SPMD partitioner cannot split: bare inside a
+    multi-device jit it is refused, or all-gathered to run every (batch,
+    head) on every chip."""
     specs = tuple(_qkv_spec(mesh, None, r.shape[2]) for r in rows)
     everywhere = P()  # lint: allow-spec (shard_map spec)
+    a_head = tuple(
+        P(*(None,) * (h.ndim - 2), specs[0][2], None)  # lint: allow-spec
+        for h in heads)
     return jax.shard_map(
-        fn, mesh=mesh, in_specs=specs + (everywhere,) * len(whole),
+        fn, mesh=mesh,
+        in_specs=specs + (everywhere,) * len(whole) + a_head,
         out_specs=specs[0], check_vma=False)
 
 
@@ -113,17 +119,18 @@ def own_shape(shape) -> Optional[Tuple[int, ...]]:
     return (shape[0] // batch, shape[1], shape[2] // heads, shape[3])
 
 
-def on_own_rows(kernel, *rows, whole=()):
-    """``kernel(*rows, *whole)`` per device (``_per_device``) over the
-    mesh of the enclosing ``with mesh:`` block, or None when the batch
+def on_own_rows(kernel, *rows, whole=(), heads=()):
+    """``kernel(*rows, *whole, *heads)`` per device (``_per_device``) over
+    the mesh of the enclosing ``with mesh:`` block, or None when the batch
     does not split over that mesh (``own_shape``); where no mesh is to be
     mapped over (``_mesh_to_map``), the bare call."""
     mesh = _mesh_to_map()
     if mesh is None:
-        return kernel(*rows, *whole)
+        return kernel(*rows, *whole, *heads)
     if own_shape(rows[0].shape) is None:
         return None
-    return _per_device(kernel, mesh, rows, whole)(*rows, *whole)
+    return _per_device(kernel, mesh, rows, whole, heads)(
+        *rows, *whole, *heads)
 
 
 def full_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

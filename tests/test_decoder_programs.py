@@ -11,6 +11,12 @@ head width and ``gated_delta_rule`` a decay a key channel, picked from
 ``g``'s shape: the JSON's diff is ONE added line, the seven older hashes
 unmoved, and the seven older presets' keys of the ``.npz`` are the parent's
 bit for bit (checked against the parent's file before it was remade). PR
+52 put ``KimiDeltaAttention``'s convolution, ``silu`` and L2 norm behind ONE
+entry (``linear_attention.conv_silu_norm``; the preset's heads of 8 keep
+XLA's form, with q's norm and scale now made right after q's convolution
+and not after all three) and remade the JSON: ``kimi_linear_tiny``'s hash
+moved (the operations' order), the seven others' did not, and the ``.npz``
+came out byte for byte the parent's. PR
 47 added the seventh
 (``sdar_moe_tiny``: rows of ``[noised | clean]``, its program the gradient
 of ``masked_diffusion_loss`` with every noised position weighed 1) after

@@ -648,3 +648,58 @@ def test_mosaic_compiles_a_heads_norm_and_turn_under_names_of_their_own(
             metric
     assert f"f32[{B},{L},{H},{d}]" not in text
     assert f"f32[{B},{L},{H * d}]" not in text
+
+
+@pytest.mark.parametrize("norm,scale", [
+    (True, 128 ** -0.5), (True, 1.0), (False, 1.0)], ids=["q", "k", "v"])
+def test_mosaic_compiles_the_convolution_silu_and_norm_under_names_of_their_own(
+        topo, as_on_chip, norm, scale):
+    """``ops/pallas_conv_norm.py`` forward and backward at
+    ``kimi-linear-48b-a3b-train-ep32share-16k``'s three projections through
+    Mosaic for a v5e: two calls, found by ``kda.conv_norm_ms`` and by no
+    other reader's pattern; of float32 arrays of the rows' size the program
+    holds the normed rows the chunk call reads and their cotangent, and
+    nothing for v: the padded copy, the mix and the norm's broadcast column
+    are what the calls take out of it."""
+    from jax.sharding import SingleDeviceSharding
+    from mmlspark_tpu.ops import linear_attention as la
+    from mmlspark_tpu.ops import pallas_conv_norm as pcn
+    B, L, H, d, W = 1, 16384, 32, 128, 4
+    assert pcn.supports((B, L, H, d), W, jnp.bfloat16)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def grads(y, taps, ct):
+        out, pull = jax.vjp(
+            lambda y, taps: la.conv_silu_norm(y, taps, H, norm, scale),
+            y, taps)
+        return out, pull(ct)
+
+    text = jax.jit(grads).lower(
+        s((B, L, H * d), jnp.bfloat16), s((W, H * d)),
+        s((B, L, H * d), jnp.float32 if norm else jnp.bfloat16)
+    ).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    names = sorted(re.match(r"(?:ROOT )?%([a-z_]+)", c).group(1)
+                   for c in calls)
+    assert names == ["conv_silu_norm_bwd", "conv_silu_norm_fwd"]
+    mine = _benchmark_pattern("kda.conv_norm_ms")
+    assert all(mine.search(c) for c in calls)
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics")
+    others = [m[:-5] for m in sorted(os.listdir(metrics))
+              if m != "kda.conv_norm_ms.json" and "pattern" in open(
+                  os.path.join(metrics, m)).read()]
+    assert len(others) > 10
+    for metric in others:
+        assert not any(_benchmark_pattern(metric).search(c) for c in calls), \
+            metric
+    # no padded copy of the rows, in either type
+    assert f"[{B},{L + W - 1},{H * d}]" not in text
+    # float32 rows: the two the calls hand over where they norm, none for v
+    assert len(re.findall(rf"f32\[{B},{L},{H * d}\]\S* parameter", text)) \
+        == (1 if norm else 0)
+    assert f"f32[{B},{L},{H},{d}]" not in text
