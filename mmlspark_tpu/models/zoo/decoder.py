@@ -1,5 +1,5 @@
 """Decoder LMs: ONE skeleton (``Decoder``: embed, blocks, final norm,
-head) over the parts of ``models/zoo/parts.py``, and the eight families as
+head) over the parts of ``models/zoo/parts.py``, and the nine families as
 registry entries that say which part sits at which layer:
 ``glm4_moe_lite`` (GLM-4.7-Flash; the layers are DeepSeek-V3's),
 ``qwen3_next`` (Qwen3-Next: three Gated DeltaNet layers to one gated
@@ -25,7 +25,9 @@ head under softmax-routed experts with no shared one, called on rows of
 with a decay a key channel, three to one with latent attention that has
 no query rank, no positions and keys wider than its values, by two
 published lists; a dense part, then sigmoid-routed experts beside a shared
-one).
+one) and ``keye_vl2`` (Keye-VL-2.0-30B-A3B's language model: ``sdar_moe``'s
+block under a plain causal loss, each query's keys chosen by a learned
+indexer that has a loss of its own).
 
 What a family IS lives in its entry, beside the name of the published
 ``config.json`` it reads: the mixer and the feed-forward part of layer
@@ -58,8 +60,8 @@ import jax.numpy as jnp
 from mmlspark_tpu.models.zoo import register_model
 from mmlspark_tpu.models.zoo.moe import DroplessMoe
 from mmlspark_tpu.models.zoo.parts import (
-    _INIT, ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SHORT_CONV_IN,
-    GatedAttention, GatedDeltaNet, GroupedAttention, Head,
+    _INIT, ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SELECTION,
+    SHORT_CONV_IN, GatedAttention, GatedDeltaNet, GroupedAttention, Head,
     KimiDeltaAttention, Mamba2Mixer, MlaAttention, RMSNorm, ShortConv,
     SwiGluMlp, _dense, plain_frequencies, yarn_frequencies)
 
@@ -76,8 +78,11 @@ class PartsBlock(nn.Module):
     feed-forward part are factories, called in ``setup`` with no name: the
     parts are ``norm1``, ``attn``, ``norm2``, ``ffn`` in the parameter
     tree. A feed-forward part may return ``(y, stats)``, ``stats`` a dict
-    of scalars (a routed layer's load); the block returns ``(y, stats)``
-    always. With ``norm_output`` the norms sit on each half's OUTPUT,
+    of scalars (a routed layer's load), and so may a mixer (a loss of its
+    own under ``aux_loss``, a dict of what it counts under ``counts``);
+    ``mix``, ``feed`` and the block return ``(y, stats)`` always, the
+    block the two parts' joined. With ``norm_output`` the norms sit on
+    each half's OUTPUT,
     OLMo 2's wiring: ``h = x + r norm(attention(x))``, ``y = h + r
     norm(ffn(h))`` (scope ``post_norm``). It is an argument of the block
     and not a wrapper around each part, so that ``norm1`` and ``norm2``
@@ -97,9 +102,10 @@ class PartsBlock(nn.Module):
         self.norm1, self.attn = self.make_norm(None), self.make_attention(None)
         self.norm2, self.ffn = self.make_norm(None), self.make_ffn(None)
 
-    def mix(self, x):
-        return _add(x, _half(self.norm1, self.attn, x, self.norm_output),
-                    self.residual_scale)
+    def mix(self, x) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        out = _half(self.norm1, self.attn, x, self.norm_output)
+        y, stats = out if isinstance(out, tuple) else (out, {})
+        return _add(x, y, self.residual_scale), stats
 
     def feed(self, h) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         out = _half(self.norm2, self.ffn, h, self.norm_output)
@@ -107,7 +113,9 @@ class PartsBlock(nn.Module):
         return _add(h, y, self.residual_scale), stats
 
     def __call__(self, x) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        return self.feed(self.mix(x))
+        h, mixed = self.mix(x)
+        y, stats = self.feed(h)
+        return y, {**mixed, **stats}
 
 
 def _half(norm, part, x, norm_output: bool):
@@ -155,7 +163,13 @@ def _remat_block(norm, attention, ffn, name: str, split: bool = False,
     ``laguna``'s, the TURNED rows carry the name: the turn's derivative
     reads no rows, so as many bytes are kept and no layer turns twice), 5 in
     ``laguna``'s sliding blocks and 4 in its full ones (1.54 GB a step),
-    1.5 in ``granite_hybrid``'s and ``lfm2_moe``'s. Each paid on the chip
+    1.5 in ``granite_hybrid``'s and ``lfm2_moe``'s; the mask of a selection
+    (``SELECTION``: int8, a byte a pair of the row, 4 in ``keye_vl2``'s
+    blocks at a row of 16,384; without it the indexer's scores and the
+    choice among them, which pass no gradient, would be made again for the
+    core's backward pass, and what the selected core kept under
+    ``FLASH_RESIDUALS`` would have to match a second choice bit for bit).
+    Each but the last paid on the chip
     (PERF.md section 6; PR 29: +5.6% and +1.5% of a ``glm4_moe_lite``
     step; PR 36: the tiles +3.7% and the projection +2.0% of a
     ``qwen3_next`` step, the products +5.3% of a ``granite_hybrid`` step;
@@ -187,7 +201,8 @@ def _remat_block(norm, attention, ffn, name: str, split: bool = False,
     from mmlspark_tpu.ops.pallas_delta_rule import DELTA_CHUNK_TILES
     policy = jax.checkpoint_policies.save_only_these_names(*(
         n for n in (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES,
-                    DELTA_NET_QKVZ, SHORT_CONV_IN, MAMBA2_IN, ATTN_QKV)
+                    DELTA_NET_QKVZ, SHORT_CONV_IN, MAMBA2_IN, ATTN_QKV,
+                    SELECTION)
         if n not in let_go))
     return nn.remat(PartsBlock, policy=policy,
                     methods=("mix", "feed") if split else None)(
@@ -212,7 +227,8 @@ class Decoder(nn.Module):
     loss wants instead: ``{"hidden", "stats"}`` and with ``mtp``
     ``"mtp_hidden"``, the normed rows each head reads ALREADY divided by
     ``logits_scaling``, and the routed layers' load (``_load_stats``;
-    empty without a routed layer), so that ``next_token_loss(out, W,
+    empty without a routed layer), and ``"aux_loss"`` where a mixer has a
+    loss of its own (the layers' sum), so that ``next_token_loss(out, W,
     tokens)`` with ``W`` the head's kernel (``params["lm_head"]["kernel"]``,
     or ``params["token_embedding"]["embedding"].T``) is the model's loss.
     """
@@ -283,6 +299,9 @@ class Decoder(nn.Module):
         if not self.tied and self.is_initializing():
             head(out["hidden"][:, :1])
         out["stats"] = _load_stats(loads)
+        aux = [s["aux_loss"] for s in loads if "aux_loss" in s]
+        if aux:
+            out["aux_loss"] = sum(aux)
         return out
 
 
@@ -291,11 +310,16 @@ def _load_stats(loads) -> Dict[str, jax.Array]:
     ``moe.slots_here`` and ``moe.rows_moved`` (the rows of the rungs their
     expert-order buffers took) summed over them, ``moe.overflow_layers``
     (how many of them ran at full size this step),
-    ``moe.load_max_over_mean`` of the worst."""
-    loads = [s for s in loads if s]
+    ``moe.load_max_over_mean`` of the worst; and what the mixers count
+    (a layer's ``counts``, by the names they give), summed over the
+    layers."""
+    mixers = [s["counts"] for s in loads if "counts" in s]
+    counts = {k: sum(c[k] for c in mixers)
+              for k in (mixers[0] if mixers else ())}
+    loads = [s for s in loads if "slots_here" in s]
     if not loads:
-        return {}
-    return {"moe.slots_here": sum(
+        return counts
+    return {**counts, "moe.slots_here": sum(
                 s["slots_here"] for s in loads).astype(jnp.float32),
             "moe.rows_moved": sum(
                 s["rows"] for s in loads).astype(jnp.float32),
@@ -839,6 +863,69 @@ def sdar_moe_tiny(**overrides):
     layers of eight experts, two a token, rows of two copies of 32
     positions in blocks of 4."""
     return sdar_moe(**{**_SDAR_TINY, **overrides})
+
+
+@register_model("keye_vl2")
+def keye_vl2(vocab: int = 151936, dim: int = 2048, depth: int = 48,
+             heads: int = 32, kv_heads: int = 4, head_dim: int = 128,
+             index_heads: int = 16, index_head_dim: int = 64,
+             index_top_k: int = 2048, expert_hidden: int = 768,
+             num_experts: int = 128, top_k: int = 8, experts_held=None,
+             gate_grad: bool = True, theta: float = 1e7, eps: float = 1e-6,
+             max_len: int = 16384, dtype=jnp.bfloat16):
+    """Keye-VL-2.0-30B-A3B's LANGUAGE MODEL as published (huggingface.co/
+    Kwai-Keye/Keye-VL-2.0-30B-A3B ``config.json``, ``model_type: KeyeVL2``;
+    the vision tower is not here, the model reads token ids): ``depth``
+    equal layers of Qwen3-MoE's block, ``sdar_moe``'s to the letter (a
+    ``GroupedAttention`` of ``heads`` query heads over ``kv_heads`` of
+    ``head_dim``, an RMS norm over each q and k head, plain rotary on the
+    whole head: on token ids the three streams of ``mrope_section`` are one
+    and the sectioned turn is the plain one; a ``DroplessMoe`` routed by
+    softmax over all ``num_experts``, the top ``top_k`` weighted by their
+    scores over their sum, no shared expert; plain RMS norms, untied
+    tables), in which every mixer's keys are CHOSEN (``sa_config``:
+    DeepSeek Sparse Attention, arXiv:2512.02556): an ``Indexer`` of
+    ``index_heads`` heads of ``index_head_dim`` over one key head, turned
+    at the model's ``theta`` over its whole width, scores the past, and a
+    query keeps its ``index_top_k`` best keys
+    (``GroupedAttention(indexer=)``, ``ops/sparse_attention``). Under a
+    plain causal next-token loss; each layer's indexer learns from its own
+    term, which ``Decoder`` hands the loss as ``aux_loss``
+    (``train/lm_loss.next_token_loss`` adds it), and takes no gradient from
+    the language-model loss, as no other leaf takes any from that term.
+    ``experts_held`` and ``gate_grad`` as ``sdar_moe``'s. Each block is
+    recomputed whole and keeps all of ``_remat_block``'s names: here the
+    selected core's residuals, the selection, and the q, k and v rows."""
+    held = None if experts_held is None else tuple(experts_held)
+
+    def attention(n):
+        return GroupedAttention(
+            dim, heads, kv_heads, head_dim, None, dtype, None, eps,
+            norm_heads=True, rotary_freqs=plain_frequencies(head_dim, theta),
+            indexer=(index_heads, index_head_dim, index_top_k,
+                     plain_frequencies(index_head_dim, theta)), name=n)
+
+    def routed(n):
+        return DroplessMoe(
+            dim, num_experts, expert_hidden, top_k, experts_held=held,
+            dtype=dtype, scores="softmax", gate_grad=gate_grad, name=n)
+
+    return _spec(Decoder(vocab, dim, (attention,) * depth, (routed,) * depth,
+                         _rms(eps), dtype=dtype), max_len)
+
+
+_KEYE_TINY = dict(vocab=96, dim=32, depth=2, heads=4, kv_heads=2, head_dim=8,
+                  index_heads=2, index_head_dim=8, index_top_k=8,
+                  expert_hidden=16, num_experts=8, top_k=2, max_len=64,
+                  dtype=jnp.float32)
+
+
+@register_model("keye_vl2_tiny")
+def keye_vl2_tiny(**overrides):
+    """Test-scale ``keye_vl2`` (float32, so CPU parity is tight): two
+    layers of eight experts, two a token; an indexer of two heads of 8 that
+    keeps 8 keys a query, fewer than the tests' rows hold."""
+    return keye_vl2(**{**_KEYE_TINY, **overrides})
 
 
 KIMI_LINEAR_FULL_LAYERS = (4, 8, 12, 16, 20, 24, 27)

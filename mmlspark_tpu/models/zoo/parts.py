@@ -26,7 +26,11 @@ say which part sits at which layer). A part is a flax module ``x -> y`` on
   positions, no gate, a softmax scale of its own, and if asked an RMS norm
   over the whole q and the whole k projection, or (``norm_heads``) over
   each head's channels, (``rotary_freqs``) rotary positions, and
-  (``block_diffusion``) a row of two copies under the block-diffusion mask;
+  (``block_diffusion``) a row of two copies under the block-diffusion mask,
+  or (``indexer``) keys chosen for each query by an ``Indexer``;
+- ``Indexer``: DeepSeek Sparse Attention's lightning indexer: a few small
+  heads over ONE key head score every (query, key) pair of the past from a
+  DETACHED input, and learn from a loss of their own;
 - ``ShortConv``: LFM2's gated short convolution, which IS the mixer:
   ``[B | C | x] = u W_in``, a causal depthwise convolution of three taps
   over ``B * x`` with no activation, the gate ``C`` on its output;
@@ -81,6 +85,8 @@ MAMBA2_IN = "mamba2_in"
 # and of ``GroupedAttention``'s q, k and v projections' outputs (turned,
 # where a kernel turns q and k without a norm)
 ATTN_QKV = "attn_qkv"
+# and of the mask an ``Indexer``'s choice makes (int8, a byte a pair)
+SELECTION = "attention_selection"
 
 
 class RMSNorm(nn.Module):
@@ -537,7 +543,24 @@ class GroupedAttention(nn.Module):
     head width (``lfm2_moe``'s 64, the tiny presets'), a layer without
     positions
     (``granite_hybrid``, ``olmo_hybrid``) and a norm over the whole
-    projection keep XLA's form, ``RMSNorm`` then ``rotary``."""
+    projection keep XLA's form, ``RMSNorm`` then ``rotary``.
+
+    ``indexer`` = ``(heads, head_dim, top_k, rotary_freqs)`` makes it
+    ``keye_vl2``'s mixer (DeepSeek Sparse Attention over grouped heads): an
+    ``Indexer`` of that many heads, turned by its own frequencies, scores
+    every pair of the past from the part's input,
+    each query keeps its ``top_k`` best keys (``ops/sparse_attention``: the
+    choice is exact and a mask, no gradient passes through it), and the
+    softmax runs over the kept keys alone. Projections, norms, turn and
+    grouping do not change; the call is ``sparse_attention.selected_core``
+    and not ``attention_fn`` (no window, no block diffusion, no
+    ``attention_fn`` with it). The part then returns ``(y, stats)``:
+    ``aux_loss``, the indexer's own loss (``sparse_attention.indexer_loss``
+    against this layer's mean probabilities over the kept keys, which the
+    main weights take no gradient from), and under ``counts`` the pairs
+    the mask keeps beside the causal ones; the mask is sown as
+    ``selection`` ``(B, L, L)`` bool, ``[b, t, s]``. Everything lies under
+    the scope ``sparse_attention_layer``."""
     dim: int
     heads: int
     kv_heads: int
@@ -552,12 +575,22 @@ class GroupedAttention(nn.Module):
     rotary_factor: float = 1.0
     head_gate: bool = False
     block_diffusion: Optional[int] = None   # None: one copy, causal
+    # (heads, width, top_k, the indexer's own rotary_freqs or None)
+    indexer: Optional[Tuple[int, int, int, Optional[Tuple[float, ...]]]] \
+        = None
 
     @nn.compact
     def __call__(self, x):
         if self.heads % self.kv_heads:
             raise ValueError(f"{self.heads} query heads over "
                              f"{self.kv_heads} key/value heads")
+        if self.indexer is not None and (
+                self.window is not None or self.block_diffusion is not None
+                or self.attention_fn is not None):
+            raise ValueError(
+                f"indexer {self.indexer} with window {self.window}, "
+                f"block_diffusion {self.block_diffusion} or an attention_fn:"
+                " a selection is made over the whole causal half")
         B, L, _ = x.shape
         H, G, d, dt = self.heads, self.kv_heads, self.head_dim, self.dtype
         attn_fn = self.attention_fn or full_attention
@@ -570,6 +603,8 @@ class GroupedAttention(nn.Module):
             positions = jnp.arange(L, dtype=jnp.float32) % (L // 2)
         banded = contextlib.nullcontext() if self.window is None \
             else jax.named_scope("window_attention_layer")
+        if self.indexer is not None:
+            banded = jax.named_scope("sparse_attention_layer")
         with jax.named_scope("grouped_attention"), banded:
             x32, x = x, x.astype(dt)
 
@@ -616,6 +651,28 @@ class GroupedAttention(nn.Module):
             v = heads_of("attn_value", G)
             if self.scale is not None:
                 q = q * jnp.asarray(self.scale * d ** 0.5, dt)
+            if self.indexer is not None:
+                from mmlspark_tpu.ops import sparse_attention as sparse
+                heads, width, top_k, freqs = self.indexer
+                scored = Indexer(heads, width, freqs, dt,
+                                 name="indexer")(x32)
+                with jax.named_scope("indexer"):
+                    scores = sparse.indexer_scores(
+                        *scored, sparse.tile_of(L))
+                mask = checkpoint_name(sparse.select(scores, top_k),
+                                       SELECTION)
+                self.sow("intermediates", "selection", mask.transpose(
+                    0, 1, 3, 2).reshape(B, L, L) != 0)
+                o, lse = sparse.selected_core(q, k, v, mask)
+                with jax.named_scope("indexer_loss"):
+                    aux = sparse.indexer_loss(*scored, q, k, lse, mask)
+                kept, causal = sparse.pair_counts(mask)
+                return _dense(self.dim, dt, "attn_out")(
+                    o.reshape(B, L, H * d)), {
+                        "aux_loss": aux, "counts": {
+                            "sparse_attention.selected_pairs": kept,
+                            "sparse_attention.causal_pairs": jnp.asarray(
+                                causal, jnp.float32)}}
             k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
             if self.block_diffusion is not None:
                 with jax.named_scope("block_diffusion_attention"):
@@ -633,6 +690,47 @@ class GroupedAttention(nn.Module):
                         name="attn_head_gate")(x32.astype(jnp.float32)))
                     o = o * gate[..., None].astype(dt)
             return _dense(self.dim, dt, "attn_out")(o.reshape(B, L, H * d))
+
+
+class Indexer(nn.Module):
+    """DeepSeek Sparse Attention's lightning indexer (DeepSeek-V3.2-Exp's
+    report, arXiv:2512.02556; its published ``inference/model.py``
+    ``Indexer`` without the Hadamard rotation and the fp8 storage): ``x
+    (B, L, dim) -> (qI (B, L, heads, head_dim), kI (B, L, head_dim), w (B,
+    L, heads))``, the operands of the score of every (query, key) pair of
+    a row, ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
+    (``ops/sparse_attention.indexer_scores``). On ``x`` DETACHED: ``qI =
+    turn(x W_qI)``; ``kI = turn(LayerNorm(x W_kI))``, ONE head (scale and
+    bias, eps 1e-6); ``w = (x W_w) heads^-1/2 head_dim^-1/2``. ``qI`` and
+    ``kI`` are ``dtype`` (operands of the scores' product); ``w``, the
+    LayerNorm and the turn float32. ``rotary_freqs`` turn the first ``2
+    len`` channels by ``rotary`` (None: no positions). Nothing here
+    receives a gradient from what the scores are used to CHOOSE (a choice
+    passes none, and the input is detached): the four leaves learn from
+    whatever loss reads the scores themselves."""
+    heads: int
+    head_dim: int
+    rotary_freqs: Optional[Tuple[float, ...]] = None
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, _ = x.shape
+        Hi, di, dt = self.heads, self.head_dim, self.dtype
+        with jax.named_scope("indexer"):
+            x = jax.lax.stop_gradient(x).astype(dt)
+            q = _dense(Hi * di, dt, "index_query")(x).reshape(B, L, Hi, di)
+            k = nn.LayerNorm(epsilon=1e-6, dtype=jnp.float32,
+                             name="index_key_norm")(
+                _dense(di, dt, "index_key")(x).astype(jnp.float32))
+            k = k[:, :, None, :]
+            if self.rotary_freqs is not None:
+                q, k = (rotary(t, self.rotary_freqs) for t in (q, k))
+            w = jnp.dot(x, self.param(
+                "index_weight", _INIT, (x.shape[-1], Hi),
+                jnp.float32).astype(dt), preferred_element_type=jnp.float32
+                ) * (Hi ** -0.5 * di ** -0.5)
+            return q.astype(dt), k[:, :, 0].astype(dt), w
 
 
 class ShortConv(nn.Module):

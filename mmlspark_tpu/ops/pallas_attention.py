@@ -133,7 +133,7 @@ def _blocks_seen(key, query, length: int, low, high):
     return seen if low is None else seen & (key >= own + low)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
+def _flash_kernel(q_ref, k_ref, v_ref, *refs, block_k: int, causal: bool,
                   scale: float, window: Optional[int] = None,
                   block_diffusion: Optional[Tuple[int, int]] = None):
     """One program per (batch, head, query block) against the (batch,
@@ -168,7 +168,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     noised query block runs clear over the clean K tiles before its rows,
     masked over the clean tiles of its rows and, last, masked over the
     noised tiles of its rows; a clean one the first two; no other tile is
-    visited."""
+    visited.
+
+    With a fourth operand (``refs`` = ``(sel_ref, o_ref)``; causal only) the
+    mask is DATA: ``sel_ref`` is the query block's ``(L, bq)`` int8 column
+    of a selection, keys in rows as the scores are held, non-zero where the
+    query keeps the key (``flash_attention(selected=)``). Every tile up to
+    the diagonal's is visited and masked by its rows of it; the selection
+    lies inside the causal half, so no tile is masked by position besides.
+    A row that keeps no key of the first tiles leaves rubbish in its ``l``
+    and accumulator, which its first kept key's correction, exp(-1e30 -
+    m), multiplies by 0 (a window's rows do the same)."""
+    sel_ref, o_ref = refs if len(refs) == 2 else (None,) + refs
     # q/k/v refs are (1, 1, L-block, D): batch and head ride the grid, so
     # the last two dims are the (8, 128)-tileable (rows, lanes) pair Mosaic
     # wants; o_ref is (1, 1, 1, bq [+ lse rows], D)
@@ -190,7 +201,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
         v = v_ref[0, 0, rows, :]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if blocks is not None:
+        if sel_ref is not None:
+            st = jnp.where(
+                sel_ref[0, 0, rows, :].astype(jnp.int32) != 0, st, _NEG_INF)
+        elif blocks is not None:
             st = jnp.where(_blocks_seen(
                 diagonal + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0),
                 jax.lax.broadcasted_iota(jnp.int32, st.shape, 1),
@@ -393,17 +407,25 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
                    causal: bool = False, block_q: int = BLOCK_Q,
                    block_k: int = BLOCK_K, save_lse: bool = False,
                    window: Optional[int] = None,
-                   block_diffusion: Optional[Tuple[int, int]] = None):
+                   block_diffusion: Optional[Tuple[int, int]] = None,
+                   selected: Optional[jax.Array] = None):
     """(output (B, L, H, D), None), or under differentiation
     (``save_lse``) the rows' log-sum-exps in the None's place: float32
     (B, H, L / block_q, block_q). A call that is not differentiated
     writes none and is the kernel it was before it could. The caller's
-    blocks are the floor of the tiles the call runs (``_fwd_tiles``)."""
+    blocks are the floor of the tiles the call runs (``_fwd_tiles``).
+    ``selected`` (``(B, L / t, L, t)`` int8: ``selected_attention``) makes
+    the query tile ``t``, its own, and the call's name
+    ``selected_attention_fwd``."""
     b, L, h, dk = q.shape
     d = v.shape[3]              # the output's, the accumulator's
     scale = 1.0 / float(np.sqrt(dk))
     vmem = pl.ANY if _interpret() else pltpu.VMEM
-    bq, bk = _fwd_tiles(block_q, block_k, L, d, window, block_diffusion)
+    if selected is None:
+        bq, bk = _fwd_tiles(block_q, block_k, L, d, window, block_diffusion)
+    else:
+        bq = selected.shape[3]
+        bk = min(_fwd_tiles(block_q, block_k, L, d)[1], bq)
     kernel = functools.partial(_flash_kernel, block_k=bk, causal=causal,
                                scale=scale)
     # a band's call carries a name of its own (as the backward's does, and
@@ -419,8 +441,17 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel = functools.partial(kernel, block_diffusion=block_diffusion)
         scope, named = jax.named_scope(_DIFFUSION_FWD_NAME), {
             "name": _DIFFUSION_FWD_NAME}
+    operands, columns = (), []
+    if selected is not None:
+        scope, named = jax.named_scope(_SELECTED_FWD_NAME), {
+            "name": _SELECTED_FWD_NAME}
+        # a query tile's column of the selection, whole, for every head
+        operands, columns = (selected,), [pl.BlockSpec(
+            (1, 1, L, bq), lambda bi, hi, qi: (bi, qi, 0, 0),
+            memory_space=vmem)]
     rows = bq + (_lse_rows(bq, d, q.dtype)[1] if save_lse else 0)
-    need = _flash_fwd_vmem_bytes(L, d, bq, bk, rows, q.dtype.itemsize, dk)
+    need = _flash_fwd_vmem_bytes(L, d, bq, bk, rows, q.dtype.itemsize, dk) \
+        + 2 * L * bq * len(columns)
     # (B, L, H, D) -> (B, H, L, D): head ahead of length so kernel blocks
     # end in the tileable (rows, lanes) pair; XLA fuses the transposes
     # into the surrounding program
@@ -437,7 +468,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
                              memory_space=vmem),
                 pl.BlockSpec((1, 1, L, d), lambda bi, hi, qi: (bi, hi, 0, 0),
                              memory_space=vmem),
-            ],
+            ] + columns,
             out_specs=pl.BlockSpec((1, 1, 1, rows, d),
                                    lambda bi, hi, qi: (bi, hi, qi, 0, 0),
                                    memory_space=vmem),
@@ -447,7 +478,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array,
                 vmem_limit_bytes=_vmem_limit(need)),
             interpret=_interpret(),
             **named,
-        )(qt, kt, vt)
+        )(qt, kt, vt, *operands)
     o = out[:, :, :, :bq].reshape(b, h, L, d).transpose(0, 2, 1, 3)
     if not save_lse:
         return o, None
@@ -534,13 +565,15 @@ _WINDOW_BWD_NAME = "window_attention_bwd"
 # and a block-diffusion row's, for the same reason
 _DIFFUSION_FWD_NAME = "block_diffusion_attention_fwd"
 _DIFFUSION_BWD_NAME = "block_diffusion_attention_bwd"
+# and those of a selection that is data
+_SELECTED_FWD_NAME = "selected_attention_fwd"
+_SELECTED_BWD_NAME = "selected_attention_bwd"
 # what a program may ask of a v5e's 128 MiB of VMEM
 _VMEM_CAP = 100 << 20
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      block_q: int, causal: bool, scale: float,
+                      *refs, block_q: int, causal: bool, scale: float,
                       window: Optional[int] = None,
                       block_diffusion: Optional[Tuple[int, int]] = None):
     """One program per (batch, head, K block): q and do of the whole
@@ -559,7 +592,12 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     noised K block meets the noised query blocks over its own rows alone,
     masked to a query's own block; a clean one meets the noised and the
     clean query blocks over its rows, masked, and those after them in
-    either half, clear."""
+    either half, clear. With a seventh operand (``refs[0]``: this K
+    block's rows of a selection, ``(L / bq, bk, bq)`` int8, a query block a
+    slab) every query block from the diagonal's on is masked by its slab
+    and by nothing else."""
+    sel_ref = refs[0] if len(refs) == 7 else None
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[-6:]
     kj = pl.program_id(2)
     bk = k_ref.shape[0]
     nq = q_ref.shape[0] // block_q
@@ -582,7 +620,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[rows, :]
         st = jax.lax.dot_general(
             k, q, _NT, preferred_element_type=jnp.float32) * scale
-        if blocks is not None:
+        if sel_ref is not None:
+            st = jnp.where(sel_ref[qi].astype(jnp.int32) != 0, st, _NEG_INF)
+        elif blocks is not None:
             half, length = block_diffusion
             st = jnp.where(_blocks_seen(
                 jax.lax.rem(kj * bk, half) + jax.lax.broadcasted_iota(
@@ -639,7 +679,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # blocks the diagonal crosses pay for a mask
         first = (kj * bk) // block_q
         clear = jnp.minimum(nq, ((kj + 1) * bk + block_q - 2) // block_q)
-        if window is None:
+        if sel_ref is not None:
+            loop(first, nq, False)
+        elif window is None:
             loop(first, clear, True)
             loop(clear, nq, False)
         else:
@@ -798,7 +840,7 @@ def _flash_bwd_vmem_bytes(L: int, d: int, block_q: int, block_k: int,
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "window", "block_diffusion"))
 def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
-                    window=None, block_diffusion=None):
+                    window=None, block_diffusion=None, selected=None):
     b, L, h, d = q.shape
     dv = v.shape[3]
     tile, name = functools.partial(_bwd_tile, L=L), _BWD_NAME
@@ -809,6 +851,12 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
         tile = functools.partial(_diffusion_tile, half=block_diffusion[0])
         name = _DIFFUSION_BWD_NAME
     block_q, block_k = tile(block_q), tile(block_k)
+    slabs = []
+    if selected is not None:
+        # the query tile is the selection's; a K block's rows of every slab
+        block_q, name = selected.shape[3], _SELECTED_BWD_NAME
+        slabs = [pl.BlockSpec((None, L // block_q, block_k, block_q),
+                              lambda bi, hi, kj: (bi, 0, kj, 0))]
     kernel = functools.partial(_flash_bwd_kernel, block_q=block_q,
                                causal=causal, scale=1.0 / float(np.sqrt(d)))
     if window is not None:
@@ -833,7 +881,7 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
                             lambda bi, hi, kj: (bi, hi, kj, 0))
 
     need = _flash_bwd_vmem_bytes(L, dv, block_q, block_k, q.dtype.itemsize,
-                                 d)
+                                 d) + 2 * L * block_k * len(slabs)
     # the scope's name is the call's instruction name in the compiled
     # program and so in a device trace: no `flash` in it, because the
     # benchmark's readers of the FORWARD find theirs by that word
@@ -843,7 +891,7 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
             name=name,
             grid=(b, h, L // block_k),
             in_specs=[whole(L, d), block(d), block(dv), whole(L, dv),
-                      whole(nq, block_q), whole(nq, block_q)],
+                      whole(nq, block_q), whole(nq, block_q)] + slabs,
             out_specs=[whole(L, d), block(d), block(dv)],
             out_shape=[jax.ShapeDtypeStruct(xt.shape, x.dtype)
                        for xt, x in ((qt, q), (kt, k), (vt, v))],
@@ -854,7 +902,8 @@ def _flash_backward(q, k, v, out, lse, do, causal, block_q, block_k,
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=_vmem_limit(need)),
             interpret=_interpret(),
-        )(qt, kt, vt, dot, lse, delta)
+        )(qt, kt, vt, dot, lse, delta,
+          *(() if selected is None else (selected,)))
     return tuple(g.transpose(0, 2, 1, 3) for g in grads)
 
 
@@ -867,6 +916,61 @@ def _flash_bwd_rule(causal, block_q, block_k, window, block_diffusion, res,
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+# The query tile of a selection: what both passes of ``selected_attention``
+# run and what ``ops/sparse_attention`` lays its mask out by. A tile's
+# column of the mask is ``L x t`` bytes in VMEM beside K and V (8 MiB at a
+# row of 16,384), a K block's slabs as many.
+SELECTED_TILE = 512
+
+
+def supports_selected(q_shape, tile: int = SELECTED_TILE,
+                      itemsize: int = 4) -> bool:
+    """Whether the flash kernels take a selection at query tile ``tile``:
+    a shape they take at all (``supports``), keys and values of one width,
+    and rows of whole tiles."""
+    return supports(q_shape, itemsize=itemsize) and tile % BLOCK_K == 0 \
+        and q_shape[1] % tile == 0
+
+
+@jax.custom_vjp
+def selected_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                       selected: jax.Array):
+    """Causal attention over keys that are chosen for each query by DATA:
+    ``(output (B, L, H, D), log-sum-exps (B, H, L) float32)``, each row's
+    softmax over the keys ``selected`` keeps for it and no other.
+    ``selected`` is ``(B, L / t, L, t)`` int8, ``[b, i, s, j]`` non-zero
+    where query ``i t + j`` keeps key ``s`` (keys in rows, a query tile a
+    slab: the layout both kernels read a block of as it lies); it has to
+    lie inside the causal half and keep a key for every query, and it is
+    the same for every head. The two calls are the flash kernels with one
+    more operand, named ``selected_attention_fwd`` / ``_bwd``: every tile
+    up to the diagonal is visited, so the cost is the causal call's
+    whatever the selection keeps. The log-sum-exps come back because the
+    caller's second loss reads them; no cotangent of theirs is taken. The
+    residuals carry ``FLASH_RESIDUALS``. No gradient reaches
+    ``selected``."""
+    return _selected_fwd_rule(q, k, v, selected)[0]
+
+
+def _selected_fwd_rule(q, k, v, selected):
+    b, L, h, _ = q.shape
+    out, lse = _flash_forward(q, k, v, True, save_lse=True,
+                              selected=selected)
+    out = checkpoint_name(out, FLASH_RESIDUALS)
+    lse = checkpoint_name(lse, FLASH_RESIDUALS)
+    return (out, lse.reshape(b, h, L)), (q, k, v, selected, out, lse)
+
+
+def _selected_bwd_rule(res, cotangents):
+    obsmetrics.counter("attention.flash_bwd_calls.pallas").inc()
+    q, k, v, selected, out, lse = res
+    return _flash_backward(q, k, v, out, lse, cotangents[0], True, BLOCK_Q,
+                           BLOCK_K, selected=selected) + (None,)
+
+
+selected_attention.defvjp(_selected_fwd_rule, _selected_bwd_rule)
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,8 @@ chunks, and each chunk's logits are recomputed in the backward pass
 predicts token ``i + 1``; a multi-token-prediction head's row ``i``
 (DeepSeek-V3 section 2.2, depth 1) predicts token ``i + 2`` through the
 same output matrix. Rows that have no target count nothing. No document
-mask: a packed row is one sequence.
+mask: a packed row is one sequence. A term the model made itself
+(``out["aux_loss"]``: the loss a learned indexer is trained by) is added.
 
 ``masked_diffusion_loss`` is block diffusion's (BD3-LM, arXiv:2503.09573;
 the linear schedule of MDLM, arXiv:2406.07524): of a row ``[noised copy |
@@ -71,7 +72,10 @@ def next_token_loss(out: Dict[str, jax.Array], kernel: jax.Array,
     ``{"hidden", "mtp_hidden"?}`` rows (``(B, L, D)``, already normed),
     the output matrix and the ``(B, L)`` tokens they came from; ``loss =
     main + mtp_weight * mtp``, each a mean over the rows that have a
-    target."""
+    target. A model with a loss of its own beside the language model's
+    (``out["aux_loss"]``: ``keye_vl2``'s indexers', whose leaves the other
+    terms do not reach and which reaches no other leaf) has it added with
+    coefficient 1 and reported as ``loss.indexer``."""
     B, L = tokens.shape
     pos = jnp.arange(L)
 
@@ -92,6 +96,9 @@ def next_token_loss(out: Dict[str, jax.Array], kernel: jax.Array,
     aux = {"loss.main": main}
     if mtp is not None:
         aux["loss.mtp"] = mtp
+    if "aux_loss" in out:
+        loss = loss + out["aux_loss"]
+        aux["loss.indexer"] = out["aux_loss"]
     return loss, aux
 
 

@@ -2,9 +2,18 @@
 check that a change to ``models/zoo/decoder.py`` or ``parts.py`` which is
 meant to move no family's arithmetic has moved none.
 
-Two files under ``tests/data/`` hold, for each of the eight tiny presets,
+Two files under ``tests/data/`` hold, for each of the nine tiny presets,
 what THIS module computed on the commit that last remade them (float32,
-the CPU backend, every function under ``jax.jit``). PR 51 added the eighth
+the CPU backend, every function under ``jax.jit``). PR 53 added the ninth
+(``keye_vl2_tiny``: ``sdar_moe``'s block under a causal loss with an
+``Indexer`` in every mixer, its program the gradient of ``next_token_loss``
+with the indexers' own loss added) after giving ``GroupedAttention`` one
+more argument (``indexer``), ``PartsBlock.mix`` a ``stats`` to return
+beside ``h`` (a mixer's, or none), ``_remat_block``'s list one more name (``SELECTION``) and
+``next_token_loss`` a model's ``aux_loss`` to add: the JSON's diff is ONE
+added line, the eight older hashes unmoved, and the eight older presets'
+keys of the ``.npz`` are the parent's bit for bit (checked against the
+parent's file before it was remade). PR 51 added the eighth
 (``kimi_linear_tiny``) after giving ``MlaAttention`` two arguments whose
 defaults are GLM's (no query rank, no turn), ``flash_attention`` a second
 head width and ``gated_delta_rule`` a decay a key channel, picked from
@@ -59,7 +68,7 @@ A PR that changes a family's program on purpose remakes both with
 
     JAX_PLATFORMS=cpu python tests/test_decoder_programs.py --write
 
-(without ``--write`` it prints the eight hashes and writes nothing), names
+(without ``--write`` it prints the nine hashes and writes nothing), names
 its own commit here, and its diff of the JSON then shows which families it
 touched and which it did not.
 """
@@ -84,7 +93,7 @@ from mmlspark_tpu.train.lm_loss import (  # noqa: E402
 
 PRESETS = ("glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
            "olmo_hybrid_tiny", "lfm2_moe_tiny", "laguna_tiny",
-           "sdar_moe_tiny", "kimi_linear_tiny")
+           "sdar_moe_tiny", "kimi_linear_tiny", "keye_vl2_tiny")
 DIFFUSION = ("sdar_moe_tiny",)
 DATA = Path(__file__).resolve().parent / "data"
 OUTPUTS = DATA / "decoder_parent_outputs.npz"

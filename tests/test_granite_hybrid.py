@@ -35,7 +35,8 @@ from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
     GRANITE_4_H_MICRO_LAYERS, PartsBlock)
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SHORT_CONV_IN,
+    ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SELECTION,
+    SHORT_CONV_IN,
     GroupedAttention, Mamba2Mixer, RMSNorm, SwiGluMlp)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
@@ -504,7 +505,7 @@ def test_each_block_recomputed_in_halves_is_the_block_not_recomputed():
 
 @pytest.mark.parametrize("preset", [
     "glm4_moe_lite_tiny", "qwen3_next_tiny", "granite_hybrid_tiny",
-    "lfm2_moe_tiny", "laguna_tiny"])
+    "lfm2_moe_tiny", "laguna_tiny", "keye_vl2_tiny"])
 def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
                                                           preset):
     """``_remat_block`` has ONE list of names for every family (the policy
@@ -527,7 +528,7 @@ def test_every_family_builds_its_blocks_with_the_one_list(monkeypatch,
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     assert seen and set(seen) == {
         (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES, DELTA_NET_QKVZ,
-         SHORT_CONV_IN, MAMBA2_IN, ATTN_QKV)}
+         SHORT_CONV_IN, MAMBA2_IN, ATTN_QKV, SELECTION)}
 
 
 @pytest.mark.parametrize("kept", [True, False], ids=["kept", "let_go"])

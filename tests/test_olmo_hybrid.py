@@ -39,7 +39,8 @@ from mmlspark_tpu.models.zoo import build_model, decoder  # noqa: E402
 from mmlspark_tpu.models.zoo.decoder import (  # noqa: E402
     OLMO_HYBRID_7B_LAYERS, PartsBlock)
 from mmlspark_tpu.models.zoo.parts import (  # noqa: E402
-    ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SHORT_CONV_IN,
+    ATTN_QKV, DELTA_NET_QKVZ, MAMBA2_IN, MLP_GATE_UP, SELECTION,
+    SHORT_CONV_IN,
     GatedDeltaNet, GroupedAttention, RMSNorm, SwiGluMlp)
 from mmlspark_tpu.observability import metrics as obsmetrics  # noqa: E402
 from mmlspark_tpu.ops import linear_attention as la  # noqa: E402
@@ -473,8 +474,8 @@ def nothing_recomputed(params):
 _NAMES = (FLASH_RESIDUALS, MLP_GATE_UP, DELTA_CHUNK_TILES,
           DELTA_NET_QKVZ, ATTN_QKV)     # ``_remat_block``'s one list, but
 # for the names this family has no value of (``lfm2_moe``'s and
-# ``granite_hybrid``'s mixers')
-_NOT_HERE = (SHORT_CONV_IN, MAMBA2_IN)
+# ``granite_hybrid``'s mixers', ``keye_vl2``'s selection)
+_NOT_HERE = (SHORT_CONV_IN, MAMBA2_IN, SELECTION)
 
 
 @pytest.mark.parametrize("split", [True, False], ids=["halves", "whole"])

@@ -703,3 +703,53 @@ def test_mosaic_compiles_the_convolution_silu_and_norm_under_names_of_their_own(
     assert len(re.findall(rf"f32\[{B},{L},{H * d}\]\S* parameter", text)) \
         == (1 if norm else 0)
     assert f"f32[{B},{L},{H},{d}]" not in text
+
+
+def test_mosaic_compiles_the_choice_and_the_selected_core_under_names_of_their_own(
+        topo, as_on_chip):
+    """``keye-vl2-30b-a3b-train-ep8share-16k``'s three new calls through
+    Mosaic for a v5e at the cell's shape: the choice
+    (``ops/pallas_select.topk_mask``: a tile's 32 MiB of ordered integers in
+    VMEM) and the flash kernels with a selection as an operand, forward and
+    backward; each found by its own metric's pattern and by no other
+    reader's, and no array a (query, key) pair but the mask outside
+    them."""
+    from jax.sharding import SingleDeviceSharding
+    from mmlspark_tpu.ops import sparse_attention as sparse
+    B, L, H, G, d, t = 1, 16384, 32, 4, 128, 512
+    assert sparse.tile_of(L) == t
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def grads(scores, q, k, v, ct):
+        mask = sparse.select(scores, 2048)
+        (out, _), pull = jax.vjp(lambda q, k, v: sparse.selected_core(
+            q, k, v, mask), q, k, v)
+        return mask, out, pull((ct, jnp.zeros((B, H, L), jnp.float32)))
+
+    text = jax.jit(grads).lower(
+        s((B, L // t, L, t), jnp.float32), s((B, L, H, d)),
+        s((B, L, G, d)), s((B, L, G, d)), s((B, L, H, d))
+    ).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    by_name = {re.match(r"(?:ROOT )?%([a-z_]+)", c).group(1): c
+               for c in calls}
+    assert sorted(by_name) == ["selected_attention_bwd",
+                               "selected_attention_fwd", "topk_mask"]
+    readers = {"topk_mask": "sparseattn.select_ms",
+               "selected_attention_fwd": "sparseattn.core_fwd_ms",
+               "selected_attention_bwd": "sparseattn.core_bwd_ms"}
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics")
+    patterned = [m[:-5] for m in sorted(os.listdir(metrics))
+                 if "pattern" in open(os.path.join(metrics, m)).read()]
+    assert len(patterned) > 15
+    for name, call in by_name.items():
+        hits = {m for m in patterned if _benchmark_pattern(m).search(call)}
+        assert hits == {readers[name],
+                        readers[name].replace("_ms", "_roofline")}, name
+    assert f"f32[{B},{H},{L},{L}]" not in text
+    assert f"[{B},{G},{H // G},{L},{L}]" not in text
